@@ -5,8 +5,9 @@ HOST; the reference's ETL discipline (PerformanceListener.java:111,178
 reporting lastEtlTime per iteration) treats the feed path as a first-class
 perf concern. On TPU the missing half is the host->device hop: a batch
 shipped synchronously inside the step pays the full transfer latency
-serially (BENCH_r05: a 407 ms/step transfer floor flattened the piped
-ResNet-50 row to 0.008x the device-resident rate). JAX's async dispatch
+serially (a 407 ms/step transfer floor flattened the piped ResNet-50 row
+to 0.008x the device-resident rate on the pre-PR-1 rig; not measured on
+today's machine). JAX's async dispatch
 makes the fix cheap — ``jax.device_put`` returns immediately while the
 copy proceeds — so a background thread that ships batch N+1 while step N
 computes hides the transfer entirely whenever step time exceeds the

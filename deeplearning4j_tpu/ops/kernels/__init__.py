@@ -6,9 +6,9 @@ builtin kernel registrations load lazily on first registry query.
 """
 from . import envutil  # noqa: F401
 from .registry import (KernelSpec, ParityPin, active_impl, get,  # noqa: F401
-                       kernels_snapshot, names, record_kernel_timing,
-                       register)
+                       kernels_snapshot, names, parity_error,
+                       record_kernel_timing, register)
 
 __all__ = ["KernelSpec", "ParityPin", "active_impl", "get",
-           "kernels_snapshot", "names", "record_kernel_timing", "register",
-           "envutil"]
+           "kernels_snapshot", "names", "parity_error",
+           "record_kernel_timing", "register", "envutil"]

@@ -10,7 +10,11 @@ the *discipline* around it:
     ``kernel|shape_sig|backend`` so the next process REPLAYS the choice
     without re-measuring (each replay is counted — the acceptance
     criterion that caching actually short-circuits measurement is
-    testable from the record itself);
+    testable from the record itself). The file lives OUTSIDE the
+    checkout and changes kernel block sizes, so ``loaded_from_file``
+    says whether one was read (``chip_smoke.py`` prints it), and the
+    trace-time replay (``cached_decision``) only reads: tracing a kernel
+    never writes outside the checkout;
   - when no trustworthy measurement is possible (no measure thunk — e.g.
     a CPU run, where interpret-mode timings say nothing about the TPU) the
     harness records the default WITH the reason in ``why``, so "defaults
@@ -51,6 +55,7 @@ class AutotuneCache:
     def __init__(self, path: Optional[str] = None):
         self.path = path or cache_path()
         self._decisions: Dict[str, Dict[str, Any]] = {}
+        self.loaded_from_file = False     # a decision file was read
         self._load()
 
     def _load(self) -> None:
@@ -61,6 +66,7 @@ class AutotuneCache:
                 dec = data.get("decisions")
                 if isinstance(dec, dict):
                     self._decisions = dec
+                    self.loaded_from_file = True
         except (OSError, ValueError):
             self._decisions = {}
 
@@ -115,14 +121,13 @@ def _backend() -> str:
 
 def cached_decision(kernel: str, shape_sig: str,
                     backend: Optional[str] = None) -> Optional[Sequence]:
-    """Replay path: the cached choice for this rig, or None. Counts the
-    replay on the record (proof no re-measurement happened)."""
-    cache = get_cache()
-    rec = cache.lookup(kernel, shape_sig, backend or _backend())
+    """Replay path (called at kernel TRACE time): the cached choice for
+    this rig, or None. Counts the replay on the in-memory record (proof
+    no re-measurement happened) and writes nothing."""
+    rec = get_cache().lookup(kernel, shape_sig, backend or _backend())
     if rec is None or "choice" not in rec:
         return None
     rec["replays"] = int(rec.get("replays", 0)) + 1
-    cache._save()
     return rec["choice"]
 
 

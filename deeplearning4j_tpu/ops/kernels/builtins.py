@@ -28,8 +28,14 @@ def _attention_parity(seed: int):
     B, H, T, D = 1, 2, 256, 64
     q, k, v = (jnp.asarray(rng.standard_normal((B, H, T, D)) * 0.3, f32)
                for _ in range(3))
-    fused = pa.flash_attention(q, k, v, causal=True)
-    fb = xla_attention(q, k, v, causal=True)
+    # Full-f32 matmuls on BOTH sides: at the TPU's default precision f32
+    # operands reach the MXU as bf16, and the two algorithms round the
+    # probabilities at different points (normalized p vs exp(s - m)), so
+    # they differ by a bf16 ulp (1.5e-3 measured on a v5e) for reasons
+    # that say nothing about the recurrence this pin checks.
+    with jax.default_matmul_precision("highest"):
+        fused = pa.flash_attention(q, k, v, causal=True)
+        fb = xla_attention(q, k, v, causal=True)
     return [fused], [fb]
 
 
@@ -48,7 +54,6 @@ def _register_attention():
         fused=pa.flash_attention,
         fallback=xla_attention,
         applicable=pa.fused_attention_applicable,
-        available=lambda: pa.PALLAS_AVAILABLE,
         kill_aliases=("DL4J_TPU_FUSED_ATTENTION",),
         interpret_aliases=("DL4J_TPU_FUSED_ATTN_INTERPRET",),
         parity=ParityPin(run=_attention_parity, tol=2e-5,
@@ -108,7 +113,6 @@ def _register_lstm():
         fused=pls.fused_lstm,
         fallback=_lstm_scan_ref,
         applicable=pls.fused_lstm_applicable,
-        available=lambda: pls.PALLAS_AVAILABLE,
         kill_aliases=("DL4J_TPU_FUSED_LSTM",),
         interpret_aliases=("DL4J_TPU_FUSED_LSTM_INTERPRET",),
         parity=ParityPin(run=_lstm_parity, tol=1e-5,
@@ -150,7 +154,6 @@ def _register_encode():
         fused=pc.threshold_encode_pallas,
         fallback=_encode_xla,
         applicable=pc.fused_threshold_encode_applicable,
-        available=lambda: pc.PALLAS_AVAILABLE,
         kill_aliases=("DL4J_TPU_FUSED_ENCODE",),
         interpret_aliases=("DL4J_TPU_FUSED_ENCODE_INTERPRET",),
         parity=ParityPin(run=_encode_parity, tol=0.0,
@@ -171,7 +174,6 @@ def _register_int8_matmul():
         fused=qz.int8_matmul_pallas,
         fallback=qz.int8_matmul_xla,
         applicable=qz.int8_matmul_applicable,
-        available=lambda: qz.PALLAS_AVAILABLE,
         parity=ParityPin(run=qz._parity_run, tol=0.0,
                          note="exact int32 accumulation both paths"),
         roofline=qz.roofline,
@@ -190,7 +192,6 @@ def _register_conv():
         fused=cv.conv1x1_bias_relu,
         fallback=cv._conv1x1_xla,
         applicable=cv.conv1x1_bias_relu_applicable,
-        available=lambda: cv.PALLAS_AVAILABLE,
         parity=ParityPin(run=cv._parity_run, tol=1e-5,
                          note="same f32-accumulate recipe both paths"),
         roofline=cv.roofline,

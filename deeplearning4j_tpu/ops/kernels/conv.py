@@ -26,14 +26,8 @@ import jax.numpy as jnp
 
 from . import envutil as kenv
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    PALLAS_AVAILABLE = _CompilerParams is not None
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 f32 = jnp.float32
 _BM = 256           # rows (pixels) per block; f32 sublane tile is 8
@@ -46,8 +40,6 @@ def conv1x1_bias_relu_applicable(kernel_size, stride, dilation, padding,
     """Probe (the helper seam): geometry must be a pure pointwise conv,
     channels tile-aligned, relu + bias present, f32/bf16, backend
     admitted. Everything else rides the stock XLA path."""
-    if not PALLAS_AVAILABLE:
-        return False
     if not kenv.fused_enabled("conv1x1_bias_relu"):
         return False
     if tuple(kernel_size) != (1, 1) or tuple(stride) != (1, 1) \
@@ -76,13 +68,14 @@ def _conv_kernel(x_ref, w_ref, b_ref, o_ref):
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=f32)
-    y = acc + b_ref[...][None, :].astype(f32)
+    y = acc + b_ref[...].astype(f32)
     o_ref[...] = jnp.maximum(y, 0.0).astype(o_ref.dtype)
 
 
 def _conv1x1_pallas(xm, wm, b):
     """[M, C] @ [C, F] + b, relu — M may be ragged (Mosaic masks the tail
-    block's store)."""
+    block's store). The bias rides as a [1, F] row: a 1-D (128,) block
+    falls under Mosaic's (8|16, 128) tiling of the last two dims."""
     M, C = xm.shape
     F = wm.shape[1]
     grid = (pl.cdiv(M, _BM), F // _BN)
@@ -92,14 +85,14 @@ def _conv1x1_pallas(xm, wm, b):
         in_specs=[
             pl.BlockSpec((_BM, C), lambda i, j: (i, 0)),
             pl.BlockSpec((C, _BN), lambda i, j: (0, j)),
-            pl.BlockSpec((_BN,), lambda i, j: (j,)),
+            pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, F), xm.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(xm, wm, b)
+    )(xm, wm, b[None, :])
 
 
 def _conv1x1_xla(xm, wm, b):

@@ -32,18 +32,12 @@ import jax.numpy as jnp
 
 from . import envutil as kenv
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    PALLAS_AVAILABLE = _CompilerParams is not None
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 f32 = jnp.float32
 # int8 native tile is (32, 128) (pallas guide); the M block also serves
-# f32 scale rows, so keep it a multiple of 8 too.
+# the f32 scale column, so keep it a multiple of 8 too.
 _BM, _BN = 32, 128
 
 
@@ -68,8 +62,6 @@ def quantize_rows(x) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def int8_matmul_applicable(M: int, K: int, N: int) -> bool:
     """Probe for the FUSED path (the registry-dispatch seam): tile-aligned
     shapes on an admitted backend. The XLA fallback serves everything."""
-    if not PALLAS_AVAILABLE:
-        return False
     if not kenv.fused_enabled("int8_matmul"):
         return False
     if M % _BM or K % 128 or N % _BN:
@@ -86,12 +78,14 @@ def _matmul_kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref):
         xq_ref[...], wq_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
-    o_ref[...] = acc.astype(f32) * xs_ref[...][:, None] * ws_ref[...][None, :]
+    o_ref[...] = acc.astype(f32) * xs_ref[...] * ws_ref[...]
 
 
 def int8_matmul_pallas(x_q, w_q, x_scale, w_scale):
     """Fused int8 GEMM: [M,K]i8 @ [K,N]i8 → [M,N]f32, K resident per
-    block (serving layer widths fit VMEM comfortably)."""
+    block (serving layer widths fit VMEM comfortably). The scales ride as
+    a [M,1] column and a [1,N] row: Mosaic tiles the last two dims by
+    (8, 128), and a 1-D (32,) or (128,) block falls under that tiling."""
     M, K = x_q.shape
     N = w_q.shape[1]
     grid = (M // _BM, N // _BN)
@@ -101,15 +95,15 @@ def int8_matmul_pallas(x_q, w_q, x_scale, w_scale):
         in_specs=[
             pl.BlockSpec((_BM, K), lambda i, j: (i, 0)),
             pl.BlockSpec((K, _BN), lambda i, j: (0, j)),
-            pl.BlockSpec((_BM,), lambda i, j: (i,)),
-            pl.BlockSpec((_BN,), lambda i, j: (j,)),
+            pl.BlockSpec((_BM, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), f32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(x_q, w_q, x_scale, w_scale)
+    )(x_q, w_q, x_scale[:, None], w_scale[None, :])
 
 
 def int8_matmul_xla(x_q, w_q, x_scale, w_scale):

@@ -43,13 +43,11 @@ class KernelSpec:
     """One registered kernel. ``fused``/``fallback`` are the two impls
     (callable; signature is kernel-specific — callers go through the
     module-level entry points, the registry is the metadata/parity/tuning
-    spine). ``applicable`` is the probe predicate. ``available()`` reports
-    whether Pallas can serve this kernel at all on this install."""
+    spine). ``applicable`` is the probe predicate."""
     name: str
     fused: Callable
     fallback: Callable
     applicable: Callable[..., bool]
-    available: Callable[[], bool]
     kill_aliases: Tuple[str, ...] = ()
     interpret_aliases: Tuple[str, ...] = ()
     parity: Optional[ParityPin] = None
@@ -116,10 +114,12 @@ def names() -> List[str]:
 def active_impl(name: str) -> str:
     """Which implementation a dispatch would use RIGHT NOW on this
     backend: 'fused' (TPU Pallas), 'interpret' (CPU pallas interpreter,
-    parity-test opt-in), or 'fallback' (XLA path — killed, unavailable,
-    or backend without a fused path)."""
+    parity-test opt-in), or 'fallback' (XLA path — killed, or backend
+    without a fused path). Answers from the backend's NAME only; whether
+    a compiled program really carries the kernel is read off the program
+    (``chip_smoke.py`` counts its ``tpu_custom_call``s)."""
     spec = get(name)
-    if not spec.available() or not spec.enabled():
+    if not spec.enabled():
         return "fallback"
     import jax
     backend = jax.default_backend()
@@ -128,6 +128,22 @@ def active_impl(name: str) -> str:
     if backend == "cpu" and spec.interpret_opted_in():
         return "interpret"
     return "fallback"
+
+
+def parity_error(name: str, seed: int = 0) -> float:
+    """Max absolute fused-vs-fallback error of kernel ``name``'s parity
+    pin on THIS backend (compare against ``get(name).parity.tol``). The
+    tier-1 suite runs it in the CPU interpreter, ``chip_smoke.py`` on the
+    TPU — the same pin both ways."""
+    import numpy as np
+    fused, fallback = get(name).parity.run(seed)
+    if not fused or len(fused) != len(fallback):
+        raise ValueError(f"kernel {name!r}: parity pin returned "
+                         f"{len(fused)} fused vs {len(fallback)} fallback "
+                         f"outputs")
+    return max(float(np.max(np.abs(np.asarray(a, np.float64)
+                                   - np.asarray(b, np.float64))))
+               for a, b in zip(fused, fallback))
 
 
 def kernels_snapshot() -> Dict[str, Dict[str, Any]]:
@@ -163,7 +179,7 @@ def record_kernel_timing(name: str, shape_sig: str,
     ``.roofline_ms`` / ``.vs_roofline`` / ``.below_roofline`` (1.0 when
     the kernel runs slower than 2x its roofline bound, the same
     flagging threshold BASELINE.md uses). No-op (returns None) when the
-    kernel has no roofline model or telemetry is disabled."""
+    kernel has no roofline model or the device's peaks are unknown."""
     spec = get(name)
     if spec.roofline is None or measured_s <= 0:
         return None
@@ -173,9 +189,10 @@ def record_kernel_timing(name: str, shape_sig: str,
         return None
     from ...telemetry import get_registry
     from ...telemetry.perf import classify_roofline
-    cls = classify_roofline(flops, nbytes)
     # attainable_tflops already folds in memory-bound derating
-    att = max(cls.get("attainable_tflops", 0.0), 1e-9)
+    att = classify_roofline(flops, nbytes)["attainable_tflops"]
+    if not att:
+        return None
     roof_s = (flops / 1e12) / att if flops else 0.0
     ratio = (measured_s / roof_s) if roof_s else 0.0
     row = {"measured_ms": measured_s * 1e3, "roofline_ms": roof_s * 1e3,

@@ -37,22 +37,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from .kernels import envutil as kenv
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    # renamed across JAX versions: new ships CompilerParams, old
-    # TPUCompilerParams — same fields for the dimension_semantics we pass.
-    # A version exposing NEITHER counts as pallas-unavailable so the
-    # eligibility probes route callers to the XLA fallback instead of
-    # dying on a None call at kernel launch.
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    PALLAS_AVAILABLE = _CompilerParams is not None
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 f32 = jnp.float32
 NEG = -1e30
@@ -60,11 +50,9 @@ NEG = -1e30
 
 def _kernel_eligible(D: int, dtype) -> bool:
     """The eligibility policy SHARED by every fused-attention probe
-    (single-device and ring): Pallas present + not env-disabled, dtype,
+    (single-device and ring): not env-disabled, dtype,
     head-dim, and backend rules. Per-probe sequence-length rules layer on
     top."""
-    if not PALLAS_AVAILABLE:
-        return False
     if not kenv.fused_enabled("attention", ("DL4J_TPU_FUSED_ATTENTION",)):
         return False
     dt = jnp.dtype(dtype)
@@ -213,7 +201,7 @@ def _fwd(q3, k3, v3, mask2, causal, scale):
         scratch_shapes=[pltpu.VMEM((BQ, D), f32),
                         pltpu.VMEM((BQ, 128), f32),
                         pltpu.VMEM((BQ, 128), f32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -349,7 +337,7 @@ def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype)],
         scratch_shapes=[pltpu.VMEM((BQ, D), f32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)[0]
@@ -382,7 +370,7 @@ def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
                    jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
         scratch_shapes=[pltpu.VMEM((BK, D), f32),
                         pltpu.VMEM((BK, D), f32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -464,7 +452,7 @@ def flash_block_update(acc, m, l, q3, k3, v3, *, causal: bool,
         scratch_shapes=[pltpu.VMEM((BQ, D), f32),
                         pltpu.VMEM((BQ, 128), f32),
                         pltpu.VMEM((BQ, 128), f32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(q3, k3, v3, acc, m, l)
@@ -510,6 +498,39 @@ def _flash_bwd(causal, scale, res, do3):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _device_split(B: int, H: int):
+    """How a program traced for SEVERAL devices must run the kernels.
+
+    A Mosaic custom call cannot be partitioned automatically: under a jit
+    over more than one device (ParallelWrapper's GSPMD step, a
+    (data, model) mesh, the head-sharded decode prefill) it refuses to
+    lower ("Mosaic kernels cannot be automatically partitioned" — first
+    seen on four real chips; the CPU interpreter path is plain ops and
+    never showed it). Attention is independent per batch row and per
+    head, so each device runs the kernels on its own block under a
+    ``shard_map``: batch over the mesh's ``data`` axis, heads over its
+    ``model`` axis (the repo's axis names — parallel/mesh.py,
+    parallel/tensor_parallel.py), T and D whole.
+
+    The mesh comes from the tracing context
+    (``jax.sharding.use_abstract_mesh``, which ParallelWrapper.fit and
+    GenerationProgramSet.warm enter). Returns ``(spec, axis_names)`` for
+    the shard_map, or None when there is nothing to split: no mesh in
+    context (a one-device program), or every axis already manual (inside
+    ParallelWrapper's own shard_map)."""
+    from ..parallel.tensor_parallel import MODEL_AXIS
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if mesh.empty or not auto:
+        return None
+
+    def axis(name, n):         # an axis that is still ours to split evenly
+        return name if name in auto and n % mesh.shape[name] == 0 else None
+    # every remaining axis turns manual (Mosaic accepts nothing less); one
+    # the spec does not name just repeats the work on its devices
+    return P(axis("data", B), axis(MODEL_AXIS, H), None, None), auto
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None, key_mask=None):
     """Fused softmax attention, [B,H,T,D] in/out — drop-in for
@@ -517,9 +538,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``key_mask`` [B,T] excludes padded timesteps as keys."""
     B, H, T, D = q.shape
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
-    q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H, T, D)
-    v3 = v.reshape(B * H, T, D)
-    mask2 = None if key_mask is None else jnp.asarray(key_mask)
-    o = _flash(q3, k3, v3, mask2, causal, scale)
-    return o.reshape(B, H, T, D)
+
+    def kernels(q, k, v, *mask):          # one device's [b,h,T,D] block
+        b, h = q.shape[:2]
+        o = _flash(q.reshape(b * h, T, D), k.reshape(b * h, T, D),
+                   v.reshape(b * h, T, D), mask[0] if mask else None,
+                   causal, scale)
+        return o.reshape(b, h, T, D)
+
+    args = (q, k, v) + (() if key_mask is None else (jnp.asarray(key_mask),))
+    split = _device_split(B, H)
+    if split is None:
+        return kernels(*args)
+    spec, axis_names = split
+    return jax.shard_map(
+        kernels, in_specs=(spec,) * 3 + (P(spec[0], None),) * (len(args) - 3),
+        out_specs=spec, axis_names=axis_names, check_vma=False)(*args)

@@ -3,8 +3,8 @@
 Reference: EncodingHandler.java:64-66 — the native ND4J thresholdEncode is
 ONE pass over the gradient buffer. The XLA bounded-payload compaction path
 (ops/compression.threshold_encode) costs mask + prefix-sum + scatter
-passes (BENCH_r05: 6.08ms on a 25M-element residual, 3.6x its 1.66ms HBM
-floor), which makes compressed DP pay more in encode than it saves on the
+passes (6.08ms on a 25M-element residual, 3.6x its 1.66ms HBM floor, on a
+v5e before PR 1; not measured on today's code), which makes compressed DP pay more in encode than it saves on the
 wire. This kernel restores the reference's single-pass cost for the DENSE
 sign-map wire format (the EncodedAccumulator default): per block, read the
 residual once and emit BOTH outputs — the packed int8 sign map (what a DCN
@@ -38,14 +38,8 @@ import jax.numpy as jnp
 
 from .kernels import envutil as kenv
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    PALLAS_AVAILABLE = _CompilerParams is not None
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # 64K elements/block: 256KB f32 in + 256KB out + 64KB signs in VMEM —
 # comfortably inside the ~16MB budget with double buffering, and a
@@ -56,8 +50,6 @@ _BLOCK = 1 << 16
 def fused_threshold_encode_applicable(n: int, dtype) -> bool:
     """Probe: can the fused kernel serve a flat [n] residual? (Callers
     fall back to the XLA elementwise path when False.)"""
-    if not PALLAS_AVAILABLE:
-        return False
     if not kenv.fused_enabled("threshold_encode", ("DL4J_TPU_FUSED_ENCODE",)):
         return False
     dt = jnp.dtype(dtype)
@@ -109,7 +101,7 @@ def threshold_encode_pallas(residual: jnp.ndarray, threshold: float
                    pl.BlockSpec((_BLOCK,), lambda i: (i,))],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int8),
                    jax.ShapeDtypeStruct((n,), residual.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
     )(residual)

@@ -39,12 +39,8 @@ from jax import lax
 
 from .kernels import envutil as kenv
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    PALLAS_AVAILABLE = True
-except ImportError:  # pragma: no cover - pallas ships with jax on this image
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # VMEM is ~16MB/core (pallas guide): backward needs R + dR resident
 # (2 * 16*H^2 bytes) plus ~1.5MB of blocks — H=512 uses ~9.5MB.
@@ -58,8 +54,6 @@ def fused_lstm_applicable(B: int, H: int, dtype, *, peepholes, mask,
     ``peepholes`` may be None (plain LSTM) or the (pi, pf, po) tuple
     (GravesLSTM); ``mask`` may be None or a per-step mask — all four
     combinations run fused."""
-    if not PALLAS_AVAILABLE:
-        return False
     if not kenv.fused_enabled("lstm", ("DL4J_TPU_FUSED_LSTM",)):
         return False
     if reverse:

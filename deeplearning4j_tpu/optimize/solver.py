@@ -440,7 +440,7 @@ class Solver:
                 k = len(item)
                 with span("window", k=k, iteration=net.iteration_count):
                     xs, ys, lms, fms = item.stacked(
-                        cast=lambda a: _cast_features(a, dtype))
+                        cast=lambda a: cast_feed(a, dtype))
                     step_fn = self._get_window_step(lms is not None,
                                                     fms is not None,
                                                     health=watch is not None)
@@ -641,7 +641,7 @@ class Solver:
                 for ds in iterator:
                     feats = ds.features if isinstance(ds.features, (list, tuple)) \
                         else [ds.features]
-                    xs = [_cast_features(f, dtype) for f in feats]
+                    xs = [cast_feed(f, dtype) for f in feats]
                     rng = jax.random.fold_in(base_rng, it_count * 1000 + vi)
                     lp, opt_state, loss = pretrain_step(
                         net.params[vi], net.params, net.state, opt_state,
@@ -701,7 +701,7 @@ class Solver:
             it_count = 0
             for _ in range(epochs):
                 for ds in iterator:
-                    x = _cast_features(ds.features, dtype)
+                    x = cast_feed(ds.features, dtype)
                     rng = jax.random.fold_in(base_rng, it_count * 1000 + li)
                     lp, opt_state, loss = pretrain_step(
                         net.params[li], net.params, net.state, opt_state,
@@ -722,31 +722,27 @@ def _is_multi(x):
             and isinstance(x[0], (np.ndarray, jnp.ndarray)))
 
 
-def cast_feed(x, dtype, *, keep_ints: bool = True):
-    """THE feed-boundary cast, device-resident aware: an array the
-    DevicePrefetchIterator already shipped is never round-tripped through
-    the host (cast on device only if needed); host arrays go through
-    jnp.asarray. ``keep_ints`` preserves integer dtypes (token ids, uint8
-    wire images — the Solver rule); ParallelWrapper passes False to keep
-    its historical everything-to-dtype semantics."""
+def cast_feed(x, dtype):
+    """THE feed-boundary cast (Solver and ParallelWrapper), device-resident
+    aware: an array the DevicePrefetchIterator already shipped is never
+    round-tripped through the host (cast on device only if needed); host
+    arrays go through jnp.asarray. Integer dtypes (token ids, uint8 wire
+    images) are preserved: a token id cast to bf16 and back names another
+    token above 256."""
     if isinstance(x, jax.Array):
-        if keep_ints and x.dtype.kind in "iu":
+        if x.dtype.kind in "iu":
             return x
         return x if x.dtype == dtype else x.astype(dtype)
     x = np.asarray(x)
-    if keep_ints and x.dtype.kind in "iu":
+    if x.dtype.kind in "iu":
         return jnp.asarray(x)
     return jnp.asarray(x, dtype)
-
-
-def _cast_features(x, dtype):
-    return cast_feed(x, dtype, keep_ints=True)
 
 
 def _cast_any(x, dtype):
     """Cast a single array or a list of arrays (MultiDataSet features/labels)."""
     if isinstance(x, (list, tuple)):
-        return [_cast_features(v, dtype) for v in x]
-    return _cast_features(x, dtype)
+        return [cast_feed(v, dtype) for v in x]
+    return cast_feed(x, dtype)
 
 

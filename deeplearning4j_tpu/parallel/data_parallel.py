@@ -475,9 +475,11 @@ class ParallelWrapper:
         rep = P()
         osh = P("data")                      # [N, L] state shards
         dsh = rep if replicated_feed else P("data")
-        # NOTE: no auto model axis here — the engine's axis_index /
-        # psum_scatter collectives only lower under a fully-manual
-        # region. On a (data, model) mesh the flat update stays sharded
+        # NOTE: no auto model axis here — on jax 0.4.37 the engine's
+        # axis_index / psum_scatter collectives only lowered under a
+        # fully-manual region; whether jax 0.9.0's shard_map (axis_names=)
+        # lifts that is UNTESTED (ROADMAP Queue 3 item 8 retries it).
+        # On a (data, model) mesh the flat update stays sharded
         # d ways over 'data' (replicated across model); params are
         # model-sharded AT REST via the jit boundary and gathered for
         # the step — the at-rest m× memory win composes, the compute
@@ -720,14 +722,20 @@ class ParallelWrapper:
                           if self.prefetch_buffer >= 1 else base)
             prefetcher = None
 
-        # historical ParallelWrapper semantics: EVERYTHING to dtype (the
-        # Solver path keeps ints instead — see cast_feed)
+        # the Solver's feed rule: floats to the net's dtype, integers
+        # (token ids, uint8 wire images) stay integers — a token id cast
+        # to bf16 and back names another token above 256
         def feed(v):
-            return cast_feed(v, dtype, keep_ints=False)
+            return cast_feed(v, dtype)
 
         reg = get_registry()
-        with span("fit", epochs=epochs, mode=self.training_mode,
-                  devices=self.n, net="ParallelWrapper"):
+        # the step programs are traced at their first dispatch below: let
+        # layer code see the mesh they will run over (a Pallas kernel
+        # must split itself per device — ops/pallas_attention.py); this
+        # context changes tracing only, never where eager arrays land
+        with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh), \
+                span("fit", epochs=epochs, mode=self.training_mode,
+                     devices=self.n, net="ParallelWrapper"):
             for epoch in range(epochs):
                 with span("epoch", index=epoch):
                     self._fit_epoch(net, it_wrapped, prefetcher, iterator,
@@ -883,8 +891,8 @@ class ParallelWrapper:
         net = self.net
         with span("window", k=len(buf), kind="averaging",
                   iteration=net.iteration_count):
-            xs = jnp.stack([jnp.asarray(np.asarray(d.features), dtype) for d in buf])
-            ys = jnp.stack([jnp.asarray(np.asarray(d.labels), dtype) for d in buf])
+            xs = jnp.stack([cast_feed(d.features, dtype) for d in buf])
+            ys = jnp.stack([cast_feed(d.labels, dtype) for d in buf])
             rng = jax.random.fold_in(base_rng, net.iteration_count)
             # remainder batches (size not tiling the mesh) dispatch the
             # replicated-feed averaging program — same contract as the

@@ -10,24 +10,8 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (the sharded modules import it here)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """Version-portable ``shard_map``: new JAX exports it as
-    ``jax.shard_map`` (with ``check_vma``); older versions ship
-    ``jax.experimental.shard_map.shard_map`` (same semantics, the kwarg is
-    named ``check_rep``). One seam so every sharded module runs on both."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    if check_vma is not None:
-        kw["check_vma"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 def make_mesh(shape: Optional[Tuple[int, ...]] = None,

@@ -5,8 +5,8 @@ collective cost behind compute by design (SilentTrainingDriver.java:109-142
 streams updates while workers keep training). The TPU-native sync path lost
 that: one monolithic post-backward sweep of per-leaf ``pmean`` binds —
 O(leaves) collective launches, all serialized after the last gradient is
-produced (BENCH_r05 ``collective_overhead_by_mesh``: 6.9ms -> 41.2ms from
-mesh 1 to 8, ~44% of an 8-device step).
+produced (``collective_overhead_by_mesh`` on virtual CPU devices: 6.9ms ->
+41.2ms from mesh 1 to 8, ~44% of an 8-device step).
 
 Two techniques close the gap (PAPERS.md):
 - arXiv:2004.13336 (cross-replica weight-update sharding): collectives

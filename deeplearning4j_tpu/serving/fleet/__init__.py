@@ -21,16 +21,16 @@ horizontal decode throughput, and elasticity:
                   pulls with replica attribution, cross-process timeline
                   stitching, dead-replica spool recovery, merged-bucket
                   fleet metrics + fleet-level SLO watchdog
-  - coldstart.py  load-not-compile cold start via the persistent
-                  compilation cache (DL4J_TPU_COMPILE_CACHE)
+  - coldstart.py  load-not-compile cold start: fresh-compile accounting
+                  over the shared persistent compilation cache
+                  (util/compile_cache.py, JAX_COMPILATION_CACHE_DIR)
   - http.py       the front door: single-replica wire protocol, fleet
                   semantics
 """
 from .affinity import AffinityMap, AffinityPolicy, prompt_chain, \
     rendezvous_order
 from .autoscale import Autoscaler, AutoscalePolicy, decide
-from .coldstart import (configure_compile_cache, configured_cache_dir,
-                        fresh_compile_count)
+from .coldstart import fresh_compile_count
 from .collector import AggregateRegistry, FleetCollector, merge_raw_metrics
 from .http import FleetHTTPServer
 from .replica import ReplicaProcess
@@ -41,7 +41,6 @@ __all__ = [
     "AffinityMap", "AffinityPolicy", "prompt_chain", "rendezvous_order",
     "Autoscaler", "AutoscalePolicy", "decide",
     "AggregateRegistry", "FleetCollector", "merge_raw_metrics",
-    "configure_compile_cache", "configured_cache_dir",
     "fresh_compile_count",
     "FleetHTTPServer", "ReplicaProcess",
     "DEAD_AFTER", "FleetError", "FleetHTTPError", "FleetRouter",

@@ -1,15 +1,16 @@
-"""Cold start as load-not-compile: the persistent compilation cache knob.
+"""Cold start as load-not-compile: persistent-compilation-cache accounting.
 
 Scaling out on an SLO burn-rate signal only works if a fresh replica is
 serving before the traffic spike is over — and a generation replica's
 startup cost is dominated by compiling its prefill/decode/replay/COW
 program set, not by loading weights. The JAX persistent compilation
-cache turns that compile storm into file loads: every replica points at
-one shared cache directory (the ``DL4J_TPU_COMPILE_CACHE`` env knob, or
-an explicit path in the replica spec), the FIRST replica to see a
-program pays the compile and writes the executable, and every later
-replica — including one spawned mid-spike by the autoscaler — warms the
-identical program set in checkpoint-load time.
+cache turns that compile storm into file loads: every replica shares one
+cache directory (``util.compile_cache.ensure_compile_cache`` — placed by
+``JAX_COMPILATION_CACHE_DIR``, which replicas inherit from their
+supervisor), the FIRST replica to see a program pays the compile and
+writes the executable, and every later replica — including one spawned
+mid-spike by the autoscaler — warms the identical program set in
+checkpoint-load time.
 
 Accounting: on this jax line the ``backend_compile_duration`` monitoring
 event fires even when the executable was answered from the cache, so
@@ -20,56 +21,20 @@ ZERO fresh compiles for already-seen programs.
 """
 from __future__ import annotations
 
-import os
-from typing import Optional
-
-ENV_CACHE = "DL4J_TPU_COMPILE_CACHE"
-
-_configured_dir: Optional[str] = None
-
-
-def configure_compile_cache(path: Optional[str] = None, *,
-                            min_compile_time_s: float = 0.0
-                            ) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``path`` (or the
-    ``DL4J_TPU_COMPILE_CACHE`` env var when ``path`` is None). ``"0"`` or
-    empty disables. ``min_compile_time_s=0.0`` caches EVERY program —
-    tiny CPU-tier executables included, which is what makes the
-    cold-start pin testable off-TPU; a production TPU fleet can raise it
-    to skip sub-second compiles. Returns the configured directory (also
-    recorded for :func:`snapshot`), or None when disabled. Idempotent;
-    call it BEFORE the first compile or already-compiled programs stay
-    uncached."""
-    global _configured_dir
-    cache = os.environ.get(ENV_CACHE, "") if path is None else path
-    if not cache or cache == "0":
-        return None
-    os.makedirs(cache, exist_ok=True)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_s))
-    _configured_dir = cache
-    return cache
-
-
-def configured_cache_dir() -> Optional[str]:
-    return _configured_dir
-
 
 def fresh_compile_count() -> int:
     """Programs this process actually compiled (cache hits excluded)."""
-    from ...telemetry import xla_cache_hit_count, xla_compile_count
-    return max(0, xla_compile_count() - xla_cache_hit_count())
+    return snapshot()["fresh_compiles"]
 
 
 def snapshot() -> dict:
     """The cold-start accounting block replicas publish at ready time."""
+    import jax
+
     from ...telemetry import xla_cache_hit_count, xla_compile_count
     compiles = xla_compile_count()
     hits = xla_cache_hit_count()
-    return {"cache_dir": _configured_dir,
+    return {"cache_dir": jax.config.jax_compilation_cache_dir,
             "compiles": compiles,
             "cache_hits": hits,
             "fresh_compiles": max(0, compiles - hits)}

@@ -8,16 +8,19 @@ hold warm state, coupled only through cheap periodic state publication
 (here: the ``/health`` steering payload), never through tight RPC.
 
 Child lifecycle (``python -m deeplearning4j_tpu.serving.fleet.replica``):
-  1. configure the persistent compilation cache (coldstart.py) BEFORE
-     any program is built, so warm-cache replicas load instead of
-     compile;
+  1. turn on the persistent compilation cache (util/compile_cache.py)
+     BEFORE any program is built, so warm-cache replicas load instead of
+     compile, and refuse to start on a CPU nobody asked for (jax falls
+     back to it with only a warning when the chip is held by another
+     process);
   2. build the model from the spec — a checkpoint/model-zip ``path``
      (serving.registry.load_net) or a deterministic ``zoo`` constructor
      (same seed -> identical params in every replica, no weight
      distribution step needed for benches and tests);
   3. construct + AOT-warm the GenerationEngine, start the HTTP server;
-  4. atomically write the ready file (port, pid, ready_s, cold-start
-     accounting) — the supervisor's readiness gate, then double-gated by
+  4. atomically write the ready file (port, pid, ready_s, platform,
+     device_kind, cold-start accounting) — the supervisor's readiness
+     gate, then double-gated by
      ``GET /health`` 200;
   5. wait for SIGTERM/SIGINT -> drain-then-stop (in-flight generations
      finish, new admissions see 503) -> exit 0.
@@ -35,8 +38,6 @@ import subprocess
 import sys
 import time
 from typing import Optional
-
-from .coldstart import ENV_CACHE
 
 
 def _default_spec_model() -> dict:
@@ -58,8 +59,16 @@ class ReplicaProcess:
 
     ``spec`` keys: ``model`` ({"path": ...} or {"zoo": name,
     "kwargs": {...}}), ``model_name``, ``generation`` (GenerationConfig
-    kwargs), ``host``, ``port``, ``compile_cache`` (falls back to the
-    ``DL4J_TPU_COMPILE_CACHE`` env knob).
+    kwargs), ``host``, ``port``.
+
+    The child inherits the caller's environment plus ``env``. A chip
+    belongs to ONE process: a caller that has touched JAX on a TPU holds
+    it, so its replicas must be sent elsewhere (``env={"JAX_PLATFORMS":
+    "cpu"}``); a child that lands on the CPU WITHOUT being told to exits
+    3 instead of serving on jax's fallback. The ready record and
+    ``/health`` name the platform each replica really runs on. The
+    compilation cache is shared through the inherited
+    ``JAX_COMPILATION_CACHE_DIR`` (util/compile_cache.py).
     """
 
     def __init__(self, spec: dict, replica_id: str, *, workdir: str,
@@ -248,11 +257,20 @@ def _child_main(argv=None) -> int:
         spec = json.load(f)
 
     t0 = time.monotonic()
-    # cache config must precede the first compile (see coldstart.py)
+    # the cache must be on before the first compile (util/compile_cache.py)
+    from ...util.compile_cache import ensure_compile_cache
+    from ...util.device import cpu_was_requested, device_record
     from . import coldstart
-    cache_dir = coldstart.configure_compile_cache(spec.get("compile_cache"))
+    cache_dir = ensure_compile_cache()
     from ...telemetry import ensure_monitoring_hook
     ensure_monitoring_hook()
+    device = device_record()
+    if device["platform"] == "cpu" and not cpu_was_requested():
+        print(f"replica {spec.get('replica_id')}: jax fell back to the CPU "
+              f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} does "
+              f"not ask for it — is the chip held by another process?); "
+              f"refusing to serve on a fallback", file=sys.stderr)
+        return 3
 
     from ..generation import GenerationEngine
     from ..http import ServingHTTPServer
@@ -263,6 +281,8 @@ def _child_main(argv=None) -> int:
 
     replica_info = {"id": spec.get("replica_id"),
                     "pid": os.getpid(),
+                    "platform": device["platform"],
+                    "device_kind": device["kind"],
                     "ready_s": None,        # filled below, served forever
                     "coldstart": None}
     srv = ServingHTTPServer(
@@ -274,6 +294,8 @@ def _child_main(argv=None) -> int:
     replica_info["coldstart"] = coldstart.snapshot()
     ready = {"port": port, "pid": os.getpid(),
              "ready_s": replica_info["ready_s"],
+             "platform": device["platform"],
+             "device_kind": device["kind"],
              "cache_dir": cache_dir, **replica_info["coldstart"]}
     tmp = args.ready_file + ".tmp"
     with open(tmp, "w") as f:

@@ -162,7 +162,7 @@ def make_pools(n_layers: int, num_blocks: int, block_len: int,
 def pool_bytes(pool) -> int:
     """Total device bytes of one pool entry (plain array or QuantizedPool)."""
     return sum(leaf.size * leaf.dtype.itemsize
-               for leaf in jax.tree_util.tree_leaves(pool))
+               for leaf in jax.tree.leaves(pool))
 
 
 def cow_copy(k_pool, v_pool, src, dst):
@@ -173,8 +173,8 @@ def cow_copy(k_pool, v_pool, src, dst):
     dependency. Generic over plain and quantized pools (a quantized COW
     copies codes AND scales — bit-exact sharing)."""
     copy = lambda p: p.at[:, dst].set(p[:, src])
-    k_pool = jax.tree_util.tree_map(copy, k_pool)
-    v_pool = jax.tree_util.tree_map(copy, v_pool)
+    k_pool = jax.tree.map(copy, k_pool)
+    v_pool = jax.tree.map(copy, v_pool)
     return k_pool, v_pool
 
 

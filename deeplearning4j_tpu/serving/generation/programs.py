@@ -10,7 +10,7 @@ every program the steady-state loop can ever need is lowered and compiled at
     first token sampled in-program;
   - one **decode-step** executable: one token per in-flight slot, gather via
     block tables, scatter the step's K/V, sample the next token — cache
-    buffers donated so the pool updates in place on real devices.
+    buffers donated so the pool updates in place.
 
 Params/state are arguments, not constants, so hot-swap reuses executables
 exactly as the forward-serving ProgramSet does (``with_params_from``).
@@ -23,6 +23,7 @@ bookkeeping but the program/scheduler contract is identical).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -129,9 +130,12 @@ class GenerationConfig:
                          f"rung {self.prompt_rungs[-1]}")
 
 
-def _donate_argnums() -> Tuple[int, ...]:
-    # cache donation is a no-op (with a warning) on the CPU test backend
-    return (2,) if jax.default_backend() in ("tpu", "gpu") else ()
+# Every cache-carrying program donates its cache argument (argnum 2): the
+# pools update in place, and the caller must rebind the returned cache and
+# never touch the one it passed in. Every backend of jax 0.9 honours
+# donation, the CPU included, so the test suite runs the same aliasing the
+# chip does.
+_DONATE_CACHE = (2,)
 
 
 class GenerationProgramSet:
@@ -469,6 +473,17 @@ class GenerationProgramSet:
                 hooked(sp.rewind_state_fn()), verify)
 
     # --------------------------------------------------------------- warm-up
+    def _aot(self, fn, donate: Tuple[int, ...], *avals):
+        """AOT-compile one program, traced under the mesh it will run over
+        so layer code can see it (a Pallas kernel must split itself per
+        device under a multi-device jit — ops/pallas_attention.py). The
+        context wraps the tracing ONLY: it is part of every jit cache key,
+        and the small eager programs of warm-up (pool zeros, shard
+        placement) must be the ones first traffic reuses."""
+        with (jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
+              if self.mesh is not None else contextlib.nullcontext()):
+            return jax.jit(fn, donate_argnums=donate).lower(*avals).compile()
+
     def warm(self) -> "GenerationProgramSet":
         """Compile every prefill rung and the decode step; touch each once
         so first traffic pays no one-time dispatch setup. NEVER called on
@@ -481,8 +496,8 @@ class GenerationProgramSet:
         decode = self._decode_fn()
         for P in c.prefill_batches:
             for L in c.prompt_rungs:
-                jitted = jax.jit(prefill, donate_argnums=_donate_argnums())
-                self._compiled[("prefill", P, L)] = jitted.lower(
+                self._compiled[("prefill", P, L)] = self._aot(
+                    prefill, _DONATE_CACHE,
                     self.params, self.state, cache_spec,
                     jax.ShapeDtypeStruct((P, L), i32),
                     jax.ShapeDtypeStruct((P,), i32),
@@ -490,26 +505,23 @@ class GenerationProgramSet:
                     jax.ShapeDtypeStruct((P,), i32),
                     key_spec,
                     jax.ShapeDtypeStruct((P,), jnp.float32),
-                    jax.ShapeDtypeStruct((P,), i32)).compile()
+                    jax.ShapeDtypeStruct((P,), i32))
         S = c.decode_slots
-        jitted = jax.jit(decode, donate_argnums=_donate_argnums())
-        self._compiled[("decode",)] = jitted.lower(
-            self.params, self.state, cache_spec,
+        self._compiled[("decode",)] = self._aot(
+            decode, _DONATE_CACHE, self.params, self.state, cache_spec,
             jax.ShapeDtypeStruct((S,), i32),
             jax.ShapeDtypeStruct((S,), i32),
             jax.ShapeDtypeStruct((S, mb), i32),
             jax.ShapeDtypeStruct((S,), jnp.bool_),
             key_spec,
             jax.ShapeDtypeStruct((S,), jnp.float32),
-            jax.ShapeDtypeStruct((S,), i32)).compile()
+            jax.ShapeDtypeStruct((S,), i32))
         if self.prefix_enabled:
             # the copy-on-write block copy: src/dst are runtime scalars, so
             # ONE executable serves every copy
-            donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
-            self._compiled[("cow",)] = jax.jit(
-                self._cow_fn(), donate_argnums=donate).lower(
-                cache_spec, jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((), i32)).compile()
+            self._compiled[("cow",)] = self._aot(
+                self._cow_fn(), (0,), cache_spec,
+                jax.ShapeDtypeStruct((), i32), jax.ShapeDtypeStruct((), i32))
         if self.spec_k:
             self._warm_spec(cache_spec, i32)
         # one touch per executable: first real traffic must not pay
@@ -576,54 +588,47 @@ class GenerationProgramSet:
 
     def _warm_spec(self, cache_spec, i32):
         """Compile the draft + verify executables (speculative decoding).
-        Cache-carrying programs donate their cache argument on TPU/GPU,
-        exactly like the decode step — the pools update in place."""
+        Cache-carrying programs donate their cache argument, exactly like
+        the decode step — the pools update in place."""
         c = self.config
         S, mb, k = c.decode_slots, c.blocks_per_seq, self.spec_k
         dcache_spec = self._draft_cache_spec()
         d_prefill, propose, rewind, verify = self._spec_fns()
         sds = jax.ShapeDtypeStruct
-        donate = _donate_argnums()             # (2,) on tpu/gpu, () on cpu
         for P in c.prefill_batches:
             for L in c.prompt_rungs:
                 if self.draft_adapter == "dense":
-                    self._compiled[("draft_prefill", P, L)] = jax.jit(
-                        d_prefill, donate_argnums=donate).lower(
+                    self._compiled[("draft_prefill", P, L)] = self._aot(
+                        d_prefill, _DONATE_CACHE,
                         self.draft_params, self.draft_state, dcache_spec,
-                        sds((P, L), i32), sds((P,), i32)).compile()
+                        sds((P, L), i32), sds((P,), i32))
                 else:
-                    self._compiled[("draft_prefill", P, L)] = jax.jit(
-                        d_prefill, donate_argnums=donate).lower(
+                    self._compiled[("draft_prefill", P, L)] = self._aot(
+                        d_prefill, _DONATE_CACHE,
                         self.draft_params, self.draft_state, dcache_spec,
-                        sds((P, L), i32), sds((P,), i32),
-                        sds((P,), i32)).compile()
+                        sds((P, L), i32), sds((P,), i32), sds((P,), i32))
         if self.draft_adapter == "dense":
-            self._compiled[("propose",)] = jax.jit(
-                propose, donate_argnums=donate).lower(
+            self._compiled[("propose",)] = self._aot(
+                propose, _DONATE_CACHE,
                 self.draft_params, self.draft_state, dcache_spec,
-                sds((S,), i32), sds((S,), i32),
-                sds((S,), jnp.bool_)).compile()
+                sds((S,), i32), sds((S,), i32), sds((S,), jnp.bool_))
         else:
             # the state propose RETURNS its input states untouched inside
             # the stack; no donation (the scheduler still needs states_all
             # until rewind commits)
-            self._compiled[("propose",)] = jax.jit(propose).lower(
-                self.draft_params, self.draft_state, dcache_spec,
-                sds((S,), i32)).compile()
+            self._compiled[("propose",)] = self._aot(
+                propose, (), self.draft_params, self.draft_state,
+                dcache_spec, sds((S,), i32))
             stack_spec = jax.tree.map(
                 lambda a: sds((k + 1, S) + a.shape[1:], a.dtype),
                 dcache_spec)
-            rw_donate = (0,) if jax.default_backend() in ("tpu", "gpu") \
-                else ()
-            self._compiled[("rewind",)] = jax.jit(
-                rewind, donate_argnums=rw_donate).lower(
-                dcache_spec, stack_spec, sds((S,), i32),
-                sds((S,), jnp.bool_)).compile()
-        self._compiled[("verify",)] = jax.jit(
-            verify, donate_argnums=donate).lower(
+            self._compiled[("rewind",)] = self._aot(
+                rewind, (0,), dcache_spec, stack_spec, sds((S,), i32),
+                sds((S,), jnp.bool_))
+        self._compiled[("verify",)] = self._aot(
+            verify, _DONATE_CACHE,
             self.params, self.state, cache_spec, sds((S, k + 1), i32),
-            sds((S,), i32), sds((S, mb), i32),
-            sds((S,), jnp.bool_)).compile()
+            sds((S,), i32), sds((S, mb), i32), sds((S,), jnp.bool_))
 
     def _touch_spec(self, cache):
         c = self.config

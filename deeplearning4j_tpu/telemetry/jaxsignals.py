@@ -247,15 +247,23 @@ _sync_installed = False
 _sync_detectors: List["HostSyncDetector"] = []
 
 
+# set on an array by the tripwire after a host read that jax did not cache
+_HOST_READ_MARK = "_dl4j_tpu_host_read"
+
+
 def _install_sync_tripwire() -> None:
     """Wrap ArrayImpl._value (idempotent, installed once per process).
 
     ``_value`` is the single host-materialization funnel for implicit
     readbacks: ``float()``, ``bool()``, ``str()``, ``.tolist()``,
     iteration, printing. The wrapper costs one list check when no
-    detector is armed. Only the FIRST materialization of a buffer goes
-    through (jax caches ``_npy_value``) — which is exactly the event that
-    blocks on the device; cached re-reads are free and stay unflagged.
+    detector is armed. Only the FIRST materialization of a buffer is
+    flagged — exactly the event that blocks on the device; re-reads are
+    free and stay unflagged. jax caches the host copy in ``_npy_value``
+    when it made one (a real device); on the zero-copy CPU backend jax
+    0.9.0 keeps no cache, so the wrapper marks the array itself after the
+    read. (An array read before the tripwire was first installed carries
+    no mark: on the CPU its next read inside a scope is flagged once.)
     """
     global _sync_installed
     if _sync_installed:
@@ -264,25 +272,30 @@ def _install_sync_tripwire() -> None:
         if _sync_installed:
             return
         from jax._src import array as _jarray
-        orig = _jarray.ArrayImpl._value
-        fget = orig.fget if isinstance(orig, property) else None
-        if fget is None:          # unexpected jax internals: stay inert
-            log.warning(
-                "HostSyncDetector: ArrayImpl._value is not a property on "
-                "this jax version — the readback tripwire cannot install, "
-                "detectors will report zero syncs (transfer_guard= still "
-                "works on device backends)")
-            _sync_installed = True
-            return
+        orig = getattr(_jarray.ArrayImpl, "_value", None)
+        if not isinstance(orig, property) \
+                or not hasattr(_jarray.ArrayImpl, "_npy_value"):
+            # written for jax 0.9.0's ArrayImpl; anything else must not
+            # pass for a clean bill of health
+            raise RuntimeError(
+                "HostSyncDetector: jax._src.array.ArrayImpl no longer has "
+                "the _value property / _npy_value cache this tripwire "
+                "wraps (written for jax 0.9.0) — it would report zero "
+                "syncs whatever the code did; port it before relying on "
+                "sync-freedom checks")
+        fget = orig.fget
 
         def _traced_value(self):
-            # _npy_value set => already materialized on a previous read:
-            # this access is a host-cache hit, not a device sync
-            if _sync_detectors and getattr(self, "_npy_value", None) is None:
+            first = self._npy_value is None \
+                and not getattr(self, _HOST_READ_MARK, False)
+            if first and _sync_detectors:
                 tid = threading.get_ident()
                 for det in list(_sync_detectors):
                     det._on_sync(self, tid)
-            return fget(self)
+            out = fget(self)
+            if first and self._npy_value is None:    # zero-copy: no cache
+                setattr(self, _HOST_READ_MARK, True)
+            return out
 
         _jarray.ArrayImpl._value = property(_traced_value)
         _sync_installed = True

@@ -11,9 +11,12 @@ implementation and turns it into *live* gauges:
   backends and a dict on others), :func:`implied_mfu`,
   :func:`roofline_dt` and :func:`classify_roofline` (compute- vs
   memory-bound from arithmetic intensity against the ridge point). Peak
-  numbers come from the same ``BENCH_PEAK_TFLOPS`` / ``BENCH_HBM_GBPS``
-  env knobs bench.py uses — bench delegates here, so bench rows and live
-  gauges can never disagree on the model.
+  numbers come from ONE table keyed by the running device's
+  ``device_kind`` (:data:`DEVICE_PEAKS`); ``BENCH_PEAK_TFLOPS`` /
+  ``BENCH_HBM_GBPS`` are explicit overrides. A device the table does not
+  know, with no override, has NO peak: MFU and roofline gauges are then
+  absent, never computed against another chip's numbers. bench.py
+  delegates here, so bench rows and live gauges cannot disagree.
 
 - **:class:`ProgramCostIndex`** — captures the XLA cost analysis of
   every program the system compiles, keyed by the program's span path:
@@ -79,19 +82,36 @@ def accounting_enabled() -> bool:
 
 
 # ------------------------------------------------------------ chip model
-# Defaults match bench.py (v5e bf16 MXU peak / HBM bandwidth); overridable
-# per call so bench's module-level constants keep working when tests
-# monkeypatch them.
-def peak_tflops(override: Optional[float] = None) -> float:
-    if override is not None:
-        return float(override)
-    return float(os.environ.get("BENCH_PEAK_TFLOPS", "197.0"))
+# Published per-chip peaks keyed by ``jax.devices()[0].device_kind``:
+# (bf16 MXU TFLOP/s, HBM GB/s). Source: Google Cloud TPU documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM per chip). f32 matmuls/convs
+# at JAX's default precision also run as bf16 MXU passes, so the bf16
+# peak is the denominator for both dtypes.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197.0, 819.0),
+}
 
 
-def hbm_gbps(override: Optional[float] = None) -> float:
+def _resolve_peak(override: Optional[float], env: str,
+                  column: int) -> Optional[float]:
+    """Explicit argument, else the env override, else the table row of
+    the device this process runs on; None when that device is unknown."""
     if override is not None:
         return float(override)
-    return float(os.environ.get("BENCH_HBM_GBPS", "819"))
+    v = os.environ.get(env)
+    if v:
+        return float(v)
+    import jax
+    row = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    return row[column] if row else None
+
+
+def peak_tflops(override: Optional[float] = None) -> Optional[float]:
+    return _resolve_peak(override, "BENCH_PEAK_TFLOPS", 0)
+
+
+def hbm_gbps(override: Optional[float] = None) -> Optional[float]:
+    return _resolve_peak(override, "BENCH_HBM_GBPS", 1)
 
 
 def max_plausible_mfu(override: Optional[float] = None) -> float:
@@ -122,17 +142,22 @@ def cost_analysis_of(program) -> dict:
 
 def implied_mfu(flops_per_step, dt_s, *, peak: Optional[float] = None
                 ) -> Optional[float]:
-    """MFU implied by a measured per-step time (None if flops unknown)."""
-    if not flops_per_step or not dt_s or dt_s <= 0:
+    """MFU implied by a measured per-step time (None if the flops or the
+    device's peak are unknown)."""
+    pk = peak_tflops(peak)
+    if not flops_per_step or not dt_s or dt_s <= 0 or not pk:
         return None
-    return flops_per_step / dt_s / 1e12 / peak_tflops(peak)
+    return flops_per_step / dt_s / 1e12 / pk
 
 
 def roofline_dt(flops_per_step, *, peak: Optional[float] = None,
                 mfu_ceiling: Optional[float] = None) -> float:
     """Fastest physically plausible per-step time at the MFU ceiling."""
-    return flops_per_step / (peak_tflops(peak) * 1e12
-                             * max_plausible_mfu(mfu_ceiling))
+    pk = peak_tflops(peak)
+    if not pk:
+        raise ValueError("no peak FLOP/s known for this device kind "
+                         "(not in DEVICE_PEAKS, BENCH_PEAK_TFLOPS unset)")
+    return flops_per_step / (pk * 1e12 * max_plausible_mfu(mfu_ceiling))
 
 
 def classify_roofline(flops, bytes_accessed, *,
@@ -143,11 +168,16 @@ def classify_roofline(flops, bytes_accessed, *,
     ``attainable_tflops`` is the roofline ceiling for this intensity —
     the honest denominator for "how close to the roof are we"."""
     pk, bw = peak_tflops(peak), hbm_gbps(gbps)
-    ridge = pk * 1e12 / (bw * 1e9) if bw > 0 else float("inf")
-    if not flops or not bytes_accessed:
+    intensity = (float(flops) / float(bytes_accessed)
+                 if flops and bytes_accessed else None)
+    if not pk or not bw:        # unknown device: nothing to classify against
+        return {"bound": "unknown",
+                "intensity": round(intensity, 3) if intensity else None,
+                "ridge": None, "attainable_tflops": None}
+    ridge = pk * 1e12 / (bw * 1e9)
+    if intensity is None:
         return {"bound": "unknown", "intensity": None, "ridge": round(ridge, 2),
                 "attainable_tflops": None}
-    intensity = float(flops) / float(bytes_accessed)
     attainable = min(pk, intensity * bw / 1e3)
     return {"bound": "compute" if intensity >= ridge else "memory",
             "intensity": round(intensity, 3), "ridge": round(ridge, 2),
@@ -338,6 +368,7 @@ class ProgramCostIndex:
         if not accounting_enabled():
             return rows
         ceiling = max_plausible_mfu()
+        peak = peak_tflops()        # None: unknown device, no MFU gauges
         # the whole fold runs under the index lock: concurrent folds
         # (epoch boundary vs /metrics scrape vs flight dump) must not
         # consume the same timing delta twice or tear _last_count/_sum.
@@ -376,27 +407,29 @@ class ProgramCostIndex:
                     row["step_ms"] = dt_step_ms
                     if e.flops_per_step:
                         achieved = e.flops_per_step / (dt_step_ms / 1e3) / 1e12
-                        mfu = achieved / peak_tflops()
                         # full precision: a toy CPU program's MFU is ~1e-8 —
                         # rounding here would zero it and break the
                         # report-vs-bench agreement check (renderers format)
                         row["achieved_tflops"] = achieved
-                        row["mfu"] = mfu
-                        # an MFU past the plausibility ceiling means the
-                        # timing under-measured (async dispatch slack), not a
-                        # fast chip — published, but flagged
-                        row["implausible"] = mfu > ceiling
+                        if peak:
+                            row["mfu"] = achieved / peak
+                            # an MFU past the plausibility ceiling means
+                            # the timing under-measured (async dispatch
+                            # slack), not a fast chip — published, flagged
+                            row["implausible"] = row["mfu"] > ceiling
                     if reg.enabled:
                         p = f"perf.{e.path}"
                         reg.gauge(f"{p}.step_ms").set(round(dt_step_ms, 6))
-                        if row["mfu"] is not None:
-                            reg.gauge(f"{p}.mfu").set(row["mfu"])
+                        if row["achieved_tflops"] is not None:
                             reg.gauge(f"{p}.achieved_tflops").set(
                                 row["achieved_tflops"])
+                        if row["mfu"] is not None:
+                            reg.gauge(f"{p}.mfu").set(row["mfu"])
                             reg.gauge(f"{p}.implausible").set(
                                 1.0 if row["implausible"] else 0.0)
-                        reg.gauge(f"{p}.roofline_compute_bound").set(
-                            1.0 if rf["bound"] == "compute" else 0.0)
+                        if rf["bound"] != "unknown":
+                            reg.gauge(f"{p}.roofline_compute_bound").set(
+                                1.0 if rf["bound"] == "compute" else 0.0)
                 e.last_row = row
                 rows.append(row)
         return rows

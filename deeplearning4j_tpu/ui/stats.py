@@ -163,7 +163,7 @@ class StatsListener(TrainingListener):
 
     def _snapshot(self, params):
         # Copy so the solver's buffer donation can't invalidate the snapshot.
-        self._prev_snapshot = jax.tree_util.tree_map(
+        self._prev_snapshot = jax.tree.map(
             lambda x: jnp.array(x, copy=True), params)
 
     def _activation_stats(self, model) -> Optional[Dict[str, Any]]:

@@ -52,18 +52,22 @@ GEN_KW = dict(block_len=16, max_seq_len=224, decode_slots=2,
 def fleet(tmp_path_factory):
     work = tmp_path_factory.mktemp("fleet")
     spec = {"model": {"zoo": "transformer_lm", "kwargs": MODEL_KW},
-            "model_name": "lm", "generation": GEN_KW,
-            "compile_cache": str(work / "cache")}
-    procs = {rid: ReplicaProcess(spec, rid, workdir=str(work))
+            "model_name": "lm", "generation": GEN_KW}
+    # the compile cache is placed from outside, by the env the children
+    # inherit (util/compile_cache.py) — the replicas share this one
+    env = {"JAX_COMPILATION_CACHE_DIR": str(work / "cache")}
+    procs = {rid: ReplicaProcess(spec, rid, workdir=str(work), env=env)
              for rid in ("f0", "f1")}
     router = FleetRouter(policy="affinity", health_period_s=0.1).start()
     front = FleetHTTPServer(router)
     client = HTTPClient(max_per_host=4, timeout=60.0)
     try:
+        for rid in ("f0", "f1"):       # parallel spawn, serial readiness
+            procs[rid].start()
         for rid in ("f0", "f1"):
             router.add_process(procs[rid], wait_ready=True, timeout=240.0)
         base = f"http://127.0.0.1:{front.start()}"
-        yield SimpleNamespace(work=work, spec=spec, procs=procs,
+        yield SimpleNamespace(work=work, spec=spec, env=env, procs=procs,
                               router=router, front=front, base=base,
                               client=client)
     finally:
@@ -125,8 +129,10 @@ def test_readiness_gate_and_health_steering(fleet):
         info = proc.ready_info
         assert info["port"] > 0 and info["pid"] > 0
         assert info["ready_s"] > 0
-        assert info["cache_dir"] == fleet.spec["compile_cache"]
+        assert info["cache_dir"] == fleet.env["JAX_COMPILATION_CACHE_DIR"]
         assert "fresh_compiles" in info
+        # every replica names the device it REALLY runs on
+        assert info["platform"] == "cpu" and info["device_kind"]
         # the steering payload the router (and autoscaler) steer on
         status, health = fleet.client.request_json(
             "GET", proc.base_url + "/health", timeout=10.0)
@@ -138,6 +144,7 @@ def test_readiness_gate_and_health_steering(fleet):
             assert key in s, key
         assert s["block_len"] == 16
         assert health["replica"]["id"] == rid
+        assert health["replica"]["platform"] == "cpu"
     # front door aggregates
     status, body = fleet.client.request_json(
         "GET", fleet.base + "/health", timeout=10.0)
@@ -380,7 +387,8 @@ def test_warm_cache_replica_joins_and_drains_out(fleet):
     """The autoscaler's scale-out path: a third replica pointed at the
     WARM shared compilation cache must reach ready as load-not-compile —
     zero fresh backend compiles — and scale-in must drain, not drop."""
-    f2 = ReplicaProcess(fleet.spec, "f2", workdir=str(fleet.work))
+    f2 = ReplicaProcess(fleet.spec, "f2", workdir=str(fleet.work),
+                        env=fleet.env)
     added = False
     try:
         fleet.router.add_process(f2, wait_ready=True, timeout=240.0)
@@ -422,7 +430,7 @@ def test_orphaned_replica_exits_when_supervisor_is_killed(fleet):
         print(json.dumps({"replica_pid": p.pid}), flush=True)
         time.sleep(600)                 # hang until SIGKILLed
     """)
-    env = {**os.environ,
+    env = {**os.environ, **fleet.env,
            "PYTHONPATH": os.pathsep.join(
                [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                 os.environ.get("PYTHONPATH", "")])}
@@ -451,29 +459,31 @@ def test_orphaned_replica_exits_when_supervisor_is_killed(fleet):
             sup.wait()
 
 
-def test_compile_cache_env_knob(tmp_path, monkeypatch):
-    """DL4J_TPU_COMPILE_CACHE drives jax's persistent compilation cache;
-    '0' (or empty) disables. Restores the process-global jax config."""
+def test_compile_cache_default_dir(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR unset the cache is the fixed
+    <checkout>/.jax_cache, exported for child processes (the env-set leg
+    is pinned by the fixture: every replica's ready record reports the
+    directory its inherited env named). Restores the process-global jax
+    config."""
     import jax
 
     from deeplearning4j_tpu.serving.fleet import coldstart
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_configured = coldstart._configured_dir
-    cache = str(tmp_path / "cc")
+    from deeplearning4j_tpu.util import compile_cache
+    olds = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_entry_size_bytes",
+        "jax_persistent_cache_min_compile_time_secs")}
+    monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, "")     # = unset
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     try:
-        monkeypatch.setenv(coldstart.ENV_CACHE, cache)
-        assert coldstart.configure_compile_cache() == cache
+        cache = compile_cache.ensure_compile_cache()
+        assert cache == os.path.join(repo, ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == cache
-        assert coldstart.configured_cache_dir() == cache
-        assert os.path.isdir(cache)
-        monkeypatch.setenv(coldstart.ENV_CACHE, "0")
-        assert coldstart.configure_compile_cache() is None
-        # explicit path beats the env var
-        explicit = str(tmp_path / "explicit")
-        assert coldstart.configure_compile_cache(explicit) == explicit
+        assert os.environ[compile_cache.ENV_CACHE_DIR] == cache
+        assert compile_cache.ensure_compile_cache() == cache   # idempotent
     finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        coldstart._configured_dir = old_configured
+        for k, v in olds.items():
+            jax.config.update(k, v)
     snap = coldstart.snapshot()
     assert {"compiles", "cache_hits", "fresh_compiles"} <= set(snap)
     assert snap["fresh_compiles"] >= 0

@@ -21,7 +21,6 @@ Pins:
 import json
 import os
 
-import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops import kernels
@@ -51,7 +50,6 @@ def test_every_kernel_has_parity_pin(name):
     spec = kernels.get(name)
     assert spec.parity is not None, \
         f"kernel {name!r} registered without a parity pin"
-    assert spec.available() in (True, False)
 
 
 @pytest.mark.parametrize("name", kernels.names())
@@ -59,21 +57,15 @@ def test_kernel_parity_interpret_mode(name, monkeypatch):
     """Auto-generated per-kernel pin: fused impl (CPU pallas interpreter)
     vs XLA fallback on identical inputs, within the declared tol."""
     spec = kernels.get(name)
-    if not spec.available():
-        pytest.skip("pallas unavailable on this install")
     monkeypatch.setenv(spec.interpret_env, "1")
     for alias in spec.interpret_aliases:
         monkeypatch.setenv(alias, "1")
     monkeypatch.delenv(spec.kill_env, raising=False)
     for alias in spec.kill_aliases:
         monkeypatch.delenv(alias, raising=False)
-    fused, fallback = spec.parity.run(0)
-    assert len(fused) == len(fallback) and fused
-    for a, b in zip(fused, fallback):
-        err = float(np.max(np.abs(np.asarray(a, np.float64)
-                                  - np.asarray(b, np.float64))))
-        assert err <= spec.parity.tol, \
-            (name, err, spec.parity.tol, spec.parity.note)
+    err = kernels.parity_error(name)
+    assert err <= spec.parity.tol, \
+        (name, err, spec.parity.tol, spec.parity.note)
 
 
 # ------------------------------------------------------------ env plumbing
@@ -122,8 +114,7 @@ def test_legacy_interpret_aliases_honored(monkeypatch):
     assert kernels.active_impl("attention") == "fallback"   # cpu, no opt-in
     monkeypatch.setenv("DL4J_TPU_FUSED_ATTN_INTERPRET", "1")
     assert spec.interpret_opted_in()
-    if spec.available():
-        assert kernels.active_impl("attention") == "interpret"
+    assert kernels.active_impl("attention") == "interpret"
 
 
 def test_backend_admits_rule(monkeypatch):
@@ -183,12 +174,19 @@ def test_autotune_replays_without_remeasuring(tuned_cache):
                           default=(512, 1024))
     assert rec["choice"] == [512, 1024]
     assert rec["replays"] == 1
+    # the trace-time replay counts in memory and never rewrites the file
+    # (it lives outside the checkout; tracing a kernel must not write it)
+    before = os.stat(tuned_cache).st_mtime_ns
     assert autotune.cached_decision("attention", "T777") == [512, 1024]
+    assert rec["replays"] == 2
+    assert os.stat(tuned_cache).st_mtime_ns == before
     with open(tuned_cache) as f:
         data = json.load(f)
     key = autotune.AutotuneCache.key("attention", "T777",
                                      autotune._backend())
-    assert data["decisions"][key]["replays"] == 2
+    assert data["decisions"][key]["replays"] == 1
+    assert not autotune.get_cache().loaded_from_file    # built in-process
+    assert autotune.AutotuneCache(tuned_cache).loaded_from_file
 
 
 def test_autotune_defaults_stand_without_measurement(tuned_cache):

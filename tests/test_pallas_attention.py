@@ -189,3 +189,43 @@ def test_multi_block_grid_parity(causal):
         rel = (float(jnp.max(jnp.abs(a - b)))
                / (float(jnp.max(jnp.abs(b))) + 1e-9))
         assert rel < 1e-4, (name, rel)
+
+
+def test_kernels_split_per_device_on_a_mesh():
+    """A program traced for several devices must run the kernels per
+    device block instead of refusing to lower them (on a TPU a bare Mosaic
+    call under a multi-device jit raises "cannot be automatically
+    partitioned"; the CPU interpreter path hides that, so this pins the
+    split RULE): under the mesh tracing context, forward+backward on a
+    (data, model) mesh, masked and causal, is bit-equal to the one-device
+    program and keeps the (batch, heads) split — also from inside a
+    shard_map that is already manual over ``data`` (the overlap/ZeRO step
+    on a 2-D mesh); a head count the model axis does not divide repeats
+    the work on that axis instead of mis-splitting it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    B, H, T, D = 2, 2, 128, 64
+    q, k, v = (jnp.asarray(R.standard_normal((B, H, T, D)) * 0.3, jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(R.random((B, T)) > 0.2)
+
+    def fwd(q, k, v, mask):
+        return flash_attention(q, k, v, causal=True, key_mask=mask)
+    grad = jax.grad(lambda *a: jnp.sum(fwd(*a) ** 2), argnums=(0, 1, 2))
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    bh = NamedSharding(mesh, P("data", "model"))
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        got = jax.jit(grad, in_shardings=(bh, bh, bh, NamedSharding(
+            mesh, P("data"))))(q, k, v, mask)
+        inside_manual_data = jax.jit(jax.shard_map(
+            fwd, mesh=mesh, in_specs=(P("data"),) * 4, out_specs=P("data"),
+            axis_names={"data"}, check_vma=False))(q, k, v, mask)
+    for a, b in zip(got, jax.jit(grad)(q, k, v, mask)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert tuple(a.sharding.spec)[:2] == ("data", "model")
+    want = np.asarray(jax.jit(fwd)(q, k, v, mask))
+    np.testing.assert_array_equal(np.asarray(inside_manual_data), want)
+    mesh3 = Mesh(np.array(jax.devices()[:6]).reshape(2, 3), ("data", "model"))
+    with jax.sharding.use_abstract_mesh(mesh3.abstract_mesh):
+        uneven = jax.jit(fwd, in_shardings=NamedSharding(mesh3, P("data")))(
+            q, k, v, mask)
+    np.testing.assert_array_equal(np.asarray(uneven), want)

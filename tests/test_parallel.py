@@ -51,6 +51,34 @@ def test_sync_dp_matches_single_device_math():
                        np.asarray(net_b.params_flat()), atol=1e-5)
 
 
+@pytest.mark.parametrize("kw", [{}, {"training_mode": "averaging",
+                                     "averaging_frequency": 2}])
+def test_token_ids_reach_a_bf16_net_as_integers(kw):
+    """Regression: ParallelWrapper cast EVERY feed to the net's dtype, so a
+    bf16 language model got its token ids through bf16 — above 256 another
+    token (301 -> 300). One Adam step moves exactly the embedding rows of
+    the ids that were fed; a rounded id moves its neighbour's row."""
+    from deeplearning4j_tpu import InputType
+    from deeplearning4j_tpu.nn.layers import (EmbeddingSequenceLayer,
+                                              RnnOutputLayer)
+    conf = (NeuralNetConfiguration(seed=3, updater=Adam(1e-2),
+                                   dtype="bfloat16")
+            .list(EmbeddingSequenceLayer(n_in=512, n_out=8),
+                  RnnOutputLayer(n_out=512, activation="softmax",
+                                 loss="mcxent"))
+            .set_input_type(InputType.recurrent(1, 8)).build())
+    net = MultiLayerNetwork(conf).init()
+    before = np.asarray(net.params[0]["W"], np.float32)
+    ids = np.array([[301, 303, 257, 259, 411, 413, 499, 501]] * 4, np.int32)
+    onehot = np.eye(512, dtype=np.float32)[np.roll(ids, -1, 1)]
+    ParallelWrapper(net, workers=2, **kw).fit(
+        ListDataSetIterator(features=ids, labels=onehot, batch_size=4),
+        epochs=2)
+    after = np.asarray(net.params[0]["W"], np.float32)
+    moved = set(np.nonzero(np.any(after != before, axis=1))[0].tolist())
+    assert moved == set(ids[0].tolist())
+
+
 def test_averaging_frequency_mode_converges():
     x, y = _data(512)
     it = ListDataSetIterator(features=x, labels=y, batch_size=64)

@@ -152,6 +152,35 @@ def test_classify_roofline_bounds(monkeypatch):
     assert classify_roofline(None, 1e6)["bound"] == "unknown"
 
 
+def test_unknown_device_kind_has_no_peak(monkeypatch, fresh_registry,
+                                         fresh_index):
+    """A device the DEVICE_PEAKS table does not know (the CPU here), with
+    no override: no peak, no MFU, no roofline class — and NO gauge, rather
+    than a number against another chip's peak."""
+    from deeplearning4j_tpu.telemetry import perf
+    monkeypatch.delenv("BENCH_PEAK_TFLOPS")
+    monkeypatch.delenv("BENCH_HBM_GBPS")
+    assert perf.peak_tflops() is None and perf.hbm_gbps() is None
+    assert perf.peak_tflops(3.0) == 3.0                # explicit arg wins
+    assert implied_mfu(1e12, 1.0) is None
+    with pytest.raises(ValueError, match="DEVICE_PEAKS"):
+        roofline_dt(1e12)
+    cls = classify_roofline(1e9, 1e6)
+    assert cls["bound"] == "unknown" and cls["attainable_tflops"] is None
+    reg, idx = fresh_registry, fresh_index
+    idx.register("prog", flops_per_step=2e9, bytes_per_step=1e6,
+                 timing_metric="t_ms")
+    reg.histogram("t_ms").observe(2.0)
+    row = idx.fold(reg)[0]
+    assert row["mfu"] is None and row["step_ms"] == pytest.approx(2.0)
+    assert row["achieved_tflops"] == pytest.approx(1.0, rel=1e-6)
+    assert reg.gauge_if_exists("perf.prog.mfu") is None
+    assert reg.gauge_if_exists("perf.prog.roofline_compute_bound") is None
+    assert reg.gauge_if_exists("perf.prog.step_ms") is not None
+    # the table itself: the v5e row the chip runs resolve
+    assert perf.DEVICE_PEAKS["TPU v5 lite"] == (197.0, 819.0)
+
+
 # ------------------------------------------------------------- cost index
 def test_cost_index_register_and_fold_math(fresh_registry, fresh_index):
     reg, idx = fresh_registry, fresh_index

@@ -24,6 +24,9 @@ from deeplearning4j_tpu.serving import (BucketLadder, DeadlineExceededError,
                                         UnknownModelError, xla_compile_count)
 
 R = np.random.default_rng(77)
+# engine-vs-direct-forward agreement for these softmax outputs: a few f32
+# ulps of values <= 1 (the tolerance this file's other parity checks use)
+_ATOL = 1e-6
 
 
 def _net(seed=3, n_in=4, n_out=3):
@@ -290,13 +293,20 @@ def test_multi_model_routing_and_unknown_model():
 def _hot_swap_under_load(n_clients, min_requests, post_swap_requests):
     """Shared body for the tier-1 and slow hot-swap tests: hammer the
     engine while swapping mid-load; ZERO failures allowed, every result
-    must match the old or the new model bit-for-bit, and any request
-    SUBMITTED after the cutover must see the new model."""
+    must be the old or the new model's answer, and any request SUBMITTED
+    after the cutover must see the new model. "Is model X's answer" is
+    _ATOL-close to ``net.output``, not bit-equal: the engine runs the
+    rows inside a padded bucket program of another batch shape than the
+    direct call, and whether two differently shaped XLA programs agree in
+    the last ulp is the compiler's choice (they did on jax 0.4, they do
+    not on 0.9), not a property of this code. The models differ by far
+    more than _ATOL, so the classification is unambiguous."""
     net_old, net_new = _net(seed=5), _net(seed=6)
     x = R.normal(size=(3, 4)).astype(np.float32)
     want_old = np.asarray(net_old.output(x))
     want_new = np.asarray(net_new.output(x))
-    assert not np.allclose(want_old, want_new)   # swap must be observable
+    # swap must be observable, by a margin that dwarfs the tolerance
+    assert np.max(np.abs(want_old - want_new)) > 1e3 * _ATOL
     eng = InferenceEngine(net_old, feature_shape=(4,), buckets=(4, 8),
                           batch_window_ms=0.5)
     compiles0 = xla_compile_count()
@@ -336,11 +346,11 @@ def _hot_swap_under_load(n_clients, min_requests, post_swap_requests):
     assert xla_compile_count() == compiles0
     n_old = n_new = 0
     for submitted_after_swap, out in outputs:
-        if np.array_equal(out, want_old):
+        if np.allclose(out, want_old, rtol=0, atol=_ATOL):
             n_old += 1
             assert not submitted_after_swap, \
                 "request submitted after the cutover served by the old model"
-        elif np.array_equal(out, want_new):
+        elif np.allclose(out, want_new, rtol=0, atol=_ATOL):
             n_new += 1
         else:                            # pragma: no cover
             raise AssertionError("output matches neither model")
@@ -525,7 +535,9 @@ def test_oversized_request_chunks_across_max_bucket():
 
 # ------------------------------------------------------------ hammer (soak)
 def _hammer(eng, net, n_threads, n_requests, sizes):
-    """Every caller must get exactly its own rows back, bit-identical."""
+    """Every caller must get exactly its own rows back (to _ATOL of the
+    direct forward — see _hot_swap_under_load on why not bit-equal; any
+    other request's rows are O(1) away)."""
     failures = []
 
     def client(tid):
@@ -539,7 +551,8 @@ def _hammer(eng, net, n_threads, n_requests, sizes):
             try:
                 out = eng.predict(x, timeout=30)
                 want = np.asarray(net.output(x))
-                if not np.array_equal(out, want):
+                if out.shape != want.shape or not np.allclose(
+                        out, want, rtol=0, atol=_ATOL):
                     failures.append((tid, k, "mismatch"))
             except Exception as e:
                 failures.append((tid, k, repr(e)))
@@ -673,15 +686,21 @@ def test_hot_swap_same_shapes_different_arch_rewarms():
         traces0 = eng.trace_count
         eng.hot_swap("default", net_relu)
         assert eng.trace_count == traces0 + 1   # forced full re-warm
-        np.testing.assert_array_equal(eng.predict(x),
-                                      np.asarray(net_relu.output(x)))
+        # the relu net's answer (to _ATOL: bucket-of-4 program vs a direct
+        # batch-2 call), and nowhere near the old activation's
+        np.testing.assert_allclose(eng.predict(x),
+                                   np.asarray(net_relu.output(x)),
+                                   rtol=0, atol=_ATOL)
+        assert not np.allclose(eng.predict(x),
+                               np.asarray(net_tanh.output(x)), atol=1e-3)
         # seed-only difference stays on the free fast path
         net_relu2 = build("relu")
         net_relu2.init(seed=12345)
         traces1 = eng.trace_count
         eng.hot_swap("default", net_relu2)
         assert eng.trace_count == traces1       # no re-warm
-        np.testing.assert_array_equal(eng.predict(x),
-                                      np.asarray(net_relu2.output(x)))
+        np.testing.assert_allclose(eng.predict(x),
+                                   np.asarray(net_relu2.output(x)),
+                                   rtol=0, atol=_ATOL)
     finally:
         eng.stop()

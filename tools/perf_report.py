@@ -27,8 +27,9 @@ the same ``perf`` block), gzipped or not, and renders:
 
 Like the other tools/ CLIs, this file must stay importable without the
 package (no jax): stdlib only. Peak TFLOP/s for the MFU recomputation
-comes from the dump when present, else BENCH_PEAK_TFLOPS, else the v5e
-default — the same knob chain bench.py and telemetry/perf.py use.
+comes from the dump (which stamps the peak of the device it was folded
+on), else the BENCH_PEAK_TFLOPS override; a dump from a device with no
+known peak gets no MFU column, never another chip's.
 """
 from __future__ import annotations
 
@@ -38,8 +39,6 @@ import json
 import os
 import sys
 from typing import List, Optional
-
-DEFAULT_PEAK_TFLOPS = 197.0
 
 
 def _read_text(path: str) -> str:
@@ -71,13 +70,12 @@ def load_dump(path: str) -> dict:
                      "dump, nor a registry snapshot")
 
 
-def _peak_tflops(dump: dict) -> float:
+def _peak_tflops(dump: dict) -> Optional[float]:
     # the dump stamps the peak it was folded against (perf_snapshot);
-    # env/default is the fallback for older or hand-built dumps
-    v = dump.get("perf", {}).get("peak_tflops")
-    if v:
-        return float(v)
-    return float(os.environ.get("BENCH_PEAK_TFLOPS", DEFAULT_PEAK_TFLOPS))
+    # the env override serves older or hand-built dumps
+    v = dump.get("perf", {}).get("peak_tflops") \
+        or os.environ.get("BENCH_PEAK_TFLOPS")
+    return float(v) if v else None
 
 
 def _fmt_bytes(n) -> str:
@@ -111,7 +109,7 @@ def roofline_rows(dump: dict) -> List[dict]:
         mfu = achieved = None
         if flops and step_ms:
             achieved = float(flops) / (float(step_ms) / 1e3) / 1e12
-            mfu = achieved / peak
+            mfu = achieved / peak if peak else None
         rows.append({
             "path": row.get("path", "?"),
             "flops_per_step": flops,

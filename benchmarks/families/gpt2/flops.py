@@ -1,0 +1,22 @@
+"""Analytic FLOPs of GPT-2, the operations the forward and backward passes
+require (no recomputation; causal attention counted at the half it needs).
+The arithmetic of ``bench.py`` ``_tlm_flops``, owned by the benchmark."""
+from __future__ import annotations
+
+from typing import Dict
+
+
+def forward_flops_per_token(cfg: Dict, context: float) -> float:
+    """Matmul FLOPs of one token's forward pass attending to ``context``
+    positions: 2 per multiply-add."""
+    d, L, V = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
+    weights = L * 12 * d * d + d * V
+    attention = L * 4 * d * context          # QK^T and PV
+    return 2.0 * weights + attention
+
+
+def train_flops_per_sample(cfg: Dict, seq_len: int) -> float:
+    """Forward + backward (3x forward) of one sequence of ``seq_len``;
+    a causal position i attends to i + 1 positions: mean (T + 1) / 2."""
+    per_token = forward_flops_per_token(cfg, (seq_len + 1) / 2.0)
+    return 3.0 * per_token * seq_len

@@ -1,0 +1,14 @@
+"""Tokens a decode step's gather reads per layer (``gathered_tokens``:
+every slot's whole table) over the tokens the step attends to
+(``live_tokens``: the valid positions of its live slots), both on the
+``generation.decode_step`` span; mean over the window's steps. The outside
+twin, ``kvcache.gathered_over_live.lat``, rebuilds the live tokens from
+the clients' stamps."""
+from benchmarks.lib import program_events
+
+
+def read(obs):
+    if obs.get("kind") != "open_loop":
+        return None
+    return program_events.mean_ratio(obs, "generation.decode_step",
+                                     "gathered_tokens", "live_tokens")

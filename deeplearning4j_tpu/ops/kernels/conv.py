@@ -63,6 +63,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# the kernel's name in the compiled program and in a device trace (an
+# outer scope takes a transformation's wrapping: see ops/pallas_attention.py)
+SCOPE = "conv1x1"
+KERNEL_NAME = "conv1x1_bias_relu"
+
+
 def _conv_kernel(x_ref, w_ref, b_ref, o_ref):
     acc = jax.lax.dot_general(
         x_ref[...], w_ref[...],
@@ -79,20 +85,22 @@ def _conv1x1_pallas(xm, wm, b):
     M, C = xm.shape
     F = wm.shape[1]
     grid = (pl.cdiv(M, _BM), F // _BN)
-    return pl.pallas_call(
-        _conv_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((_BM, C), lambda i, j: (i, 0)),
-            pl.BlockSpec((C, _BN), lambda i, j: (0, j)),
-            pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, F), xm.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
-    )(xm, wm, b[None, :])
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            _conv_kernel,
+            name=KERNEL_NAME,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((_BM, C), lambda i, j: (i, 0)),
+                pl.BlockSpec((C, _BN), lambda i, j: (0, j)),
+                pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((M, F), xm.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=_interpret(),
+        )(xm, wm, b[None, :])
 
 
 def _conv1x1_xla(xm, wm, b):

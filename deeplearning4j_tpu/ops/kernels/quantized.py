@@ -73,6 +73,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# the kernel's name in the compiled program and in a device trace (an
+# outer scope takes a transformation's wrapping: see ops/pallas_attention.py)
+SCOPE = "quantized"
+KERNEL_NAME = "int8_matmul"
+
+
 def _matmul_kernel(xq_ref, wq_ref, xs_ref, ws_ref, o_ref):
     acc = jax.lax.dot_general(
         xq_ref[...], wq_ref[...],
@@ -89,21 +95,23 @@ def int8_matmul_pallas(x_q, w_q, x_scale, w_scale):
     M, K = x_q.shape
     N = w_q.shape[1]
     grid = (M // _BM, N // _BN)
-    return pl.pallas_call(
-        _matmul_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((_BM, K), lambda i, j: (i, 0)),
-            pl.BlockSpec((K, _BN), lambda i, j: (0, j)),
-            pl.BlockSpec((_BM, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), f32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=_interpret(),
-    )(x_q, w_q, x_scale[:, None], w_scale[None, :])
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            _matmul_kernel,
+            name=KERNEL_NAME,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((_BM, K), lambda i, j: (i, 0)),
+                pl.BlockSpec((K, _BN), lambda i, j: (0, j)),
+                pl.BlockSpec((_BM, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, _BN), lambda i, j: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((_BM, _BN), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((M, N), f32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=_interpret(),
+        )(x_q, w_q, x_scale[:, None], w_scale[None, :])
 
 
 def int8_matmul_xla(x_q, w_q, x_scale, w_scale):

@@ -123,6 +123,15 @@ def _causal_mask_block(i, j, BQ, BK, s):
 
 
 # ------------------------------------------------------------------ forward
+# The kernels' names in the compiled program and in a device trace: the
+# HLO instruction is named after the innermost scope around the call, and
+# ``pallas_call(name=)`` opens one. A transformation wraps the FIRST scope
+# opened under it (``jvp(name)``), so every call also sits in the outer
+# scope ``SCOPE``, which takes that wrapping and leaves the name bare.
+SCOPE = "flash_attention"
+FWD_NAME = "flash_attention_fwd"
+
+
 def _fwd_body(causal, masked, scale, BQ, BK, *refs):
     if masked:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc, m, l = refs
@@ -192,23 +201,28 @@ def _fwd(q3, k3, v3, mask2, causal, scale):
                  jax.ShapeDtypeStruct((BH, T, 128), f32)]
     out_specs = [pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0)),
                  pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0))]
-    o, lse = pl.pallas_call(
-        functools.partial(_fwd_body, causal, masked, scale, BQ, BK),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((BQ, D), f32),
-                        pltpu.VMEM((BQ, 128), f32),
-                        pltpu.VMEM((BQ, 128), f32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope(SCOPE):
+        o, lse = pl.pallas_call(
+            functools.partial(_fwd_body, causal, masked, scale, BQ, BK),
+            name=FWD_NAME,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((BQ, D), f32),
+                            pltpu.VMEM((BQ, 128), f32),
+                            pltpu.VMEM((BQ, 128), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+        )(*args)
     return o, lse
 
 
 # ------------------------------------------------------------------ dq pass
+DQ_NAME = "flash_attention_bwd_dq"
+
+
 def _dq_body(causal, masked, scale, BQ, BK, *refs):
     if masked:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -253,6 +267,9 @@ def _dq_body(causal, masked, scale, BQ, BK, *refs):
 
 
 # ---------------------------------------------------------------- dkv pass
+DKV_NAME = "flash_attention_bwd_dkv"
+
+
 def _dkv_body(causal, masked, scale, BQ, BK, *refs):
     if masked:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -330,17 +347,19 @@ def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
         in_specs.append(pl.BlockSpec(
             (1, 1, BK), lambda b, i, j: (b // H, 0, j)))
         args.append(mask2[:, None, :].astype(f32))
-    dq = pl.pallas_call(
-        functools.partial(_dq_body, causal, masked, scale, BQ, BK),
-        grid=(BH, T // BQ, T // BK),
-        in_specs=in_specs,
-        out_specs=[qspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype)],
-        scratch_shapes=[pltpu.VMEM((BQ, D), f32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args)[0]
+    with jax.named_scope(SCOPE):
+        dq = pl.pallas_call(
+            functools.partial(_dq_body, causal, masked, scale, BQ, BK),
+            name=DQ_NAME,
+            grid=(BH, T // BQ, T // BK),
+            in_specs=in_specs,
+            out_specs=[qspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype)],
+            scratch_shapes=[pltpu.VMEM((BQ, D), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+        )(*args)[0]
 
     # dkv grid is (b, jk, i): q-indexed rows use the INNER index i
     def kv_side(which):
@@ -361,23 +380,28 @@ def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
             (1, 1, BK), lambda b, jk, i: (b // H, 0, jk)))
         args.append(mask2[:, None, :].astype(f32))
     kvspec = pl.BlockSpec((1, BK, D), lambda b, jk, i: (b, jk, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_body, causal, masked, scale, BQ, BK),
-        grid=(BH, T // BK, T // BQ),
-        in_specs=in_specs,
-        out_specs=[kvspec, kvspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
-                   jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((BK, D), f32),
-                        pltpu.VMEM((BK, D), f32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope(SCOPE):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_body, causal, masked, scale, BQ, BK),
+            name=DKV_NAME,
+            grid=(BH, T // BK, T // BQ),
+            in_specs=in_specs,
+            out_specs=[kvspec, kvspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
+                       jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
+            scratch_shapes=[pltpu.VMEM((BK, D), f32),
+                            pltpu.VMEM((BK, D), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+        )(*args)
     return dq, dk, dv
 
 
 # ------------------------------------------------- ring-hop carry kernel
+FWD_CARRY_NAME = "flash_attention_fwd_carry"
+
+
 def _fwd_carry_body(causal, scale, BQ, BK, *refs):
     """One ring hop's local block, CARRY-EMITTING: the online-softmax
     state (acc, m, l) enters as kernel inputs and leaves raw (no
@@ -441,21 +465,23 @@ def flash_block_update(acc, m, l, q3, k3, v3, *, causal: bool,
     qspec = pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0))
     lspec = pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0))
-    return pl.pallas_call(
-        functools.partial(_fwd_carry_body, causal, scale, BQ, BK),
-        grid=grid,
-        in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
-        out_specs=[qspec, lspec, lspec],
-        out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), f32),
-                   jax.ShapeDtypeStruct((BH, Tq, 128), f32),
-                   jax.ShapeDtypeStruct((BH, Tq, 128), f32)],
-        scratch_shapes=[pltpu.VMEM((BQ, D), f32),
-                        pltpu.VMEM((BQ, 128), f32),
-                        pltpu.VMEM((BQ, 128), f32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(q3, k3, v3, acc, m, l)
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            functools.partial(_fwd_carry_body, causal, scale, BQ, BK),
+            name=FWD_CARRY_NAME,
+            grid=grid,
+            in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
+            out_specs=[qspec, lspec, lspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), f32),
+                       jax.ShapeDtypeStruct((BH, Tq, 128), f32),
+                       jax.ShapeDtypeStruct((BH, Tq, 128), f32)],
+            scratch_shapes=[pltpu.VMEM((BQ, D), f32),
+                            pltpu.VMEM((BQ, 128), f32),
+                            pltpu.VMEM((BQ, 128), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=_interpret(),
+        )(q3, k3, v3, acc, m, l)
 
 
 def flash_block_bwd(q3, k3, v3, o3, lse, do3, *, causal: bool,

@@ -67,6 +67,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# the kernel's name in the compiled program and in a device trace (an
+# outer scope takes a transformation's wrapping: see ops/pallas_attention.py)
+SCOPE = "compression"
+KERNEL_NAME = "threshold_encode"
+
+
 def _encode_kernel(r_ref, signs_ref, res_ref, *, threshold):
     """One block: threshold compare + sign-pack + residual update, all in
     VMEM registers — the int8 sign map and the new residual are the only
@@ -93,16 +99,18 @@ def threshold_encode_pallas(residual: jnp.ndarray, threshold: float
     n = residual.shape[0]
     grid = (pl.cdiv(n, _BLOCK),)
     kernel = functools.partial(_encode_kernel, threshold=float(threshold))
-    signs, new_res = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,)),
-                   pl.BlockSpec((_BLOCK,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int8),
-                   jax.ShapeDtypeStruct((n,), residual.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(residual)
+    with jax.named_scope(SCOPE):
+        signs, new_res = pl.pallas_call(
+            kernel,
+            name=KERNEL_NAME,
+            grid=grid,
+            in_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,))],
+            out_specs=[pl.BlockSpec((_BLOCK,), lambda i: (i,)),
+                       pl.BlockSpec((_BLOCK,), lambda i: (i,))],
+            out_shape=[jax.ShapeDtypeStruct((n,), jnp.int8),
+                       jax.ShapeDtypeStruct((n,), residual.dtype)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=_interpret(),
+        )(residual)
     return signs, new_res

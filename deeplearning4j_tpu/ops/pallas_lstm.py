@@ -81,6 +81,12 @@ def _interpret() -> bool:
 
 
 # ------------------------------------------------------------------ forward
+# the kernel's name in the compiled program and in a device trace (an
+# outer scope takes a transformation's wrapping: see ops/pallas_attention.py)
+SCOPE = "fused_lstm"
+FWD_NAME = "fused_lstm_fwd"
+
+
 def _fwd_body(peephole, masked, x_ref, r_ref, h0_ref, c0_ref, *rest):
     if masked:
         m_ref, rest = rest[0], rest[1:]
@@ -171,19 +177,24 @@ def _fwd_call(x_proj, h0, c0, R, mask, peep=None):
     if peep is not None:
         in_specs += [peep_spec()] * 3
         args += [p.reshape(1, H) for p in peep]
-    return pl.pallas_call(
-        functools.partial(_fwd_body, peep is not None, mask is not None),
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=[step_block(H), step_block(H4), step_block(H),
-                   step_block(H), step_block(H), const(), const()],
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((B, H), f32), pltpu.VMEM((B, H), f32)],
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            functools.partial(_fwd_body, peep is not None, mask is not None),
+            name=FWD_NAME,
+            grid=(T,),
+            in_specs=in_specs,
+            out_specs=[step_block(H), step_block(H4), step_block(H),
+                       step_block(H), step_block(H), const(), const()],
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((B, H), f32), pltpu.VMEM((B, H), f32)],
+            interpret=_interpret(),
+        )(*args)
 
 
 # ----------------------------------------------------------------- backward
+BWD_NAME = "fused_lstm_bwd"
+
+
 def _bwd_body(peephole, masked, gates_ref, cs_ref, cprev_ref, hprev_ref,
               dhs_ref, r_ref, dhT_ref, dcT_ref, *rest):
     if masked:
@@ -309,15 +320,17 @@ def _bwd_call(gates, cs, c_prev, h_prev, dhs, R, dhT, dcT, mask, peep=None):
         out_shape += [jax.ShapeDtypeStruct((1, H), io)] * 3  # dpi dpf dpo
         out_specs += [peep_spec()] * 3
         scratch += [pltpu.VMEM((1, H), f32)] * 3
-    return pl.pallas_call(
-        functools.partial(_bwd_body, peep is not None, mask is not None),
-        grid=(T,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=_interpret(),
-    )(*args)
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            functools.partial(_bwd_body, peep is not None, mask is not None),
+            name=BWD_NAME,
+            grid=(T,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=_interpret(),
+        )(*args)
 
 
 # -------------------------------------------------------------- custom VJP

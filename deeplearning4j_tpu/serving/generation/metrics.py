@@ -20,6 +20,10 @@ from ...telemetry import get_registry
 from ...telemetry.registry import _percentile
 
 
+# loop phase (scheduler.py) -> its histogram and snapshot key
+_PHASE_METRIC = {"admit_batch": "admit_ms", "emit": "emit_ms"}
+
+
 class GenerationMetrics:
     def __init__(self, window: int = 4096, name: str = "default",
                  registry=None):
@@ -29,6 +33,8 @@ class GenerationMetrics:
         self._ttft_ms = deque(maxlen=window)
         self._step_ms = deque(maxlen=window)
         self._tok_t = deque(maxlen=window)       # emission timestamps
+        self._queue_wait_ms = deque(maxlen=window)
+        self._phase_ms = {p: deque(maxlen=window) for p in _PHASE_METRIC}
         self.requests = 0
         self.tokens_out = 0
         self.prefills = 0
@@ -73,6 +79,30 @@ class GenerationMetrics:
         reg = self.registry
         if reg.enabled:
             reg.counter(f"generation.{self.name}.requests").inc()
+
+    def record_admission(self, queue_ms: float) -> None:
+        """One request's wait from submission to its slot."""
+        with self._lock:
+            self._queue_wait_ms.append(queue_ms)
+        reg = self.registry
+        if reg.enabled:
+            reg.histogram(
+                f"generation.{self.name}.queue_wait_ms").observe(queue_ms)
+
+    def record_phase(self, phase: str, ms: float) -> None:
+        """A host phase of the loop between two program calls: the
+        admission pass (``admit_batch`` -> ``admit_ms``) or the emission
+        after a step (``emit`` -> ``emit_ms``). The loop's idle wait has
+        its trace event only."""
+        ring = self._phase_ms.get(phase)
+        if ring is None:
+            return
+        with self._lock:
+            ring.append(ms)
+        reg = self.registry
+        if reg.enabled:
+            reg.histogram(f"generation.{self.name}."
+                          f"{_PHASE_METRIC[phase]}").observe(ms)
 
     def record_prefill(self, rows: int, ttft_ms_per_row,
                        emitted: int = 0) -> None:
@@ -300,6 +330,9 @@ class GenerationMetrics:
             ttft_c = sorted(self._ttft_cached_ms)
             step = sorted(self._step_ms)
             verify = sorted(self._verify_ms)
+            host = {"queue_wait_ms": sorted(self._queue_wait_ms)}
+            for phase, ring in self._phase_ms.items():
+                host[_PHASE_METRIC[phase]] = sorted(ring)
             # occupancy over BOTH step kinds: a speculation-saturated
             # engine advances slots through verify windows, not plain
             # decode steps — counting only the latter read near-zero
@@ -319,6 +352,11 @@ class GenerationMetrics:
                             "p99": round(_percentile(ttft, 0.99), 3)},
                 "decode_step_ms": {"p50": round(_percentile(step, 0.50), 3),
                                    "p99": round(_percentile(step, 0.99), 3)},
+                # host side of the loop: a request's wait for its slot, an
+                # admission pass, the emission after a step
+                **{k: {"p50": round(_percentile(v, 0.50), 3),
+                       "p99": round(_percentile(v, 0.99), 3)}
+                   for k, v in host.items()},
                 "slot_occupancy": round(occ, 4),
                 "tokens_per_sec_recent": self._recent_tokens_per_sec(now),
                 "finished": dict(self.finished),

@@ -37,6 +37,7 @@ from ...models.decode import LSTMDecodeSpec, TransformerDecodeSpec
 from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
                                          model_axis_size, per_replica_bytes,
                                          shard_params)
+from ...telemetry import span
 from ..programs import _arch_key, _tree_signature
 from .kvcache import (PagedStore, QuantSimStore, make_pools,
                       prefill_scatter)
@@ -136,6 +137,20 @@ class GenerationConfig:
 # donation, the CPU included, so the test suite runs the same aliasing the
 # chip does.
 _DONATE_CACHE = (2,)
+
+
+def _launch_and_read(program: str, exe, *args):
+    """Call a compiled executable and read its FIRST result back, the two
+    halves of the blocking ``generation.prefill`` / ``decode_step`` /
+    ``verify`` span the caller holds open: ``generation.dispatch`` is the
+    host-to-device transfer of the arguments and the launch (the call
+    returns with the results still pending), ``generation.readback`` the
+    ``np.asarray`` that blocks until the device has them."""
+    with span("generation.dispatch", program=program):
+        first, *rest = exe(*args)
+    with span("generation.readback", program=program):
+        first = np.asarray(first)
+    return (first, *rest)
 
 
 class GenerationProgramSet:
@@ -678,9 +693,9 @@ class GenerationProgramSet:
                 f"no warmed prefill program for (batch={P}, rung={L}) — "
                 f"call warm() before serving (warmed: "
                 f"{sorted(k for k in self._compiled if k[0] == 'prefill')})")
-        tok, cache, key = exe(self.params, self.state, cache, tokens,
-                              lengths, tables, slots, key, temp, topk)
-        return np.asarray(tok), cache, key
+        return _launch_and_read("prefill", exe, self.params, self.state,
+                                cache, tokens, lengths, tables, slots, key,
+                                temp, topk)
 
     def run_decode(self, cache, tokens, pos, tables, active, key, temp,
                    topk):
@@ -690,9 +705,9 @@ class GenerationProgramSet:
             from ..errors import ServingError
             raise ServingError("no warmed decode program — call warm() "
                                "before serving")
-        tok, cache, key = exe(self.params, self.state, cache, tokens, pos,
-                              tables, active, key, temp, topk)
-        return np.asarray(tok), cache, key
+        return _launch_and_read("decode", exe, self.params, self.state,
+                                cache, tokens, pos, tables, active, key,
+                                temp, topk)
 
     def _exe(self, key):
         exe = self._compiled.get(key)
@@ -739,9 +754,9 @@ class GenerationProgramSet:
     def run_verify(self, cache, feeds, pos, tables, active):
         """One batched target pass over [S, k+1] fed tokens. Returns
         (greedy targets np [S,k+1], cache')."""
-        tgt, cache = self._exe(("verify",))(self.params, self.state, cache,
-                                            feeds, pos, tables, active)
-        return np.asarray(tgt), cache
+        return _launch_and_read("verify", self._exe(("verify",)),
+                                self.params, self.state, cache, feeds, pos,
+                                tables, active)
 
     # --------------------------------------------------------------- hot-swap
     def with_params_from(self, net, draft_net=None) -> "GenerationProgramSet":

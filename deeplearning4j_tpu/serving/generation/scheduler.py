@@ -10,6 +10,12 @@ One dispatch thread per model owns the decode loop:
               slots (stop token / max_tokens / deadline / cancel), which
               frees their cache blocks for the next admission
 
+The loop's host phases between the blocking program spans are recorded as
+complete events of category ``phase`` (``generation.admit_batch``,
+``generation.emit``, ``generation.idle_wait``): not ``span``, because a
+reader that lines the device's clock up with the host's does so on spans
+that BLOCK on the device, and these cover the rest of the loop.
+
 Admission happens at step boundaries only — a new request never stalls
 in-flight decode, it just lands in the next step's batch (freed slots are
 backfilled from the queue; idle slots ride along masked). All device work
@@ -33,7 +39,7 @@ from typing import Dict, List, Optional, Sequence, Set
 
 import numpy as np
 
-from ...telemetry import RecompileDetector, span
+from ...telemetry import RecompileDetector, record_external_span, span
 from ...telemetry.flightrec import get_flight_recorder
 from ...telemetry.tracecontext import current_trace_id, event
 from ..errors import (BlockPoolExhaustedError, DeadlineExceededError,
@@ -306,16 +312,25 @@ class ModelRuntime:
     def _loop(self):
         if self._det is not None:
             self._det.__enter__()
+        idle_t0 = None
         try:
             while True:
                 with self._cond:
                     if self._stopped:
                         break
                     if not self._queue and not self._slot_req:
+                        # one event per idle PERIOD, not per 20 ms wake-up:
+                        # an idle engine must not fill the trace ring
+                        if idle_t0 is None:
+                            idle_t0 = time.perf_counter()
                         self._cond.wait(0.02)
                         continue
+                if idle_t0 is not None:
+                    self._phase("idle_wait", idle_t0)
+                    idle_t0 = None
                 try:
-                    self._admit()
+                    if self._queue:
+                        self._admit()
                     self._step()
                 except Exception as e:       # defensive: nobody may hang
                     self._fail_all(e)
@@ -323,6 +338,17 @@ class ModelRuntime:
             if self._det is not None:
                 self._det.__exit__(None, None, None)
             self._shutdown_flush()
+
+    def _phase(self, name: str, t0: float) -> float:
+        """Record the host phase of the loop that began at ``t0``
+        (``time.perf_counter``) and ends now; returns now, so that phases
+        chain."""
+        now = time.perf_counter()
+        ms = (now - t0) * 1e3
+        record_external_span("generation." + name, ms, cat="phase",
+                             model=self.name)
+        self.metrics.record_phase(name, ms)
+        return now
 
     def _cohort_for_admission(self) -> _Cohort:
         ps = self.active_ps
@@ -379,6 +405,14 @@ class ModelRuntime:
         r.matched_tokens = matched
 
     def _admit(self):
+        """One admission pass; what of it is host work between program
+        calls is recorded as ``generation.admit_batch`` phases."""
+        self._phase("admit_batch", self._admit_pass(time.perf_counter()))
+
+    def _admit_pass(self, t_phase: float) -> float:
+        """``t_phase`` is where the pass began; returns the clock from
+        which its host work is not recorded yet (a prefill on the way
+        records the part before its launch and its own emission)."""
         cfg = self.config
         cands: List[_GenRequest] = []
         now = time.monotonic()
@@ -399,7 +433,7 @@ class ModelRuntime:
                     keep.append(r)
             self._queue = keep
             if not self._queue or not self._slots_free:
-                return
+                return t_phase
             coh = self._cohort_for_admission()
             max_p = cfg.prefill_batches[-1]
             blk = cfg.block_len
@@ -431,19 +465,21 @@ class ModelRuntime:
                     self._setup_blocks(coh, r)
                 cands.append(r)
         if not cands:
-            return
+            return t_phase
+        now = time.monotonic()
         for r in cands:
-            if r.trace_id is not None:
-                # admission: queue -> slot handoff, stamped per request
-                # (the loop thread has no context of its own)
-                event("generation.admit", trace_id=r.trace_id,
-                      model=self.name, slot=r.slot,
-                      queue_ms=round((time.monotonic() - r.enqueue_t) * 1e3,
-                                     3))
+            # admission: queue -> slot handoff, stamped per request (the
+            # loop thread has no context of its own); the wait is taken on
+            # one clock by the one thread that knows both ends
+            queue_ms = (now - r.enqueue_t) * 1e3
+            event("generation.admit", trace_id=r.trace_id, model=self.name,
+                  slot=r.slot, prompt_len=len(r.prompt),
+                  queue_ms=round(queue_ms, 3))
+            self.metrics.record_admission(queue_ms)
         hits = [r for r in cands if r.matched_tokens]
         misses = [r for r in cands if not r.matched_tokens]
         if misses:
-            self._prefill_misses(coh, misses)
+            t_phase = self._prefill_misses(coh, misses, t_phase)
         if hits:
             self._admit_hits(coh, hits)
         if coh.ps.spec_k:
@@ -454,8 +490,14 @@ class ModelRuntime:
                 self._draft_prefill(coh, spec_cands)
         if coh.prefix is not None:
             self.metrics.set_prefix_gauges(coh.prefix.stats())
+        return t_phase
 
-    def _prefill_misses(self, coh: _Cohort, cands: List["_GenRequest"]):
+    def _prefill_misses(self, coh: _Cohort, cands: List["_GenRequest"],
+                        t_phase: float) -> float:
+        """One batched prefill for the admitted misses. ``t_phase`` is
+        where the admission pass began: the pass up to the launch is one
+        ``generation.admit_batch`` phase, the emission after the read-back
+        one ``generation.emit``; returns the clock at which that ended."""
         cfg = self.config
         S, mb = cfg.decode_slots, cfg.blocks_per_seq
         P = cfg.prefill_rung(len(cands))
@@ -474,11 +516,14 @@ class ModelRuntime:
             slots[i] = r.slot
             temp[i] = r.temperature
             topk[i] = r.top_k
+        self._phase("admit_batch", t_phase)
         with span("generation.prefill", model=self.name, batch=len(cands),
-                  rung=L):
+                  rung=L, rows=P, tokens=int(lengths[:len(cands)].sum()),
+                  padded_tokens=P * L):
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
                 temp, topk)
+        t_phase = time.perf_counter()
         now = time.monotonic()
         emitted = 0
         for i, r in enumerate(cands):
@@ -510,6 +555,7 @@ class ModelRuntime:
         self.metrics.record_prefill(
             len(cands), [(now - r.enqueue_t) * 1e3 for r in cands],
             emitted)
+        return self._phase("emit", t_phase)
 
     def _admit_hits(self, coh: _Cohort, hits: List["_GenRequest"]):
         """Cache-hit admission: NO target prefill. The sequence's table
@@ -610,13 +656,20 @@ class ModelRuntime:
         S = cfg.decode_slots
         mask = np.zeros(S, np.bool_)
         mask[live] = True
-        t0 = time.perf_counter()
+        attrs = {}
+        if coh.ps.adapter == "paged":
+            # what the step attends to against what its gather reads: each
+            # live slot's valid positions (this step's included) and, per
+            # layer, every slot's whole table
+            attrs = {"live_tokens": int(self._pos[live].sum()) + len(live),
+                     "gathered_tokens": S * cfg.capacity}
         with span("generation.decode_step", model=self.name,
-                  slots=len(live)):
+                  slots=len(live), **attrs) as sp:
             nxt, coh.cache, self._key = coh.ps.run_decode(
                 coh.cache, self._tokens, self._pos, coh.tables, mask,
                 self._key, self._temp, self._topk)
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        t_phase = time.perf_counter()
+        dt_ms = sp.dur_ms
         now = time.monotonic()
         emitted = 0
         for s in live:
@@ -639,6 +692,7 @@ class ModelRuntime:
             blocks_used=coh.allocator.used_blocks,
             blocks_total=coh.allocator.total_usable,
             queue_depth=len(self._queue))
+        self._phase("emit", t_phase)
 
     def _replay_advance(self, coh: _Cohort, r: "_GenRequest", sampled: int,
                         now: float) -> int:
@@ -689,9 +743,8 @@ class ModelRuntime:
         S, k = cfg.decode_slots, coh.ps.spec_k
         mask = np.zeros(S, np.bool_)
         mask[specs] = True
-        t0 = time.perf_counter()
         with span("generation.verify", model=self.name, slots=len(specs),
-                  k=k):
+                  k=k) as sp:
             props, aux = coh.ps.run_propose(
                 coh.draft_cache, self._tokens, self._pos, mask)
             if coh.ps.draft_adapter == "dense":
@@ -700,7 +753,8 @@ class ModelRuntime:
                 [self._tokens[:, None], props], axis=1).astype(np.int32)
             targets, coh.cache = coh.ps.run_verify(
                 coh.cache, feeds, self._pos, coh.tables, mask)
-        dt_ms = (time.perf_counter() - t0) * 1e3
+        t_phase = time.perf_counter()
+        dt_ms = sp.dur_ms
         counts, emitted_toks = accept_greedy(props, targets)
         now = time.monotonic()
         emitted = 0
@@ -737,6 +791,7 @@ class ModelRuntime:
             blocks_used=coh.allocator.used_blocks,
             blocks_total=coh.allocator.total_usable,
             queue_depth=len(self._queue))
+        self._phase("emit", t_phase)
 
     def _check_quiesce(self):
         """Block-accounting invariant at quiesce (no in-flight requests):

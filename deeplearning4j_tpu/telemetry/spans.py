@@ -14,12 +14,20 @@ of dict writes — it never touches a device value, so instrumenting the
 dispatch loop cannot serialize it (the tier-1 sync-freedom test pins
 this). When the registry is disabled, ``span()`` returns a shared no-op
 context manager: one attribute check, zero allocation.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` named by its path:
+while a profile is being taken (``jax.profiler.start_trace``, an
+operator's capture) the spans are events of the host plane of the same
+``.xplane.pb``, on the profiler's clock, beside the runtime's launch
+events. With no profile active the annotation is a flag check.
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .registry import MetricsRegistry, get_registry
 from .tracecontext import current_trace_id
@@ -42,10 +50,12 @@ def _stack() -> List["Span"]:
 
 
 class _NoopSpan:
-    """Shared do-nothing span for a disabled registry."""
+    """Shared do-nothing span for a disabled registry: it times nothing,
+    so ``dur_ms`` stays 0.0."""
 
     __slots__ = ()
     name = path = "<disabled>"
+    dur_ms = 0.0
 
     def __enter__(self):
         return self
@@ -70,16 +80,20 @@ class Span:
     """One timed, attributed region. Context-manager use is the norm;
     ``start()``/``end()`` exist for regions that do not nest lexically
     (e.g. ProfilerListener's capture window opens in one listener callback
-    and closes in a later one)."""
+    and closes in a later one). ``dur_ms`` holds the duration once the
+    span has ended, for a caller that feeds a metric of its own from the
+    interval the span already timed."""
 
-    __slots__ = ("name", "attrs", "path", "registry", "_t0", "_tid",
-                 "_ended", "_trace_id")
+    __slots__ = ("name", "attrs", "path", "registry", "dur_ms", "_t0",
+                 "_tid", "_ended", "_trace_id", "_annotation")
 
     def __init__(self, name: str, registry: MetricsRegistry, attrs: dict):
         self.name = name
         self.attrs = attrs
         self.registry = registry
         self.path = name          # parent path resolved at start()
+        self.dur_ms = 0.0
+        self._annotation = None
         self._t0 = 0
         self._tid = 0
         self._ended = False
@@ -102,6 +116,8 @@ class Span:
         # closed event is keyed by trace id alongside its span path
         self._trace_id = current_trace_id()
         self._tid = threading.get_ident() & 0xFFFFFFFF
+        self._annotation = TraceAnnotation(self.path)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -110,6 +126,8 @@ class Span:
         if self._ended:
             return self
         self._ended = True
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         stack = _stack()
         # the common case is LIFO exit; tolerate out-of-order manual end()
         if stack and stack[-1] is self:
@@ -118,6 +136,7 @@ class Span:
             while stack and stack.pop() is not self:
                 pass
         dur_ns = t1 - self._t0
+        self.dur_ms = dur_ns / 1e6
         reg = self.registry
         if reg.enabled:
             args = self.attrs
@@ -128,7 +147,7 @@ class Span:
                               "ts": (self._t0 + _EPOCH_NS) // 1000,
                               "dur": dur_ns // 1000,
                               "pid": 1, "tid": self._tid, "args": args})
-            reg.histogram("span." + self.name + "_ms").observe(dur_ns / 1e6)
+            reg.histogram("span." + self.name + "_ms").observe(self.dur_ms)
         return self
 
     def set_attr(self, key: str, value) -> None:

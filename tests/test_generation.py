@@ -591,3 +591,116 @@ def test_generation_hammer_soak():
         assert eng.metrics()["lm"]["tokens_out"] > 500
     finally:
         eng.stop()
+
+
+# ------------------------------------- what the loop says about itself
+# (ISSUE 24: the program measures each of its layers where the work
+# happens, under the names the benchmark's readers key on)
+@pytest.fixture(scope="module")
+def pair_events(shared_lm):
+    """The trace events of one hand-made pair of requests, prompts of 5
+    and 11 tokens and 3 tokens out each, sent WITHOUT a trace context and
+    admitted in one pass (the loop cannot take its lock between the two
+    submissions): one prefill at rows 2 x rung 64, then two decode steps.
+    The engine has sat idle first, so the pass ends an idle period."""
+    _, _, eng = shared_lm
+    rt = eng._get("lm")
+    rng = np.random.default_rng(2424)
+    a, b = (rng.integers(1, 53, size=n).tolist() for n in (5, 11))
+    time.sleep(0.1)                       # five idle wake-ups of the loop
+    reg = get_registry()
+    seq0 = reg.last_seq
+    with rt._cond:
+        streams = [eng.generate(p, max_tokens=3, stream=True) for p in (a, b)]
+    assert [len(s.result()[0]) for s in streams] == [3, 3]
+    while rt.in_flight:                   # the emit after the last step
+        time.sleep(0.005)
+    time.sleep(0.05)
+    events = reg.trace_events_since(seq0)
+    return [e for e in events if e["name"].startswith("generation.")]
+
+
+def _named(events, name, **match):
+    return [e for e in events if e["name"] == name
+            and all(e.get(k) == v for k, v in match.items())]
+
+
+@pytest.mark.parametrize("parent,program,calls", [
+    ("generation.prefill", "prefill", 1),
+    ("generation.decode_step", "decode", 2)])
+def test_program_spans_split_into_dispatch_and_readback(pair_events, parent,
+                                                        program, calls):
+    outer = _named(pair_events, parent, ph="X", cat="span")
+    assert len(outer) == calls
+    for child in ("generation.dispatch", "generation.readback"):
+        inner = [e for e in _named(pair_events, child, ph="X", cat="span")
+                 if e["args"]["program"] == program]
+        assert len(inner) == calls
+        for o, i in zip(outer, inner):
+            # nested in the parent: by path, and in time
+            assert i["args"]["path"] == parent + "/" + child
+            assert o["ts"] <= i["ts"]
+            assert i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1
+    # launch first, then the blocking read
+    d, r = (_named(pair_events, n)[0] for n in
+            ("generation.dispatch", "generation.readback"))
+    assert d["ts"] + d["dur"] <= r["ts"] + 1
+
+
+@pytest.mark.parametrize("phase,at_least", [
+    ("generation.admit_batch", 1), ("generation.emit", 3),
+    ("generation.idle_wait", 1)])
+def test_loop_host_phases_are_phase_events(pair_events, phase, at_least):
+    """Complete events of category ``phase``, never ``span``: the
+    benchmark hands every ``span`` to a clock calibration that needs spans
+    which block on the device."""
+    found = _named(pair_events, phase)
+    assert len(found) >= at_least
+    assert all(e["ph"] == "X" and e["cat"] == "phase" and e["dur"] >= 0
+               and e["args"]["model"] == "lm" for e in found)
+    if phase == "generation.idle_wait":
+        # one event for the whole idle period, not one per 20 ms wake-up
+        assert len(found) == 1 and found[0]["dur"] >= 90_000
+    else:
+        # a phase never overlaps a program call
+        for p in found:
+            for s in _named(pair_events, "generation.dispatch") + \
+                    _named(pair_events, "generation.readback"):
+                assert p["ts"] + p["dur"] <= s["ts"] + 1 \
+                    or s["ts"] + s["dur"] <= p["ts"] + 1
+
+
+def test_admit_event_for_a_request_without_trace_context(pair_events):
+    admits = _named(pair_events, "generation.admit", ph="i")
+    assert sorted(e["args"]["prompt_len"] for e in admits) == [5, 11]
+    for e in admits:
+        assert e["args"]["queue_ms"] >= 0.0
+        assert "trace_id" not in e["args"]
+        assert e["args"]["slot"] in range(4)
+    # the per-token heartbeat stays with traced requests only
+    assert not _named(pair_events, "generation.decode_step", ph="i")
+
+
+def test_step_and_prefill_spans_count_their_tokens(pair_events):
+    (fill,) = _named(pair_events, "generation.prefill", ph="X")
+    a = fill["args"]
+    assert (a["rows"], a["tokens"], a["padded_tokens"]) == (2, 16, 2 * 64)
+    assert (a["batch"], a["rung"]) == (2, 64)         # as before
+    steps = _named(pair_events, "generation.decode_step", ph="X")
+    # positions valid in the cache, this step's included: (5+1)+(11+1),
+    # then one more each; the gather reads 4 slots x capacity 64 a layer
+    assert [s["args"]["live_tokens"] for s in steps] == [18, 20]
+    assert [s["args"]["gathered_tokens"] for s in steps] == [256, 256]
+    assert all(s["args"]["slots"] == 2 for s in steps)
+
+
+def test_metrics_show_queue_wait_and_host_phases(shared_lm, pair_events):
+    _, _, eng = shared_lm
+    snap = eng.metrics()["lm"]
+    for key in ("queue_wait_ms", "admit_ms", "emit_ms"):
+        assert set(snap[key]) == {"p50", "p99"} and snap[key]["p99"] >= 0.0
+    hists = get_registry().snapshot()["histograms"]
+    for key in ("queue_wait_ms", "admit_ms", "emit_ms", "decode_step_ms"):
+        assert hists[f"generation.lm.{key}"]["count"] >= 2
+    # the step histogram is fed from the span's own interval
+    assert hists["span.generation.decode_step_ms"]["count"] >= 2

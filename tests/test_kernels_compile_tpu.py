@@ -8,6 +8,8 @@ attached, so the real compiler runs here: "the compiler refuses it" is a
 tier-1 failure instead of a chip-budget discovery. This proves COMPILES,
 not RUNS — ``chip_smoke.py`` runs the parity pins on the chip.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,14 +50,26 @@ def _no_interpreter(monkeypatch):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
-def _custom_calls(sharding, fn, *avals) -> int:
+def _compiled_text(sharding, fn, *avals) -> str:
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
             for shape, dtype in avals]
     # conftest turns x64 on for the gradient checks; the chip runs with it
     # off, and under x64 the index maps' literal 0s lower as i64
     with jax.enable_x64(False):
-        text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _custom_calls(sharding, fn, *avals) -> int:
+    return _compiled_text(sharding, fn, *avals).count(
+        'custom_call_target="tpu_custom_call"')
+
+
+def _instruction_names(text: str) -> set:
+    """Names of the compiled program's custom-call instructions, without
+    their instance numbers (what a device trace's ``XLA Ops`` line
+    carries and the benchmark's ``op_family`` keys on)."""
+    return {re.sub(r"[.\-_]?\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
 
 
 def test_every_registered_kernel_is_compiled_here():
@@ -132,3 +146,64 @@ def test_threshold_encode_compiles(v5e):
     assert _custom_calls(
         v5e, lambda r: pallas_compression.threshold_encode_pallas(r, 1e-3),
         ((n,), f32)) == 1
+
+
+def _flash_grad_text(v5e):
+    def loss(q, k, v):
+        o = pallas_attention.flash_attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(f32))
+    qkv = ((8, 16, 1024, 64), bf16)          # the benchmark's train cell
+    return _compiled_text(v5e, jax.grad(loss, argnums=(0, 1, 2)),
+                          qkv, qkv, qkv)
+
+
+def _flash_carry_text(v5e):
+    BH, T, D = 4, 256, 64
+    return _compiled_text(
+        v5e, lambda acc, m, l, q, k, v: pallas_attention.flash_block_update(
+            acc, m, l, q, k, v, causal=True, scale=0.125),
+        ((BH, T, D), f32), ((BH, T, 128), f32), ((BH, T, 128), f32),
+        ((BH, T, D), bf16), ((BH, T, D), bf16), ((BH, T, D), bf16))
+
+
+@pytest.mark.parametrize("constant,program", [
+    ("FWD_NAME", _flash_grad_text), ("DQ_NAME", _flash_grad_text),
+    ("DKV_NAME", _flash_grad_text), ("FWD_CARRY_NAME", _flash_carry_text)])
+def test_flash_kernels_carry_their_names_into_the_compiled_program(
+        v5e, constant, program):
+    """The device trace names an operation after its HLO instruction, and
+    ``pallas_call(name=)`` is what names the instruction: the per-kernel
+    roofline shares of the benchmark key on these four constants."""
+    name = getattr(pallas_attention, constant)
+    assert not name[-1].isdigit()      # op_family strips instance numbers
+    names = _instruction_names(program(v5e))
+    assert name in names, names
+    assert not names & {"jvp__", "transpose_jvp___", "fn", "loss"}
+
+
+@pytest.mark.parametrize("module,constant", [
+    (conv, "KERNEL_NAME"), (quantized, "KERNEL_NAME"),
+    (pallas_lstm, "FWD_NAME"), (pallas_lstm, "BWD_NAME"),
+    (pallas_compression, "KERNEL_NAME")])
+def test_the_other_kernels_are_named_too(v5e, module, constant):
+    name = getattr(module, constant)
+    assert not name[-1].isdigit()
+    T, B, H = 4, 8, 128
+
+    def lstm_loss(xp, h0, c0, R):
+        hs, (hT, cT) = pallas_lstm.fused_lstm(xp, h0, c0, R)
+        return jnp.sum(hs) + jnp.sum(hT) + jnp.sum(cT)
+    programs = {
+        conv: (conv.conv1x1_bias_relu, ((2, 4, 4, 128), f32),
+               ((1, 1, 128, 128), f32), ((128,), f32)),
+        quantized: (quantized.int8_matmul_pallas, ((64, 256), i8),
+                    ((256, 256), i8), ((64,), f32), ((256,), f32)),
+        pallas_lstm: (jax.grad(lstm_loss, argnums=(0, 3)),
+                      ((T, B, 4 * H), f32), ((B, H), f32), ((B, H), f32),
+                      ((H, 4 * H), f32)),
+        pallas_compression: (
+            lambda r: pallas_compression.threshold_encode_pallas(r, 1e-3),
+            (((1 << 16) + 777,), f32)),
+    }
+    fn, *avals = programs[module]
+    assert name in _instruction_names(_compiled_text(v5e, fn, *avals))

@@ -210,6 +210,77 @@ def test_disabled_registry_is_near_noop(fresh_registry):
     reg.enabled = True
 
 
+# ------------------------------------- spans on the profiler's own clock
+def _host_plane_event_names(trace_dir) -> set:
+    """Names of the host planes' events in the ``.xplane.pb`` a
+    ``jax.profiler`` capture wrote under ``trace_dir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    return {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def _profiled(trace_dir, body):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_plane_event_names(trace_dir)
+
+
+def test_spans_are_events_of_the_profilers_host_plane(fresh_registry,
+                                                      tmp_path):
+    """While a profile is being taken every span is an annotation named
+    by its PATH, in the same file as the runtime's own events."""
+    def body():
+        with span("outer24", k=1):
+            with span("inner24"):
+                jnp.ones((8, 8)).sum().block_until_ready()
+        s = span("manual24").start()
+        s.end()
+    names = _profiled(tmp_path, body)
+    assert {"outer24", "outer24/inner24", "manual24"} <= names
+    # and the registry's own record is what it was
+    assert [e["args"]["path"] for e in fresh_registry.trace_events()
+            if e["cat"] == "span"] == ["outer24/inner24", "outer24",
+                                       "manual24"]
+
+
+def test_disabled_registry_opens_no_annotation(fresh_registry, tmp_path):
+    fresh_registry.enabled = False
+
+    def body():
+        with span("silent24"):
+            with jax.profiler.TraceAnnotation("control24"):
+                pass
+    names = _profiled(tmp_path, body)
+    fresh_registry.enabled = True
+    assert "control24" in names          # the capture itself works
+    assert not [n for n in names if "silent24" in n]
+
+
+def test_span_keeps_its_duration_after_end(fresh_registry):
+    with span("timed24") as s:
+        pass
+    (ev,) = fresh_registry.trace_events()
+    assert s.dur_ms >= 0.0 and abs(ev["dur"] - s.dur_ms * 1000) <= 1.0
+    h = fresh_registry.snapshot()["histograms"]["span.timed24_ms"]
+    assert h["count"] == 1 and h["sum"] == pytest.approx(s.dur_ms, abs=1e-6)
+    fresh_registry.enabled = False
+    with span("timed24") as off:
+        pass
+    fresh_registry.enabled = True
+    assert off.dur_ms == 0.0             # the shared no-op times nothing
+
+
 # ------------------------------------------------------------------- spans
 def test_span_nesting_and_paths(fresh_registry):
     reg = fresh_registry

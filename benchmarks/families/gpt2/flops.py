@@ -6,13 +6,19 @@ from __future__ import annotations
 from typing import Dict
 
 
+def head_flops_per_token(cfg: Dict) -> float:
+    """The vocabulary projection's part of ``forward_flops_per_token``: a
+    prefill needs it at a prompt's last position only."""
+    return 2.0 * cfg["n_embd"] * cfg["vocab_size"]
+
+
 def forward_flops_per_token(cfg: Dict, context: float) -> float:
     """Matmul FLOPs of one token's forward pass attending to ``context``
     positions: 2 per multiply-add."""
-    d, L, V = cfg["n_embd"], cfg["n_layer"], cfg["vocab_size"]
-    weights = L * 12 * d * d + d * V
+    d, L = cfg["n_embd"], cfg["n_layer"]
+    blocks = 2.0 * L * 12 * d * d
     attention = L * 4 * d * context          # QK^T and PV
-    return 2.0 * weights + attention
+    return blocks + head_flops_per_token(cfg) + attention
 
 
 def train_flops_per_sample(cfg: Dict, seq_len: int) -> float:

@@ -168,7 +168,6 @@ def run(ctx: Dict) -> Dict:
                    "finished_inside": len(s["done"]),
                    "compiles_in_window": compiles},
         "obs": {"kind": "open_loop", "summary": s, "events": events,
-                "all_requests": requests,
                 "window_perf": (t0, t1_wall), "engine": info, "trace": trace,
                 "peak_bytes": peak, "device": ctx["device"], "traffic": tr,
                 "config": cfg, "seconds": ctx["seconds"]},

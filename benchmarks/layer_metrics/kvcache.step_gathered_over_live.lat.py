@@ -1,9 +1,9 @@
 """Tokens a decode step's gather reads per layer (``gathered_tokens``:
 every slot's whole table) over the tokens the step attends to
 (``live_tokens``: the valid positions of its live slots), both on the
-``generation.decode_step`` span; mean over the window's steps. The outside
-twin, ``kvcache.gathered_over_live.lat``, rebuilds the live tokens from
-the clients' stamps."""
+``generation.decode_step`` span; mean over the window's steps. Its outside
+twin, which reckoned the gather from the engine's shapes, was retired in
+PR 26: it would go on reading 5-7 for a step that gathers nothing."""
 from benchmarks.lib import program_events
 
 

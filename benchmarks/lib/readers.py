@@ -2,7 +2,6 @@
 time, request latencies. A reader that finds nothing to read returns None."""
 from __future__ import annotations
 
-from bisect import bisect_right
 from typing import Dict, List, Optional, Tuple
 
 from benchmarks.lib.stats import percentile
@@ -45,17 +44,6 @@ def tpot_so_far(r: Dict) -> Optional[float]:
     return (st[-1] - st[0]) * 1e3 / (len(st) - 1) if len(st) >= 2 else None
 
 
-def live_tokens_at(requests: List[Dict], t: float) -> int:
-    """Tokens in the cache at clock ``t``: for each request with a first
-    token by then and not yet ended, its prompt plus the tokens stamped."""
-    n = 0
-    for r in requests:
-        st = r["stamps"]
-        if st and st[0] <= t and (r["end"] is None or r["end"] >= t):
-            n += len(r["prompt"]) + bisect_right(st, t)
-    return n
-
-
 def hist_sum_delta(obs: Dict, name: str) -> Optional[Tuple[float, int]]:
     after = obs.get("reg_after", {}).get("histograms", {}).get(name)
     if after is None:
@@ -77,5 +65,4 @@ def peak_hbm_gb(obs: Dict) -> Optional[float]:
 
 
 __all__ = ["percentile", "spans", "span_seconds", "window_seconds",
-           "tpot_so_far", "live_tokens_at", "hist_sum_delta", "idle_pct",
-           "peak_hbm_gb"]
+           "tpot_so_far", "hist_sum_delta", "idle_pct", "peak_hbm_gb"]

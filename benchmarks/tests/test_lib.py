@@ -4,6 +4,7 @@
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import subprocess
@@ -70,20 +71,70 @@ def test_serve_chat_offers_the_same_work_for_twelve_seeds():
 
 
 def test_serve_longprompt_offers_the_same_work_for_twelve_seeds():
+    """Every block holds the same multiset, whatever the seed: the blocks
+    of three seeds in full, the head block of all twelve."""
     from benchmarks.kinds import closed_loop
     tr = _traffic("serve-longprompt")
-    offers, heads = [], []
-    for seed in SEEDS:
+    n = tr["block"]
+    one_block = traffic.offered([{"prompt": [0] * p, "max_tokens": o} for p, o
+                                 in traffic.stratified_pairs(tr["lengths"], n)])
+    for seed in SEEDS[::5]:
         reqs = closed_loop.request_list(tr, np.random.default_rng(seed), 50257)
-        assert len(reqs) == tr["block"] * tr["blocks"]
-        offers.append(traffic.offered(reqs))
-        heads.append(tuple(len(r["prompt"]) for r in reqs[:tr["block"]]))
-        # every block holds the same multiset
-        blocks = [traffic.offered(reqs[i:i + tr["block"]])
-                  for i in range(0, len(reqs), tr["block"])]
-        assert all(b == blocks[0] for b in blocks)
-    assert all(o == offers[0] for o in offers)
+        assert len(reqs) == n * tr["blocks"]
+        assert all(traffic.offered(reqs[i:i + n]) == one_block
+                   for i in range(0, len(reqs), n))
+    heads = []
+    for seed in SEEDS:
+        head = closed_loop.request_list(dict(tr, blocks=1),
+                                        np.random.default_rng(seed), 50257)
+        assert traffic.offered(head) == one_block
+        heads.append(tuple(len(r["prompt"]) for r in head))
     assert len(set(heads)) == len(SEEDS)
+
+
+@pytest.mark.parametrize("seed", [7, 2**31 + 13, 3000000019])
+def test_a_longer_list_starts_with_the_list_it_replaces(seed):
+    """``blocks`` went from 64 to 512 (PR 26): for a given seed the first
+    4,096 requests are the ones the cell sent before, token for token."""
+    from benchmarks.kinds import closed_loop
+    tr = _traffic("serve-longprompt")
+    was = closed_loop.request_list(dict(tr, blocks=64),
+                                   np.random.default_rng(seed), 50257)
+    now = closed_loop.request_list(tr, np.random.default_rng(seed), 50257)
+    assert len(was) == 4096 < len(now)
+    for a, b in zip(was, now):
+        assert a["max_tokens"] == b["max_tokens"]
+        assert np.array_equal(a["prompt"], b["prompt"])
+
+
+def _closed_loop_cells():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    return [pytest.param(w, bench["run_seconds"], id=w["name"])
+            for w in bench["workloads"]
+            if _traffic(w["traffic"])["kind"] == "closed_loop"]
+
+
+@pytest.mark.parametrize("w,run_seconds", _closed_loop_cells())
+def test_a_closed_loop_list_outlasts_what_the_chip_can_prefill(w, run_seconds):
+    """A list that runs out fails ``request_list_outlasted_the_window`` and
+    may not be cycled (the prefix cache would serve a repeated prompt), so it
+    holds more than the chip could take at 100% of its bf16 peak through
+    pre-roll and window: prompts only, causal attention at the half it
+    needs, without the head at positions whose logits nobody reads."""
+    tr = _traffic(w["traffic"])
+    cfg = json.load(open(os.path.join(BENCH, "configs", w["config"],
+                                      "config.json")))
+    flops = importlib.import_module(
+        f"benchmarks.families.{cfg['family']}.flops")
+    head = flops.head_flops_per_token(cfg)
+    pairs = traffic.stratified_pairs(tr["lengths"], tr["block"])
+    per_request = sum(
+        p * (flops.forward_flops_per_token(cfg, (p + 1) / 2.0) - head)
+        for p, _ in pairs) / len(pairs)
+    peak = w["chips"] * max(p["bf16_flops"] for p in peaks.PEAKS.values())
+    most = tr["callers"] + (tr["preroll_s"] + run_seconds) * \
+        peak / per_request
+    assert tr["block"] * tr["blocks"] >= most
 
 
 def test_mid_quantiles_follow_the_distribution():
@@ -237,3 +288,6 @@ def test_benchmark_json_names_files_that_exist():
     for m in bench["per_layer"]:
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
         assert m["moves"] in e2e
+    # and the converse: a retired metric does not leave its reader behind
+    assert {f[:-3] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
+            if f.endswith(".py")} == {m["name"] for m in bench["per_layer"]}

@@ -17,14 +17,14 @@ zoo models:
       whenever ``fused_attention_applicable`` says the shapes allow).
     * ``decode_step`` — one token per sequence through a ``KVStore``
       protocol object (serving/generation/kvcache.py provides the paged
-      implementation). Every op replays the layer objects' own ``apply``
-      math position-wise, and the attention row is the same
-      ``parallel.ring_attention.attention`` softmax the full forward takes,
+      implementation). Every op but attention replays the layer objects'
+      own ``apply`` math position-wise; the attention row is the store's:
+      the same masked softmax over the same keys the full forward takes,
       so greedy decode through the cache is token-for-token identical to
-      naive full recompute. (The bit-for-bit claim holds when the full
-      forward takes the XLA attention path — always true for Tq=1 decode;
-      at flash-eligible prefill shapes on TPU the fused kernel's rounding
-      can differ from the per-row decode in the last ulp.)
+      naive full recompute. (Not bit-for-bit: the paged store's kernel
+      folds the keys page group by page group in an online softmax, which
+      reorders float32 additions, as the flash kernel of a flash-eligible
+      prefill does.)
 - ``LSTMDecodeSpec`` — the recurrent analogue for ``text_generation_lstm``
   MultiLayerNetworks: the "cache" is the fixed-shape per-layer recurrent
   state (no paging needed), prefill is a masked ``lax.scan`` over the padded
@@ -39,7 +39,7 @@ transformer decode is net-new capability.
 """
 from __future__ import annotations
 
-from typing import Any, List, Optional, Protocol, Sequence, Tuple
+from typing import Any, List, Optional, Protocol, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -49,12 +49,15 @@ import numpy as np
 # --------------------------------------------------------------- KV protocol
 class KVStore(Protocol):
     """What ``decode_step`` needs from a cache: write this step's K/V for
-    layer ``i``, read back the full (gathered) K/V context + key mask."""
+    layer ``i``, then attend to the context with the current position
+    already visible. How the context is read is the store's business: the
+    paged store attends to its pages where they lie, a dense store hands
+    its array to ``parallel.ring_attention.attention`` under a key mask."""
 
-    def put_get(self, i: int, k_tok, v_tok) -> Tuple[Any, Any, Any]:
-        """k_tok/v_tok: [B,H,Dh] for the current position. Returns
-        (K [B,H,L,Dh], V [B,H,L,Dh], key_mask [B,L]) with the current
-        position already visible."""
+    def attend(self, i: int, q, k_tok, v_tok) -> Any:
+        """q: [B,H,1,Dh]; k_tok/v_tok: [B,H,Dh] for the current position.
+        Returns the attention output [B,H,1,Dh]. (A window store, for
+        ``decode_window``, takes q [B,H,W,Dh] and k/v [B,W,H,Dh].)"""
         ...
 
 
@@ -66,8 +69,9 @@ def window_attention(q, k, v, row_mask):
     (same scale cast, same -1e30 fill, same softmax axis) but with a
     per-query-row key mask — row ``i`` seeing keys ``<= pos+i`` computes
     the very numbers the one-token ``attention(..., key_mask=)`` row
-    computes, which is what keeps a batched speculative verify
-    token-for-token identical to W sequential decode steps."""
+    computes. The dense-context attention of the int8 KV tier (decode,
+    verify and the fake-quantized prefill alike) and the plain reference
+    the paged kernel is pinned to."""
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * jnp.asarray(scale, q.dtype)
     s = jnp.where(row_mask[:, None, :, :], s, -1e30)
@@ -193,10 +197,9 @@ class TransformerDecodeSpec:
     # ---------------------------------------------------------- decode step
     def decode_step(self, params, state, tokens, pos, store: KVStore):
         """One incremental step: ``tokens`` [B] int ids at positions ``pos``
-        [B]. K/V for the step go through ``store`` (write-then-read), whose
-        gathered context must be position-ordered so attention row ``pos``
-        reproduces the naive causal row bit-for-bit. Returns pre-activation
-        logits [B,V]."""
+        [B]. K/V for the step go through ``store`` (write, then attend), so
+        attention row ``pos`` sees the keys the naive causal row sees.
+        Returns pre-activation logits [B,V]."""
         x = self.embed_tokens(params, tokens[:, None])        # [B,1,d]
         P = self._p(params, "pos")["P"]
         x = x + P[pos][:, None, :]
@@ -212,7 +215,6 @@ class TransformerDecodeSpec:
         return logits[:, 0, :]
 
     def _block_step(self, params, state, i, x, pos, store: KVStore):
-        from ..parallel.ring_attention import attention
         h = x
         y = self._apply(params, state, f"b{i}_ln1", x)        # [B,1,d]
         ap = self._p(params, f"b{i}_attn")
@@ -221,8 +223,7 @@ class TransformerDecodeSpec:
         q = self._heads(y @ ap["Wq"])                          # [B,H,1,Dh]
         k_tok = (y @ ap["Wk"]).reshape(B, self.n_heads, self.head_dim)
         v_tok = (y @ ap["Wv"]).reshape(B, self.n_heads, self.head_dim)
-        K, V, key_mask = store.put_get(i, k_tok, v_tok)
-        out = attention(q, K, V, causal=False, key_mask=key_mask)
+        out = store.attend(i, q, k_tok, v_tok)
         out = out.transpose(0, 2, 1, 3).reshape(B, 1, self.d_model)
         if attn_layer.project_out:
             out = out @ ap["Wo"] + ap["b"]
@@ -238,10 +239,11 @@ class TransformerDecodeSpec:
     def decode_window(self, params, state, tokens, pos, store):
         """W tokens per sequence in ONE pass — the speculative-verify
         forward. ``tokens`` [B,W] are fed at positions ``pos .. pos+W-1``;
-        ``store`` is a window store (``put_get`` takes [B,W,H,Dh] and
-        returns per-row key masks). Every op is the [B,W,·] batched form of
-        the exact per-position ``decode_step`` math (all non-attention ops
-        are position-wise; attention rows carry per-row masks), so the
+        ``store`` is a window store (``attend`` takes k/v [B,W,H,Dh] and
+        lets row ``i`` see the keys at positions ``<= pos+i``). Every op is
+        the [B,W,·] batched form of the exact per-position ``decode_step``
+        math (all non-attention ops are position-wise; attention rows
+        carry per-row limits), so the
         returned logits [B,W,V] match W sequential decode steps
         token-for-token — the property the verify acceptance rule needs."""
         B, W = tokens.shape
@@ -268,8 +270,7 @@ class TransformerDecodeSpec:
         q = self._heads(y @ ap["Wq"])                          # [B,H,W,Dh]
         k_win = (y @ ap["Wk"]).reshape(B, W, self.n_heads, self.head_dim)
         v_win = (y @ ap["Wv"]).reshape(B, W, self.n_heads, self.head_dim)
-        K, V, row_mask = store.put_get(i, k_win, v_win)
-        out = window_attention(q, K, V, row_mask)
+        out = store.attend(i, q, k_win, v_win)
         out = out.transpose(0, 2, 1, 3).reshape(B, W, self.d_model)
         if attn_layer.project_out:
             out = out @ ap["Wo"] + ap["b"]

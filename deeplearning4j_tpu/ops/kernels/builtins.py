@@ -202,6 +202,58 @@ def _register_conv():
     ))
 
 
+# --------------------------------------------------------- paged_attention
+def _paged_attention_parity(seed: int):
+    from .. import pallas_paged_attention as ppa
+    rng = np.random.default_rng(seed)
+    S, H, Dh, blk, mb, L, layer = 5, 2, 64, 16, 8, 2, 1
+    # an idle slot, one key, a page boundary from both sides, a full table
+    lens = np.array([0, 1, 16, 17, mb * blk - 2], np.int32)
+    nb = S * mb + 1
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal(
+        (L, nb, blk, H * Dh)) * 0.5, f32) for _ in range(2))
+    tables = jnp.asarray(
+        1 + rng.permutation(nb - 1)[:S * mb].reshape(S, mb), jnp.int32)
+    fused, fb = [], []
+    # full-f32 matmuls on both sides, as for "attention" above
+    with jax.default_matmul_precision("highest"):
+        for W in (1, 3):            # the decode step, a verify window
+            q = jnp.asarray(rng.standard_normal((S, H, W, Dh)) * 0.5, f32)
+            args = (q, k_pool, v_pool, layer, tables, jnp.asarray(lens))
+            fused.append(ppa.paged_attention_decode(*args))
+            fb.append(ppa.paged_attention_reference(*args))
+    return fused, fb
+
+
+def _paged_attention_roofline(shape_sig: str):
+    H, W, Dh, live, itemsize = (int(v) for v in shape_sig.split("x"))
+    flops = 4.0 * W * H * Dh * live          # QK^T + PV over the live keys
+    nbytes = 2.0 * live * H * Dh * itemsize  # each live key and value, once
+    return flops, nbytes
+
+
+def _register_paged_attention():
+    from .. import pallas_paged_attention as ppa
+    register(KernelSpec(
+        name="paged_attention",
+        fused=ppa.paged_attention_decode,
+        fallback=ppa.paged_attention_reference,
+        # plain pools always take it (the interpreter off the TPU): no
+        # probe, no kill switch; the int8 tier never calls it
+        applicable=lambda *a, **k: True,
+        parity=ParityPin(run=_paged_attention_parity, tol=2e-5,
+                         note="online softmax over page groups vs one-shot "
+                              "softmax over the gathered table"),
+        roofline=_paged_attention_roofline,
+        tunable="keys folded per online-softmax step (their pages' DMAs "
+                "are in flight together)",
+        default_choice=(ppa._KEYS_PER_STEP,),
+        notes="decode / verify attention reading the paged KV pool in "
+              "place through the block table; memory-bound",
+    ))
+
+
 for _reg in (_register_attention, _register_lstm, _register_encode,
-             _register_int8_matmul, _register_conv):
+             _register_int8_matmul, _register_conv,
+             _register_paged_attention):
     _reg()

@@ -1,0 +1,244 @@
+"""Paged decode attention (TPU Pallas): attention over a paged KV cache
+that reads the pages where they lie.
+
+The decode step's XLA path first copied every slot's whole block table out
+of the pool into a dense ``[S, H, ctx, Dh]`` array and then attended to it
+under a mask: for 16 slots of 1024 positions that is the whole table
+written and read again per layer per token, whatever the live lengths.
+This kernel walks each slot's block table instead (scalar prefetch) and
+DMAs only the pages that hold live keys from the pool in HBM into VMEM,
+folding them into an online softmax: HBM traffic is the live keys and
+values, once.
+
+Layout. A pool is ``[n_layers, num_blocks, block_len, H * Dh]``: one page
+of one layer is ``block_len`` rows of all heads side by side, contiguous
+and lane-dense (a ``[.., H, Dh]`` minor pair with ``Dh`` = 64 fills half
+of each 128-lane row in VMEM, and cost the prefill's scatter a copy of
+the pool, PERF.md PR 27). The layer is picked by index inside the DMA, so
+no slice of the pool is ever materialised.
+
+Heads are folded into ONE matmul per page group rather than looped over:
+the queries enter as a block-diagonal ``[W * H, H * Dh]`` matrix (row
+``w * H + h`` carries head ``h``'s query in its own ``Dh`` lanes, zeros
+elsewhere), so ``q_bd @ K^T`` is every head's score row at once and
+``p @ V`` every head's output in its own lanes (the off-diagonal blocks are
+discarded). That spends H times the FLOPs the algorithm needs on an MXU
+that a one-row decode leaves idle anyway, and needs no per-head slice of a
+page, which the tiling would make a strided gather.
+
+Precision: scores, the softmax recurrence (running max and sum) and both
+accumulations are float32; ``p`` is cast to the pool's dtype for ``p @ V``
+and the output is the pool's dtype, as on the XLA path.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from .pallas_attention import NEG, _device_split
+
+f32 = jnp.float32
+
+# names in the compiled program and in a device trace (see
+# pallas_attention.SCOPE for why a call sits in two scopes)
+SCOPE = "paged_attention"
+KERNEL_NAME = "paged_attention_decode"
+
+# keys folded into the online softmax per step: 16 pages of 16, the DMAs of
+# one step in flight while the previous step's pages are computed on. On
+# the v5e at the serving cells' shape 256 beat 128 and 512 at long
+# contexts (16 x 750 keys x 24 layers: 2.18 against 2.40 and 2.31 ms) and
+# tied them at short ones (PERF.md, PR 27)
+_KEYS_PER_STEP = 256
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _body(W, H, Dh, blk, G, cap, scale,
+          layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+          kbuf, vbuf, sem, m_s, l_s, acc_s):
+    T = G * blk
+    s = pl.program_id(0)
+    layer = layer_ref[0]
+    n0 = lens_ref[s]                      # keys row 0 sees; 0 = idle slot
+    last = jnp.where(n0 > 0, jnp.minimum(n0 + (W - 1), cap), 0)
+    npages = (last + (blk - 1)) // blk
+    ngroups = (npages + (G - 1)) // G
+
+    def each_copy(group, slot, act):
+        """``act`` ("start" or "wait") on the DMA of every live page of
+        ``group``: page ``tables[s, j]`` of this layer into rows
+        ``g * blk`` of the group's buffer. Pages past the slot's length
+        are not fetched."""
+        for g in range(G):
+            page = group * G + g
+
+            @pl.when(page < npages)
+            def _():
+                bid = tables_ref[s, page]
+                for c, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf))):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[layer, bid],
+                        buf.at[slot, pl.ds(g * blk, blk)],
+                        sem.at[c, slot]), act)()
+
+    m_s[:] = jnp.full_like(m_s, NEG)
+    l_s[:] = jnp.zeros_like(l_s)
+    acc_s[:] = jnp.zeros_like(acc_s)
+
+    @pl.when(ngroups > 0)
+    def _first():
+        each_copy(0, 0, "start")
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (acc_s.shape[0], T), 0)
+    # row w * H + h belongs to window position w, which sees n0 + w keys
+    limit = jnp.minimum(n0 + rows // H, last)
+
+    def step(gi, carry):
+        slot = gi % 2
+
+        @pl.when(gi + 1 < ngroups)
+        def _next():
+            each_copy(gi + 1, 1 - slot, "start")
+
+        each_copy(gi, slot, "wait")
+        k = kbuf[slot]
+        v = vbuf[slot]
+        sc = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=f32) * scale
+        kpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(kpos < limit, sc, NEG)
+        m_prev = m_s[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[:] = jnp.broadcast_to(
+            l_s[:, :1] * corr + p.sum(1, keepdims=True), l_s.shape)
+        # rows of the buffer past the slot's length were never fetched (or
+        # are the unwritten tail of its last page): whatever they hold,
+        # 0 * it must stay 0
+        vpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(vpos < last, v, jnp.zeros_like(v))
+        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        return carry
+
+    jax.lax.fori_loop(0, ngroups, step, 0)
+
+    l = l_s[:, :1]
+    out = acc_s[:] * jnp.where(l > 0, 1.0 / l, 0.0)     # idle slot: zeros
+    r = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+    out = jnp.where(lane // Dh == r % H, out, 0.0)      # head h, own lanes
+    for w in range(W):
+        o_ref[0, w:w + 1, :] = out[w * H:(w + 1) * H].sum(
+            axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
+    """One device's heads: q [S,H,W,Dh], pools [L,nb,blk,H*Dh]; ``layer``
+    an int32 scalar. Jitted with the layer an OPERAND, so that a program
+    of 24 layers traces this and lowers the kernel to Mosaic once, not 24
+    times (7 s of every process's warm-up at the serving cells' shape)."""
+    S, H, W, Dh = q.shape
+    HD = H * Dh
+    blk = k_pool.shape[2]
+    mb = tables.shape[1]
+    G = max(1, min(mb, _KEYS_PER_STEP // blk))
+    T = G * blk
+    R = W * H
+    Rp = -(-R // 16) * 16                 # whole sublane tiles, bf16 too
+    # block-diagonal queries [S, W*H, H*Dh]: row w*H+h holds q[s,h,w] in
+    # lanes h*Dh..(h+1)*Dh
+    qt = q.transpose(0, 2, 1, 3)[:, :, :, None, :]          # [S,W,H,1,Dh]
+    own = jnp.eye(H, dtype=bool)[None, None, :, :, None]
+    q_bd = jnp.where(own, qt, jnp.zeros((), q.dtype)).reshape(S, R, HD)
+    q_bd = jnp.pad(q_bd, ((0, 0), (0, Rp - R), (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,            # layer, tables, lens
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, Rp, HD), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, W, HD), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, T, HD), k_pool.dtype),
+                        pltpu.VMEM((2, T, HD), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, HD), f32)])
+    with jax.named_scope(SCOPE):
+        o = pl.pallas_call(
+            functools.partial(_body, W, H, Dh, blk, G, mb * blk,
+                              1.0 / float(np.sqrt(Dh))),
+            name=KERNEL_NAME,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, W, HD), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(layer.reshape(1), tables.astype(jnp.int32), lens.astype(jnp.int32),
+          q_bd, k_pool, v_pool)
+    return o.reshape(S, W, H, Dh).transpose(0, 2, 1, 3)
+
+
+def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
+    """Softmax attention of a decode window over a paged cache.
+
+    q       [S, H, W, Dh]: W query rows a slot (1 in the decode step,
+            k + 1 in a speculative verify)
+    k_pool, v_pool  [n_layers, num_blocks, block_len, H * Dh]; ``layer``
+            picks the layer inside the kernel's DMAs
+    tables  [S, max_blocks] int32: position p of slot s lies in page
+            ``tables[s, p // block_len]``
+    lens    [S] int32: keys row 0 of the slot sees (``pos + 1``, the
+            step's own token already written); row w sees ``lens + w``.
+            0 marks an idle slot: it fetches nothing and returns zeros.
+
+    Returns [S, H, W, Dh] in q's dtype. Under a mesh tracing context the
+    heads split over the model axis with ``shard_map`` (a Mosaic call is
+    not partitioned automatically); attention is head-local."""
+    S, H = q.shape[:2]
+    layer = jnp.asarray(layer, jnp.int32)
+    kernel = functools.partial(_one_device, interpret=_interpret())
+    split = _device_split(S, H)
+    if split is None:
+        return kernel(layer, q, k_pool, v_pool, tables, lens)
+    spec, axis_names = split
+    heads = spec[1]                        # slots stay whole: S is small
+    return jax.shard_map(
+        kernel,
+        in_specs=(P(), P(None, heads), P(None, None, None, heads),
+                  P(None, None, None, heads), P(), P()),
+        out_specs=P(None, heads), axis_names=axis_names,
+        check_vma=False)(layer, q, k_pool, v_pool, tables, lens)
+
+
+def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens):
+    """The same attention the plain way: gather every slot's whole table
+    into a dense context and attend under a mask. The parity pin of the
+    kernel, and what the decode step did before it."""
+    from ..models.decode import window_attention
+    S, H, W, Dh = q.shape
+    ctx = tables.shape[1] * k_pool.shape[2]
+
+    def dense(pool):
+        return pool[layer][tables].reshape(S, ctx, H, Dh).transpose(0, 2, 1, 3)
+
+    limit = lens[:, None] + jnp.arange(W)[None, :]                # [S,W]
+    mask = jnp.arange(ctx)[None, None, :] < limit[:, :, None]
+    out = window_attention(q, dense(k_pool), dense(v_pool), mask)
+    return jnp.where((lens > 0)[:, None, None, None], out,
+                     jnp.zeros((), out.dtype))

@@ -11,8 +11,10 @@ ever recompiles after warm-up.
 
 Pillars:
   - kvcache.py      block pool, refcounted free-list allocator,
-                    gather/scatter, the PagedStore / PagedWindowStore
-                    bridges into models/decode.py, the COW block copy
+                    scatter, the PagedStore / PagedWindowStore bridges
+                    into models/decode.py (attention reads the pages in
+                    place: ops/pallas_paged_attention.py), the COW block
+                    copy
   - prefix.py       copy-on-write prefix-cache sharing: rolling
                     prompt-prefix hash chain over immutable full blocks,
                     refcounts + LRU + eviction under pool pressure

@@ -3,10 +3,12 @@
 The central shape discipline of the decode subsystem: the cache is ONE pair
 of pool arrays per model —
 
-    k_pool / v_pool : [n_layers, num_blocks, block_len, n_heads, head_dim]
+    k_pool / v_pool : [n_layers, num_blocks, block_len, n_heads * head_dim]
 
-— and a sequence's cache is the set of pool blocks its (host-side) block
-table points at. "Growing" a sequence's context is block *allocation*, a
+(one page of one layer is ``block_len`` rows of all heads side by side:
+contiguous, lane-dense, one DMA for the attention kernel) — and a
+sequence's cache is the set of pool blocks its (host-side) block table
+points at. "Growing" a sequence's context is block *allocation*, a
 bookkeeping edit to an int32 table; no device array ever changes shape, so
 nothing ever recompiles (the vLLM PagedAttention idea fused with the
 repo's AOT-warmed-program discipline).
@@ -14,12 +16,16 @@ repo's AOT-warmed-program discipline).
 Block 0 is the reserved TRASH block: inactive decode slots and the unused
 tail of a prefill's table all point at it, so the fixed-shape scatter always
 has a legal destination and garbage lands where nothing ever reads it
-(attention masks it out regardless).
+(decode attention walks a slot's table only as far as its length).
 
 Host side: ``BlockAllocator`` — a free-list over block ids 1..num_blocks-1.
-Device side: pure gather/scatter helpers used inside the jitted prefill and
-decode programs; ``PagedStore`` adapts them to the ``models.decode.KVStore``
-protocol.
+Device side: pure scatter helpers used inside the jitted prefill and decode
+programs; ``PagedStore`` / ``PagedWindowStore`` adapt them to the
+``models.decode.KVStore`` protocol: scatter the step's K/V, then attend to
+the pages IN PLACE (``ops.pallas_paged_attention``: the kernel reads page
+``tables[s, j]`` of the layer straight from the pool, live pages only).
+The int8 tier alone still gathers a dense context (``_pool_gather``) and
+dequantizes it for XLA attention.
 """
 from __future__ import annotations
 
@@ -28,6 +34,8 @@ from typing import List, NamedTuple, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...models.decode import window_attention
+from ...ops.pallas_paged_attention import paged_attention_decode
 from ..errors import BlockPoolExhaustedError
 
 
@@ -116,9 +124,9 @@ class BlockAllocator:
 
 
 class QuantizedPool(NamedTuple):
-    """int8-quantized block pool (ISSUE 17): the same
-    [n_layers, num_blocks, block_len, n_heads, *] geometry, with each
-    (token, head) vector stored as int8 codes plus ONE f32 scale —
+    """int8-quantized block pool (ISSUE 17): the plain pool's blocks with
+    the head axis kept apart, [n_layers, num_blocks, block_len, n_heads,
+    *], each (token, head) vector stored as int8 codes plus ONE f32 scale —
     2*(Dh+4) bytes per token/layer/head instead of f32's 8*Dh, so the
     same ``num_blocks`` holds ~2-3.5x the tokens per byte (and every
     prefix-cache hit shares the smaller blocks). A NamedTuple is a pytree,
@@ -156,6 +164,7 @@ def make_pools(n_layers: int, num_blocks: int, block_len: int,
             return QuantizedPool(jnp.zeros(shape, jnp.int8),
                                  jnp.zeros(shape[:-1], jnp.float32))
         return qp(), qp()
+    shape = shape[:3] + (n_heads * head_dim,)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
@@ -181,8 +190,8 @@ def cow_copy(k_pool, v_pool, src, dst):
 def prefill_scatter(pool, layer_kv, tables):
     """Write a prefill's K or V for one layer into the pool.
 
-    pool      [n_layers, nb, blk, H, Dh] (functional update; plain or
-              ``QuantizedPool`` — quantized pools quantize-on-write)
+    pool      [n_layers, nb, blk, H*Dh] (functional update), or a
+              ``QuantizedPool`` (quantize-on-write)
     layer_kv  list of [P, L, H, Dh] per layer (L % blk == 0)
     tables    [P, max_blocks] int32 — first L//blk entries are the
               sequence's blocks (rest point at trash block 0).
@@ -203,7 +212,7 @@ def prefill_scatter(pool, layer_kv, tables):
     nblk = L // blk
     for i, kv in enumerate(layer_kv):
         pool = pool.at[i, tables[:, :nblk]].set(
-            kv.reshape(P, nblk, blk, H, Dh))
+            kv.reshape(P, nblk, blk, H * Dh))
     return pool
 
 
@@ -215,19 +224,18 @@ def _pool_write(pool, i, bid, off, tok):
         q, s = kv_quantize(tok)
         return QuantizedPool(pool.q.at[i, bid, off].set(q),
                              pool.scale.at[i, bid, off].set(s))
-    return pool.at[i, bid, off].set(tok)
+    return pool.at[i, bid, off].set(tok.reshape(tok.shape[:-2] + (-1,)))
 
 
-def _pool_gather(pool, i, tables, S, ctx_len, H, Dh, dtype):
-    """Gather the full context for one layer → [S, H, ctx, Dh] — the
-    dequantize-in-attention seam."""
-    if isinstance(pool, QuantizedPool):
-        ctx = pool.q[i][tables].reshape(S, ctx_len, H, Dh)
-        sc = pool.scale[i][tables].reshape(S, ctx_len, H)
-        ctx = kv_dequantize(ctx, sc, dtype)
-    else:
-        ctx = pool[i][tables].reshape(S, ctx_len, H, Dh)
-    return ctx.transpose(0, 2, 1, 3)
+def _pool_gather(pool: QuantizedPool, i, tables, dtype):
+    """Gather and dequantize one layer's full context, every slot's whole
+    table → [S, H, ctx, Dh]: the int8 tier's dequantize-in-attention seam
+    (plain pools are read in place by the attention kernel)."""
+    S = tables.shape[0]
+    H, Dh = pool.q.shape[-2:]
+    ctx = pool.q[i][tables].reshape(S, -1, H, Dh)
+    sc = pool.scale[i][tables].reshape(S, -1, H)
+    return kv_dequantize(ctx, sc, dtype).transpose(0, 2, 1, 3)
 
 
 class QuantSimStore:
@@ -251,105 +259,87 @@ class QuantSimStore:
         self.ks: List = [None] * n_layers
         self.vs: List = [None] * n_layers
 
-    def put_get(self, i: int, k_win, v_win):
-        """k_win/v_win: [B, W, H, Dh]. Returns (K [B,H,W,Dh],
-        V [B,H,W,Dh], causal row_mask [B,W,W])."""
+    def attend(self, i: int, q, k_win, v_win):
+        """q [B,H,W,Dh]; k_win/v_win [B,W,H,Dh]. Returns [B,H,W,Dh]."""
         self.ks[i] = k_win
         self.vs[i] = v_win
         B, W = k_win.shape[:2]
 
         def fakeq(x):
-            q, s = kv_quantize(x)
-            return kv_dequantize(q, s, x.dtype).transpose(0, 2, 1, 3)
+            codes, scale = kv_quantize(x)
+            return kv_dequantize(codes, scale, x.dtype).transpose(0, 2, 1, 3)
 
         mask = (jnp.arange(W)[None, None, :]
                 <= jnp.arange(W)[None, :, None])
         mask = jnp.broadcast_to(mask, (B, W, W))
-        return fakeq(k_win), fakeq(v_win), mask
-
-
-class PagedStore:
-    """``models.decode.KVStore`` over the paged pools for ONE decode step.
-
-    Scatter-then-gather: the current token's K/V lands in its block slot
-    first, then the gathered context (position-ordered, so attention row
-    ``pos`` is bit-identical to the naive causal row) includes it.
-    Inactive rows scatter to the trash block."""
-
-    def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int):
-        self.k_pool = k_pool
-        self.v_pool = v_pool
-        self.tables = tables              # [S, max_blocks] int32
-        self.pos = pos                    # [S] int32
-        self.active = active              # [S] bool
-        self.block_len = int(block_len)
-        S, mb = tables.shape
-        self._ctx_len = mb * self.block_len
-        bid = jnp.take_along_axis(tables, (pos // self.block_len)[:, None],
-                                  axis=1)[:, 0]
-        self._bid = jnp.where(active, bid, 0)      # trash for idle slots
-        self._off = jnp.where(active, pos % self.block_len, 0)
-        self._mask = (jnp.arange(self._ctx_len)[None, :] <= pos[:, None])
-
-    def put_get(self, i: int, k_tok, v_tok):
-        S = k_tok.shape[0]
-        H, Dh = k_tok.shape[-2:]
-        self.k_pool = _pool_write(self.k_pool, i, self._bid, self._off, k_tok)
-        self.v_pool = _pool_write(self.v_pool, i, self._bid, self._off, v_tok)
-        K = _pool_gather(self.k_pool, i, self.tables, S, self._ctx_len,
-                         H, Dh, k_tok.dtype)
-        V = _pool_gather(self.v_pool, i, self.tables, S, self._ctx_len,
-                         H, Dh, v_tok.dtype)
-        return K, V, self._mask
-
-    @property
-    def pools(self):
-        return self.k_pool, self.v_pool
+        return window_attention(q, fakeq(k_win), fakeq(v_win), mask)
 
 
 class PagedWindowStore:
-    """``models.decode`` window store over the paged pools for ONE
-    speculative-verify pass: W = k+1 fed tokens per slot land at positions
-    ``pos .. pos+W-1`` (crossing block boundaries via per-position
-    (block, offset) indices), then the gathered context plus per-row key
-    masks reproduce, row by row, exactly the visibility the one-token
-    ``PagedStore`` gives position ``pos+i`` — which is what makes the
-    batched verify bit-identical to W sequential decode steps."""
+    """``models.decode`` window store over the paged pools for ONE pass of
+    W fed tokens per slot (W = k+1 in a speculative verify): they land at
+    positions ``pos .. pos+W-1`` (crossing block boundaries via
+    per-position (block, offset) indices), then row ``i`` attends to the
+    keys at positions ``<= pos+i`` — exactly the visibility the one-token
+    ``PagedStore`` gives position ``pos+i``, through the same kernel and
+    the same arithmetic, which is what keeps a batched verify
+    token-for-token identical to W sequential decode steps.
+
+    Scatter, then attend in place: the window's K/V is written first, so
+    the kernel finds it in the pool like any other key. Plain pools are
+    never gathered: ``paged_attention_decode`` reads each slot's live pages
+    through the block table. The int8 tier (``QuantizedPool``) gathers and
+    dequantizes every slot's whole table for XLA attention, as both tiers
+    did before the kernel. Idle slots scatter to the trash block, read
+    nothing and get zeros."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
                  window: int):
         self.k_pool = k_pool
         self.v_pool = v_pool
         self.tables = tables              # [S, max_blocks] int32
-        self.block_len = int(block_len)
-        S, mb = tables.shape
-        self._ctx_len = mb * self.block_len
+        mb = tables.shape[1]
+        ctx_len = mb * block_len
         w_pos = pos[:, None] + jnp.arange(window)[None, :]       # [S, W]
-        bidx = jnp.clip(w_pos // self.block_len, 0, mb - 1)
+        bidx = jnp.clip(w_pos // block_len, 0, mb - 1)
         bid = jnp.take_along_axis(tables, bidx, axis=1)          # [S, W]
         # idle slots AND window positions past capacity (a verify window is
         # always W wide even when < W tokens of budget remain) go to trash —
         # a clipped in-range write would corrupt the last real block
-        ok = active[:, None] & (w_pos < mb * self.block_len)
+        ok = active[:, None] & (w_pos < ctx_len)
         self._bid = jnp.where(ok, bid, 0)
-        self._off = jnp.where(ok, w_pos % self.block_len, 0)
-        # row i of a slot's mask: keys at positions <= pos+i are visible
-        self._mask = (jnp.arange(self._ctx_len)[None, None, :]
-                      <= w_pos[:, :, None])                      # [S, W, ctx]
+        self._off = jnp.where(ok, w_pos % block_len, 0)
+        # keys row 0 sees, this pass's first token included; 0 = idle
+        self._lens = jnp.where(active, pos + 1, 0).astype(jnp.int32)
+        if isinstance(k_pool, QuantizedPool):
+            # row i of a slot's mask: keys at positions <= pos+i are visible
+            self._mask = (jnp.arange(ctx_len)[None, None, :]
+                          <= w_pos[:, :, None])                  # [S, W, ctx]
 
-    def put_get(self, i: int, k_win, v_win):
-        """k_win/v_win: [S, W, H, Dh] for the window. Returns
-        (K [S,H,ctx,Dh], V [S,H,ctx,Dh], row_mask [S,W,ctx])."""
-        S = k_win.shape[0]
-        H, Dh = k_win.shape[-2:]
+    def attend(self, i: int, q, k_win, v_win):
+        """q [S,H,W,Dh]; k_win/v_win [S,W,H,Dh] for the window. Returns
+        the attention output [S,H,W,Dh]."""
         self.k_pool = _pool_write(self.k_pool, i, self._bid, self._off, k_win)
         self.v_pool = _pool_write(self.v_pool, i, self._bid, self._off, v_win)
-        K = _pool_gather(self.k_pool, i, self.tables, S, self._ctx_len,
-                         H, Dh, k_win.dtype)
-        V = _pool_gather(self.v_pool, i, self.tables, S, self._ctx_len,
-                         H, Dh, v_win.dtype)
-        return K, V, self._mask
+        if isinstance(self.k_pool, QuantizedPool):
+            K = _pool_gather(self.k_pool, i, self.tables, k_win.dtype)
+            V = _pool_gather(self.v_pool, i, self.tables, v_win.dtype)
+            return window_attention(q, K, V, self._mask)
+        return paged_attention_decode(q, self.k_pool, self.v_pool, i,
+                                      self.tables, self._lens)
 
     @property
     def pools(self):
         return self.k_pool, self.v_pool
+
+
+class PagedStore(PagedWindowStore):
+    """``models.decode.KVStore`` over the paged pools for ONE decode step:
+    a window of one token a slot."""
+
+    def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int):
+        super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1)
+
+    def attend(self, i: int, q, k_tok, v_tok):
+        """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh]."""
+        return super().attend(i, q, k_tok[:, None], v_tok[:, None])

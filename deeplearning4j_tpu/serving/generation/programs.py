@@ -8,8 +8,9 @@ every program the steady-state loop can ever need is lowered and compiled at
     padded prompt -> per-position logits via the graph's own ``apply_fn``
     (bit-identical to ``net.output``), K/V scattered into the paged pools,
     first token sampled in-program;
-  - one **decode-step** executable: one token per in-flight slot, gather via
-    block tables, scatter the step's K/V, sample the next token — cache
+  - one **decode-step** executable: one token per in-flight slot, scatter
+    the step's K/V, attend to the slot's pages in place through the block
+    table (``ops.pallas_paged_attention``), sample the next token — cache
     buffers donated so the pool updates in place.
 
 Params/state are arguments, not constants, so hot-swap reuses executables
@@ -292,10 +293,11 @@ class GenerationProgramSet:
     def _pool_sharding(self) -> Optional[NamedSharding]:
         """Head-axis sharding for the paged pools: every pool-shaped array
         in the decode subsystem carries its heads on axis 3 —
-        k/v pools [n_layers, nb, blk, H, Dh], int8 scales
-        [n_layers, nb, blk, H], dense draft caches
-        [n_layers, slots+1, cap, H, Dh] — so ONE spec serves them all
-        (PartitionSpec pads trailing axes with None)."""
+        k/v pools [n_layers, nb, blk, H*Dh] (heads side by side, so an
+        even split of the axis is a split by head), int8 codes
+        [n_layers, nb, blk, H, Dh] and scales [n_layers, nb, blk, H],
+        dense draft caches [n_layers, slots+1, cap, H, Dh] — so ONE spec
+        serves them all (PartitionSpec pads trailing axes with None)."""
         if self.mesh is None:
             return None
         return NamedSharding(self.mesh,

@@ -658,11 +658,17 @@ class ModelRuntime:
         mask[live] = True
         attrs = {}
         if coh.ps.adapter == "paged":
-            # what the step attends to against what its gather reads: each
-            # live slot's valid positions (this step's included) and, per
-            # layer, every slot's whole table
-            attrs = {"live_tokens": int(self._pos[live].sum()) + len(live),
-                     "gathered_tokens": S * cfg.capacity}
+            # what the step attends to against what it reads per layer:
+            # each live slot's valid positions (this step's included), and
+            # the whole pages that hold them, which the attention kernel
+            # fetches through the table; the int8 tier still gathers every
+            # slot's whole table
+            seen = self._pos[live] + 1
+            blk = cfg.block_len
+            attrs = {"live_tokens": int(seen.sum()),
+                     "gathered_tokens": S * cfg.capacity
+                     if coh.ps.kv_quantized
+                     else int((-(-seen // blk) * blk).sum())}
         with span("generation.decode_step", model=self.name,
                   slots=len(live), **attrs) as sp:
             nxt, coh.cache, self._key = coh.ps.run_decode(

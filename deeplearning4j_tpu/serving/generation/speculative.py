@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...parallel.ring_attention import attention
+
 
 # ------------------------------------------------------------- dense store
 class DenseDraftStore:
@@ -55,13 +57,13 @@ class DenseDraftStore:
         self._off = jnp.where(ok, pos, 0)
         self._mask = (jnp.arange(cap)[None, :] <= pos[:, None])
 
-    def put_get(self, i: int, k_tok, v_tok):
+    def attend(self, i: int, q, k_tok, v_tok):
         self.k_cache = self.k_cache.at[i, self._row, self._off].set(k_tok)
         self.v_cache = self.v_cache.at[i, self._row, self._off].set(v_tok)
         S = k_tok.shape[0]
         K = self.k_cache[i, :S].transpose(0, 2, 1, 3)   # [S,H,cap,Dh]
         V = self.v_cache[i, :S].transpose(0, 2, 1, 3)
-        return K, V, self._mask
+        return attention(q, K, V, causal=False, key_mask=self._mask)
 
     @property
     def caches(self):

@@ -688,9 +688,11 @@ def test_step_and_prefill_spans_count_their_tokens(pair_events):
     assert (a["batch"], a["rung"]) == (2, 64)         # as before
     steps = _named(pair_events, "generation.decode_step", ph="X")
     # positions valid in the cache, this step's included: (5+1)+(11+1),
-    # then one more each; the gather reads 4 slots x capacity 64 a layer
+    # then one more each; the attention kernel reads the whole pages of 8
+    # that hold them, 8 + 16 a layer (the gather read 4 slots x capacity
+    # 64 = 256, whatever was live)
     assert [s["args"]["live_tokens"] for s in steps] == [18, 20]
-    assert [s["args"]["gathered_tokens"] for s in steps] == [256, 256]
+    assert [s["args"]["gathered_tokens"] for s in steps] == [24, 24]
     assert all(s["args"]["slots"] == 2 for s in steps)
 
 

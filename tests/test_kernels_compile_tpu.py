@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.ops import (pallas_attention, pallas_compression,
-                                    pallas_lstm)
+                                    pallas_lstm, pallas_paged_attention)
 from deeplearning4j_tpu.ops import kernels
 from deeplearning4j_tpu.ops.kernels import conv, quantized
 
@@ -46,7 +46,7 @@ def _no_interpreter(monkeypatch):
     # the modules pick the interpreter from the (CPU) default backend;
     # the lowering here targets the TPU, so take the Mosaic path
     for mod in (pallas_attention, pallas_lstm, pallas_compression,
-                quantized, conv):
+                pallas_paged_attention, quantized, conv):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -74,7 +74,8 @@ def _instruction_names(text: str) -> set:
 
 def test_every_registered_kernel_is_compiled_here():
     assert set(kernels.names()) == {"attention", "lstm", "threshold_encode",
-                                    "int8_matmul", "conv1x1_bias_relu"}, \
+                                    "int8_matmul", "conv1x1_bias_relu",
+                                    "paged_attention"}, \
         "a kernel was registered without a TPU compile check in this file"
 
 
@@ -127,6 +128,58 @@ def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):
         assert _custom_calls(on_mesh, grad, qkv, qkv, qkv) == 3
     with pytest.raises(NotImplementedError, match="automatically partition"):
         _custom_calls(on_mesh, grad, qkv, qkv, qkv)
+
+
+def _paged_avals(S, H, W, Dh, blk, mb, L, dtype, sharding=None):
+    """(q, k_pool, v_pool, tables, lens) of a paged decode attention call:
+    the pool holds every slot's full table plus the trash block."""
+    pool = ((L, S * mb + 1, blk, H * Dh), dtype)
+    return (((S, H, W, Dh), dtype), pool, pool,
+            ((S, mb), jnp.int32), ((S,), jnp.int32))
+
+
+@pytest.mark.parametrize("S,H,W,Dh,blk,mb,L,dtype", [
+    (5, 2, 1, 64, 16, 8, 2, f32),            # the parity pin, W = 1
+    (5, 2, 3, 64, 16, 8, 2, f32),            # and its verify window
+    (16, 16, 1, 64, 16, 64, 24, bf16),       # the benchmark's serving cells
+    (16, 16, 5, 64, 16, 64, 24, bf16),       # a verify window of k = 4 there
+    (4, 8, 1, 64, 16, 64, 12, bf16)])        # chip_smoke's LM
+def test_paged_attention_decode_compiles(v5e, S, H, W, Dh, blk, mb, L, dtype):
+    text = _compiled_text(
+        v5e, lambda q, k, v, t, n: pallas_paged_attention.
+        paged_attention_decode(q, k, v, L - 1, t, n),
+        *_paged_avals(S, H, W, Dh, blk, mb, L, dtype))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert pallas_paged_attention.KERNEL_NAME in _instruction_names(text)
+    assert not pallas_paged_attention.KERNEL_NAME[-1].isdigit()
+
+
+def test_paged_attention_decode_splits_heads_on_a_mesh(v5e_devices):
+    """The head-sharded decode program (pools split over ``model``): the
+    kernel runs per device on its own heads under the mesh tracing context
+    ``GenerationProgramSet._aot`` enters, and is refused loudly without."""
+    from deeplearning4j_tpu.parallel.tensor_parallel import MODEL_AXIS
+    mesh = Mesh(np.array(v5e_devices[:2]).reshape(1, 2), ("data", MODEL_AXIS))
+    heads = NamedSharding(mesh, P(None, MODEL_AXIS))
+    pools = NamedSharding(mesh, P(None, None, None, MODEL_AXIS))
+    whole = NamedSharding(mesh, P())
+    avals = _paged_avals(16, 16, 1, 64, 16, 64, 24, bf16)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+            for (shape, dtype), sh in zip(avals, (heads, pools, pools,
+                                                  whole, whole))]
+
+    def compile_it():
+        with jax.enable_x64(False):
+            return jax.jit(
+                lambda q, k, v, t, n: pallas_paged_attention.
+                paged_attention_decode(q, k, v, 3, t, n),
+                out_shardings=heads).lower(*args).compile().as_text()
+    with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+        text = compile_it()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "all-gather" not in text and "all-to-all" not in text
+    with pytest.raises(NotImplementedError, match="automatically partition"):
+        compile_it()
 
 
 def test_fused_lstm_fwd_bwd_compiles(v5e):

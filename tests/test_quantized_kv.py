@@ -99,7 +99,11 @@ def test_pool_capacity_per_byte():
     ratio = (pool_bytes(kf) + pool_bytes(vf)) / \
         (pool_bytes(kq) + pool_bytes(vq))
     assert ratio >= 1.9, ratio
-    assert kq.q.shape == kf.shape and kq.scale.shape == kf.shape[:-1]
+    # the same blocks: the plain pool lays a token's heads side by side
+    # (the attention kernel's page), the codes keep them apart for the
+    # per-(token, head) scale
+    assert kq.q.shape == kf.shape[:3] + (2, 32) and kf.shape[3] == 2 * 32
+    assert kq.scale.shape == kq.q.shape[:-1]
 
 
 def test_kv_bytes_per_token_row_gauge_and_ratio(lm_net, eng8):

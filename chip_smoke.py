@@ -426,7 +426,10 @@ def main() -> int:
         f"libtpu={libtpu_version}")
     log(f"compile cache: {cache_dir}")
     T = LM["max_length"]
-    log(f"attention blocks (BQ, BK) at T={T}: {pallas_attention._blocks(T)}; "
+    log(f"attention tiles (BQ, BK) at T={T}: "
+        f"{pallas_attention._blocks(T, causal=True)} causal (visited, masked,"
+        f" total = {pallas_attention.tile_schedule(T, True)}), "
+        f"{pallas_attention._blocks(T)} otherwise; "
         f"autotune file {autotune.cache_path()} "
         f"{'READ' if autotune.get_cache().loaded_from_file else 'not read'}")
 

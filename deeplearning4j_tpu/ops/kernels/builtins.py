@@ -60,10 +60,12 @@ def _register_attention():
                          note="online-softmax f32 recurrence vs one-shot "
                               "softmax: associativity-level error only"),
         roofline=_attention_roofline,
-        tunable="(BQ, BK) score-block sizes (DL4J_TPU_ATTN_BQ/BK env, "
-                "autotune key T<T>)",
+        tunable="(BQ, BK) score-tile sizes (DL4J_TPU_ATTN_BQ/BK env, "
+                "autotune key T<T>, T<T>causal); causal default (256, 256)",
         default_choice=(512, 1024),
-        notes="flash attention fwd+bwd; O(T) HBM traffic",
+        notes="flash attention fwd+bwd; O(T) HBM traffic; causal calls "
+              "visit 10 of 16 score tiles at T=1024 "
+              "(pallas_attention.tile_schedule)",
     ))
 
 

@@ -8,14 +8,26 @@ block in VMEM with the online-softmax recurrence, so HBM traffic is
 O(B*H*T*D) regardless of T.
 
 Design (same helper-probe-with-fallback seam as ops/pallas_lstm.py):
-  - forward: grid (B*H, T/BQ, T/BK), k-blocks innermost ("arbitrary"
-    semantics) with the (acc, m, l) carry in VMEM scratch; saves the
-    logsumexp rows for the backward.
+  - scores are held KEYS ON SUBLANES, QUERIES ON LANES ([BK, n], see
+    ``_scores``): a query's running max, sum, logsumexp and delta are rows
+    [1, n], the softmax reductions run down the sublanes, and no kernel
+    transposes a score tile. lse and delta travel as [BH, 1, T] float32.
+  - forward: grid (B*H, T/RQ, T/RK) over RESIDENT blocks, k-blocks
+    innermost ("arbitrary" semantics) with the (acc, m, l) carry in VMEM
+    scratch; inside a grid step a static loop (``_visits``) walks the
+    resident block one key tile a pass; saves the logsumexp for the
+    backward.
   - backward (FlashAttention-2 style, custom_vjp): one kernel accumulates
     dq over k-blocks, a second accumulates (dk, dv) over q-blocks; softmax
     probabilities are recomputed from the saved logsumexp, never stored.
-  - causal blocks strictly above the diagonal are skipped (@pl.when), so
-    causal attention does ~half the work.
+  - ONE rule (``_tile_rule``) says which [BQ,BK] score tiles causal
+    attention visits: a tile with no position at or under the diagonal is
+    never computed (and its operands never fetched), a tile wholly under
+    it is computed without a mask, and only a tile the diagonal crosses
+    pays the iota/compare/select. ``tile_schedule`` counts what that comes
+    to for the tiles ``_blocks`` picks (T=1024: 10 of 16 tiles, 4 masked).
+  - ``scale`` is folded into a [rows, D] operand (q; dq's accumulator),
+    never multiplied over a score tile.
   - masking uses a large negative (-1e30) everywhere, matching the XLA
     fallback: a fully-masked query row degrades to uniform attention
     instead of NaN.
@@ -23,14 +35,11 @@ Design (same helper-probe-with-fallback seam as ops/pallas_lstm.py):
     recurrence (s, m, l, lse) are f32; with bf16 inputs the dot operands
     (q/k/v/do and the p/ds tiles) run in bf16 for full MXU rate — the
     standard flash-kernel precision recipe.
-
-lse/delta are carried as [BH, T, 128] lane-replicated f32 (the standard
-layout trick: per-row scalars live on all 128 lanes so no sub-tile
-transposes are needed).
 """
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -79,13 +88,13 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _blocks(T: int) -> tuple:
-    """(BQ, BK) block sizes. Resolution order: explicit env override
-    (DL4J_TPU_ATTN_BQ / DL4J_TPU_ATTN_BK, for re-tuning sweeps) → a cached
-    autotune decision for this (T, backend) from ops/kernels/autotune.py →
-    the v5e-sweep defaults (tools/autotune_attention.py; see BASELINE.md's
-    attention roofline note — the same preference order won at every head
-    dim tried)."""
+def _blocks(T: int, causal: bool = False) -> tuple:
+    """(BQ, BK): the score TILE, the [BQ,BK] block of scores one pass of
+    the online-softmax recurrence takes. Resolution order: explicit env
+    override (DL4J_TPU_ATTN_BQ / DL4J_TPU_ATTN_BK, the sweep's handles) →
+    a cached autotune decision for this (T, causal, backend) from
+    ops/kernels/autotune.py → the defaults below, chosen from what the
+    call shows: T and ``causal``."""
     def pick(env, pref):
         v = os.environ.get(env)
         if v:
@@ -97,18 +106,24 @@ def _blocks(T: int) -> tuple:
             if T % b == 0:
                 return b
         raise ValueError(f"T={T} not a multiple of 128")
-    # v5e sweep @ T=2048 (B=4,H=8, causal fwd+bwd): BK=1024 beats the old
-    # BQ=BK=512 default at every head dim tried (D=128: 2.17 vs 2.75
-    # ms/step; D=64: consistently top-2 across repeated sweeps) — bigger
-    # k-blocks amortize the online-softmax carry updates and feed the MXU
-    # longer contractions. BK=2048 was no better and BQ=1024 failed to
-    # compile with it, so 512/1024 is the stable optimum.
-    pref_q = (512, 256, 128)
-    pref_k = (1024, 512, 256, 128)
+    if causal:
+        # v5e sweep at the shape both benchmark cells run (B8 H16 T1024 D64
+        # bfloat16, tools/autotune_attention.py; table in PERF.md §5):
+        # forward + backward 1,322 us at 256/256 (10 of 16 tiles), 1,334
+        # at 128/128 (36 of 64: fewer elements, twice the passes), 1,396
+        # at 512/512 (3 of 4); the old 512/1024 visited the whole square
+        pref_q = pref_k = (256, 128)
+    else:
+        # every tile is needed: big k-tiles amortize the carry updates and
+        # feed the MXU longer contractions (v5e, T=1024 at the same shape:
+        # 512/1024 1,859 us forward + backward, 512/512 2,122)
+        pref_q = (512, 256, 128)
+        pref_k = (1024, 512, 256, 128)
     if os.environ.get("DL4J_TPU_ATTN_BQ") is None and \
             os.environ.get("DL4J_TPU_ATTN_BK") is None:
         from .kernels import autotune   # lazy: avoids an import cycle
-        cached = autotune.cached_decision("attention", f"T{T}")
+        cached = autotune.cached_decision(
+            "attention", f"T{T}causal" if causal else f"T{T}")
         if cached is not None:
             bq, bk = int(cached[0]), int(cached[1])
             if T % bq == 0 and T % bk == 0:
@@ -116,10 +131,150 @@ def _blocks(T: int) -> tuple:
     return pick("DL4J_TPU_ATTN_BQ", pref_q), pick("DL4J_TPU_ATTN_BK", pref_k)
 
 
-def _causal_mask_block(i, j, BQ, BK, s):
-    row = i * BQ + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    col = j * BK + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(col <= row, s, NEG)
+# a causal resident block is at most this square: one head's q, k, v at the
+# benchmark's context stay in VMEM for a whole grid step (under 1 MiB in
+# bfloat16). On the v5e a grid of 256 blocks took 3,167 us where this takes
+# 1,322 (a grid step per tile, and narrow passes); 2048 at T=2048 was 14%
+# faster again than 1024 but leaves float32 inputs no room in VMEM
+_RESIDENT_MAX = 1024
+
+
+def _resident(T: int, BQ: int, BK: int, causal: bool) -> tuple:
+    """(RQ, RK): the rows of q and of k/v one grid step holds in VMEM; the
+    kernels walk its tiles in a static loop (``_visits``). Non-causal:
+    the tile itself (one tile a step, as ever). Causal: a SQUARE block of
+    whole tiles, the whole sequence up to ``_RESIDENT_MAX``, so that a
+    tile the schedule skips costs no grid step, and the blocks on the
+    diagonal have i == j, which makes every tile's place static."""
+    if not causal:
+        return BQ, BK
+    step = BQ * BK // math.gcd(BQ, BK)
+    if T <= _RESIDENT_MAX:
+        return T, T
+    for r in (1024, 512, 256, 128):
+        if r <= _RESIDENT_MAX and T % r == 0 and r % step == 0:
+            return r, r
+    return step, step
+
+
+# ------------------------------------------------------- the tile schedule
+SKIP, FULL, DIAG = "skip", "full", "diag"
+
+
+def _tile_rule(row0, rows, col0, cols):
+    """THE rule of which score tiles causal attention visits, for the tile
+    of query rows [row0, row0 + rows) and key columns [col0, col0 + cols):
+    ``(skip, full)``. skip: every column lies after every row, the tile
+    holds no position with col <= row. full: every column is at or before
+    every row, no position is masked. Neither: the diagonal crosses it.
+    Python ints give bools (the static tile loops, ``tile_schedule``);
+    program ids give traced predicates (the grid). A sliding window or
+    segment mask changes this function and ``_scores``' mask, nothing
+    else."""
+    return col0 > row0 + rows - 1, col0 + cols - 1 <= row0
+
+
+def tile_kind(row0: int, rows: int, col0: int, cols: int,
+              causal: bool = True) -> str:
+    """SKIP, FULL or DIAG for one tile at static offsets."""
+    if not causal:
+        return FULL
+    skip, full = _tile_rule(row0, rows, col0, cols)
+    return SKIP if skip else FULL if full else DIAG
+
+
+def tile_schedule(T: int, causal: bool) -> tuple:
+    """(visited, masked, total) score tiles a head's [T,T] square costs
+    with the tiles ``_blocks`` picks: how many the kernels compute, how
+    many of those pay the causal mask, how many the square has. A count
+    from the same rule the kernels run, so it can be tested on a CPU."""
+    BQ, BK = _blocks(T, causal)
+    kinds = [tile_kind(r, BQ, c, BK, causal)
+             for r in range(0, T, BQ) for c in range(0, T, BK)]
+    return (sum(k != SKIP for k in kinds), sum(k == DIAG for k in kinds),
+            len(kinds))
+
+
+def _last_col_block(i, RQ, RK):
+    """Last k/v block the row block i needs (the index maps clamp to it: a
+    skipped grid step asks for the block already resident, no copy)."""
+    return ((i + 1) * RQ - 1) // RK
+
+
+def _first_row_block(j, RQ, RK):
+    """First q block the column block j needs (the dk/dv pass's clamp)."""
+    return (j * RK) // RQ
+
+
+def _for_block(causal, i, j, RQ, RK, emit):
+    """Emit the work of the resident block (row block i, column block j)
+    of the grid: ``emit(diagonal)`` traces the static tile loop, once for
+    a block wholly under the diagonal (no mask anywhere) and once for a
+    block on it; a block the rule skips runs neither."""
+    if not causal:
+        return emit(False)
+    assert RQ == RK, "causal resident blocks are square"
+    skip, full = _tile_rule(i * RQ, RQ, j * RK, RK)
+    pl.when(full)(lambda: emit(False))
+    pl.when(jnp.logical_not(skip | full))(lambda: emit(True))
+
+
+def _visits(diagonal, RQ, RK, BQ, BK):
+    """The walk all four kernels make over a resident block, one pass a
+    key tile: yields ``(c0, r_lo, masked)`` for the BK keys at c0, visited
+    by the queries [r_lo, RQ) (causal attention looks back, so the tiles
+    the rule does not skip are the END of a tile column), the first
+    ``masked`` of which sit in tiles the diagonal crosses. Offsets are
+    relative to the block: on a diagonal block i == j, so relative and
+    absolute offsets differ by the same amount. One pass takes a whole
+    column of tiles at once: the queries lie on the lanes (``_scores``),
+    and wide passes keep every MXU fed."""
+    for c0 in range(0, RK, BK):
+        kinds = [tile_kind(r0, BQ, c0, BK, diagonal)
+                 for r0 in range(0, RQ, BQ)]
+        visited = [kind for kind in kinds if kind != SKIP]
+        n_diag = visited.count(DIAG)
+        # skipped tiles first, then the diagonal's, then the full ones
+        assert kinds == ([SKIP] * (len(kinds) - len(visited))
+                         + [DIAG] * n_diag
+                         + [FULL] * (len(visited) - n_diag)), kinds
+        if visited:
+            yield c0, RQ - len(visited) * BQ, n_diag * BQ
+
+
+def _scaled(x, scale):
+    """x * scale in float32, back in x's dtype: the softmax scale applied
+    over a [rows, D] operand, once, instead of over every score tile."""
+    return (x.astype(f32) * scale).astype(x.dtype)
+
+
+def _scores(k, q, r_lo, c_lo, masked, key_mask):
+    """Scores of the keys k [nk, D] under the queries q [nq, D] (already
+    scaled), float32, KEYS ON SUBLANES and queries on lanes: [nk, nq]. A
+    query's running max, sum, logsumexp and delta are then rows [1, nq]
+    that broadcast down the sublanes for nothing, and the softmax
+    reductions run down the sublanes, elementwise between vector
+    registers, where the other way round every row of every tile pays a
+    cross-lane reduction. Only the first ``masked`` queries, those of the
+    tiles the diagonal crosses, build the iotas of the causal mask (key
+    c_lo + a is kept under query r_lo + b when a - b <= r_lo - c_lo);
+    ``key_mask`` [nk, 128], a padded batch's mask replicated over the
+    lanes, applies on every tile it touches."""
+    # dots take the refs' NATIVE dtype with f32 accumulation: bf16 inputs
+    # run the MXU at full rate (upcasting first would halve it); the
+    # softmax recurrence stays f32 throughout
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=f32)
+    if masked:
+        head = s[:, :masked]
+        a_minus_b = (jax.lax.broadcasted_iota(jnp.int32, head.shape, 0)
+                     - jax.lax.broadcasted_iota(jnp.int32, head.shape, 1))
+        head = jnp.where(a_minus_b <= r_lo - c_lo, head, NEG)
+        s = head if masked == s.shape[1] else jnp.concatenate(
+            [head, s[:, masked:]], axis=1)
+    if key_mask is not None:
+        s = jnp.where(jnp.tile(key_mask, (1, s.shape[1] // 128)) > 0, s, NEG)
+    return s
 
 
 # ------------------------------------------------------------------ forward
@@ -132,11 +287,36 @@ SCOPE = "flash_attention"
 FWD_NAME = "flash_attention_fwd"
 
 
+def _softmax_block(diagonal, scale, BQ, BK, q_ref, k_ref, v_ref, mask_ref,
+                   acc, m, l):
+    """Fold one resident block into the online-softmax carry in VMEM
+    scratch (acc [D, RQ] transposed like the scores, m and l [1, RQ]):
+    one pass of the recurrence a key tile, over all the queries that
+    visit it."""
+    RQ, RK = q_ref.shape[1], k_ref.shape[1]
+    q = _scaled(q_ref[0], scale)
+    vT = v_ref[0].T                       # [D, RK]: p^T contracts its keys
+    for c0, r_lo, masked in _visits(diagonal, RQ, RK, BQ, BK):
+        at = slice(r_lo, RQ)
+        s = _scores(k_ref[0, c0:c0 + BK, :], q[at], r_lo, c0, masked,
+                    None if mask_ref is None else mask_ref[0, c0:c0 + BK, :])
+        m_prev = m[:, at]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l[:, at] = l[:, at] * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc[:, at] = acc[:, at] * corr + jax.lax.dot_general(
+            vT[:, c0:c0 + BK], p.astype(vT.dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m[:, at] = m_new
+
+
 def _fwd_body(causal, masked, scale, BQ, BK, *refs):
     if masked:
         q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc, m, l = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m, l = refs
+        mask_ref = None
     i = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
@@ -147,76 +327,89 @@ def _fwd_body(causal, masked, scale, BQ, BK, *refs):
         m[:] = jnp.full_like(m, NEG)
         l[:] = jnp.zeros_like(l)
 
-    compute = True if not causal else (j * BK < (i + 1) * BQ)
-
-    @pl.when(compute)
-    def _update():
-        # dots take the refs' NATIVE dtype with f32 accumulation: bf16
-        # inputs run the MXU at full rate (upcasting first would halve
-        # it); the softmax recurrence stays f32 throughout
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-        if causal:
-            s = _causal_mask_block(i, j, BQ, BK, s)
-        if masked:
-            s = jnp.where(mask_ref[0][0:1, :] > 0, s, NEG)
-        m_prev = m[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l[:] = jnp.broadcast_to(l[:, :1] * corr + p.sum(1, keepdims=True),
-                                l.shape)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32)
-        m[:] = jnp.broadcast_to(m_new, m.shape)
+    _for_block(causal, i, j, q_ref.shape[1], k_ref.shape[1],
+               lambda diagonal: _softmax_block(
+                   diagonal, scale, BQ, BK, q_ref, k_ref, v_ref, mask_ref,
+                   acc, m, l))
 
     @pl.when(j == nj - 1)
     def _finalize():
-        o_ref[0] = (acc[:] / l[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc[:] / l[:]).T.astype(o_ref.dtype)
         lse_ref[0] = m[:] + jnp.log(l[:])
 
 
-def _fwd(q3, k3, v3, mask2, causal, scale):
-    """q3/k3/v3: [BH, T, D]; mask2: [B, T] or None. Returns (o, lse)."""
+def _row_major_specs(causal, RQ, RK, D, mask_heads):
+    """BlockSpecs of a (b, i, j) grid, k-blocks innermost: (q-side rows,
+    k-side rows, per-query scalars [., 1, T], key mask [., T, 128] or
+    None). Under ``causal`` the k side stops at the last block row block
+    i needs."""
+    def col(i, j):
+        return jnp.minimum(j, _last_col_block(i, RQ, RK)) if causal else j
+    qspec = pl.BlockSpec((1, RQ, D), lambda b, i, j: (b, i, 0))
+    kspec = pl.BlockSpec((1, RK, D), lambda b, i, j: (b, col(i, j), 0))
+    lspec = pl.BlockSpec((1, 1, RQ), lambda b, i, j: (b, 0, i))
+    mspec = None if mask_heads is None else pl.BlockSpec(
+        (1, RK, 128), lambda b, i, j: (b // mask_heads, col(i, j), 0))
+    return qspec, kspec, lspec, mspec
+
+
+def _lane_replicated(mask2):
+    """[B, T] key mask -> [B, T, 128] float32: a key's flag on every lane
+    of its sublane, the layout ``_scores`` tiles across its queries."""
+    return jnp.broadcast_to(mask2.astype(f32)[:, :, None],
+                            mask2.shape + (128,))
+
+
+def _tiles(T, causal):
+    """(BQ, BK, RQ, RK) of a call: resolved where the call is traced, and
+    handed to the jitted kernels below as a static argument."""
+    BQ, BK = _blocks(T, causal)
+    return (BQ, BK) + _resident(T, BQ, BK, causal)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "tiles",
+                                             "interpret"))
+def _fwd_call(q3, k3, v3, mask2, *, causal, scale, tiles, interpret):
+    """Jitted, so that a program of 24 layers traces the kernel body and
+    lowers it to Mosaic once, not once a layer: the static tile loop makes
+    the body several times longer than a one-tile kernel's, and every
+    process pays its tracing before it can even look in the compile cache
+    (PR 27's lesson with the paged kernel, PERF.md §6)."""
     BH, T, D = q3.shape
-    BQ, BK = _blocks(T)
-    grid = (BH, T // BQ, T // BK)
-    in_specs = [
-        pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0)),
-    ]
-    args = [q3, k3, v3]
+    BQ, BK, RQ, RK = tiles
     masked = mask2 is not None
+    qspec, kspec, lspec, mspec = _row_major_specs(
+        causal, RQ, RK, D, BH // mask2.shape[0] if masked else None)
+    in_specs = [qspec, kspec, kspec]
+    args = [q3, k3, v3]
     if masked:
-        H = BH // mask2.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, 1, BK), lambda b, i, j: (b // H, 0, j)))
-        args.append(mask2[:, None, :].astype(f32))
-    out_shape = [jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
-                 jax.ShapeDtypeStruct((BH, T, 128), f32)]
-    out_specs = [pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0)),
-                 pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0))]
+        in_specs.append(mspec)
+        args.append(_lane_replicated(mask2))
     with jax.named_scope(SCOPE):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_body, causal, masked, scale, BQ, BK),
             name=FWD_NAME,
-            grid=grid,
+            grid=(BH, T // RQ, T // RK),
             in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((BQ, D), f32),
-                            pltpu.VMEM((BQ, 128), f32),
-                            pltpu.VMEM((BQ, 128), f32)],
+            out_specs=[qspec, lspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
+                       jax.ShapeDtypeStruct((BH, 1, T), f32)],
+            scratch_shapes=[pltpu.VMEM((D, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
+            interpret=interpret,
         )(*args)
     return o, lse
+
+
+def _fwd(q3, k3, v3, mask2, causal, scale):
+    """q3/k3/v3: [BH, T, D]; mask2: [B, T] or None. Returns (o, lse), lse
+    [BH, 1, T] float32."""
+    return _fwd_call(q3, k3, v3, mask2, causal=causal, scale=scale,
+                     tiles=_tiles(q3.shape[1], causal),
+                     interpret=_interpret())
 
 
 # ------------------------------------------------------------------ dq pass
@@ -230,40 +423,39 @@ def _dq_body(causal, masked, scale, BQ, BK, *refs):
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dq_acc) = refs
+        mask_ref = None
     i = pl.program_id(1)
     j = pl.program_id(2)
     nj = pl.num_programs(2)
+    RQ, RK = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    compute = True if not causal else (j * BK < (i + 1) * BQ)
+    def block(diagonal):
+        q = _scaled(q_ref[0], scale)
+        kT = k_ref[0].T                   # [D, RK]: ds^T contracts its keys
+        for c0, r_lo, masked in _visits(diagonal, RQ, RK, BQ, BK):
+            at = slice(r_lo, RQ)
+            keys = slice(c0, c0 + BK)
+            s = _scores(k_ref[0, keys, :], q[at], r_lo, c0, masked,
+                        None if mask_ref is None else mask_ref[0, keys, :])
+            p = jnp.exp(s - lse_ref[0, :, at])
+            dp = jax.lax.dot_general(v_ref[0, keys, :], do_ref[0, at, :],
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)
+            ds = p * (dp - delta_ref[0, :, at])
+            dq_acc[:, at] += jax.lax.dot_general(
+                kT[:, keys], ds.astype(kT.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
 
-    @pl.when(compute)
-    def _update():
-        # native-dtype dot inputs, f32 accumulation (see _fwd_body)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-        if causal:
-            s = _causal_mask_block(i, j, BQ, BK, s)
-        if masked:
-            s = jnp.where(mask_ref[0][0:1, :] > 0, s, NEG)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=f32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dq_acc[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
-                                         (((1,), (0,)), ((), ())),
-                                         preferred_element_type=f32)
+    _for_block(causal, i, j, RQ, RK, block)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        # ds carries no scale: it multiplies the [D, RQ] sum once
+        dq_ref[0] = (dq_acc[:] * scale).T.astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------- dkv pass
@@ -277,40 +469,40 @@ def _dkv_body(causal, masked, scale, BQ, BK, *refs):
     else:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
+        mask_ref = None
     jk = pl.program_id(1)          # k-block (outer)
     i = pl.program_id(2)           # q-block (inner, "arbitrary")
     ni = pl.num_programs(2)
+    RQ, RK = q_ref.shape[1], k_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    compute = True if not causal else ((i + 1) * BQ > jk * BK)
+    def block(diagonal):
+        # scaled q serves the scores AND dk = ds (q * scale)
+        qs = _scaled(q_ref[0], scale)
+        for c0, r_lo, masked in _visits(diagonal, RQ, RK, BQ, BK):
+            at = slice(r_lo, RQ)
+            keys = slice(c0, c0 + BK)
+            q = qs[at]
+            do = do_ref[0, at, :]
+            s = _scores(k_ref[0, keys, :], q, r_lo, c0, masked,
+                        None if mask_ref is None else mask_ref[0, keys, :])
+            p = jnp.exp(s - lse_ref[0, :, at])                   # [BK, n]
+            dv_acc[keys, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            dp = jax.lax.dot_general(v_ref[0, keys, :], do,
+                                     (((1,), (1,)), ((), ())),
+                                     preferred_element_type=f32)
+            ds = p * (dp - delta_ref[0, :, at])
+            dk_acc[keys, :] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=f32)
 
-    @pl.when(compute)
-    def _update():
-        # native-dtype dot inputs, f32 accumulation (see _fwd_body)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-        if causal:
-            s = _causal_mask_block(i, jk, BQ, BK, s)
-        if masked:
-            s = jnp.where(mask_ref[0][0:1, :] > 0, s, NEG)
-        p = jnp.exp(s - lse_ref[0][:, :1])                    # [BQ, BK]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=f32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32)
+    _for_block(causal, i, jk, RQ, RK, block)
 
     @pl.when(i == ni - 1)
     def _finalize():
@@ -318,84 +510,72 @@ def _dkv_body(causal, masked, scale, BQ, BK, *refs):
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "tiles",
+                                             "interpret"))
+def _bwd_call(q3, k3, v3, mask2, o3, lse, do3, *, causal, scale, tiles,
+              interpret):
+    """Both backward kernels; jitted for the reason ``_fwd_call`` is."""
     BH, T, D = q3.shape
-    BQ, BK = _blocks(T)
+    BQ, BK, RQ, RK = tiles
     masked = mask2 is not None
-    # delta = rowsum(dO * O), lane-replicated like lse
-    delta = jnp.sum(do3.astype(f32) * o3.astype(f32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (BH, T, 128))
-
-    common_args = [q3, k3, v3, do3, lse, delta]
-    qspec = pl.BlockSpec((1, BQ, D), lambda b, x, y: (b, x, 0))
-
-    def q_side(which):
-        # index maps for the dq grid (b, i, j): q-indexed rows use i
-        return {
-            "q": pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0)),
-            "k": pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0)),
-            "v": pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0)),
-            "do": pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0)),
-            "lse": pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0)),
-            "delta": pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0)),
-        }[which]
-
-    in_specs = [q_side(n) for n in ("q", "k", "v", "do", "lse", "delta")]
-    args = list(common_args)
+    H = BH // mask2.shape[0] if masked else None
+    # delta = rowsum(dO * O), a row of per-query scalars like lse
+    delta = jnp.sum(do3.astype(f32) * o3.astype(f32), axis=-1)[:, None, :]
+    args = [q3, k3, v3, do3, lse, delta]
     if masked:
-        H = BH // mask2.shape[0]
-        in_specs.append(pl.BlockSpec(
-            (1, 1, BK), lambda b, i, j: (b // H, 0, j)))
-        args.append(mask2[:, None, :].astype(f32))
+        args.append(_lane_replicated(mask2))
+
+    # dq grid (b, i, j): q-indexed rows use i, k-blocks innermost
+    qspec, kspec, lspec, mspec = _row_major_specs(causal, RQ, RK, D, H)
+    in_specs = [qspec, kspec, kspec, qspec, lspec, lspec]
     with jax.named_scope(SCOPE):
         dq = pl.pallas_call(
             functools.partial(_dq_body, causal, masked, scale, BQ, BK),
             name=DQ_NAME,
-            grid=(BH, T // BQ, T // BK),
-            in_specs=in_specs,
+            grid=(BH, T // RQ, T // RK),
+            in_specs=in_specs + ([mspec] if masked else []),
             out_specs=[qspec],
             out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype)],
-            scratch_shapes=[pltpu.VMEM((BQ, D), f32)],
+            scratch_shapes=[pltpu.VMEM((D, RQ), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
+            interpret=interpret,
         )(*args)[0]
 
-    # dkv grid is (b, jk, i): q-indexed rows use the INNER index i
-    def kv_side(which):
-        return {
-            "q": pl.BlockSpec((1, BQ, D), lambda b, jk, i: (b, i, 0)),
-            "k": pl.BlockSpec((1, BK, D), lambda b, jk, i: (b, jk, 0)),
-            "v": pl.BlockSpec((1, BK, D), lambda b, jk, i: (b, jk, 0)),
-            "do": pl.BlockSpec((1, BQ, D), lambda b, jk, i: (b, i, 0)),
-            "lse": pl.BlockSpec((1, BQ, 128), lambda b, jk, i: (b, i, 0)),
-            "delta": pl.BlockSpec((1, BQ, 128), lambda b, jk, i: (b, i, 0)),
-        }[which]
-
-    in_specs = [kv_side(n) for n in ("q", "k", "v", "do", "lse", "delta")]
-    args = list(common_args)
+    # dkv grid is (b, jk, i): q-indexed rows use the INNER index i, and
+    # under ``causal`` start at the first q block the k block jk needs
+    def row(jk, i):
+        return jnp.maximum(i, _first_row_block(jk, RQ, RK)) if causal else i
+    qspec = pl.BlockSpec((1, RQ, D), lambda b, jk, i: (b, row(jk, i), 0))
+    lspec = pl.BlockSpec((1, 1, RQ), lambda b, jk, i: (b, 0, row(jk, i)))
+    kvspec = pl.BlockSpec((1, RK, D), lambda b, jk, i: (b, jk, 0))
+    in_specs = [qspec, kvspec, kvspec, qspec, lspec, lspec]
     if masked:
-        H = BH // mask2.shape[0]
         in_specs.append(pl.BlockSpec(
-            (1, 1, BK), lambda b, jk, i: (b // H, 0, jk)))
-        args.append(mask2[:, None, :].astype(f32))
-    kvspec = pl.BlockSpec((1, BK, D), lambda b, jk, i: (b, jk, 0))
+            (1, RK, 128), lambda b, jk, i: (b // H, jk, 0)))
     with jax.named_scope(SCOPE):
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_body, causal, masked, scale, BQ, BK),
             name=DKV_NAME,
-            grid=(BH, T // BK, T // BQ),
+            grid=(BH, T // RK, T // RQ),
             in_specs=in_specs,
             out_specs=[kvspec, kvspec],
             out_shape=[jax.ShapeDtypeStruct((BH, T, D), k3.dtype),
                        jax.ShapeDtypeStruct((BH, T, D), v3.dtype)],
-            scratch_shapes=[pltpu.VMEM((BK, D), f32),
-                            pltpu.VMEM((BK, D), f32)],
+            scratch_shapes=[pltpu.VMEM((RK, D), f32),
+                            pltpu.VMEM((RK, D), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=_interpret(),
+            interpret=interpret,
         )(*args)
     return dq, dk, dv
+
+
+def _bwd(q3, k3, v3, mask2, causal, scale, o3, lse, do3):
+    """(dq, dk, dv); lse [BH, 1, T] as ``_fwd`` returns it."""
+    return _bwd_call(q3, k3, v3, mask2, o3, lse, do3, causal=causal,
+                     scale=scale, tiles=_tiles(q3.shape[1], causal),
+                     interpret=_interpret())
 
 
 # ------------------------------------------------- ring-hop carry kernel
@@ -405,8 +585,9 @@ FWD_CARRY_NAME = "flash_attention_fwd_carry"
 def _fwd_carry_body(causal, scale, BQ, BK, *refs):
     """One ring hop's local block, CARRY-EMITTING: the online-softmax
     state (acc, m, l) enters as kernel inputs and leaves raw (no
-    normalize) so the ring can keep folding hops in. Same recurrence as
-    _fwd_body; m/l ride the lane-replicated [.,128] layout between hops."""
+    normalize) so the ring can keep folding hops in. The recurrence is
+    _fwd_body's own (``_softmax_block``); m/l ride between hops as rows
+    [., 1, Tq], acc as [., Tq, D]."""
     (q_ref, k_ref, v_ref, acc_in, m_in, l_in,
      acc_out, m_out, l_out, accs, ms, ls) = refs
     i = pl.program_id(1)
@@ -415,36 +596,18 @@ def _fwd_carry_body(causal, scale, BQ, BK, *refs):
 
     @pl.when(j == 0)
     def _init():
-        accs[:] = acc_in[0]
+        accs[:] = acc_in[0].T
         ms[:] = m_in[0]
         ls[:] = l_in[0]
 
-    compute = True if not causal else (j * BK < (i + 1) * BQ)
-
-    @pl.when(compute)
-    def _update():
-        # native-dtype dot inputs, f32 accumulation (see _fwd_body)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-        if causal:
-            s = _causal_mask_block(i, j, BQ, BK, s)
-        m_prev = ms[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        ls[:] = jnp.broadcast_to(ls[:, :1] * corr + p.sum(1, keepdims=True),
-                                 ls.shape)
-        accs[:] = accs[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32)
-        ms[:] = jnp.broadcast_to(m_new, ms.shape)
+    _for_block(causal, i, j, q_ref.shape[1], k_ref.shape[1],
+               lambda diagonal: _softmax_block(
+                   diagonal, scale, BQ, BK, q_ref, k_ref, v_ref, None,
+                   accs, ms, ls))
 
     @pl.when(j == nj - 1)
     def _finalize():
-        acc_out[0] = accs[:]
+        acc_out[0] = accs[:].T
         m_out[0] = ms[:]
         l_out[0] = ls[:]
 
@@ -455,29 +618,30 @@ def flash_block_update(acc, m, l, q3, k3, v3, *, causal: bool,
     [BH,Tk,D] block into the running online-softmax carry WITHOUT
     materializing the [Tq,Tk] scores in HBM (the XLA ring body's
     _block_update does — parallel/ring_attention.py). acc [BH,Tq,D] f32;
-    m/l lane-replicated [BH,Tq,128] f32. Returns the updated carry, raw
-    (caller normalizes after the last hop)."""
+    m/l rows [BH,1,Tq] f32. Returns the updated carry, raw (caller
+    normalizes after the last hop). A causal hop is the ring's diagonal
+    one: Tq == Tk."""
     BH, Tq, D = q3.shape
     Tk = k3.shape[1]
-    BQ, _ = _blocks(Tq)
-    _, BK = _blocks(Tk)
-    grid = (BH, Tq // BQ, Tk // BK)
-    qspec = pl.BlockSpec((1, BQ, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, BK, D), lambda b, i, j: (b, j, 0))
-    lspec = pl.BlockSpec((1, BQ, 128), lambda b, i, j: (b, i, 0))
+    if causal:
+        assert Tq == Tk, (Tq, Tk)
+        BQ, BK, RQ, RK = _tiles(Tq, True)
+    else:
+        BQ, BK = RQ, RK = _blocks(Tq)[0], _blocks(Tk)[1]
+    qspec, kspec, lspec, _ = _row_major_specs(causal, RQ, RK, D, None)
     with jax.named_scope(SCOPE):
         return pl.pallas_call(
             functools.partial(_fwd_carry_body, causal, scale, BQ, BK),
             name=FWD_CARRY_NAME,
-            grid=grid,
+            grid=(BH, Tq // RQ, Tk // RK),
             in_specs=[qspec, kspec, kspec, qspec, lspec, lspec],
             out_specs=[qspec, lspec, lspec],
             out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), f32),
-                       jax.ShapeDtypeStruct((BH, Tq, 128), f32),
-                       jax.ShapeDtypeStruct((BH, Tq, 128), f32)],
-            scratch_shapes=[pltpu.VMEM((BQ, D), f32),
-                            pltpu.VMEM((BQ, 128), f32),
-                            pltpu.VMEM((BQ, 128), f32)],
+                       jax.ShapeDtypeStruct((BH, 1, Tq), f32),
+                       jax.ShapeDtypeStruct((BH, 1, Tq), f32)],
+            scratch_shapes=[pltpu.VMEM((D, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),

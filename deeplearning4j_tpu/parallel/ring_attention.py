@@ -146,14 +146,14 @@ def _ring_fused_fwd(q3, k3, v3, axis, n, causal, scale):
         return (acc, m, l, k, v), None
 
     acc = jnp.zeros((BH, t, D), f32)
-    m = jnp.full((BH, t, 128), -1e30, f32)
-    l = jnp.zeros((BH, t, 128), f32)
+    m = jnp.full((BH, 1, t), -1e30, f32)     # per-query rows, the kernels'
+    l = jnp.zeros((BH, 1, t), f32)           # layout (pallas_attention._scores)
     (acc, m, l, _, _), _ = jax.lax.scan(step, (acc, m, l, k3, v3),
                                         jnp.arange(n))
     # epsilon guard matching the XLA ring body: a row that accumulated no
     # probability mass (a future key_mask / all-hops-skipped case) degrades
     # to zeros instead of NaN
-    o3 = (acc / jnp.maximum(l[:, :, :1], 1e-20)).astype(q3.dtype)
+    o3 = (acc / jnp.maximum(l, 1e-20).reshape(BH, t, 1)).astype(q3.dtype)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     return o3, lse
 

@@ -98,16 +98,29 @@ def test_conv1x1_bias_relu_compiles(v5e, shape, F, dtype):
     assert n == 1
 
 
-@pytest.mark.parametrize("B,H,T,D,dtype", [
-    (1, 2, 256, 64, f32),                                # the parity pin
-    (8, 8, 1024, 64, bf16)])                             # chip_smoke's LM
-def test_flash_attention_fwd_bwd_compiles(v5e, B, H, T, D, dtype):
+@pytest.mark.parametrize("B,H,T,D,dtype,backward", [
+    (1, 2, 256, 64, f32, True),                          # the parity pin
+    (8, 8, 1024, 64, bf16, True),                        # chip_smoke's LM
+    (8, 16, 1024, 64, bf16, True),       # the benchmark's training cell
+    (4, 16, 768, 64, bf16, False),       # the long-prompt cell's prefills,
+    (4, 16, 1024, 64, bf16, False),      # rung 768 and rung 1024
+    (2, 4, 1024, 128, f32, True),        # the widest operands a resident
+    (2, 4, 2048, 128, f32, True),        # block holds; two blocks a side
+    (2, 4, 1024, 96, bf16, True)])
+def test_flash_attention_fwd_bwd_compiles(v5e, B, H, T, D, dtype, backward):
+    """Mosaic takes the resident blocks and the static tile walk at the
+    shapes the cells run (a VMEM or lowering refusal shows here, without
+    a chip), forward alone as a prefill runs it and with both backward
+    kernels as ``fit`` does."""
+    def forward(q, k, v):
+        return pallas_attention.flash_attention(q, k, v, causal=True)
+
     def loss(q, k, v):
-        o = pallas_attention.flash_attention(q, k, v, causal=True)
-        return jnp.sum(o.astype(f32))
+        return jnp.sum(forward(q, k, v).astype(f32))
     qkv = ((B, H, T, D), dtype)
-    n = _custom_calls(v5e, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
-    assert n == 3           # forward, dq pass, dk/dv pass
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else forward
+    n = _custom_calls(v5e, fn, qkv, qkv, qkv)
+    assert n == (3 if backward else 1)  # forward, dq pass, dk/dv pass
 
 
 def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):
@@ -215,7 +228,7 @@ def _flash_carry_text(v5e):
     return _compiled_text(
         v5e, lambda acc, m, l, q, k, v: pallas_attention.flash_block_update(
             acc, m, l, q, k, v, causal=True, scale=0.125),
-        ((BH, T, D), f32), ((BH, T, 128), f32), ((BH, T, 128), f32),
+        ((BH, T, D), f32), ((BH, 1, T), f32), ((BH, 1, T), f32),
         ((BH, T, D), bf16), ((BH, T, D), bf16), ((BH, T, D), bf16))
 
 

@@ -42,7 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-# The one language model the repo has, at full width (bench.py ``_TLM``).
+# The zoo's language model (``models/zoo_extra.transformer_lm``) at d=512.
 LM = dict(vocab_size=4096, d_model=512, n_heads=8, n_blocks=12,
           max_length=1024, dtype="bfloat16")
 TRAIN = dict(batch=8, steps=6, window=4)

@@ -1,7 +1,5 @@
 """LeNet (reference deeplearning4j-zoo zoo/model/LeNet.java — conv(5x5,20)
 -> maxpool -> conv(5x5,50) -> maxpool -> dense(500) -> softmax(10)).
-
-BASELINE config #1: LeNet MNIST on a single TPU chip.
 """
 from __future__ import annotations
 

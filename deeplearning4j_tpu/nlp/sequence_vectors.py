@@ -144,7 +144,7 @@ class SequenceVectors:
         """Jitted batched SGNS step with scatter-add-only table updates: the
         gradient is derived analytically on the gathered rows (_sgns_grads) so
         no dense [V,D] gradient buffer exists — the update cost scales with
-        the batch, not the vocabulary (the 1M-word workload of BASELINE #4;
+        the batch, not the vocabulary (a 1M-word vocabulary is the case;
         same per-pair math as jax.grad of the dense loss, colliding rows
         accumulate via scatter-add exactly as autodiff's gather-transpose
         would)."""

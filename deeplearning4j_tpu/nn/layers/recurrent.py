@@ -43,9 +43,7 @@ def _lstm_scan(x_proj, h0, c0, R, act, gate_act, peepholes=None, mask=None,
     The fused path is the reference's accelerated-helper seam
     (ConvolutionLayer.java:72 reflection probe for cuDNN) done the TPU way:
     ops/pallas_lstm.py pins the recurrent matrix in VMEM across the whole
-    time loop; measured 2.4-2.7x device-time vs this scan and 3.0x vs the
-    flax OptimizedLSTMCell reference at the char-RNN bench shape (2-layer
-    net, T=64, B=32, H=512) — numbers in ops/pallas_lstm.py.
+    time loop.
     """
     H = h0.shape[-1]
     from ...ops.pallas_lstm import (fused_lstm, fused_lstm_applicable,

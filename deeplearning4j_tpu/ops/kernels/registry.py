@@ -177,8 +177,8 @@ def record_kernel_timing(name: str, shape_sig: str,
     """Fold one measured kernel time into the live perf gauges and flag
     below-roofline kernels — ``perf.kernels.<name>.measured_ms`` /
     ``.roofline_ms`` / ``.vs_roofline`` / ``.below_roofline`` (1.0 when
-    the kernel runs slower than 2x its roofline bound, the same
-    flagging threshold BASELINE.md uses). No-op (returns None) when the
+    the kernel runs slower than 2x its roofline bound). No-op (returns
+    None) when the
     kernel has no roofline model or the device's peaks are unknown."""
     spec = get(name)
     if spec.roofline is None or measured_s <= 0:

@@ -14,10 +14,8 @@ matmul chain. This is the accelerated-helper seam of the reference
 path is used when it applies, the scan fallback otherwise, and parity tests
 pin one to the other (tests/test_pallas_lstm.py).
 
-Measured on v5e (device-slope timing, bench.py _loop_slope_time) at the
-char-RNN bench shape (2-layer net, T=64, B=32, H=512, f32): single-layer
-train step 164us fused vs 297us scan; full-net 4.0M tokens/s fused vs
-1.33M flax OptimizedLSTMCell (3.0x).
+No benchmark cell runs this kernel yet, so it has no chip timing on record
+(ROADMAP Queue 3 item 8).
 
 Supported fast path: tanh/sigmoid activations, float32, H % 128 == 0,
 B % 8 == 0, VMEM-resident R (H <= 512); with or without peephole

@@ -478,7 +478,8 @@ class ParallelWrapper:
         # NOTE: no auto model axis here — on jax 0.4.37 the engine's
         # axis_index / psum_scatter collectives only lowered under a
         # fully-manual region; whether jax 0.9.0's shard_map (axis_names=)
-        # lifts that is UNTESTED (ROADMAP Queue 3 item 8 retries it).
+        # lifts that is UNTESTED (ROADMAP Queue 3, the ParallelWrapper
+        # item, retries it).
         # On a (data, model) mesh the flat update stays sharded
         # d ways over 'data' (replicated across model); params are
         # model-sharded AT REST via the jit boundary and gathered for

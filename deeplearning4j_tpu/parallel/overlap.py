@@ -80,7 +80,7 @@ class BucketSchedule:
         return len(self.leaf_shapes)
 
     def describe(self) -> List[dict]:
-        """Host-side summary rows (telemetry / bench / dryrun)."""
+        """Host-side summary rows (telemetry / dryrun)."""
         return [{"bucket": i, "leaves": len(b), "bytes": b.nbytes}
                 for i, b in enumerate(self.buckets)]
 
@@ -188,7 +188,7 @@ def profile_schedule(mesh, schedule: BucketSchedule, axis: str = "data",
     per bucket, best-of-``repeats``), emit a per-bucket Chrome-trace event
     (cat="collective") under the current span path, and set the
     ``parallel.collective_ms`` gauge to the total. Host-side tooling for
-    bench/dryrun/traces — the training step itself never calls this."""
+    dryrun/traces — the training step itself never calls this."""
     from jax.sharding import PartitionSpec as P
     from .mesh import shard_map
 

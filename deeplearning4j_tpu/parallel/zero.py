@@ -246,9 +246,8 @@ class ZeroUpdateEngine:
 
     @property
     def shard_state_bytes(self) -> int:
-        """Per-replica updater-state bytes under sharding (the number the
-        zero_sharded_update bench row reports against the replicated
-        allocation)."""
+        """Per-replica updater-state bytes under sharding (set against
+        ``replicated_state_bytes``, about 1/n of it)."""
         return sum(g.length * g.dtype.itemsize * len(g.state_keys)
                    for g in self.groups)
 
@@ -578,7 +577,7 @@ class ZeroUpdateEngine:
         tools/trace2summary.py folds into their own phase buckets, the
         gather half under a ``zero.allgather`` span, and refresh the
         ``zero.*`` gauges. Per-row ``bytes`` is the padded buffer the
-        collective actually moves. Host-side tooling for bench/dryrun —
+        collective actually moves. Host-side tooling for dryrun/traces —
         the training step never calls this."""
         from jax.sharding import PartitionSpec as P
         from .mesh import shard_map

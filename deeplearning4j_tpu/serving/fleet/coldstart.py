@@ -15,9 +15,9 @@ checkpoint-load time.
 Accounting: on this jax line the ``backend_compile_duration`` monitoring
 event fires even when the executable was answered from the cache, so
 "did this replica compile anything NEW" is ``xla_compile_count() -
-xla_cache_hit_count()`` — :func:`fresh_compile_count`. The fleet bench's
-cold-start acceptance pins that a warm-cache replica reaches ready with
-ZERO fresh compiles for already-seen programs.
+xla_cache_hit_count()`` — :func:`fresh_compile_count`. The acceptance
+(tests/test_fleet_process.py) is that a warm-cache replica reaches ready
+with ZERO fresh compiles for already-seen programs.
 """
 from __future__ import annotations
 

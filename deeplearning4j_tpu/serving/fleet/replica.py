@@ -16,7 +16,7 @@ Child lifecycle (``python -m deeplearning4j_tpu.serving.fleet.replica``):
   2. build the model from the spec — a checkpoint/model-zip ``path``
      (serving.registry.load_net) or a deterministic ``zoo`` constructor
      (same seed -> identical params in every replica, no weight
-     distribution step needed for benches and tests);
+     distribution step needed for tests and drives);
   3. construct + AOT-warm the GenerationEngine, start the HTTP server;
   4. atomically write the ready file (port, pid, ready_s, platform,
      device_kind, cold-start accounting) — the supervisor's readiness
@@ -42,7 +42,7 @@ from typing import Optional
 
 def _default_spec_model() -> dict:
     """The tiny deterministic LM used when a spec omits ``model`` —
-    bench/test scaffolding, not a production default."""
+    test scaffolding, not a production default."""
     return {"zoo": "transformer_lm",
             "kwargs": {"vocab_size": 64, "d_model": 16, "n_heads": 2,
                        "n_blocks": 1, "max_length": 64, "seed": 7,

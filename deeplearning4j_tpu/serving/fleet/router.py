@@ -35,9 +35,10 @@ from ...util.retry import RetryPolicy
 from .affinity import DEFAULT_BLOCK_LEN, AffinityPolicy, prompt_chain
 from .replica import ReplicaProcess
 
-# consecutive transport failures after which a replica is DEAD (the
-# bench_smoke guard pins this: flapping sockets must not flap membership,
-# and a hard-killed replica must stop receiving traffic within 3 strikes)
+# consecutive transport failures after which a replica is DEAD
+# (tests/test_fleet_router.py pins it: flapping sockets must not flap
+# membership, and a hard-killed replica must stop receiving traffic within
+# 3 strikes)
 DEAD_AFTER = 3
 
 STARTING, READY, DRAINING, DEAD = "starting", "ready", "draining", "dead"

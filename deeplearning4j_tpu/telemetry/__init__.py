@@ -16,19 +16,16 @@ Built-in instrumentation reports here from ``Solver``/``MultiLayerNetwork``
 /``ComputationGraph.fit`` (fit/epoch/window/dispatch spans),
 ``DevicePrefetchIterator`` (queue depth, ship latency, stall time),
 ``ParallelWrapper``, ``PerformanceListener`` and the ``serving/`` engine —
-disable it all with ``get_registry().enabled = False`` (a near-no-op; the
-``telemetry_overhead_pct`` bench row guards <5% enabled overhead on a
-dispatch-bound loop).
+disable it all with ``get_registry().enabled = False`` (a near-no-op).
 """
 from .flightrec import (FlightRecorder, configure_flight_recorder,
                         get_flight_recorder, set_flight_recorder)
 from .jaxsignals import (HostSyncDetector, HostSyncError, RecompileDetector,
                          device_memory_gauges, ensure_monitoring_hook,
                          xla_cache_hit_count, xla_compile_count)
-from .perf import (PerfBaseline, ProgramCostIndex, StepAccounting,
-                   classify_roofline, get_cost_index, implied_mfu,
-                   normalize_cost_analysis, perf_snapshot, set_cost_index,
-                   write_perf_dump)
+from .perf import (ProgramCostIndex, StepAccounting, classify_roofline,
+                   get_cost_index, implied_mfu, normalize_cost_analysis,
+                   perf_snapshot, set_cost_index, write_perf_dump)
 from .registry import (Counter, Gauge, Histogram, HistogramLadderMismatch,
                        MetricsRegistry, bucket_quantile, get_registry,
                        merge_cumulative_buckets, set_registry)
@@ -58,7 +55,7 @@ __all__ = [
     "configure_flight_recorder",
     "SLOWatchdog", "LatencySLO", "ErrorRateSLO", "ThroughputSLO",
     "get_slo_watchdog", "set_slo_watchdog",
-    "ProgramCostIndex", "StepAccounting", "PerfBaseline",
+    "ProgramCostIndex", "StepAccounting",
     "get_cost_index", "set_cost_index", "perf_snapshot", "write_perf_dump",
     "implied_mfu", "classify_roofline", "normalize_cost_analysis",
     "TrainingWatch", "get_training_watch", "set_training_watch",

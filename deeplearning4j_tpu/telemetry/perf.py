@@ -1,10 +1,8 @@
 """Performance observability: the live cost-model accounting layer.
 
-Until now only ``bench.py`` knew how fast the hardware allows: its
-private cost-analysis/MFU helpers computed FLOPs, bytes and implied MFU
-for bench rows, while the live fit/serving/generation paths exposed
-wall-clock only. This module hoists that cost model into ONE shared
-implementation and turns it into *live* gauges:
+ONE cost model (FLOPs, bytes, implied MFU, roofline class) for every
+program the system compiles, turned into *live* gauges on the
+fit/serving/generation paths:
 
 - **Shared cost model** — :func:`normalize_cost_analysis` (the one place
   that knows ``compiled.cost_analysis()`` returns a list-of-dict on some
@@ -15,8 +13,7 @@ implementation and turns it into *live* gauges:
   ``device_kind`` (:data:`DEVICE_PEAKS`); ``BENCH_PEAK_TFLOPS`` /
   ``BENCH_HBM_GBPS`` are explicit overrides. A device the table does not
   know, with no override, has NO peak: MFU and roofline gauges are then
-  absent, never computed against another chip's numbers. bench.py
-  delegates here, so bench rows and live gauges cannot disagree.
+  absent, never computed against another chip's numbers.
 
 - **:class:`ProgramCostIndex`** — captures the XLA cost analysis of
   every program the system compiles, keyed by the program's span path:
@@ -42,11 +39,6 @@ implementation and turns it into *live* gauges:
   histograms): the fit loop appends plain floats and the buffers flush
   at window boundaries, same zero-host-sync discipline as TrainingWatch.
 
-- **:class:`PerfBaseline`** — loads the checked-in ``BENCH_r*.json``
-  trajectory (tolerating the truncated tails of real artifact files) so
-  the :class:`~.slo.ThroughputSLO` watchdog and ``tools/perf_report.py``
-  can compare live steady-state rows against the best recorded run.
-
 Kill switch: ``DL4J_TPU_PERF_ACCOUNTING=0`` disables capture and fold
 (a disabled registry disables them too).
 """
@@ -68,7 +60,7 @@ __all__ = ["normalize_cost_analysis", "cost_analysis_of", "implied_mfu",
            "roofline_dt", "classify_roofline", "peak_tflops", "hbm_gbps",
            "max_plausible_mfu", "accounting_enabled",
            "ProgramCost", "ProgramCostIndex", "get_cost_index",
-           "set_cost_index", "StepAccounting", "PerfBaseline",
+           "set_cost_index", "StepAccounting",
            "decomposition_summary", "write_perf_dump", "perf_snapshot"]
 
 _ENV_KILL = "DL4J_TPU_PERF_ACCOUNTING"
@@ -123,7 +115,7 @@ def max_plausible_mfu(override: Optional[float] = None) -> float:
 def normalize_cost_analysis(ca) -> dict:
     """Normalize a raw ``cost_analysis()`` result across backends
     (list-of-dict on some, dict on others, occasionally neither) — THE
-    one place that knows the quirk (bench.py delegates here)."""
+    one place that knows the quirk."""
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}
     return ca if hasattr(ca, "get") else {}
@@ -408,8 +400,7 @@ class ProgramCostIndex:
                     if e.flops_per_step:
                         achieved = e.flops_per_step / (dt_step_ms / 1e3) / 1e12
                         # full precision: a toy CPU program's MFU is ~1e-8 —
-                        # rounding here would zero it and break the
-                        # report-vs-bench agreement check (renderers format)
+                        # rounding here would zero it (renderers format)
                         row["achieved_tflops"] = achieved
                         if peak:
                             row["mfu"] = achieved / peak
@@ -529,134 +520,6 @@ def decomposition_summary(registry: Optional[MetricsRegistry] = None
     return out
 
 
-# --------------------------------------------------------------- baseline
-class PerfBaseline:
-    """The checked-in ``BENCH_r*.json`` trajectory as comparable rows.
-
-    Real artifact files keep only the TAIL of the bench's stdout, so the
-    final headline JSON line is often truncated mid-object; extraction
-    is therefore per-row: for each known row name, find ``"<name>":`` in
-    the tail and ``raw_decode`` the value that follows (a row cut off by
-    the truncation is skipped, never guessed). ``best(name)`` returns
-    the best value across the trajectory — the baseline the
-    :class:`~.slo.ThroughputSLO` watchdog and ``tools/perf_report.py``
-    compare against."""
-
-    # row -> (sub-key inside a dict row, or None for scalar rows)
-    KNOWN_ROWS: Dict[str, Optional[str]] = {
-        "dispatch_bound_steps_per_sec": "k8_steps_per_sec",
-        "serving_throughput": "bucketed_req_per_sec",
-        "generate_tokens_per_sec": "continuous_tokens_per_sec",
-        "transformer_lm_tokens_per_sec": None,
-        "lstm_train_tokens_per_sec": None,
-        "resnet50_amp_img_per_sec": None,
-        "word2vec_words_per_sec": "words_per_sec",
-    }
-
-    def __init__(self, per_file: Dict[str, Dict[str, float]]):
-        self.per_file = per_file          # file -> {row: value}
-
-    @classmethod
-    def load_trajectory(cls, root: str = ".",
-                        pattern: str = "BENCH_r*.json") -> "PerfBaseline":
-        import glob
-        per_file: Dict[str, Dict[str, float]] = {}
-        for path in sorted(glob.glob(os.path.join(root, pattern))):
-            try:
-                with open(path) as f:
-                    artifact = json.load(f)
-            except (OSError, ValueError):
-                continue
-            rows = cls._extract_rows(artifact)
-            if rows:
-                per_file[os.path.basename(path)] = rows
-        return cls(per_file)
-
-    @classmethod
-    def _extract_rows(cls, artifact) -> Dict[str, float]:
-        parsed = artifact.get("parsed") if isinstance(artifact, dict) \
-            else None
-        text = artifact.get("tail", "") if isinstance(artifact, dict) \
-            else ""
-        if isinstance(parsed, dict):
-            text = json.dumps(parsed) + "\n" + text
-        out: Dict[str, float] = {}
-        dec = json.JSONDecoder()
-        for name, sub in cls.KNOWN_ROWS.items():
-            # LAST occurrence: the bench re-prints the result after every
-            # row, so the final print carries the finished value
-            idx = text.rfind(f'"{name}":')
-            if idx < 0:
-                continue
-            rest = text[idx + len(name) + 3:].lstrip()
-            try:
-                val, end = dec.raw_decode(rest)
-            except ValueError:
-                continue                       # truncated mid-value
-            if end >= len(rest):
-                # the value ran to the very end of the (truncated) tail:
-                # a number cut mid-digits still parses, so anything not
-                # followed by more JSON is unverifiable — skip, never
-                # guess
-                continue
-            if isinstance(val, dict):
-                val = val.get(sub) if sub else val.get("value")
-            if isinstance(val, (int, float)) and val > 0:
-                out[name] = float(val)
-        return out
-
-    def best(self, name: str) -> Optional[float]:
-        vals = [(rows.get(name), f) for f, rows in self.per_file.items()
-                if rows.get(name)]
-        return max(vals)[0] if vals else None
-
-    def best_with_file(self, name: str) -> Tuple[Optional[float],
-                                                 Optional[str]]:
-        vals = [(rows[name], f) for f, rows in self.per_file.items()
-                if rows.get(name)]
-        return max(vals) if vals else (None, None)
-
-    def rows(self) -> List[str]:
-        names = set()
-        for rows in self.per_file.values():
-            names.update(rows)
-        return sorted(names)
-
-
-def baseline_deltas(baseline: "PerfBaseline",
-                    registry: Optional[MetricsRegistry] = None
-                    ) -> List[dict]:
-    """Live gauge vs best-baseline rows for the rows that map onto live
-    metrics ([] when neither side has data). The mapping is honest only
-    when the live workload matches the bench row's — the regression
-    watchdog exists for deployments that run the bench workloads (or
-    operator-supplied baselines); the report labels the file the best
-    value came from so a stale baseline is visible."""
-    reg = registry or get_registry()
-    live_map = {
-        "dispatch_bound_steps_per_sec": "train.windowed_steps_per_sec",
-        "generate_tokens_per_sec": None,      # resolved below (per-model)
-    }
-    out: List[dict] = []
-    for row in baseline.rows():
-        best, src = baseline.best_with_file(row)
-        live = None
-        metric = live_map.get(row)
-        if metric:
-            g = reg.gauge_if_exists(metric)
-            live = g.value if g is not None and g.value else None
-        elif row == "generate_tokens_per_sec":
-            vals = [g.value for n, g in reg.gauges_matching(
-                "generation.", ".tokens_per_sec") if g.value]
-            live = max(vals) if vals else None
-        rec = {"row": row, "baseline_best": best, "baseline_file": src,
-               "live": round(live, 3) if live else None}
-        if live and best:
-            rec["ratio"] = round(live / best, 4)
-        out.append(rec)
-    return out
-
-
 # -------------------------------------------------------------- snapshots
 def perf_snapshot(registry: Optional[MetricsRegistry] = None,
                   index: Optional[ProgramCostIndex] = None,
@@ -703,11 +566,9 @@ def perf_snapshot(registry: Optional[MetricsRegistry] = None,
 def write_perf_dump(path: str, *,
                     registry: Optional[MetricsRegistry] = None,
                     index: Optional[ProgramCostIndex] = None,
-                    baseline_root: Optional[str] = None,
                     top_k: int = 10) -> str:
     """Write the offline-report input file: folded cost table, step
-    decomposition, memory profile, full metrics snapshot and (when
-    ``baseline_root`` holds ``BENCH_r*.json`` files) baseline deltas.
+    decomposition, memory profile and full metrics snapshot.
     ``tools/perf_report.py`` renders it; a flight-recorder dump is an
     acceptable substitute (it carries the same ``perf`` block)."""
     reg = registry or get_registry()
@@ -716,10 +577,6 @@ def write_perf_dump(path: str, *,
               "perf": perf_snapshot(reg, idx, top_k=top_k,
                                     fresh_memory=True),
               "metrics": reg.snapshot()}
-    if baseline_root is not None:
-        baseline = PerfBaseline.load_trajectory(baseline_root)
-        record["baseline"] = {"files": baseline.per_file,
-                              "deltas": baseline_deltas(baseline, reg)}
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
         json.dump(record, f, default=repr)

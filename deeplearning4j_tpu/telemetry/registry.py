@@ -16,9 +16,7 @@ Design constraints (the hot paths this instruments are dispatch-bound):
   touches a device buffer, so instrumentation can never add a host sync.
 - A DISABLED registry is a near-no-op: metric lookups return shared
   null objects whose methods are empty one-liners, and ``span()`` (see
-  spans.py) short-circuits to a shared no-op context manager. The
-  ``telemetry_overhead_pct`` bench row + its bench_smoke guard pin the
-  enabled-path overhead <5% on a dispatch-bound CPU loop.
+  spans.py) short-circuits to a shared no-op context manager.
 """
 from __future__ import annotations
 

@@ -82,21 +82,19 @@ class ThroughputSLO:
     rate — ``train.windowed_steps_per_sec`` (PerformanceListener),
     ``generation.<model>.tokens_per_sec``, a ``perf.<path>.mfu`` gauge
     from the cost index (telemetry/perf.py), or any operator-published
-    rate. ``baseline`` is the best recorded value for the SAME workload
-    — typically ``PerfBaseline.load_trajectory(...).best(row)`` over the
-    checked-in ``BENCH_r*.json`` files, or an operator-pinned number.
+    rate. ``baseline`` is the best value the operator has recorded for
+    the SAME workload on the SAME device, pinned by hand.
 
     Each watchdog ``check()`` turns the gauge into one good/bad sample
-    using the paired best-of discipline the bench guards use on this
-    noisy rig: the BEST of the last ``best_of`` readings is compared
-    against ``ratio_floor * baseline`` — a co-tenant load burst dents
-    some readings but not the window's best, while a real regression
+    by a paired best-of: the BEST of the last ``best_of`` readings is
+    compared against ``ratio_floor * baseline`` — a co-tenant load burst
+    dents some readings but not the window's best, while a real regression
     lifts every reading. The good/bad stream then rides the standard
     multi-window burn-rate machinery (``target`` = the fraction of
     checks that must pass), so a sustained regression pages through the
     same breach-edge -> flight-dump path as a latency SLO. A gauge that
     has never been set (0) contributes NO sample — cold start cannot
-    breach. ``baseline`` <= 0 (row missing from the trajectory) makes
+    breach. ``baseline`` <= 0 (none recorded yet) makes
     the objective report-only: the ratio gauge is published, nothing can
     breach."""
     name: str
@@ -199,8 +197,8 @@ class SLOWatchdog:
 
     def _throughput_totals(self, obj: ThroughputSLO) -> Tuple[float, float]:
         """One good/bad sample per check from the live gauge: best of the
-        recent readings vs ``ratio_floor * baseline`` (paired best-of —
-        the bench-guard discipline for a rig with co-tenant load bursts).
+        recent readings vs ``ratio_floor * baseline`` (paired best-of,
+        for a host with co-tenant load bursts).
         An unset gauge adds no sample; an unknown baseline never bads."""
         reg = self.registry
         st = self._throughput[obj.name]

@@ -241,13 +241,11 @@ def _render_kernels_table(reg, snap, heading: str) -> str:
 def _render_performance_card(title: str, kernels_heading: str = "Kernels") -> str:
     """Performance-observability card (telemetry/perf.py + memprof.py):
     per-program MFU/roofline rows from the cost index, the step-time
-    decomposition, the live-memory top-K and — when BENCH_r*.json files
-    are present in the working directory — the baseline-delta headline.
+    decomposition and the live-memory top-K.
     Empty cost index AND empty decomposition renders nothing (a training
     run that predates the perf layer keeps its old page)."""
     from ..telemetry import get_registry
-    from ..telemetry.perf import (PerfBaseline, baseline_deltas,
-                                  get_cost_index, perf_snapshot)
+    from ..telemetry.perf import get_cost_index, perf_snapshot
     reg = get_registry()
     if not reg.enabled:
         return ""
@@ -256,7 +254,7 @@ def _render_performance_card(title: str, kernels_heading: str = "Kernels") -> st
     decomp = snap.get("step_decomposition") or {}
     if not programs and not decomp:
         return ""
-    # headline: the best live MFU + a baseline delta when one is known
+    # headline: the best live MFU
     headline = []
     with_mfu = [r for r in programs if r.get("mfu") is not None]
     if with_mfu:
@@ -264,15 +262,6 @@ def _render_performance_card(title: str, kernels_heading: str = "Kernels") -> st
         headline.append(("best MFU",
                          f"{best['mfu']:.2%} ({html.escape(best['path'])},"
                          f" {best['roofline']}-bound)"))
-    try:
-        baseline = PerfBaseline.load_trajectory(".")
-        for d in baseline_deltas(baseline, reg):
-            if d.get("ratio"):
-                headline.append(
-                    (f"vs baseline [{html.escape(d['row'])}]",
-                     f"{d['ratio']:.2f}x of {html.escape(str(d['baseline_file']))}"))
-    except Exception:           # pragma: no cover - defensive
-        pass
     hrows = "".join(
         f"<tr><th>{k}</th><td>{v}</td></tr>" for k, v in headline)
     def _cell(v, pct=False):

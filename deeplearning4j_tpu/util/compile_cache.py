@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives — placed from OUTSIDE.
 
-Every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py`` ``main``, the fleet replica child, ``__graft_entry__``) calls
+Every entry point that compiles for the chip (``chip_smoke.py``, the
+fleet replica child, ``__graft_entry__``) calls
 :func:`ensure_compile_cache` before its first compile and nothing else in
 the repo names a cache directory:
 

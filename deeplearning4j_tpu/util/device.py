@@ -12,8 +12,8 @@ import os
 
 def device_record() -> dict:
     """``{"platform", "kind", "count"}`` as JAX reports them — the label
-    every bench row, replica ready record and ``chip_smoke.py`` result
-    carries."""
+    every benchmark result, replica ready record and ``chip_smoke.py``
+    result carries."""
     import jax
     devs = jax.devices()
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,

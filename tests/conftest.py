@@ -9,8 +9,9 @@ import tempfile
 
 # Tests run on the virtual 8-device CPU platform wherever they are started
 # (SURVEY.md §4: the analogue of the reference's Spark local[n] testing);
-# the chip is reached only through chip_smoke.py / bench.py. The env var
-# covers this process and every child it spawns (fleet replicas).
+# the chip is reached only through chip_smoke.py and benchmarks/run.py.
+# The env var covers this process and every child it spawns (fleet
+# replicas).
 os.environ["JAX_PLATFORMS"] = "cpu"
 # The CPU has no row in telemetry/perf.py's DEVICE_PEAKS, so without an
 # override there is no MFU/roofline gauge at all. Tests that assert those

@@ -198,7 +198,7 @@ def test_transformer_lm_zoo_model_trains():
 def test_transformer_lm_token_input_trains():
     """token_input=True feeds [B,T] int ids through the
     EmbeddingSequenceLayer gather and learns the same shift-by-one task
-    (the TPU-first input path used by the transformer-LM bench row).
+    (the TPU-first input path the benchmark's GPT-2 configuration takes).
 
     Slow lane (tier-1 budget): the token-input path is trained in tier-1
     by tests/test_tensor_parallel.py's mesh-parity fits and decoded all

@@ -517,15 +517,3 @@ def test_chaos_soak_random_fault_plan(tmp_path):
     assert np.isfinite(_flat(b)).all()
     out = b.output(_X[:8])
     assert np.isfinite(np.asarray(out)).all()
-
-
-# ------------------------------------------------------------- bench smoke
-@pytest.mark.bench_smoke
-def test_elastic_recovery_bench_smoke():
-    import bench
-    row = bench.bench_elastic_recovery(steps=24, ckpt_every=4)
-    assert row["value"] is not None and row["value"] > 0
-    assert row["recoveries"] == 1
-    assert row["steady_steps_per_sec_ckpt"] > 0
-    assert row["steady_steps_per_sec_none"] > 0
-    assert isinstance(row["ckpt_overhead_pct"], float)

@@ -456,25 +456,3 @@ def test_fleet_report_renders_slo_and_collector_sections():
     plain = render(fold({"policy": "affinity", "block_len": 8,
                          "replicas": {}}))
     assert "fleet SLOs" not in plain and "collector:" not in plain
-
-
-# ------------------------------------------------------------- bench guard
-@pytest.mark.bench_smoke
-def test_fleet_collector_overhead_bench_smoke():
-    """Tier-1 guard for the ISSUE 19 bench variant: collector pulls +
-    spool spills riding the serving process must stay <5% on the paired
-    best-of ratio. Same retry discipline as the other telemetry guards —
-    wall clock on a shared rig swings, so fail only on three consecutive
-    breaches."""
-    import bench
-    last = None
-    for _ in range(3):
-        row = bench.bench_telemetry_overhead(steps=32, repeats=4,
-                                             serving_requests=80,
-                                             variants=("fleet",))
-        assert row["fleet_collected_req_per_sec"] > 0
-        last = row
-        if row["fleet_collector_overhead_pct"] < 5.0:
-            return
-    pytest.fail(f"fleet collector overhead >=5% in 3 consecutive runs: "
-                f"{last}")

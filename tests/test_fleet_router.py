@@ -167,11 +167,9 @@ def test_replica_dead_after_three_transport_failures(pair, fresh_registry):
         router.close()
 
 
-@pytest.mark.bench_smoke
 def test_dead_after_discipline_is_pinned():
-    """bench.py's fleet chaos row and the router tests both assume the
-    3-consecutive-failure mark-dead discipline — a change here must be a
-    deliberate one."""
+    """The router tests assume the 3-consecutive-failure mark-dead
+    discipline — a change here must be a deliberate one."""
     assert DEAD_AFTER == 3
 
 

@@ -28,7 +28,8 @@ import pytest
 from deeplearning4j_tpu.models.decode import (LSTMDecodeSpec,
                                               TransformerDecodeSpec,
                                               naive_generate,
-                                              naive_generate_lstm)
+                                              naive_generate_lstm,
+                                              truncated_draft)
 from deeplearning4j_tpu.models.zoo_extra import (text_generation_lstm,
                                                  transformer_lm)
 from deeplearning4j_tpu.serving import (BlockPoolExhaustedError,
@@ -207,34 +208,98 @@ def test_lstm_generation_matches_rnn_time_step():
 
 
 # -------------------------------------------------------- zero recompiles
-@pytest.mark.bench_smoke
-def test_zero_recompiles_generation_after_warmup():
+# engine modes: the net each serves is d=16, one block, float32 unless it
+# says otherwise; the engine has four slots and prefill batches 1 and 2
+def _steady_lm(**kw):
+    return _lm(**{**dict(seed=21, vocab=41, d_model=16, n_blocks=1,
+                         max_length=64), **kw})
+
+
+def _steady_char_lstm():
+    return text_generation_lstm(vocab_size=41, hidden=24, max_length=64,
+                                seed=5).init()
+
+
+def _no_prefix_hits(snap, row):
+    return snap["prefix"]["hits"] == 0
+
+
+def _two_way_model_mesh():
+    import jax
+    from deeplearning4j_tpu.parallel.mesh import make_mesh
+    return make_mesh((1, 2), ("data", "model"), jax.devices()[:2])
+
+
+# mode: (net, net -> what it changes of the engine, what must show)
+_STEADY_MODES = {
+    "continuous": (
+        _steady_lm, lambda net: dict(prefix_cache=False), _no_prefix_hits),
+    "one_at_a_time": (
+        _steady_lm,
+        lambda net: dict(decode_slots=1, prefill_batches=(1,),
+                         prefix_cache=False),
+        _no_prefix_hits),
+    "prefix": (
+        _steady_lm, lambda net: dict(prefix_cache=True),
+        lambda snap, row: (snap["prefix"]["hits"] >= 2
+                           and snap["prefix"]["cow_copies"] >= 1)),
+    "speculative": (
+        lambda: _steady_lm(n_blocks=2),
+        lambda net: dict(spec_k=3, draft=truncated_draft(net, 1)),
+        lambda snap, row: (snap["speculative"]["verify_steps"] > 0
+                           and snap["prefix"]["hits"] >= 2)),
+    "int8_pool": (
+        _steady_lm, lambda net: dict(kv_cache_dtype="int8"),
+        lambda snap, row: row["kv_cache_dtype"] == "int8"),
+    "bf16_pool": (
+        lambda: _steady_lm(dtype="bfloat16"), lambda net: {},
+        lambda snap, row: row["kv_cache_dtype"] is None),
+    "lstm_state": (
+        _steady_char_lstm, lambda net: {},
+        lambda snap, row: row["adapter"] == "state"),
+    "model_sharded": (
+        _steady_lm, lambda net: dict(mesh=_two_way_model_mesh()),
+        lambda snap, row: row["model_shards"] == 2),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(_STEADY_MODES))
+def test_zero_recompiles_generation_after_warmup(mode):
     """Tier-1 guard (ISSUE acceptance): after warm-up, a mixed stream of
-    prompt lengths (two rungs), generation lengths, sampling settings and
-    concurrent admissions triggers ZERO backend compiles — asserted via
-    the telemetry RecompileDetector AND the process-wide compile counter
-    AND the engine's own trace hook."""
-    net = _lm(seed=21, vocab=41, d_model=16, n_blocks=1, max_length=64)
+    prompt lengths (two rungs), generation lengths, sampling settings,
+    repeated prompts and concurrent admissions triggers ZERO backend
+    compiles in every mode the engine runs in — asserted via the
+    telemetry RecompileDetector AND the process-wide compile counter AND
+    the engine's own trace hook."""
+    build_net, engine_changes, mode_shows = _STEADY_MODES[mode]
+    net = build_net()
     eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
-                           decode_slots=4, prefill_batches=(1, 2),
-                           prompt_rungs=(16, 64), seed=3)
+                           prompt_rungs=(16, 64), seed=3,
+                           **{**dict(decode_slots=4, prefill_batches=(1, 2)),
+                              **engine_changes(net)})
     try:
         traces0 = eng.trace_count
         compiles0 = xla_compile_count()
-        work = [(3, 5, 0.0, 0), (14, 9, 0.0, 0), (30, 4, 0.7, 5),
-                (7, 12, 1.2, 0), (40, 3, 0.0, 2), (2, 17, 0.3, 3)]
+        tokens0 = get_registry().counter("generation.lm.tokens_out").value
+        # (prompt length, max_tokens, temperature, top_k); the 8s and 16s
+        # repeat one block-aligned prompt each
+        work = [(8, 6, 0.0, 0), (8, 6, 0.0, 0), (16, 5, 0.0, 0),
+                (16, 5, 0.0, 0), (3, 8, 0.7, 5), (30, 4, 0.0, 2),
+                (8, 6, 0.0, 0), (13, 9, 1.2, 0), (40, 3, 0.0, 0),
+                (2, 17, 0.3, 3)]
         results = {}
 
         def client(i):
             plen, mx, temp, topk = work[i]
-            p = [(i * 7 + j) % 40 + 1 for j in range(plen)]
+            p = [(j * 7 + 1) % 40 + 1 for j in range(plen)]
             st = eng.generate(p, max_tokens=mx, temperature=temp,
                               top_k=topk, stream=True)
             results[i] = (list(st), st.finish_reason)
 
         with RecompileDetector(allowed=0) as det:
+            client(0)                  # the repeats below find it cached
             threads = [threading.Thread(target=client, args=(i,))
-                       for i in range(len(work))]
+                       for i in range(1, len(work))]
             for t in threads:
                 t.start()
             for t in threads:
@@ -247,13 +312,42 @@ def test_zero_recompiles_generation_after_warmup():
             f"steady-state decode compiled: {det.events}"
         assert xla_compile_count() == compiles0
         assert eng.trace_count == traces0, "generation re-traced a program"
+        assert mode_shows(eng.metrics()["lm"], eng.models()["lm"])
         # telemetry mirror: the decode loop published its gauges/counters
         reg = get_registry()
-        snap = reg.snapshot()
-        assert snap["counters"].get("generation.lm.tokens_out", 0) >= 50
-        assert "generation.lm.slot_occupancy" in snap["gauges"]
+        assert reg.counter("generation.lm.tokens_out").value - tokens0 == \
+            sum(w[1] for w in work)
+        assert "generation.lm.slot_occupancy" in reg.snapshot()["gauges"]
     finally:
         eng.stop()
+
+
+def test_continuous_and_one_at_a_time_emit_same_greedy_tokens(shared_lm):
+    """Submission mode is not a numerics choice: six concurrent clients
+    over four slots and one caller at a time on a one-slot engine emit
+    the same greedy tokens for the same prompts."""
+    net, _, eng = shared_lm
+    prompts = _prompts(53, (4, 11, 17, 6, 23, 9), seed=41)
+    outs = {}
+
+    def client(i):
+        outs[i] = eng.generate(prompts[i], max_tokens=9)
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    serial = GenerationEngine(net, model_name="lm", block_len=8,
+                              max_seq_len=64, decode_slots=1,
+                              prefill_batches=(1,), prompt_rungs=(64,))
+    try:
+        for i, p in enumerate(prompts):
+            assert serial.generate(p, max_tokens=9) == outs[i], \
+                f"prompt {i} diverged between submission modes"
+    finally:
+        serial.stop()
 
 
 # ------------------------------------------------------------- sampling
@@ -526,30 +620,6 @@ def test_hot_swap_under_decode_soak_fast():
 @pytest.mark.slow
 def test_hot_swap_under_decode_soak():
     _swap_soak(n_swaps=20, clients=6, max_new=24)
-
-
-# ------------------------------------------------------------------- bench
-@pytest.mark.bench_smoke
-def test_generate_bench_smoke():
-    """Tier-1 guard for the generate_tokens_per_sec row: both modes run end
-    to end, emit tokens, and stay at zero steady-state compiles. The >=3x
-    continuous-vs-sequential acceptance ratio is measured by bench.py on
-    the real rig at full duration; CI pins 'not broken'."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    # prefix=False: the prefix sub-rows have their OWN tier-1 guard
-    # (tests/test_prefix_cache.py::test_prefix_cache_bench_smoke) — no
-    # need to warm the d=128 prefix-phase engine twice per tier-1 run
-    row = bench.bench_generate(duration=0.8, clients=3, decode_slots=4,
-                               max_new=8, prompt_len=4, prefix=False)
-    assert row["continuous_tokens_per_sec"] > 0
-    assert row["sequential_tokens_per_sec"] > 0
-    assert row["continuous_steady_state_compiles"] == 0
-    assert row["sequential_steady_state_compiles"] == 0
-    assert row["continuous_ttft_p50_ms"] > 0
 
 
 @pytest.mark.slow

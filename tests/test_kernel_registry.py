@@ -280,22 +280,3 @@ def test_record_kernel_timing_publishes_roofline_gauges():
         telemetry.set_registry(prev)
     assert kernels.record_kernel_timing("int8_matmul", "bogus", 1.0) is None
     assert kernels.record_kernel_timing("lstm", "4x8x128", 0.0) is None
-
-
-# -------------------------------------------------------------------- bench
-@pytest.mark.bench_smoke
-def test_int8_matmul_bench_smoke():
-    """Tier-1 guard for the int8_serving_matmul row: the paired windows
-    run, the quantized logits stay within the bounded-error tier, and the
-    timings are sane. (No speedup gate off-TPU: the int8 side runs the
-    XLA fallback there, and an int8 CPU GEMM may legitimately lose to
-    f32 — the row's ratio is rig information, not an acceptance.)"""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    row = bench.bench_int8_matmul(repeats=2, batch=64)
-    assert row["max_rel_err"] < 0.05, row
-    assert row["int8_ms"] > 0 and row["f32_ms"] > 0
-    assert row["int8_vs_f32_speedup"] > 0

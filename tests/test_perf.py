@@ -3,20 +3,17 @@ roofline/MFU gauges, memory profiler, and the perf-regression watchdog.
 
 Pinned here:
 - the shared cost model (normalization / implied MFU / roofline
-  classification) that bench.py now delegates to;
+  classification);
 - ProgramCostIndex capture for Solver step/window programs (one lower(),
   ZERO extra backend compiles), serving bucket programs and the fold
   into perf.<path>.mfu/.achieved_tflops/.roofline gauges;
 - the acceptance contracts: zero host syncs + zero steady-state
-  recompiles with FULL perf accounting enabled (K=1 and fused), and
-  tools/perf_report.py MFU agreeing with bench.py's independently
-  computed MFU for the same program;
+  recompiles with FULL perf accounting enabled (K=1 and fused);
 - step-time decomposition histograms, memory profiler (+ the
   device_memory_gauges live-arrays CPU fallback regression), flight
-  recorder perf/memory inclusion, PerfBaseline trajectory loading,
-  ThroughputSLO breach/recovery, PerformanceListener mfu keys,
-  dashboard Performance card (i18n'd), and the
-  perf_accounting_overhead_pct bench guard.
+  recorder perf/memory inclusion, ThroughputSLO breach/recovery,
+  PerformanceListener mfu keys, the offline report and the dashboard
+  Performance card (i18n'd).
 """
 import json
 import math
@@ -28,8 +25,7 @@ from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.telemetry import (HostSyncDetector, MetricsRegistry,
                                           RecompileDetector, SLOWatchdog,
                                           ThroughputSLO, set_slo_watchdog)
-from deeplearning4j_tpu.telemetry.perf import (PerfBaseline,
-                                               ProgramCostIndex,
+from deeplearning4j_tpu.telemetry.perf import (ProgramCostIndex,
                                                classify_roofline,
                                                get_cost_index, implied_mfu,
                                                normalize_cost_analysis,
@@ -111,33 +107,6 @@ def test_normalize_cost_analysis_variants():
     assert normalize_cost_analysis([]) == {}
     assert normalize_cost_analysis(None) == {}
     assert normalize_cost_analysis(42) == {}
-
-
-def test_bench_delegates_to_shared_cost_model():
-    """Satellite: bench's helpers ARE the shared implementation (same
-    numbers, one normalization) — bench rows and live gauges can never
-    disagree."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    assert bench._cost_analysis(_FakeCompiled([{"flops": 7.0}])) == \
-        {"flops": 7.0}
-    # same formula, bench's module peak as the denominator
-    assert bench._implied_mfu(1e12, 1.0) == pytest.approx(
-        implied_mfu(1e12, 1.0, peak=bench.PEAK_TFLOPS))
-    assert bench._roofline_dt(1e12) == pytest.approx(
-        roofline_dt(1e12, peak=bench.PEAK_TFLOPS,
-                    mfu_ceiling=bench.MAX_PLAUSIBLE_MFU))
-
-
-class _FakeCompiled:
-    def __init__(self, ca):
-        self._ca = ca
-
-    def cost_analysis(self):
-        return self._ca
 
 
 def test_classify_roofline_bounds(monkeypatch):
@@ -420,38 +389,6 @@ def test_flightrec_dump_includes_perf_and_memory(fresh_registry,
     assert "step_decomposition" in dump["perf"]
 
 
-# -------------------------------------------------------- PerfBaseline
-def test_perf_baseline_loads_checked_in_trajectory():
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    b = PerfBaseline.load_trajectory(root)
-    assert b.per_file, "no BENCH_r*.json parsed from the repo root"
-    # r03 carries a full headline; scalar rows must be recoverable
-    assert b.best("lstm_train_tokens_per_sec") > 0
-    best, src = b.best_with_file("lstm_train_tokens_per_sec")
-    assert src.startswith("BENCH_r")
-
-
-def test_perf_baseline_tolerates_truncated_tail(tmp_path):
-    full = {"metric": "m", "value": 1.0,
-            "extras": {"transformer_lm_tokens_per_sec": 1000.0,
-                       "serving_throughput": {"bucketed_req_per_sec": 50.0,
-                                              "bucketed_p99_ms": 9.0}}}
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"tail": json.dumps(full) + "\n", "parsed": None}))
-    # tail truncated mid-value: the cut row is skipped, never guessed
-    text = json.dumps(full)
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"tail": text[:text.find("1000.0") + 3], "parsed": None}))
-    (tmp_path / "BENCH_r03.json").write_text("not json at all")
-    b = PerfBaseline.load_trajectory(str(tmp_path))
-    assert b.best("transformer_lm_tokens_per_sec") == 1000.0
-    assert b.best("serving_throughput") == 50.0
-    assert "BENCH_r02.json" not in b.per_file or \
-        "transformer_lm_tokens_per_sec" not in \
-        b.per_file.get("BENCH_r02.json", {})
-
-
 # -------------------------------------------------------- ThroughputSLO
 def test_throughput_slo_breach_and_recovery(fresh_registry, recorder):
     reg = fresh_registry
@@ -479,19 +416,73 @@ def test_throughput_slo_breach_and_recovery(fresh_registry, recorder):
     assert reg.counter("slo.breaches").value >= 1
 
 
-def test_throughput_slo_cold_start_and_unknown_baseline(fresh_registry):
+@pytest.mark.parametrize("metric,baseline", [
+    ("train.windowed_steps_per_sec", 100.0),
+    ("generation.lm.tokens_per_sec", 65290.0),
+    ("perf.fit/epoch/window.mfu", 0.589)])
+def test_throughput_slo_recovers_and_pages_again(metric, baseline,
+                                                 fresh_registry, recorder):
+    """An operator-pinned baseline, on each kind of gauge the objective
+    is documented for (a step rate, a token rate, an MFU fraction): once
+    the regression is mended and its bad checks have left the window, the
+    objective clears without a second page; the next regression is a
+    fresh breach edge."""
     reg = fresh_registry
-    wd = SLOWatchdog([
-        ThroughputSLO("cold", "never.set.gauge", baseline=100.0),
-        ThroughputSLO("nobase", "some.gauge", baseline=0.0)],
-        windows=(60.0,), min_coverage=0.0)
+    gauge = reg.gauge(metric)
+    wd = SLOWatchdog([ThroughputSLO("serve_tput", metric,
+                                    baseline=baseline, ratio_floor=0.5,
+                                    target=0.5, best_of=2)],
+                     windows=(60.0,), burn_limits=(1.0,), min_coverage=0.0)
+
+    def run(share, start, n=12):
+        gauge.set(share * baseline)
+        for i in range(n):
+            out = wd.check(now=start + i)
+        return out
+
+    assert "serve_tput" in run(0.3, 1000.0)["breached"]
+    dumps = len(recorder.dumps)
+    # mended: best-of-2 still holds one bad reading for the first check
+    # after the fix; 100 s on, the 60 s window holds good checks only
+    out = run(0.9, 1100.0)
+    assert out["breached"] == []
+    assert out["objectives"]["serve_tput"]["burn_rates"]["60s"] == 0.0
+    assert reg.gauge("slo.serve_tput.breached").value == 0.0
+    assert reg.gauge("slo.serve_tput.throughput_ratio").value == \
+        pytest.approx(0.9)
+    assert reg.counter("slo.breaches").value == 1
+    assert len(recorder.dumps) == dumps
+    assert "serve_tput" in run(0.3, 1200.0)["breached"]
+    assert reg.counter("slo.breaches").value == 2
+
+
+def test_throughput_slo_cold_start_adds_no_sample(fresh_registry):
+    wd = SLOWatchdog([ThroughputSLO("cold", "never.set.gauge",
+                                    baseline=100.0)],
+                     windows=(60.0,), min_coverage=0.0)
+    for i in range(6):
+        out = wd.check(now=100.0 + i)
+    assert out["breached"] == []
+    assert out["objectives"]["cold"]["good"] == 0
+    assert out["objectives"]["cold"]["bad"] == 0
+
+
+@pytest.mark.parametrize("baseline", [0.0, -1.0])
+def test_throughput_slo_without_a_baseline_is_report_only(baseline,
+                                                          fresh_registry):
+    """No baseline pinned yet (``<= 0``): every reading counts as good,
+    nothing can breach, and no ratio is published against nothing."""
+    reg = fresh_registry
+    wd = SLOWatchdog([ThroughputSLO("nobase", "some.gauge",
+                                    baseline=baseline)],
+                     windows=(60.0,), min_coverage=0.0)
     reg.gauge("some.gauge").set(5.0)
     for i in range(6):
         out = wd.check(now=100.0 + i)
-    # unset gauge contributes no samples; unknown baseline never breaches
     assert out["breached"] == []
-    assert out["objectives"]["cold"]["good"] == 0
-    assert out["objectives"]["nobase"]["good"] > 0
+    assert out["objectives"]["nobase"]["good"] == 6
+    assert out["objectives"]["nobase"]["bad"] == 0
+    assert reg.gauge_if_exists("slo.nobase.throughput_ratio") is None
 
 
 # ------------------------------------------------------- offline report
@@ -501,10 +492,7 @@ def _fit_and_dump(tmp_path, fresh_registry, fresh_index, k=4, epochs=2):
     net.fit(iterator=_it(x, y), epochs=epochs, steps_per_dispatch=k,
             async_prefetch=False)
     path = str(tmp_path / "perf_dump.json")
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    write_perf_dump(path, registry=fresh_registry, index=fresh_index,
-                    baseline_root=root)
+    write_perf_dump(path, registry=fresh_registry, index=fresh_index)
     return net, path
 
 
@@ -521,7 +509,6 @@ def test_perf_report_renders_dump(fresh_registry, fresh_index, tmp_path,
     assert "Roofline" in out and "fit/epoch/window" in out
     assert "Step-time decomposition" in out and "compute_ms" in out
     assert "Memory: live arrays" in out and "params" in out
-    assert "Baseline deltas" in out and "BENCH_r" in out
     rows = roofline_rows(load_dump(path))
     r = [x for x in rows if x["path"] == "fit/epoch/window"][0]
     assert r["mfu"] is not None and not r["gauge_disagrees"]
@@ -529,6 +516,60 @@ def test_perf_report_renders_dump(fresh_registry, fresh_index, tmp_path,
     assert main([path, "--json"]) == 0
     data = json.loads(capsys.readouterr().out)
     assert data["roofline"] and data["memory"]
+
+
+@pytest.mark.parametrize("kind", ["perf_dump", "perf_dump_gz", "flightrec",
+                                  "registry_snapshot", "dump_of_before"])
+def test_every_dump_shape_renders_without_a_baseline(kind, fresh_registry,
+                                                     fresh_index, recorder,
+                                                     tmp_path, capsys):
+    """The shape every dump has: cost table, decomposition, memory and
+    the metrics snapshot, and nothing that compares them with a record
+    from elsewhere. Every input the report accepts (a perf dump, gzipped
+    or not, a flight-recorder dump, a bare registry snapshot, and a dump
+    written before the ``baseline`` block went) renders the same three
+    sections and no fourth."""
+    import gzip
+    import os
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from tools.perf_report import load_dump, main, render
+    fresh_index.register("prog", flops_per_step=1e9, bytes_per_step=1e6,
+                         timing_metric="t_ms")
+    fresh_registry.histogram("t_ms").observe(2.0)
+    path = write_perf_dump(str(tmp_path / "d.json"),
+                           registry=fresh_registry, index=fresh_index)
+    with open(path) as f:
+        raw = json.load(f)
+    assert set(raw) == {"perf_dump", "wall_time", "perf", "metrics"}
+    if kind == "perf_dump_gz":
+        path += ".gz"
+        with gzip.open(path, "wt") as f:
+            json.dump(raw, f)
+    elif kind == "flightrec":
+        path = recorder.dump("shape_test")
+    elif kind == "registry_snapshot":
+        with open(path, "w") as f:
+            json.dump(fresh_registry.snapshot(), f)
+    elif kind == "dump_of_before":
+        with open(path, "w") as f:
+            json.dump(dict(raw, baseline={"files": {}, "deltas": [
+                {"row": "r", "live": 1.0, "baseline_best": 2.0,
+                 "ratio": 0.5}]}), f)
+    dump = load_dump(path)
+    assert set(dump) <= {"perf", "metrics", "trigger"}
+    text = render(dump)
+    assert [ln for ln in text.splitlines() if ln.startswith("== ")] == [
+        "== Roofline: per-program cost & utilization ==",
+        "== Step-time decomposition (per step) ==",
+        "== Memory: live arrays =="]
+    assert "aseline" not in text
+    # a bare snapshot carries no cost table: the section says so
+    assert ("\nprog " in text) == (kind != "registry_snapshot")
+    assert main([path, "--json"]) == 0
+    assert set(json.loads(capsys.readouterr().out)) == {
+        "roofline", "decomposition", "memory"}
 
 
 def test_perf_report_reads_flightrec_dump(fresh_registry, fresh_index,
@@ -547,50 +588,6 @@ def test_perf_report_reads_flightrec_dump(fresh_registry, fresh_index,
     out = capsys.readouterr().out
     assert "flight-recorder dump" in out and "trigger=report_test" in out
     assert "fit/epoch/window" in out
-
-
-def test_report_mfu_agrees_with_bench(fresh_registry, fresh_index,
-                                      tmp_path):
-    """ISSUE 15 acceptance: the report's per-program MFU for an
-    instrumented fit agrees with bench.py's independently computed MFU
-    for the SAME program (bench AOT-compiles the window step itself and
-    runs its own _cost_analysis + _implied_mfu over the same step time).
-    The live capture went through Lowered.cost_analysis(), bench goes
-    through Compiled.cost_analysis() — agreement pins that the two
-    paths (and the shared formula) cannot drift apart."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import jax
-    import jax.numpy as jnp
-    import bench
-    from tools.perf_report import load_dump, roofline_rows
-    net, path = _fit_and_dump(tmp_path, fresh_registry, fresh_index, k=4)
-    row = [r for r in roofline_rows(load_dump(path))
-           if r["path"] == "fit/epoch/window"][0]
-    assert row["mfu"] is not None
-    # bench's independent pass: AOT-compile the same K=4 window program
-    # (fresh identical net -> same shapes/graph), pull flops through
-    # bench._cost_analysis, apply bench._implied_mfu to the same step
-    # time the report used
-    net2 = _tiny_net()
-    from deeplearning4j_tpu.optimize.solver import Solver
-    s = Solver(net2)
-    jitted = s._get_window_step(False, False, False)
-    x, y = _toy(n=32)
-    xs = jnp.asarray(x[:16]).reshape(4, 4, 8)
-    ys = jnp.asarray(y[:16]).reshape(4, 4, 3)
-    compiled = jitted.lower(net2.params, net2.state, net2.opt_state,
-                            jnp.asarray(0, jnp.int32),
-                            jax.random.PRNGKey(net2.conf.seed + 7919),
-                            xs, ys).compile()
-    flops = bench._cost_analysis(compiled).get("flops")
-    assert flops and flops > 0
-    bench_mfu = bench._implied_mfu(float(flops), row["step_ms"] / 1e3)
-    assert row["mfu"] == pytest.approx(bench_mfu, rel=0.05), \
-        f"report {row['mfu']} vs bench {bench_mfu} (flops {flops} vs " \
-        f"captured {row['flops_per_step']})"
 
 
 # ---------------------------------------------------------- dashboard
@@ -621,28 +618,3 @@ def test_dashboard_performance_card_i18n(fresh_registry, fresh_index):
         assert "Performance (MFU" not in render_dashboard_html(store)
     finally:
         fresh_registry.enabled = True
-
-
-# --------------------------------------------------------- bench guard
-@pytest.mark.bench_smoke
-def test_perf_accounting_overhead_bench_smoke():
-    """Tier-1 guard for the perf_accounting_overhead_pct bench variant:
-    full perf accounting (cost capture + decomposition + epoch fold)
-    must cost <5% on the K=8 fused fit. Paired best-of ratio (adjacent
-    on/off epochs share any co-tenant load burst); fails only if three
-    consecutive measurements all exceed the bound."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    last = None
-    for _ in range(3):
-        row = bench.bench_telemetry_overhead(steps=128, repeats=4,
-                                             variants=("perf",))
-        assert row["perf_steps_per_sec"] > 0
-        last = row
-        if row["perf_accounting_overhead_pct"] < 5.0:
-            return
-    pytest.fail(
-        f"perf accounting overhead >=5% in 3 consecutive runs: {last}")

@@ -289,7 +289,7 @@ def test_streaming_back_pressure_preserved_under_prefetch():
 # ------------------------------------------------- fit() routing smoke test
 def test_fit_routes_iterator_feeds_through_prefetcher(rng, monkeypatch):
     """CI guard: a regression back to serial feeding must fail tier-1, not
-    only show up in bench_piped."""
+    only show up as input wait on the chip."""
     from deeplearning4j_tpu.optimize import solver as solver_mod
     used = []
 

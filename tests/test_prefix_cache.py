@@ -393,28 +393,3 @@ def test_prefix_hit_trace_timeline(tmp_path):
         assert "cow=1" in hit["detail"]
     finally:
         eng.stop()
-
-
-# -------------------------------------------------------------------- bench
-@pytest.mark.bench_smoke
-def test_prefix_cache_bench_smoke():
-    """Tier-1 guard for the generate_tokens_per_sec prefix sub-rows
-    (ISSUE 14 acceptance): cached-prefix TTFT p50 <= 0.25x uncached on the
-    paired best-of ratio, with full hit rate on the shared-prompt windows.
-    Shared-CI CPU timings swing, so THREE consecutive failing attempts are
-    required to fail (the adjacent hit/miss windows already share any
-    co-tenant burst; retries cover burst EDGES landing between windows)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    row = None
-    for _ in range(3):
-        row = bench._bench_prefix_cache(duration=0.8, repeats=2)
-        assert row["prefix_hit_rate"] >= 0.9
-        assert row["prefix_cow_copies"] >= 1
-        assert row["ttft_cached_p50_ms"] > 0
-        if row["ttft_cached_vs_uncached"] <= 0.25:
-            return
-    pytest.fail(f"cached TTFT not <= 0.25x uncached in 3 attempts: {row}")

@@ -87,22 +87,27 @@ def test_kv_quantize_roundtrip_bound_and_determinism():
     assert np.all(np.asarray(qz) == 0) and np.all(np.asarray(sz) == 1.0)
 
 
-def test_pool_capacity_per_byte():
-    """ISSUE 17 acceptance: >= 1.9x tokens per byte vs the f32 pool at
-    identical geometry (head_dim 32: 8*32=256 f32 bytes vs 2*(32+4)=72
-    int8 bytes per token/layer/head — 3.56x)."""
+@pytest.mark.parametrize("head_dim", [32, 64, 128])
+def test_pool_capacity_per_byte(head_dim):
+    """ISSUE 17 acceptance, from shapes alone: >= 1.9x tokens per byte vs
+    the f32 pool at identical geometry. A token costs 8 * head_dim f32
+    bytes against 2 * (head_dim + 4) int8 bytes (codes plus one f32 scale)
+    per layer and head: 3.56x at 32, 3.76x at the cells' 64, 3.88x at
+    128; against a bfloat16 pool half of that."""
     geom = dict(n_layers=2, num_blocks=8, block_len=16, n_heads=2,
-                head_dim=32)
+                head_dim=head_dim)
     kf, vf = make_pools(dtype=jnp.float32, **geom)
     kq, vq = make_pools(dtype=jnp.float32, quantized=True, **geom)
     assert isinstance(kq, QuantizedPool) and isinstance(vq, QuantizedPool)
     ratio = (pool_bytes(kf) + pool_bytes(vf)) / \
         (pool_bytes(kq) + pool_bytes(vq))
+    assert ratio == pytest.approx(4 * head_dim / (head_dim + 4))
     assert ratio >= 1.9, ratio
     # the same blocks: the plain pool lays a token's heads side by side
     # (the attention kernel's page), the codes keep them apart for the
     # per-(token, head) scale
-    assert kq.q.shape == kf.shape[:3] + (2, 32) and kf.shape[3] == 2 * 32
+    assert kq.q.shape == kf.shape[:3] + (2, head_dim)
+    assert kf.shape[3] == 2 * head_dim
     assert kq.scale.shape == kq.q.shape[:-1]
 
 
@@ -230,32 +235,3 @@ def test_int8_forward_bounded_error():
            .build())
     with pytest.raises(ValueError, match="full-precision"):
         int8_forward_fn(MultiLayerNetwork(amp).init())
-
-
-# -------------------------------------------------------------------- bench
-@pytest.mark.bench_smoke
-def test_quantized_kv_bench_smoke():
-    """Tier-1 guard for the quantized_kv_decode row: zero steady-state
-    compiles in BOTH pool modes, the capacity-per-byte acceptance >=
-    1.9x, greedy probe parity between a run and itself (determinism is
-    folded into greedy_tokens_match only when int8 == f32 — informational
-    there), and the int8 window not catastrophically slower than f32.
-    Three consecutive failing attempts required to fail (rig co-tenant
-    bursts; the capacity ratio and compile counts are deterministic, the
-    tokens/sec ratio is the noisy part)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    row = None
-    for _ in range(3):
-        row = bench.bench_quantized_kv(duration=0.8, clients=3,
-                                       decode_slots=4, max_new=12)
-        assert row["int8_steady_state_compiles"] == 0, row
-        assert row["f32_steady_state_compiles"] == 0, row
-        assert row["capacity_per_byte_vs_f32"] >= 1.9, row
-        if row["int8_tokens_per_sec"] >= 0.25 * row["f32_tokens_per_sec"]:
-            return
-    pytest.fail(f"quantized decode catastrophically slower than f32 in "
-                f"3 attempts: {row}")

@@ -15,8 +15,10 @@ Pins:
     path;
   - hot-swap cohort pinning: in-flight requests finish on the old params
     AND old draft; same-arch swaps reuse every compiled executable;
-  - zero steady-state recompiles with prefix cache + speculation BOTH
-    enabled (ISSUE acceptance).
+  - the acceptance yield (>= 2 tokens a target dispatch with a
+    truncated draft). Zero steady-state recompiles with prefix cache +
+    speculation both enabled is the ``speculative`` case of
+    tests/test_generation.py::test_zero_recompiles_generation_after_warmup.
 """
 import threading
 import time
@@ -32,7 +34,6 @@ from deeplearning4j_tpu.models.zoo_extra import (text_generation_lstm,
 from deeplearning4j_tpu.serving import (GenerationEngine,
                                         xla_compile_count)
 from deeplearning4j_tpu.serving.generation import accept_greedy
-from deeplearning4j_tpu.telemetry import RecompileDetector
 
 R = np.random.default_rng(4321)
 
@@ -285,51 +286,38 @@ def test_hot_swap_spec_cohort_pinning():
         eng.stop()
 
 
-# ------------------------------------------------- zero-recompile acceptance
-@pytest.mark.bench_smoke
-def test_zero_recompiles_prefix_and_speculative():
-    """ISSUE acceptance: with BOTH features enabled, a mixed stream —
-    cache misses, block-aligned hits (COW), partial hits, sampling,
-    greedy speculation, concurrency — triggers ZERO backend compiles
-    after warm-up (RecompileDetector + process compile counter + trace
-    hook)."""
-    net = _lm(seed=21, vocab=41, d_model=16, n_blocks=2, max_length=64)
-    draft = truncated_draft(net, 1)
-    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
-                           decode_slots=4, prefill_batches=(1, 2),
-                           prompt_rungs=(16, 64), draft=draft, spec_k=3,
-                           seed=3)
+# ------------------------------------------------------- acceptance yield
+def test_truncated_draft_yields_two_tokens_a_verify():
+    """ISSUE 14 acceptance, which is a count and no timing: a draft that
+    is the target's first block, against a target whose second block
+    adds a quarter of its usual residual (the agreement a distilled draft
+    has), lands >= 2 tokens per target dispatch, the correction token
+    included; plain decode is 1.0 by definition."""
+    net = _lm(seed=123, vocab=128, d_model=64)
+    params = list(net.params)
+    for i, name in enumerate(net.vertex_names):
+        if name == "b1_attn":
+            params[i] = dict(params[i], Wo=params[i]["Wo"] * 0.25,
+                             b=params[i]["b"] * 0.25)
+        elif name == "b1_ff2":
+            params[i] = {k: v * 0.25 for k, v in params[i].items()}
+    net.params = tuple(params)
+    eng = GenerationEngine(net, model_name="lm", block_len=16,
+                           max_seq_len=64, decode_slots=2,
+                           prefill_batches=(1,), prompt_rungs=(64,),
+                           draft=truncated_draft(net, 1), spec_k=4)
     try:
-        traces0 = eng.trace_count
-        compiles0 = xla_compile_count()
-        work = [(8, 6, 0.0), (8, 6, 0.0), (16, 5, 0.0), (16, 5, 0.0),
-                (3, 8, 0.7), (30, 4, 0.0), (8, 6, 0.0), (13, 9, 0.0)]
-        res = {}
-
-        def client(i):
-            plen, mx, temp = work[i]
-            p = [(j * 7 + 1) % 40 + 1 for j in range(plen)]
-            st = eng.generate(p, max_tokens=mx, temperature=temp,
-                              stream=True)
-            res[i] = (list(st), st.finish_reason)
-
-        with RecompileDetector(allowed=0) as det:
-            threads = [threading.Thread(target=client, args=(i,))
-                       for i in range(len(work))]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-        for i, (plen, mx, _) in enumerate(work):
-            assert len(res[i][0]) == mx and res[i][1] == "length", \
-                (i, res[i])
-        assert det.count == 0, f"steady state compiled: {det.events}"
-        assert xla_compile_count() == compiles0
-        assert eng.trace_count == traces0
-        snap = eng.metrics()["lm"]
-        assert snap["prefix"]["hits"] >= 2
-        assert snap["prefix"]["cow_copies"] >= 1
-        assert snap["speculative"]["verify_steps"] > 0
+        spec = TransformerDecodeSpec(net)
+        rng = np.random.default_rng(5)
+        for _ in range(4):
+            p = rng.integers(1, 128, size=8).tolist()
+            assert eng.generate(p, max_tokens=24)[0] == \
+                naive_generate(net, p, 24, pad_to=64, spec=spec)
+        s = eng.metrics()["lm"]["speculative"]
+        assert s["verify_steps"] > 0
+        assert s["accepted_tokens_per_verify"] >= 2.0, s
+        # fewer target dispatches than tokens: the point of the scheme
+        assert s["verify_steps"] * 2 <= s["emitted"], s
     finally:
         eng.stop()
 
@@ -378,30 +366,3 @@ def test_http_speculative_surface():
         assert "accepted_tokens_per_verify" in metrics["speculative"]
     finally:
         srv.stop()
-
-
-# -------------------------------------------------------------------- bench
-@pytest.mark.bench_smoke
-def test_speculative_bench_smoke():
-    """Tier-1 guard for the speculative_decode row (ISSUE 14 acceptance):
-    accepted_tokens_per_verify >= 2 on the truncated-draft workload, zero
-    steady-state compiles, and the paired best-of spec/plain ratio not
-    catastrophically regressed. Three consecutive failing attempts
-    required to fail (rig co-tenant bursts; the acceptance yield itself is
-    deterministic, the tokens/sec ratio is the noisy part)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    row = None
-    for _ in range(3):
-        row = bench.bench_speculative(duration=0.8, clients=3, k=4,
-                                      decode_slots=4, repeats=2)
-        assert row["steady_state_compiles"] == 0
-        assert row["verify_steps"] > 0
-        assert row["accepted_tokens_per_verify"] >= 2.0, row
-        if row["spec_vs_plain"] >= 1.0:
-            return
-    pytest.fail(f"speculative decode slower than plain in 3 attempts: "
-                f"{row}")

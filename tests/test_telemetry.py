@@ -11,9 +11,12 @@ Acceptance contracts pinned here:
 - the instrumented fit path performs ZERO extra device->host transfers vs
   uninstrumented (score_to_float counting harness from test_scan_window +
   the HostSyncDetector tripwire), and a disabled registry is a near-no-op;
-- the telemetry_overhead_pct bench row reports <5% on the dispatch-bound
-  CPU loop (bench_smoke guard).
+- each instrument (registry spans, fit tracing + training watch, perf
+  accounting, request tracing + SLO watchdog, fleet collector + spool)
+  leaves the workload's outputs
+  bit-equal to a run without it, and leaves a record of its own.
 """
+import contextlib
 import json
 import logging
 
@@ -422,7 +425,6 @@ def test_recompile_detector_scoped_and_stable_loop_clean(fresh_registry):
     assert det.count == 0
 
 
-@pytest.mark.bench_smoke
 def test_serving_warm_path_zero_recompiles_under_detector(fresh_registry):
     """Steady-state serving through the warmed engine stays at ZERO
     compiles — now asserted via the first-class detector, not just the
@@ -732,32 +734,161 @@ def test_device_memory_gauges_smoke(fresh_registry):
         assert fresh_registry.gauge(name).value == val
 
 
-# ------------------------------------------------------------- bench guard
-@pytest.mark.bench_smoke
-def test_telemetry_overhead_bench_smoke():
-    """Tier-1 guard for the telemetry_overhead bench row: the enabled
-    registry must cost <5% on the dispatch-bound loop. Host wall-clock on
-    a shared CI box swings a few percent either way (the row itself uses
-    interleaved medians), so the guard retries: it fails only if three
-    consecutive measurements all exceed the bound."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    last = None
-    for _ in range(3):
-        # base variant only: the traced fit + serving variants have their
-        # own guard (tests/test_tracing.py) — no double payment here
-        row = bench.bench_telemetry_overhead(steps=128, repeats=5,
-                                             variants=("base",))
-        assert row["instrumented_steps_per_sec"] > 0
-        assert row["bare_steps_per_sec"] > 0
-        last = row
-        # guard on the paired-ratio FLOOR: the median pct (still the
-        # reported row) absorbs co-tenant load bursts asymmetrically on
-        # this rig and can flake >=5% for minutes at a stretch, while a
-        # real regression lifts every adjacent on/off pair
-        if row["telemetry_overhead_floor_pct"] < 5.0:
-            return
-    pytest.fail(f"telemetry overhead >=5% in 3 consecutive runs: {last}")
+# ------------------------------------- instruments leave the results alone
+def _fit_flat(k, rows=64):
+    """The tiny MLP's parameters after two epochs at K steps a dispatch
+    (a fresh net and the same data every call)."""
+    x, y = _toy(np.random.default_rng(17), n=rows)
+    net = _tiny_net(seed=44)
+    net.fit(iterator=_it(x, y), epochs=2, steps_per_dispatch=k,
+            async_prefetch=False)
+    return np.asarray(net.params_flat())
+
+
+def _registry_spans(on, reg, tmp_path):
+    reg.enabled = on
+    flat = _fit_flat(k=1)
+    return flat, [e for e in reg.trace_events() if e.get("cat") == "span"]
+
+
+def _tracing_and_watch(on, reg, tmp_path):
+    from deeplearning4j_tpu.telemetry import (TrainingWatch,
+                                              new_trace_context,
+                                              set_training_watch,
+                                              use_trace_context)
+    reg.enabled = on
+    if not on:
+        return _fit_flat(k=8), None
+    ctx = new_trace_context()
+    watch = TrainingWatch(window=8, registry=reg, dump_on_unhealthy=False)
+    set_training_watch(watch)
+    try:
+        with use_trace_context(ctx):
+            flat = _fit_flat(k=8)
+        assert watch.drain() and watch.healthy
+        assert watch.steps_seen == 16
+    finally:
+        set_training_watch(None)
+        watch.close()
+    return flat, [e for e in reg.trace_events()
+                  if e["args"].get("trace_id") == ctx.trace_id]
+
+
+def _perf_accounting(on, reg, tmp_path):
+    from deeplearning4j_tpu.telemetry.perf import (ProgramCostIndex,
+                                                   set_cost_index)
+    reg.enabled = on
+    idx = ProgramCostIndex()
+    prev = set_cost_index(idx)
+    try:
+        flat = _fit_flat(k=8, rows=256)     # four windows an epoch
+    finally:
+        set_cost_index(prev)
+    if on:
+        assert idx.get("fit/epoch/window") is not None
+    return flat, {n: h["count"]
+                  for n, h in reg.snapshot()["histograms"].items()
+                  if n.startswith("perf.step.")}
+
+
+@contextlib.contextmanager
+def _served_tiny_net():
+    """``post_all() -> outputs`` against the tiny MLP behind a real
+    ServingHTTPServer: twelve traced /predict requests, rows 1/3/8/2."""
+    from deeplearning4j_tpu.serving import InferenceEngine, ServingHTTPServer
+    from deeplearning4j_tpu.util.httpjson import HTTPClient
+    rng = np.random.default_rng(29)
+    batches = [rng.normal(size=(n, 4)).astype(np.float32)
+               for n in (1, 3, 8, 2)] * 3
+    eng = InferenceEngine(_tiny_net(seed=44), feature_shape=(4,),
+                          buckets=(4, 8), batch_window_ms=0.2)
+    srv = ServingHTTPServer(engine=eng)
+    url = f"http://127.0.0.1:{srv.start()}"
+    client = HTTPClient(max_per_host=1, timeout=30.0)
+
+    def post_all():
+        outs = []
+        for i, x in enumerate(batches):
+            status, body = client.request_json(
+                "POST", url + "/predict", payload={"features": x.tolist()},
+                headers={"X-Trace-Id": f"{i + 1:032x}"})
+            assert status == 200
+            outs.append(np.asarray(body["output"], np.float32))
+        return np.concatenate(outs)
+
+    try:
+        yield url, post_all
+    finally:
+        client.close()
+        srv.stop()
+        eng.stop(drain=False)
+
+
+def _request_tracing_and_slo(on, reg, tmp_path):
+    from deeplearning4j_tpu.telemetry import (LatencySLO, SLOWatchdog,
+                                              set_slo_watchdog)
+    reg.enabled = on
+    wd = SLOWatchdog([LatencySLO("predict_p99", "serving.default.latency_ms",
+                                 threshold_ms=60000.0, target=0.99)],
+                     registry=reg, dump_on_breach=False)
+    prev = set_slo_watchdog(wd if on else None)
+    try:
+        with _served_tiny_net() as (_, post_all):
+            outs = post_all()
+            checked = wd.check() if on else None
+    finally:
+        set_slo_watchdog(prev)
+    if on:
+        assert checked["objectives"]["predict_p99"]["good"] == 12
+    ids = {f"{i + 1:032x}" for i in range(12)}
+    return outs, [e for e in reg.trace_events()
+                  if e["args"].get("trace_id") in ids]
+
+
+def _fleet_collector_and_spool(on, reg, tmp_path):
+    from deeplearning4j_tpu.serving.fleet import FleetCollector, FleetRouter
+    from deeplearning4j_tpu.telemetry import TraceSpool, read_spool
+    spool_path = str(tmp_path / "replica-b0.spool.json")
+    router = FleetRouter(policy="round_robin", health_period_s=3600.0)
+    collector = spool = None
+    try:
+        with _served_tiny_net() as (url, post_all):
+            router.add_url(url, "b0")
+            if on:
+                collector = FleetCollector(
+                    router, period_s=0.02,
+                    registry=MetricsRegistry(enabled=True)).start()
+                spool = TraceSpool(spool_path, replica_id="b0",
+                                   period_s=0.02).start()
+            outs = post_all()
+            if not on:
+                return outs, None
+            collector.pull_once()
+            spool.flush(force=True)
+            snap = collector.snapshot()
+            assert snap["pulls"] > 0 and snap["pull_errors"] == 0
+            assert read_spool(spool_path)["events"]
+            return outs, snap["events_pulled"]
+    finally:
+        if collector is not None:
+            collector.stop()
+        if spool is not None:
+            spool.stop()
+        router.client.close()
+
+
+@pytest.mark.parametrize("instrument", [
+    _registry_spans, _tracing_and_watch, _perf_accounting,
+    _request_tracing_and_slo, _fleet_collector_and_spool],
+    ids=lambda f: f.__name__.strip("_"))
+def test_instrument_leaves_outputs_bit_equal(instrument, fresh_registry,
+                                             tmp_path, monkeypatch):
+    """What an instrument may cost is time, and time is read on the chip.
+    What it may never do is change the answer: with it on, the workload's
+    outputs are bit-equal to those with it off, and the instrument's own
+    record is not empty."""
+    monkeypatch.setenv("DL4J_TPU_PERF_CAPTURE_AFTER", "1")
+    bare, _ = instrument(False, fresh_registry, tmp_path)
+    with_it, record = instrument(True, fresh_registry, tmp_path)
+    np.testing.assert_array_equal(with_it, bare)
+    assert record, "the instrument ran and recorded nothing"

@@ -6,10 +6,8 @@ row-parallel, MLP ff1/ff2 split, LSTM 4H gate blocks), m=1 bit-identity
 with the 1-D data path, (2, 2) float-tolerance parity including the
 steps_per_dispatch / zero_stage compositions, per-replica memory
 reduction, model-sharded paged decode (token-identical, pool bytes/m per
-chip, hot-swap executable reuse), the write_model host-gather seam, the
-per-chip ProgramCostIndex division, and the tensor_parallel bench row
-guard."""
-import os
+chip, hot-swap executable reuse), the write_model host-gather seam and
+the per-chip ProgramCostIndex division."""
 
 import numpy as np
 import pytest
@@ -163,6 +161,10 @@ def test_22_mesh_tracks_dp_and_shrinks_replicas(dp_ref, tp22):
     full = int(dp_ref.nbytes)
     assert per_replica_bytes(tp22.params) < full
     assert per_replica_bytes(tp22.opt_state) < 2 * full
+    # a (4, 1) replica holds all of it (Adam: two more copies); with the
+    # embeddings and norms replicated, m=2 still frees over a sixth
+    held = per_replica_bytes(tp22.params) + per_replica_bytes(tp22.opt_state)
+    assert 1.2 * held < 3 * full, (held, full)
 
 
 def test_22_composes_with_steps_per_dispatch_and_zero(dp_ref):
@@ -283,31 +285,3 @@ def test_cost_index_divides_by_model_axis():
         assert row["achieved_tflops"] == pytest.approx(0.5, rel=1e-6)
     finally:
         telemetry.set_registry(prev)
-
-
-# ------------------------------------------------------------- bench smoke
-@pytest.mark.bench_smoke
-def test_tensor_parallel_bench_smoke():
-    """Tier-1 guard: the tensor_parallel bench row must run end to end
-    and report the ~m-x per-replica byte reductions; the (2, 2) step must
-    not be catastrophically slower than (4, 1) (shared-CI CPU timings
-    swing, so three consecutive failing attempts are required to fail)."""
-    import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    row = None
-    for _ in range(3):
-        # shrunk model (d16, 1 block): the guard buys the contract, not
-        # the bench's production-sized timings
-        row = bench.bench_tensor_parallel(train_batches=2, decode_steps=4,
-                                          timeout=300, d_model=16,
-                                          n_blocks=1)
-        assert row["train_bytes_reduction"] > 1.2
-        assert row["kv_pool_reduction"] >= 1.9
-        assert row["4x1"]["step_ms"] > 0 and row["2x2"]["step_ms"] > 0
-        assert row["decode"]["sharded"]["kv_pool_bytes_per_chip"] < \
-            row["decode"]["replicated"]["kv_pool_bytes_per_chip"]
-        if row["2x2"]["step_ms"] < 3 * row["4x1"]["step_ms"]:
-            return
-    pytest.fail(f"(2,2) step catastrophically slow in 3 attempts: {row}")

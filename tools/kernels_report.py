@@ -20,8 +20,7 @@ the autotune decision cache JSON (``DL4J_TPU_AUTOTUNE_CACHE``). Renders:
     recorded reason defaults stand), replay count (proof the cache
     short-circuits re-measurement), best measured candidate times;
   - **Roofline check** — measured vs roofline ms per kernel from the
-    gauges, flagging anything > 2x over its bound (the BASELINE.md
-    flagging threshold).
+    gauges, flagging anything > 2x over its bound.
 
 Like the other tools/ CLIs this must stay importable WITHOUT the
 package (no jax import): stdlib only.

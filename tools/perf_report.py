@@ -20,10 +20,6 @@ the same ``perf`` block), gzipped or not, and renders:
     step with shares: "why is steps/sec down" at a glance.
   - **Memory top-K** — live-array groups by (shape, dtype, owner) and
     per-device totals.
-  - **Baseline deltas** — live steady-state rows vs the best value in
-    the checked-in BENCH_r*.json trajectory (when the dump carried a
-    baseline block), with the source file named so a stale baseline is
-    visible.
 
 Like the other tools/ CLIs, this file must stay importable without the
 package (no jax): stdlib only. Peak TFLOP/s for the MFU recomputation
@@ -53,19 +49,18 @@ def _read_text(path: str) -> str:
 
 def load_dump(path: str) -> dict:
     """Normalize a perf dump / flight-recorder dump / bare registry
-    snapshot into {perf, metrics, baseline, trigger?}."""
+    snapshot into {perf, metrics, trigger?}."""
     data = json.loads(_read_text(path))
     if not isinstance(data, dict):
         raise ValueError(f"{path}: not a JSON object")
     if "perf_dump" in data or "flightrec" in data:
         out = {"perf": data.get("perf", {}),
-               "metrics": data.get("metrics", {}),
-               "baseline": data.get("baseline")}
+               "metrics": data.get("metrics", {})}
         if "trigger" in data:
             out["trigger"] = data["trigger"]
         return out
     if "counters" in data or "gauges" in data:   # bare snapshot
-        return {"perf": {}, "metrics": data, "baseline": None}
+        return {"perf": {}, "metrics": data}
     raise ValueError(f"{path}: neither a perf dump, a flight-recorder "
                      "dump, nor a registry snapshot")
 
@@ -205,24 +200,6 @@ def format_memory(dump: dict) -> str:
     return "\n".join(lines)
 
 
-def format_baseline(dump: dict) -> str:
-    b = dump.get("baseline") or {}
-    deltas = b.get("deltas") or []
-    if not deltas:
-        return "(no baseline block — pass baseline_root= to " \
-               "write_perf_dump, or run from the repo root)"
-    head = (f"{'row':<34}  {'live':>12}  {'best baseline':>14}  "
-            f"{'ratio':>7}  source")
-    lines = [head, "-" * len(head)]
-    for d in deltas:
-        ratio = d.get("ratio")
-        lines.append(f"{d.get('row', '?'):<34}  {_fmt(d.get('live')):>12}  "
-                     f"{_fmt(d.get('baseline_best')):>14}  "
-                     f"{f'{ratio:.2f}x' if ratio else '-':>7}  "
-                     f"{d.get('baseline_file') or '-'}")
-    return "\n".join(lines)
-
-
 def render(dump: dict) -> str:
     sections = []
     if "trigger" in dump:
@@ -233,8 +210,6 @@ def render(dump: dict) -> str:
     sections.append("== Step-time decomposition (per step) ==\n"
                     + format_decomposition(dump))
     sections.append("== Memory: live arrays ==\n" + format_memory(dump))
-    sections.append("== Baseline deltas (BENCH_r* trajectory) ==\n"
-                    + format_baseline(dump))
     return "\n\n".join(sections)
 
 
@@ -253,8 +228,8 @@ def main(argv=None) -> int:
                           "decomposition":
                               dump.get("perf", {}).get(
                                   "step_decomposition") or {},
-                          "memory": dump.get("perf", {}).get("memory"),
-                          "baseline": dump.get("baseline")}, indent=2))
+                          "memory": dump.get("perf", {}).get("memory")},
+                         indent=2))
         return 0
     print(render(dump))
     return 0

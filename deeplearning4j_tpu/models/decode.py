@@ -9,12 +9,14 @@ zoo models:
   vertex names ``embed``/``pos``/``b{i}_*``/``ln_f``/``head`` are that
   builder's contract) and exposes:
     * ``prefill_forward`` — ONE full forward over the padded prompt that
-      returns pre-activation logits for every position **plus the per-layer
-      K/V tensors** the serving layer scatters into its paged cache. It runs
-      through ``ComputationGraph.apply_fn`` — the exact program the naive
-      forward runs — so prefill logits are bit-identical to a plain
-      ``net.output`` by construction (and ride the fused Pallas attention
-      whenever ``fused_attention_applicable`` says the shapes allow).
+      returns pre-activation logits for the ONE position of each prompt the
+      caller names (the head runs on ``[B, 1, d]``, never on every padded
+      position) **plus the per-layer K/V tensors** the serving layer
+      scatters into its paged cache. Everything up to ``ln_f`` runs through
+      ``ComputationGraph.apply_fn`` — the exact program the naive forward
+      runs — so the row's logits are those of a plain ``net.output`` up to
+      the matmul's tiling (and ride the fused Pallas attention whenever
+      ``fused_attention_applicable`` says the shapes allow).
     * ``decode_step`` — one token per sequence through a ``KVStore``
       protocol object (serving/generation/kvcache.py provides the paged
       implementation). Every op but attention replays the layer objects'
@@ -167,22 +169,40 @@ class TransformerDecodeSpec:
         return embed.apply(self._p(params, "embed"), {}, onehot,
                            train=False)[0]
 
-    # ------------------------------------------------------------- prefill
-    def prefill_forward(self, params, state, tokens):
-        """Full forward over the padded prompt [B,L] through the graph's own
-        ``apply_fn`` (bit-identical to ``net.output``), plus the per-layer
-        K/V tensors for the cache.
+    # ---------------------------------------------------------------- head
+    def head_logits(self, params, y):
+        """Pre-activation logits of the head over ``ln_f``'s output:
+        y [B,T,d] -> [B,T,V]."""
+        head_v = self._v["head"]
+        if head_v.preprocessor is not None:
+            y = head_v.preprocessor.apply(y)
+        return head_v.layer_conf.pre_output(self._p(params, "head"), y)
 
-        Returns (logits [B,L,V] pre-activation, ks, vs) with
+    def logits_at(self, params, y, rows):
+        """The head on ONE position of each sequence: y [B,T,d] is
+        ``ln_f``'s output, rows [B] the position to read -> [B,V]. The
+        rows are selected BEFORE the head, so no [B,T,V] value exists."""
+        y = jnp.take_along_axis(y, rows[:, None, None], axis=1)  # [B,1,d]
+        return self.head_logits(params, y)[:, 0]
+
+    # ------------------------------------------------------------- prefill
+    def prefill_forward(self, params, state, tokens, rows):
+        """Full forward over the padded prompt [B,L] through the graph's own
+        ``apply_fn`` (everything up to ``ln_f`` is what ``net.output``
+        computes), plus the per-layer K/V tensors for the cache.
+
+        ``rows`` [B] names the position of each prompt whose logits the
+        caller will read (``lengths - 1`` for the first sampled token); the
+        head is applied to those rows alone. None asks for no logits (the
+        draft's prefill keeps only K/V).
+
+        Returns (logits [B,V] pre-activation or None, ks, vs) with
         ks[i]/vs[i]: [B,L,H,Dh]."""
         x_in = tokens if self.token_input else \
             jax.nn.one_hot(tokens, self.vocab, dtype=self.dtype)
         acts, _ = self.net.apply_fn(params, state, [x_in], train=False)
-        head_v = self._v["head"]
-        feed = acts["ln_f"]
-        if head_v.preprocessor is not None:
-            feed = head_v.preprocessor.apply(feed)
-        logits = head_v.layer_conf.pre_output(self._p(params, "head"), feed)
+        logits = None if rows is None else \
+            self.logits_at(params, acts["ln_f"], rows)
         ks, vs = [], []
         for i in range(self.n_blocks):
             ap = self._p(params, f"b{i}_attn")
@@ -208,11 +228,7 @@ class TransformerDecodeSpec:
         for i in range(self.n_blocks):
             x = self._block_step(params, state, i, x, pos, store)
         y = self._apply(params, state, "ln_f", x)
-        head_v = self._v["head"]
-        if head_v.preprocessor is not None:
-            y = head_v.preprocessor.apply(y)
-        logits = head_v.layer_conf.pre_output(self._p(params, "head"), y)
-        return logits[:, 0, :]
+        return self.head_logits(params, y)[:, 0, :]
 
     def _block_step(self, params, state, i, x, pos, store: KVStore):
         h = x
@@ -246,6 +262,13 @@ class TransformerDecodeSpec:
         carry per-row limits), so the
         returned logits [B,W,V] match W sequential decode steps
         token-for-token — the property the verify acceptance rule needs."""
+        return self.head_logits(
+            params, self.window_hidden(params, state, tokens, pos, store))
+
+    def window_hidden(self, params, state, tokens, pos, store):
+        """``decode_window`` up to ``ln_f``: [B,W,d], the head's input. A
+        caller that reads one row of the window (the int8 tier's prefill)
+        selects it here and applies the head to that (``logits_at``)."""
         B, W = tokens.shape
         x = self.embed_tokens(params, tokens)                  # [B,W,d]
         P = self._p(params, "pos")["P"]
@@ -255,11 +278,7 @@ class TransformerDecodeSpec:
         x = pos_layer.act(x)
         for i in range(self.n_blocks):
             x = self._block_window(params, state, i, x, store)
-        y = self._apply(params, state, "ln_f", x)
-        head_v = self._v["head"]
-        if head_v.preprocessor is not None:
-            y = head_v.preprocessor.apply(y)
-        return head_v.layer_conf.pre_output(self._p(params, "head"), y)
+        return self._apply(params, state, "ln_f", x)
 
     def _block_window(self, params, state, i, x, store):
         h = x

@@ -5,9 +5,9 @@ every program the steady-state loop can ever need is lowered and compiled at
 ``warm()`` —
 
   - one **prefill** executable per (admission-batch rung P, prompt rung L):
-    padded prompt -> per-position logits via the graph's own ``apply_fn``
-    (bit-identical to ``net.output``), K/V scattered into the paged pools,
-    first token sampled in-program;
+    padded prompt -> the graph's own ``apply_fn`` up to ``ln_f``, the head
+    on each prompt's last live row alone, K/V scattered into the paged
+    pools, first token sampled in-program;
   - one **decode-step** executable: one token per in-flight slot, scatter
     the step's K/V, attend to the slot's pages in place through the block
     table (``ops.pallas_paged_attention``), sample the next token — cache
@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ...models.decode import LSTMDecodeSpec, TransformerDecodeSpec
@@ -138,6 +139,28 @@ class GenerationConfig:
 # donation, the CPU included, so the test suite runs the same aliasing the
 # chip does.
 _DONATE_CACHE = (2,)
+
+
+def _head_rows(jaxpr, vocab: int) -> int:
+    """Rows of every matmul a traced program runs whose result is ``vocab``
+    wide: the positions the head is applied to. Dead code goes first, as
+    XLA drops it (``apply_fn`` traces the graph's own head over every
+    position, and the prefill program reads none of it); a scan's body
+    counts once a step."""
+    def count(j):
+        rows = 0
+        for eqn in j.eqns:
+            shape = eqn.outvars[0].aval.shape if eqn.outvars else ()
+            if eqn.primitive.name == "dot_general" \
+                    and shape[-1:] == (vocab,):
+                rows += math.prod(shape[:-1])
+            steps = eqn.params["length"] \
+                if eqn.primitive.name == "scan" else 1
+            rows += steps * sum(
+                count(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+        return rows
+    live, _ = dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    return count(live)
 
 
 def _launch_and_read(program: str, exe, *args):
@@ -273,6 +296,9 @@ class GenerationProgramSet:
                           mesh_sig, draft_sig)
         self._compiled: Dict[Any, Any] = {}
         self.kv_pool_chip_bytes: Optional[int] = None   # set by warm()
+        # (P, L) -> rows the head runs on in that prefill program, read off
+        # its jaxpr by warm(); the ``generation.prefill`` span carries it
+        self.head_rows: Dict[Tuple[int, int], int] = {}
         if self.adapter == "state":
             self._init_states = self.spec.init_states(config.decode_slots + 1)
 
@@ -379,6 +405,9 @@ class GenerationProgramSet:
                 self._trace_hook()
             if self.adapter == "paged":
                 k_pool, v_pool = cache
+                # the head runs on the one row of each prompt that is
+                # sampled from, selected before it: [P, d], not [P, L, d]
+                rows = lengths - 1
                 if self.kv_quantized:
                     # int8 tier: compute the prefill logits through FAKE-
                     # QUANTIZED attention (QuantSimStore) so the first
@@ -387,17 +416,16 @@ class GenerationProgramSet:
                     # hit path replays the unmatched suffix through the
                     # decode program, and both must see identical K/V
                     store = QuantSimStore(spec.n_blocks)
-                    logits = spec.decode_window(
+                    hidden = spec.window_hidden(
                         params, state, tokens,
                         jnp.zeros((tokens.shape[0],), jnp.int32), store)
+                    last = spec.logits_at(params, hidden, rows)
                     ks, vs = store.ks, store.vs
                 else:
-                    logits, ks, vs = spec.prefill_forward(params, state,
-                                                          tokens)
+                    last, ks, vs = spec.prefill_forward(params, state,
+                                                        tokens, rows)
                 k_pool = prefill_scatter(k_pool, ks, tables)
                 v_pool = prefill_scatter(v_pool, vs, tables)
-                last = jnp.take_along_axis(
-                    logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
                 tok, key = sample_tokens(last, key, temp, topk)
                 return tok, (k_pool, v_pool), key
             P = tokens.shape[0]
@@ -490,16 +518,20 @@ class GenerationProgramSet:
                 hooked(sp.rewind_state_fn()), verify)
 
     # --------------------------------------------------------------- warm-up
-    def _aot(self, fn, donate: Tuple[int, ...], *avals):
-        """AOT-compile one program, traced under the mesh it will run over
-        so layer code can see it (a Pallas kernel must split itself per
-        device under a multi-device jit — ops/pallas_attention.py). The
-        context wraps the tracing ONLY: it is part of every jit cache key,
-        and the small eager programs of warm-up (pool zeros, shard
-        placement) must be the ones first traffic reuses."""
+    def _traced(self, fn, donate: Tuple[int, ...], *avals):
+        """Trace one program under the mesh it will run over so layer code
+        can see it (a Pallas kernel must split itself per device under a
+        multi-device jit — ops/pallas_attention.py). The context wraps the
+        tracing ONLY: it is part of every jit cache key, and the small
+        eager programs of warm-up (pool zeros, shard placement) must be
+        the ones first traffic reuses."""
         with (jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh)
               if self.mesh is not None else contextlib.nullcontext()):
-            return jax.jit(fn, donate_argnums=donate).lower(*avals).compile()
+            return jax.jit(fn, donate_argnums=donate).trace(*avals)
+
+    def _aot(self, fn, donate: Tuple[int, ...], *avals):
+        """AOT-compile one program."""
+        return self._traced(fn, donate, *avals).lower().compile()
 
     def warm(self) -> "GenerationProgramSet":
         """Compile every prefill rung and the decode step; touch each once
@@ -513,7 +545,7 @@ class GenerationProgramSet:
         decode = self._decode_fn()
         for P in c.prefill_batches:
             for L in c.prompt_rungs:
-                self._compiled[("prefill", P, L)] = self._aot(
+                traced = self._traced(
                     prefill, _DONATE_CACHE,
                     self.params, self.state, cache_spec,
                     jax.ShapeDtypeStruct((P, L), i32),
@@ -523,6 +555,9 @@ class GenerationProgramSet:
                     key_spec,
                     jax.ShapeDtypeStruct((P,), jnp.float32),
                     jax.ShapeDtypeStruct((P,), i32))
+                self.head_rows[(P, L)] = _head_rows(traced.jaxpr.jaxpr,
+                                                    self.spec.vocab)
+                self._compiled[("prefill", P, L)] = traced.lower().compile()
         S = c.decode_slots
         self._compiled[("decode",)] = self._aot(
             decode, _DONATE_CACHE, self.params, self.state, cache_spec,
@@ -777,4 +812,5 @@ class GenerationProgramSet:
                              "required")
         new._compiled = self._compiled
         new.kv_pool_chip_bytes = self.kv_pool_chip_bytes
+        new.head_rows = self.head_rows
         return new

@@ -519,7 +519,8 @@ class ModelRuntime:
         self._phase("admit_batch", t_phase)
         with span("generation.prefill", model=self.name, batch=len(cands),
                   rung=L, rows=P, tokens=int(lengths[:len(cands)].sum()),
-                  padded_tokens=P * L):
+                  padded_tokens=P * L,
+                  head_rows=coh.ps.head_rows.get((P, L))):
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
                 temp, topk)

@@ -85,7 +85,7 @@ def draft_prefill_dense_fn(draft_spec):
     rows at the trash row)."""
     def fn(params, state, cache, tokens, slots):
         kc, vc = cache
-        _, ks, vs = draft_spec.prefill_forward(params, state, tokens)
+        _, ks, vs = draft_spec.prefill_forward(params, state, tokens, None)
         L = tokens.shape[1]
         for i in range(draft_spec.n_blocks):
             kc = kc.at[i, slots, :L].set(ks[i])

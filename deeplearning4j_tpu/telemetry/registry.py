@@ -412,9 +412,11 @@ class MetricsRegistry:
         route serves. A cursor older than the ring's tail simply returns
         the whole ring (the evicted gap is visible as non-contiguous seq
         numbers plus ``trace_dropped``; no silent pretense of
-        completeness)."""
+        completeness). Other threads record while this reads: the ring
+        is copied first, in one step under the interpreter lock, because
+        a deque appended to while Python code iterates it raises."""
         seq = int(seq)
-        return [e for e in self._trace if e.get("seq", 0) > seq]
+        return [e for e in list(self._trace) if e.get("seq", 0) > seq]
 
     @property
     def trace_dropped(self) -> int:

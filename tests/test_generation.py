@@ -19,6 +19,7 @@ Pins (ISSUE 9):
 Heavy soak variants are marked ``slow``; tier-1 keeps the same assertions
 at a handful-of-requests scale.
 """
+import re
 import threading
 import time
 
@@ -755,6 +756,9 @@ def test_step_and_prefill_spans_count_their_tokens(pair_events):
     (fill,) = _named(pair_events, "generation.prefill", ph="X")
     a = fill["args"]
     assert (a["rows"], a["tokens"], a["padded_tokens"]) == (2, 16, 2 * 64)
+    # the head ran on one row a prompt, not on the 2 x 64 padded positions
+    # (counted from the prefill program's own matmuls, programs._head_rows)
+    assert a["head_rows"] == 2
     assert (a["batch"], a["rung"]) == (2, 64)         # as before
     steps = _named(pair_events, "generation.decode_step", ph="X")
     # positions valid in the cache, this step's included: (5+1)+(11+1),
@@ -776,3 +780,105 @@ def test_metrics_show_queue_wait_and_host_phases(shared_lm, pair_events):
         assert hists[f"generation.lm.{key}"]["count"] >= 2
     # the step histogram is fed from the span's own interval
     assert hists["span.generation.decode_step_ms"]["count"] >= 2
+
+
+# ---------------- prefill applies the head to the rows it reads (ISSUE 33)
+# rungs and batches of the program set below; 24 and 64 are no other
+# dimension of the model (d 32, heads 2 x 16, ff 128, vocab 53)
+_HEAD_RUNGS, _HEAD_BATCHES = (24, 64), (2, 4)
+
+
+@pytest.fixture(scope="module")
+def head_rows_set():
+    from deeplearning4j_tpu.serving.generation.programs import \
+        GenerationProgramSet
+    net = _lm(dtype="float32")
+    cfg = GenerationConfig(block_len=8, max_seq_len=64, decode_slots=4,
+                           prefill_batches=_HEAD_BATCHES,
+                           prompt_rungs=_HEAD_RUNGS)
+    return net, GenerationProgramSet(net, config=cfg).warm()
+
+
+def _hlo_shapes(text):
+    """Every array shape an HLO module's text names, as tuples of ints."""
+    return {tuple(int(d) for d in m.split(","))
+            for m in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text)}
+
+
+@pytest.mark.parametrize("P", _HEAD_BATCHES)
+@pytest.mark.parametrize("L", _HEAD_RUNGS)
+def test_prefill_head_runs_on_the_rows_it_reads(head_rows_set, L, P):
+    """A batch of unequal prompts (one token, one that fills the rung, one
+    in between when there is room) and, at batch 4, a padding row."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.serving.generation.kvcache import prefill_scatter
+    net, ps = head_rows_set
+    spec, cfg = ps.spec, ps.config
+    V, S, mb = spec.vocab, cfg.decode_slots, cfg.blocks_per_seq
+    lens = [1, L] if P == 2 else [1, L, L // 2 + 1]
+    rng = np.random.default_rng(3300 + L + P)
+    tokens = np.zeros((P, L), np.int32)
+    lengths = np.ones(P, np.int32)
+    tables = np.zeros((P, mb), np.int32)
+    slots = np.full(P, S, np.int32)
+    for i, n in enumerate(lens):
+        tokens[i, :n] = rng.integers(1, V, size=n)
+        lengths[i] = n
+        tables[i] = 1 + i * mb + np.arange(mb)
+        slots[i] = i
+    zf, zi = np.zeros(P, np.float32), np.zeros(P, np.int32)
+    first, (k_pool, v_pool), _ = ps.run_prefill(
+        ps.make_cache(), tokens, lengths, tables, slots, ps.fresh_key(),
+        zf, zi)
+
+    # (a) the first token, and the logits it is sampled from, are
+    # net.output's row lengths - 1 of every live row
+    probs = np.asarray(net.output(tokens))               # [P, L, V] softmax
+    logits, _, _ = jax.jit(spec.prefill_forward)(
+        ps.params, ps.state, tokens, lengths - 1)
+    assert logits.shape == (P, V)
+    for i, n in enumerate(lens):
+        assert int(first[i]) == int(probs[i, n - 1].argmax())
+        np.testing.assert_allclose(jax.nn.softmax(logits[i]),
+                                   probs[i, n - 1], rtol=1e-5, atol=1e-7)
+
+    # (b) the executable the engine runs names no array with both the
+    # rung and the vocabulary in its shape: [P, L, V] cannot come back
+    shapes = _hlo_shapes(ps._compiled[("prefill", P, L)].as_text())
+    assert (P, V) in shapes and (P, L, spec.d_model) in shapes
+    assert not [s for s in shapes if L in s and V in s]
+    # and what the ``generation.prefill`` span reports as ``head_rows`` is
+    # read off the program: one row a prompt, padding rows included
+    assert ps.head_rows[(P, L)] == P
+
+    # (c) the K/V in the pool are bit for bit what the graph's forward
+    # gives: ln1's output times Wk / Wv, scattered by the block table
+    def reference(params, state, pools):
+        acts, _ = net.apply_fn(params, state, [tokens], train=False)
+        kv = {"Wk": [], "Wv": []}
+        for i in range(spec.n_blocks):
+            for w in kv:
+                kv[w].append((acts[f"b{i}_ln1"] @ params[spec.vi(
+                    f"b{i}_attn")][w]).reshape(P, L, spec.n_heads,
+                                               spec.head_dim))
+        return (prefill_scatter(pools[0], kv["Wk"], tables),
+                prefill_scatter(pools[1], kv["Wv"], tables))
+    want_k, want_v = jax.jit(reference)(ps.params, ps.state, ps.make_cache())
+    # block 0 is the trash block the padding row's writes collide in
+    assert jnp.array_equal(k_pool[:, 1:], want_k[:, 1:])
+    assert jnp.array_equal(v_pool[:, 1:], want_v[:, 1:])
+    assert bool(jnp.any(k_pool[:, 1:] != 0))
+
+
+def test_head_rows_is_read_off_the_program_not_assumed():
+    """The LSTM adapter's prefill is a scan that applies the head at every
+    step and keeps one row (no cell runs it; ISSUE 33 left it): its
+    programs must say so, P x L rows, where the transformer's say P."""
+    from deeplearning4j_tpu.serving.generation.programs import \
+        GenerationProgramSet
+    cfg = GenerationConfig(block_len=8, max_seq_len=64, decode_slots=2,
+                           prefill_batches=(1, 2), prompt_rungs=(16,))
+    ps = GenerationProgramSet(_steady_char_lstm(), config=cfg).warm()
+    assert ps.head_rows == {(1, 16): 16, (2, 16): 32,
+                            (1, 64): 64, (2, 64): 128}

@@ -133,6 +133,44 @@ def test_trace_seq_cursoring(fresh_registry):
     assert len(reg.trace_events_since(-1)) == len(events)
 
 
+def test_trace_events_since_while_other_threads_record(fresh_registry):
+    """A reader pulls the ring while the serving loop still records into
+    it (the benchmark does, at the window's end; the fleet collector does,
+    all day): a deque that is appended to while Python code iterates it
+    raises ``RuntimeError: deque mutated during iteration``. Seen on the
+    chip once the long-prompt cell recorded a quarter more events a second
+    (PR 33)."""
+    import sys
+    import threading
+    import time
+    reg = fresh_registry
+    for i in range(20000):                # a ring worth walking
+        reg.record_event({"name": "old", "ph": "i", "ts": i})
+    stop = threading.Event()
+
+    def writer():
+        while not stop.is_set():
+            reg.record_event({"name": "new", "ph": "i", "ts": 0})
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t = threading.Thread(target=writer, daemon=True)
+    t.start()
+    try:
+        deadline = time.monotonic() + 1.5
+        pulls = 0
+        while time.monotonic() < deadline:
+            cursor = reg.last_seq - 100
+            delta = reg.trace_events_since(cursor)
+            assert all(e["seq"] > cursor for e in delta)
+            pulls += 1
+    finally:
+        stop.set()
+        t.join(timeout=10.0)
+        sys.setswitchinterval(interval)
+    assert not t.is_alive() and pulls > 10
+
+
 def test_raw_metrics_round_trips_histogram_buckets(fresh_registry):
     """raw_metrics() is the mergeable wire format: cumulative buckets on
     the canonical ladder, counter values, gauge value+max."""

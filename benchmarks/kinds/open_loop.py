@@ -151,7 +151,8 @@ def run(ctx: Dict) -> Dict:
         f"{len(s['done'])}, ttft n={len(s['ttft_ms'])}, tpot n={len(s['tpot_ms'])}; "
         f"compiles {compiles}; peak {peak / 1e9:.3f} GB")
     metrics = {"setup_s": setup_s}
-    if s["tpot_ms"]:
+    if s["tpot_ms"]:       # one sample; BENCHMARK.json says which is judged
+        metrics["tpot_p50_ms"] = percentile(s["tpot_ms"], 50)
         metrics["tpot_p90_ms"] = percentile(s["tpot_ms"], 90)
     if s["ttft_ms"]:
         metrics["ttft_p50_ms"] = percentile(s["ttft_ms"], 50)

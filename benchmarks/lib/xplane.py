@@ -9,14 +9,17 @@ the trace's clock.
 """
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
+import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 SYNC_NAME = "bench.sync"
 _OPS_LINE = "XLA Ops"
 _INSTANCE = re.compile(r"[.\-_]?\d+$")
+REACH_NS = 3e6        # how far the device's clock may be shifted onto the host's
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -103,7 +106,7 @@ def _overlap(xs: List[Tuple[float, float]], ys: List[Tuple[float, float]]) -> fl
 
 def device_clock_shift(busy: List[Tuple[float, float]],
                        blocking: List[Tuple[float, float]],
-                       reach_ns: float = 3e6, step_ns: float = 1e5) -> float:
+                       reach_ns: float = REACH_NS, step_ns: float = 1e5) -> float:
     """The device plane's clock runs a millisecond or so off the host
     plane's (in the recorded trace a module starts 1.1 ms before the host
     dispatches it). Where the host's spans block on the device's result,
@@ -121,6 +124,44 @@ def device_clock_shift(busy: List[Tuple[float, float]],
     return best
 
 
+def _split_gaps(gaps: List[Tuple[float, float]],
+                spans: List[Tuple[str, float, float]],
+                into: Dict[str, float]) -> None:
+    """Add to ``into`` the length of every piece of the ``gaps`` (sorted,
+    disjoint) under the name of the first span of ``spans`` (innermost
+    first) that overlaps it, and what no span covers under
+    ``outside-spans``. One sweep: the gaps in time order against the spans
+    by start, so that a gap is split over the few spans open in it and
+    never meets the others."""
+    by_start = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    nxt = 0
+    open_ = []                        # ranks in ``spans`` of the spans open
+    for a, b in gaps:
+        while nxt < len(by_start) and spans[by_start[nxt]][1] < b:
+            bisect.insort(open_, by_start[nxt])
+            nxt += 1
+        open_ = [i for i in open_ if spans[i][2] > a]    # ended: never again
+        left = [(a, b)]
+        for i in open_:
+            if not left:
+                break
+            name, s, e = spans[i]
+            rest = []
+            for a_, b_ in left:
+                lo, hi = max(a_, s), min(b_, e)
+                if hi > lo:
+                    into[name] = into.get(name, 0.0) + (hi - lo)
+                    if lo > a_:
+                        rest.append((a_, lo))
+                    if b_ > hi:
+                        rest.append((hi, b_))
+                else:
+                    rest.append((a_, b_))
+            left = rest
+        for a_, b_ in left:
+            into["outside-spans"] = into.get("outside-spans", 0.0) + (b_ - a_)
+
+
 def reduce(trace: Dict, window: Tuple[float, float],
            host_spans: Sequence[Tuple[str, float, float]] = (),
            top: int = 10, blocking: bool = False) -> Dict:
@@ -130,7 +171,11 @@ def reduce(trace: Dict, window: Tuple[float, float],
     no span covers is ``outside-spans``). ``window`` and ``host_spans``
     (name, start_ns, end_ns) are on the trace's clock. ``blocking`` says
     that the spans block on the device's results, which lets the device's
-    clock be lined up with the host's (``device_clock_shift``)."""
+    clock be lined up with the host's (``device_clock_shift``).
+
+    Only the spans within ``REACH_NS`` of the window can meet a gap or
+    move the clock's fit (the shifted gaps lie inside that), so the others
+    are dropped first. ``cost`` says what the reduction itself took."""
     w0, w1 = window
     if not trace["devices"]:
         raise ValueError("the trace holds no TPU device plane with an "
@@ -139,8 +184,13 @@ def reduce(trace: Dict, window: Tuple[float, float],
     by_op: Dict[str, float] = {}
     by_cat: Dict[str, float] = {}
     gaps: Dict[str, float] = {}
-    spans = sorted(host_spans, key=lambda s: s[2] - s[1])   # innermost first
+    spans = sorted((s for s in host_spans
+                    if s[2] > w0 - REACH_NS and s[1] < w1 + REACH_NS),
+                   key=lambda s: s[2] - s[1])               # innermost first
+    blocking_union = union((s, e) for _, s, e in spans) if blocking else []
     shifts = []
+    cost = {"clock_fit_s": 0.0, "split_s": 0.0, "device_ops": 0, "gaps": 0,
+            "spans_given": len(host_spans), "spans_kept": len(spans)}
     for events in trace["devices"].values():
         clipped = []
         for name, s, d in events:
@@ -153,29 +203,18 @@ def reduce(trace: Dict, window: Tuple[float, float],
                 by_cat[cat] = by_cat.get(cat, 0.0) + (b - a)
         merged = union(clipped)
         busy_total += sum(b - a for a, b in merged)
-        shift = device_clock_shift(
-            merged, union((s, e) for _, s, e in spans)) if blocking else 0.0
+        began = time.perf_counter()
+        shift = device_clock_shift(merged, blocking_union) if blocking else 0.0
         shifts.append(shift)
+        fitted = time.perf_counter()
         edges = [w0] + [t + shift for ab in merged for t in ab] + [w1]
-        for i in range(0, len(edges), 2):
-            left = [(edges[i], edges[i + 1])] if edges[i + 1] > edges[i] else []
-            for name, s, e in spans:
-                if not left:
-                    break
-                rest = []
-                for a, b in left:
-                    lo, hi = max(a, s), min(b, e)
-                    if hi > lo:
-                        gaps[name] = gaps.get(name, 0.0) + (hi - lo)
-                        if lo > a:
-                            rest.append((a, lo))
-                        if b > hi:
-                            rest.append((hi, b))
-                    else:
-                        rest.append((a, b))
-                left = rest
-            for a, b in left:
-                gaps["outside-spans"] = gaps.get("outside-spans", 0.0) + (b - a)
+        idle = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+                if edges[i + 1] > edges[i]]
+        _split_gaps(idle, spans, gaps)
+        cost["clock_fit_s"] += fitted - began
+        cost["split_s"] += time.perf_counter() - fitted
+        cost["device_ops"] += len(clipped)
+        cost["gaps"] += len(idle)
     n = len(trace["devices"])
     rank = lambda d: [[k, v / n / 1e9] for k, v in
                       sorted(d.items(), key=lambda kv: -kv[1])[:top]]
@@ -183,4 +222,4 @@ def reduce(trace: Dict, window: Tuple[float, float],
             "device_ops": rank(by_op), "idle_gaps": rank(gaps),
             "by_op_s": {k: v / n / 1e9 for k, v in by_op.items()},
             "by_category_s": {k: v / n / 1e9 for k, v in by_cat.items()},
-            "device_clock_shift_ms": [x / 1e6 for x in shifts]}
+            "device_clock_shift_ms": [x / 1e6 for x in shifts], "cost": cost}

@@ -101,6 +101,7 @@ class Tracer:
         self._thread = None
         self.window_wall_ns = None        # (start, end) of the traced part
         self.sync_wall_ns = None
+        self.stop_s = None                # what stop_trace took, in the thread
         self.error = None
 
     def begin(self) -> None:
@@ -125,6 +126,7 @@ class Tracer:
             time.sleep(TRACE_SECONDS)
             t1 = time.time_ns()
             jax.profiler.stop_trace()
+            self.stop_s = (time.time_ns() - t1) / 1e9
             self.window_wall_ns = (t0, t1)
         except Exception as e:           # reported by finish(), never hidden
             self.error = e
@@ -141,7 +143,9 @@ class Tracer:
         if self.error is not None:
             raise self.error
         from benchmarks.lib import xplane
+        began = time.perf_counter()
         trace = xplane.load(xplane.find_xplane(self.dir))
+        load_s = time.perf_counter() - began
         if trace["sync_ns"] is None:
             raise RuntimeError("bench.sync is not in the trace")
         if self.rehearsal:      # a CPU trace has no device plane to reduce
@@ -151,13 +155,19 @@ class Tracer:
         w = tuple(t - off for t in self.window_wall_ns)
         spans = [(n, s - off, e - off) for n, s, e in host_spans]
         out = xplane.reduce(trace, w, spans, blocking=blocking)
+        cost = dict(out["cost"], load_s=load_s, profiler_stop_s=self.stop_s)
+        log("trace reduced: profiler's stop {profiler_stop_s:.1f}s (in its "
+            "thread), xplane.load {load_s:.1f}s, clock fit "
+            "{clock_fit_s:.1f}s, split {split_s:.1f}s; {device_ops} device "
+            "operations, {gaps} gaps, {spans_kept} of {spans_given} spans "
+            "kept".format(**cost))
         shutil.rmtree(self.dir, ignore_errors=True)
         os.makedirs(OUT, exist_ok=True)
         with open(self.dir + ".ops.json", "w") as f:     # for a reader's eyes
             json.dump({"by_op_s": out["by_op_s"], "by_category_s":
                        out["by_category_s"], "lines": trace["lines"],
                        "device_clock_shift_ms": out["device_clock_shift_ms"],
-                       "window_ns": w}, f, indent=1)
+                       "window_ns": w, "reduction_cost": cost}, f, indent=1)
         return out
 
 

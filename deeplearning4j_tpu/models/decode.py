@@ -5,9 +5,13 @@ generation loop that re-ran it per emitted token would retrace O(T) work per
 token. This module provides the *incremental* forward for the two generative
 zoo models:
 
-- ``TransformerDecodeSpec`` — walks a ``models.transformer_lm`` graph (the
-  vertex names ``embed``/``pos``/``b{i}_*``/``ln_f``/``head`` are that
-  builder's contract) and exposes:
+- ``GraphDecodeSpec`` (``TransformerDecodeSpec`` is its older name) — reads
+  a language-model ``ComputationGraph`` by the kinds of its layers, not by
+  one builder's vertex names: causal attention mixers go through a
+  ``KVStore``, recurrent mixers (the gated short convolution) through a
+  per-slot state the store carries, norms / MLPs / expert layers / adds
+  replay their own ``apply``. ``models.transformer_lm`` is one case. It
+  exposes:
     * ``prefill_forward`` — ONE full forward over the padded prompt that
       returns pre-activation logits for the ONE position of each prompt the
       caller names (the head runs on ``[B, 1, d]``, never on every padded
@@ -59,7 +63,18 @@ class KVStore(Protocol):
     def attend(self, i: int, q, k_tok, v_tok) -> Any:
         """q: [B,H,1,Dh]; k_tok/v_tok: [B,H,Dh] for the current position.
         Returns the attention output [B,H,1,Dh]. (A window store, for
-        ``decode_window``, takes q [B,H,W,Dh] and k/v [B,W,H,Dh].)"""
+        ``decode_window``, takes q [B,H,W,Dh] and k/v [B,W,H,Dh].) With
+        grouped-query attention q has the layer's query heads and k/v its
+        (fewer) key-value heads."""
+        ...
+
+    def state(self, j: int) -> Any:
+        """The state of recurrent mixer ``j`` for the step's sequences (a
+        store for a model that has none needs no such method)."""
+        ...
+
+    def set_state(self, j: int, new) -> None:
+        """Leave recurrent mixer ``j``'s state after the step."""
         ...
 
 
@@ -82,12 +97,31 @@ def window_attention(q, k, v, row_mask):
 
 
 # ---------------------------------------------------------------- transformer
-class TransformerDecodeSpec:
-    """Vertex map of a ``models.transformer_lm`` graph, validated for the
-    incremental decode path."""
+class StatefulDecodeUnsupportedError(ValueError):
+    """A serving feature that does not carry a recurrent mixer's per-slot
+    state (a multi-token decode window: speculative verify, the int8
+    tier's fake-quantized prefill) was asked of a model that has one. It
+    is refused by name: the one outcome not allowed is a silent wrong
+    token."""
+
+
+class GraphDecodeSpec:
+    """A language-model ``ComputationGraph`` read by the KINDS of its
+    layers, validated for the incremental decode path: token embedding
+    (optionally a learned position table), then any graph of blocks whose
+    sequence MIXERS are causal ``SelfAttentionLayer``s (served through a
+    ``KVStore``) or recurrent layers with a fixed-shape state
+    (``apply_with_final_state`` + ``state_at``: served from a per-slot
+    state the store carries), then a final norm and an ``RnnOutputLayer``
+    head. Every other vertex (norms, dense and gated MLPs,
+    mixture-of-experts layers, residual adds) is position-wise and
+    replays its own ``apply``. ``models.transformer_lm`` (GPT-2) is one
+    case, the hybrid convolution / attention / expert models another."""
 
     def __init__(self, net):
-        from ..nn.layers import (EmbeddingSequenceLayer, LayerNormalization,
+        from ..nn.layers import (EmbeddingSequenceLayer,
+                                 MixtureOfExpertsLayer,
+                                 PositionalEmbeddingLayer,
                                  SelfAttentionLayer)
         from ..nn.layers.core import DenseLayer, RnnOutputLayer
 
@@ -96,46 +130,108 @@ class TransformerDecodeSpec:
             raise ValueError("decode path does not support mixed "
                              "compute_dtype nets (params are served in "
                              "their stored dtype)")
+        if not hasattr(net, "vertex_names"):
+            raise ValueError("GraphDecodeSpec reads a ComputationGraph "
+                             "(MultiLayerNetwork recurrent stacks take "
+                             "LSTMDecodeSpec)")
         names = list(net.vertex_names)
         self._idx = {n: i for i, n in enumerate(names)}
-        for required in ("embed", "pos", "ln_f", "head"):
-            if required not in self._idx:
-                raise ValueError(
-                    f"not a models.transformer_lm graph: vertex {required!r} "
-                    f"missing (got {names})")
-        self.n_blocks = 0
-        while f"b{self.n_blocks}_attn" in self._idx:
-            self.n_blocks += 1
-        if self.n_blocks == 0:
-            raise ValueError("no attention blocks found (b0_attn missing)")
-        v = net.vertices
-        self._v = {n: v[i] for n, i in self._idx.items()}
-        embed = self._v["embed"].layer_conf
+        self._v = dict(zip(names, net.vertices))
+        self._inputs = {n: list(net.conf.vertex_inputs[n]) for n in names}
+        conf = net.conf
+        if len(conf.network_inputs) != 1 or len(conf.network_outputs) != 1:
+            raise ValueError("decode requires one input and one output")
+        self.input_name = conf.network_inputs[0]
+        self.head_name = conf.network_outputs[0]
+
+        def layer(n):
+            return getattr(self._v[n], "layer_conf", None)
+
+        if not isinstance(layer(self.head_name), RnnOutputLayer):
+            raise ValueError("decode requires an RnnOutputLayer head")
+        if len(self._inputs[self.head_name]) != 1:
+            raise ValueError("the head takes one input (the final norm)")
+        self.final_name = self._inputs[self.head_name][0]
+        fed = [n for n in names if self.input_name in self._inputs[n]]
+        if len(fed) != 1:
+            raise ValueError(f"not a language-model graph: the token input "
+                             f"feeds {fed} (got vertices {names})")
+        self.embed_name = fed[0]
+        embed = layer(self.embed_name)
         self.token_input = isinstance(embed, EmbeddingSequenceLayer)
         if not self.token_input and not isinstance(embed, DenseLayer):
             raise ValueError(f"unsupported embed layer {type(embed).__name__}")
-        attn0 = self._v["b0_attn"].layer_conf
-        if not isinstance(attn0, SelfAttentionLayer) or not attn0.causal:
-            raise ValueError("decode requires causal SelfAttentionLayer "
-                             "blocks")
-        if not isinstance(self._v["head"].layer_conf, RnnOutputLayer):
-            raise ValueError("decode requires an RnnOutputLayer head")
-        if not isinstance(self._v["ln_f"].layer_conf, LayerNormalization):
-            raise ValueError("decode requires a LayerNormalization final "
-                             "norm")
+        pos = [n for n in names
+               if isinstance(layer(n), PositionalEmbeddingLayer)]
+        if len(pos) > 1:
+            raise ValueError(f"more than one position table: {pos}")
+        self.pos_name = pos[0] if pos else None
+        self.attn_names = [n for n in names
+                           if isinstance(layer(n), SelfAttentionLayer)]
+        self.recurrent_names = [n for n in names
+                                if getattr(self._v[n], "recurrent", False)]
+        self.moe_names = [n for n in names
+                          if isinstance(layer(n), MixtureOfExpertsLayer)]
+        if not self.attn_names:
+            raise ValueError("no attention layer found (a graph of "
+                             "recurrent mixers alone has no pages to serve "
+                             f"from; got vertices {names})")
+        attn0 = layer(self.attn_names[0])
+        for n in self.attn_names:
+            a = layer(n)
+            if not a.causal:
+                raise ValueError("decode requires causal SelfAttentionLayer "
+                                 f"blocks ({n} is not)")
+            if (a.n_heads, a.kv_heads, a.n_out) != (
+                    attn0.n_heads, attn0.kv_heads, attn0.n_out):
+                raise ValueError("the attention layers must share one "
+                                 "head layout (one pool holds them all)")
+        for n in self.recurrent_names:
+            if not hasattr(layer(n), "state_at"):
+                raise ValueError(
+                    f"recurrent mixer {n} ({type(layer(n)).__name__}) has "
+                    f"no state_at: its state at a padded prompt's true "
+                    f"length cannot be read from one batched forward")
+        self._attn_i = {n: i for i, n in enumerate(self.attn_names)}
+        self._rec_j = {n: j for j, n in enumerate(self.recurrent_names)}
+        self.n_blocks = len(self.attn_names)       # layers the pools hold
         self.n_heads = attn0.n_heads
+        self.kv_heads = attn0.kv_heads
         self.d_model = attn0.n_out
-        self.head_dim = self.d_model // self.n_heads
-        self.vocab = self._v["head"].layer_conf.n_out
-        self.max_length = self._v["pos"].layer_conf.max_length
+        self.head_dim = attn0.head_dim
+        self.vocab = layer(self.head_name).n_out
+        self.max_length = layer(self.pos_name).max_length \
+            if self.pos_name else None
         self.dtype = jnp.dtype(net.conf.dtype)
+        self.n_moe = len(self.moe_names)
+        self.moe_top_k = layer(self.moe_names[0]).top_k if self.n_moe else 0
+        self.moe_experts = layer(self.moe_names[0]).n_experts \
+            if self.n_moe else 0
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a sequence carries more than its K/V pages."""
+        return bool(self.recurrent_names)
 
     def supports_head_sharding(self, m: int) -> bool:
         """Whether the paged KV pools (and the Q/K/V/O projections) can
         split their head axis ``m`` ways: attention is head-local, so an
         even head split keeps every per-head row on one shard and decode
         stays token-for-token identical to the single-chip program."""
-        return m >= 1 and self.n_heads % m == 0
+        return m >= 1 and self.kv_heads % m == 0 and self.n_heads % m == 0
+
+    def recurrent_state_shape(self, rows: int):
+        """[recurrent layers, rows, ...]: the per-slot state of the
+        recurrent mixers stacked (they must share a shape), or None."""
+        if not self.recurrent_names:
+            return None
+        shapes = {tuple(self._v[n].layer_conf.zero_state(rows,
+                                                         self.dtype).shape)
+                  for n in self.recurrent_names}
+        if len(shapes) != 1:
+            raise ValueError("the recurrent mixers must share one state "
+                             f"shape, got {sorted(shapes)}")
+        return (len(self.recurrent_names),) + shapes.pop()
 
     # index/param helpers ---------------------------------------------------
     def vi(self, name: str) -> int:
@@ -144,112 +240,183 @@ class TransformerDecodeSpec:
     def _p(self, params, name: str):
         return params[self._idx[name]]
 
-    def _apply(self, params, state, name: str, x):
-        """Run one named LayerVertex exactly as apply_fn would (train=False,
+    def _apply(self, params, state, name: str, xs):
+        """Run one named vertex exactly as apply_fn would (train=False,
         preprocessors honored, no mask)."""
-        v = self._v[name]
-        out, _ = v.apply(self._p(params, name), state[self._idx[name]], [x],
-                         train=False, rng=None)
+        out, _ = self._v[name].apply(self._p(params, name),
+                                     state[self._idx[name]], xs,
+                                     train=False, rng=None)
         return out
-
-    def _heads(self, x):
-        """[B,T,d] -> [B,H,T,Dh] (SelfAttentionLayer._heads layout)."""
-        B, T, _ = x.shape
-        return x.reshape(B, T, self.n_heads, self.head_dim).transpose(
-            0, 2, 1, 3)
 
     def embed_tokens(self, params, tokens):
         """[B,T] int token ids -> [B,T,d] embeddings via the model's own
         embed layer (gather, or one-hot matmul for the legacy input)."""
-        embed = self._v["embed"].layer_conf
+        embed = self._v[self.embed_name].layer_conf
         if self.token_input:
-            return embed.apply(self._p(params, "embed"), {}, tokens,
+            return embed.apply(self._p(params, self.embed_name), {}, tokens,
                                train=False)[0]
         onehot = jax.nn.one_hot(tokens, self.vocab, dtype=self.dtype)
-        return embed.apply(self._p(params, "embed"), {}, onehot,
+        return embed.apply(self._p(params, self.embed_name), {}, onehot,
                            train=False)[0]
 
     # ---------------------------------------------------------------- head
     def head_logits(self, params, y):
-        """Pre-activation logits of the head over ``ln_f``'s output:
+        """Pre-activation logits of the head over the final norm's output:
         y [B,T,d] -> [B,T,V]."""
-        head_v = self._v["head"]
+        head_v = self._v[self.head_name]
         if head_v.preprocessor is not None:
             y = head_v.preprocessor.apply(y)
-        return head_v.layer_conf.pre_output(self._p(params, "head"), y)
+        return head_v.layer_conf.pre_output(self._p(params, self.head_name),
+                                            y)
 
     def logits_at(self, params, y, rows):
-        """The head on ONE position of each sequence: y [B,T,d] is
-        ``ln_f``'s output, rows [B] the position to read -> [B,V]. The
+        """The head on ONE position of each sequence: y [B,T,d] is the
+        final norm's output, rows [B] the position to read -> [B,V]. The
         rows are selected BEFORE the head, so no [B,T,V] value exists."""
         y = jnp.take_along_axis(y, rows[:, None, None], axis=1)  # [B,1,d]
         return self.head_logits(params, y)[:, 0]
 
+    # ----------------------------------------------------- expert counters
+    def _moe_stats(self, params, name, u, live):
+        """What one expert layer routed: u [B,T,d] its input, live [B,T]
+        the rows that are real. Returns (pairs the fullest expert got,
+        experts with at least one pair), int32 scalars."""
+        layer = self._v[name].layer_conf
+        idx, _ = layer.route(self._p(params, name),
+                             u.reshape(-1, u.shape[-1]))
+        hits = jnp.broadcast_to(live.reshape(-1, 1), idx.shape)
+        load = jnp.zeros((layer.n_experts,), jnp.int32).at[idx].add(
+            hits.astype(jnp.int32))
+        return jnp.max(load), jnp.sum(load > 0).astype(jnp.int32)
+
+    @staticmethod
+    def _fold_stats(per_layer):
+        """[(load_max, touched)] a layer -> int32 [2]: the fullest expert
+        of any layer, experts touched summed over the layers."""
+        if not per_layer:
+            return None
+        a = jnp.stack([jnp.stack(s) for s in per_layer])      # [layers, 2]
+        return jnp.stack([a[:, 0].max(), a[:, 1].sum()])
+
     # ------------------------------------------------------------- prefill
-    def prefill_forward(self, params, state, tokens, rows):
+    def prefill_full(self, params, state, tokens, rows, lengths=None):
         """Full forward over the padded prompt [B,L] through the graph's own
-        ``apply_fn`` (everything up to ``ln_f`` is what ``net.output``
-        computes), plus the per-layer K/V tensors for the cache.
+        ``apply_fn`` (everything up to the final norm is what ``net.output``
+        computes), plus what the caches keep.
 
         ``rows`` [B] names the position of each prompt whose logits the
         caller will read (``lengths - 1`` for the first sampled token); the
         head is applied to those rows alone. None asks for no logits (the
-        draft's prefill keeps only K/V).
+        draft's prefill keeps only K/V). ``lengths`` [B] are the prompts'
+        true lengths: the recurrent mixers' states are taken THERE, not at
+        the rung, and the expert counters count live rows only.
 
-        Returns (logits [B,V] pre-activation or None, ks, vs) with
-        ks[i]/vs[i]: [B,L,H,Dh]."""
+        Returns (logits [B,V] pre-activation or None, ks, vs, states,
+        stats): ks[i]/vs[i] [B,L,Hkv,Dh] an attention layer, as a cache
+        keeps them (k normed and rotated where the layer does that);
+        states[j] a recurrent mixer's state after ``lengths`` rows; stats
+        int32 [2] (fullest expert's pairs, experts touched) or None."""
         x_in = tokens if self.token_input else \
             jax.nn.one_hot(tokens, self.vocab, dtype=self.dtype)
         acts, _ = self.net.apply_fn(params, state, [x_in], train=False)
         logits = None if rows is None else \
-            self.logits_at(params, acts["ln_f"], rows)
+            self.logits_at(params, acts[self.final_name], rows)
         ks, vs = [], []
-        for i in range(self.n_blocks):
-            ap = self._p(params, f"b{i}_attn")
-            y = acts[f"b{i}_ln1"]
-            B, L, _ = y.shape
-            ks.append((y @ ap["Wk"]).reshape(B, L, self.n_heads,
-                                             self.head_dim))
-            vs.append((y @ ap["Wv"]).reshape(B, L, self.n_heads,
-                                             self.head_dim))
-        return logits, ks, vs
+        for n in self.attn_names:
+            y = acts[self._inputs[n][0]]
+            _, k, v = self._v[n].layer_conf.project_qkv(self._p(params, n), y)
+            ks.append(k)
+            vs.append(v)
+        states, stats = [], []
+        if lengths is not None:
+            for n in self.recurrent_names:
+                states.append(self._v[n].layer_conf.state_at(
+                    self._p(params, n), acts[self._inputs[n][0]], lengths))
+            live = jnp.arange(tokens.shape[1])[None, :] < lengths[:, None]
+            stats = [self._moe_stats(params, n, acts[self._inputs[n][0]],
+                                     live) for n in self.moe_names]
+        return logits, ks, vs, states, self._fold_stats(stats)
+
+    def prefill_forward(self, params, state, tokens, rows):
+        """``prefill_full`` for a model that keeps K/V alone: (logits, ks,
+        vs)."""
+        return self.prefill_full(params, state, tokens, rows)[:3]
+
+    # ------------------------------------------------------------ the walk
+    def _walk(self, params, state, tokens, pos, store, *, window: bool,
+              live=None):
+        """W fed tokens a sequence (tokens [B,W] at positions ``pos ..
+        pos+W-1``) through the graph, vertex by vertex, up to the final
+        norm. Attention goes through ``store.attend`` (one-token protocol
+        unless ``window``), a recurrent mixer takes and leaves its state in
+        the store, everything else replays its own ``apply``. Returns
+        (hidden [B,W,d], expert stats or None)."""
+        B, W = tokens.shape
+        w_pos = pos[:, None] + jnp.arange(W)[None, :]            # [B,W]
+        acts = {}
+        stats = []
+        for name, v in self._v.items():
+            if name == self.head_name:
+                continue
+            ins = self._inputs[name]
+            if name == self.embed_name:
+                out = self.embed_tokens(params, tokens)
+            elif name == self.pos_name:
+                P = self._p(params, name)["P"]
+                out = v.layer_conf.act(
+                    acts[ins[0]] + P[jnp.clip(w_pos, 0, P.shape[0] - 1)])
+            elif name in self._attn_i:
+                out = self._attend(params, name, acts[ins[0]], w_pos, store,
+                                   window)
+            elif name in self._rec_j:
+                if window:
+                    raise StatefulDecodeUnsupportedError(
+                        f"a decode window does not carry {name}'s state")
+                j = self._rec_j[name]
+                out, new = v.apply_with_final_state(
+                    self._p(params, name), state[self._idx[name]],
+                    [acts[ins[0]]], train=False, rng=None,
+                    initial_state=store.state(j))
+                store.set_state(j, new)
+            else:
+                out = self._apply(params, state, name,
+                                  [acts[i] for i in ins])
+            if live is not None and name in self.moe_names:
+                stats.append(self._moe_stats(params, name, acts[ins[0]],
+                                             live))
+            acts[name] = out
+        return acts[self.final_name], self._fold_stats(stats)
+
+    def _attend(self, params, name, y, w_pos, store, window):
+        layer = self._v[name].layer_conf
+        ap = self._p(params, name)
+        B, W, _ = y.shape
+        q, k, v = layer.project_qkv(ap, y, w_pos)
+        q = q.transpose(0, 2, 1, 3)                            # [B,H,W,Dh]
+        i = self._attn_i[name]
+        out = store.attend(i, q, k, v) if window else \
+            store.attend(i, q, k[:, 0], v[:, 0])
+        out = out.transpose(0, 2, 1, 3).reshape(B, W, self.d_model)
+        return layer.project_output(ap, out)
 
     # ---------------------------------------------------------- decode step
     def decode_step(self, params, state, tokens, pos, store: KVStore):
         """One incremental step: ``tokens`` [B] int ids at positions ``pos``
         [B]. K/V for the step go through ``store`` (write, then attend), so
-        attention row ``pos`` sees the keys the naive causal row sees.
-        Returns pre-activation logits [B,V]."""
-        x = self.embed_tokens(params, tokens[:, None])        # [B,1,d]
-        P = self._p(params, "pos")["P"]
-        x = x + P[pos][:, None, :]
-        pos_layer = self._v["pos"].layer_conf
-        x = pos_layer.act(x)
-        for i in range(self.n_blocks):
-            x = self._block_step(params, state, i, x, pos, store)
-        y = self._apply(params, state, "ln_f", x)
-        return self.head_logits(params, y)[:, 0, :]
+        attention row ``pos`` sees the keys the naive causal row sees; a
+        recurrent mixer reads and leaves its state in the store. Returns
+        pre-activation logits [B,V]."""
+        return self.decode_step_stats(params, state, tokens, pos, store)[0]
 
-    def _block_step(self, params, state, i, x, pos, store: KVStore):
-        h = x
-        y = self._apply(params, state, f"b{i}_ln1", x)        # [B,1,d]
-        ap = self._p(params, f"b{i}_attn")
-        attn_layer = self._v[f"b{i}_attn"].layer_conf
-        B = y.shape[0]
-        q = self._heads(y @ ap["Wq"])                          # [B,H,1,Dh]
-        k_tok = (y @ ap["Wk"]).reshape(B, self.n_heads, self.head_dim)
-        v_tok = (y @ ap["Wv"]).reshape(B, self.n_heads, self.head_dim)
-        out = store.attend(i, q, k_tok, v_tok)
-        out = out.transpose(0, 2, 1, 3).reshape(B, 1, self.d_model)
-        if attn_layer.project_out:
-            out = out @ ap["Wo"] + ap["b"]
-        out = attn_layer.act(out)
-        x = h + out                                            # b{i}_add1
-        h2 = x
-        y2 = self._apply(params, state, f"b{i}_ln2", x)
-        f = self._apply(params, state, f"b{i}_ff2",
-                        self._apply(params, state, f"b{i}_ff1", y2))
-        return h2 + f                                          # b{i}_add2
+    def decode_step_stats(self, params, state, tokens, pos, store: KVStore,
+                          live=None):
+        """``decode_step`` and, with ``live`` [B] (the slots that are real),
+        what the expert layers routed for them: (logits [B,V], int32 [2]
+        (fullest expert's pairs, experts touched) or None)."""
+        y, stats = self._walk(params, state, tokens[:, None], pos, store,
+                              window=False,
+                              live=None if live is None else live[:, None])
+        return self.head_logits(params, y)[:, 0, :], stats
 
     # --------------------------------------------------------- decode window
     def decode_window(self, params, state, tokens, pos, store):
@@ -261,45 +428,29 @@ class TransformerDecodeSpec:
         math (all non-attention ops are position-wise; attention rows
         carry per-row limits), so the
         returned logits [B,W,V] match W sequential decode steps
-        token-for-token — the property the verify acceptance rule needs."""
+        token-for-token — the property the verify acceptance rule needs.
+        A model with a recurrent mixer is refused
+        (``StatefulDecodeUnsupportedError``)."""
         return self.head_logits(
             params, self.window_hidden(params, state, tokens, pos, store))
 
     def window_hidden(self, params, state, tokens, pos, store):
-        """``decode_window`` up to ``ln_f``: [B,W,d], the head's input. A
-        caller that reads one row of the window (the int8 tier's prefill)
-        selects it here and applies the head to that (``logits_at``)."""
-        B, W = tokens.shape
-        x = self.embed_tokens(params, tokens)                  # [B,W,d]
-        P = self._p(params, "pos")["P"]
-        w_pos = pos[:, None] + jnp.arange(W)[None, :]          # [B,W]
-        x = x + P[jnp.clip(w_pos, 0, P.shape[0] - 1)]
-        pos_layer = self._v["pos"].layer_conf
-        x = pos_layer.act(x)
-        for i in range(self.n_blocks):
-            x = self._block_window(params, state, i, x, store)
-        return self._apply(params, state, "ln_f", x)
+        """``decode_window`` up to the final norm: [B,W,d], the head's
+        input. A caller that reads one row of the window (the int8 tier's
+        prefill) selects it here and applies the head to that
+        (``logits_at``)."""
+        return self.window_hidden_stats(params, state, tokens, pos, store)[0]
 
-    def _block_window(self, params, state, i, x, store):
-        h = x
-        y = self._apply(params, state, f"b{i}_ln1", x)         # [B,W,d]
-        ap = self._p(params, f"b{i}_attn")
-        attn_layer = self._v[f"b{i}_attn"].layer_conf
-        B, W, _ = y.shape
-        q = self._heads(y @ ap["Wq"])                          # [B,H,W,Dh]
-        k_win = (y @ ap["Wk"]).reshape(B, W, self.n_heads, self.head_dim)
-        v_win = (y @ ap["Wv"]).reshape(B, W, self.n_heads, self.head_dim)
-        out = store.attend(i, q, k_win, v_win)
-        out = out.transpose(0, 2, 1, 3).reshape(B, W, self.d_model)
-        if attn_layer.project_out:
-            out = out @ ap["Wo"] + ap["b"]
-        out = attn_layer.act(out)
-        x = h + out
-        h2 = x
-        y2 = self._apply(params, state, f"b{i}_ln2", x)
-        f = self._apply(params, state, f"b{i}_ff2",
-                        self._apply(params, state, f"b{i}_ff1", y2))
-        return h2 + f
+    def window_hidden_stats(self, params, state, tokens, pos, store,
+                            live=None):
+        """``window_hidden`` and, with ``live`` [B,W] (the rows that are
+        real), the expert layers' counters over them (else None)."""
+        return self._walk(params, state, tokens, pos, store, window=True,
+                          live=live)
+
+
+# the name the GPT-2-only specification had; callers and tests keep it
+TransformerDecodeSpec = GraphDecodeSpec
 
 
 # ----------------------------------------------------------------------- LSTM

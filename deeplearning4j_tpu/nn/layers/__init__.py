@@ -8,7 +8,8 @@ from .conv import (Convolution1DLayer, ConvolutionLayer, GlobalPoolingLayer,
                    SubsamplingLayer, Subsampling1DLayer, ZeroPadding1DLayer,
                    ZeroPaddingLayer)
 from .norm import (BatchNormalization, LayerNormalization,
-                   LocalResponseNormalization)
+                   LocalResponseNormalization, RMSNorm)
+from .gated import GatedMLP, GatedShortConvLayer, MixtureOfExpertsLayer
 from .attention import SelfAttentionLayer
 from .recurrent import (GravesBidirectionalLSTM, GravesLSTM, LSTM,
                         LastTimeStepLayer)
@@ -30,7 +31,8 @@ __all__ = [
     "RnnOutputLayer", "Convolution1DLayer", "ConvolutionLayer",
     "GlobalPoolingLayer", "SubsamplingLayer", "Subsampling1DLayer",
     "ZeroPadding1DLayer", "ZeroPaddingLayer", "BatchNormalization",
-    "LayerNormalization",
+    "LayerNormalization", "RMSNorm",
+    "GatedMLP", "GatedShortConvLayer", "MixtureOfExpertsLayer",
     "LocalResponseNormalization",
     "GravesBidirectionalLSTM", "GravesLSTM", "LSTM", "LastTimeStepLayer",
 ]

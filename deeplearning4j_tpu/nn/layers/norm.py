@@ -141,3 +141,37 @@ class LocalResponseNormalization(LayerConf):
                                    ((0, 0), (0, 0), (0, 0), (half, half)))
         denom = (self.k + self.alpha * summed) ** self.beta
         return x / denom, state
+
+
+@register
+@dataclass
+class RMSNorm(LayerConf):
+    """Root-mean-square norm over the FEATURE axis, one gain a feature and
+    no bias: ``x / sqrt(mean(x^2) + eps) * gain``. The statistics are taken
+    in float32 whatever the activations' type (a bfloat16 mean of squares
+    over 2,048 features loses the third digit)."""
+    n_out: Optional[int] = None        # feature count (inferred)
+    eps: float = 1e-5
+
+    param_order: ClassVar[Tuple[str, ...]] = ("gain",)
+    weight_param_names: ClassVar[Tuple[str, ...]] = ()
+    expected_input: ClassVar[str] = "any"
+
+    def init(self, rng, itype, dtype):
+        nf = self.n_out or (itype.size if itype is not None else None)
+        if not nf:
+            raise ValueError("RMSNorm cannot infer its feature count: set "
+                             "n_out or provide an input type")
+        self.n_out = nf
+        return {"gain": jnp.ones((nf,), dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return self.act(rms_norm(x, params["gain"], self.eps)), state
+
+
+def rms_norm(x, gain, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * gain`` over the last axis, statistics
+    in float32, result in x's dtype."""
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * inv * gain.astype(jnp.float32)).astype(x.dtype)

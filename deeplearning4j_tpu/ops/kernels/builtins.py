@@ -255,7 +255,58 @@ def _register_paged_attention():
     ))
 
 
+# ------------------------------------------------------------- moe_experts
+def _moe_experts_args(seed: int):
+    rng = np.random.default_rng(seed)
+    N, d, F, E, k = 40, 128, 256, 8, 2
+    mk = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.1, f32)
+    idx = jnp.asarray(np.stack([rng.permutation(E)[:k] for _ in range(N)]),
+                      jnp.int32)
+    return (jnp.asarray(rng.standard_normal((N, d)), f32), idx,
+            jnp.asarray(rng.random((N, k)), f32), mk(E, d, F), mk(E, d, F),
+            mk(E, F, d))
+
+
+def _moe_experts_parity(seed: int):
+    from .. import grouped_matmul as gm
+    args = _moe_experts_args(seed)
+    with jax.default_matmul_precision("highest"):
+        fused = gm._expert_ffn(*args, first=0, fused=True,
+                               interpret=gm._interpret())
+        return [fused], [gm.expert_ffn_reference(*args)]
+
+
+def _moe_experts_roofline(shape_sig: str):
+    pairs, touched, d, F, itemsize = (int(v) for v in shape_sig.split("x"))
+    flops = pairs * 2.0 * 3 * d * F
+    nbytes = touched * 3.0 * d * F * itemsize + pairs * 2.0 * d * itemsize
+    return flops, nbytes
+
+
+def _register_moe_experts():
+    from .. import grouped_matmul as gm
+    register(KernelSpec(
+        name="moe_experts",
+        fused=gm.expert_ffn,
+        fallback=gm.expert_ffn_reference,
+        applicable=lambda x, idx, w, W1, *a, **k: gm.kernels_applicable(
+            x.shape[1], W1.shape[2], x.dtype),
+        parity=ParityPin(run=_moe_experts_parity, tol=1e-4,
+                         note="pairs sorted by expert through two tiled "
+                              "kernels vs every expert over every token, "
+                              "masked"),
+        roofline=_moe_experts_roofline,
+        tunable="rows of a tile (row_tile: near an expert's mean run, "
+                "16..256)",
+        default_choice=(256,),
+        notes="the experts of a mixture-of-experts layer over the pairs "
+              "routed to them: compute bound in a prefill, a stream of the "
+              "touched experts' weights in a decode step; ragged_dot off "
+              "the TPU and under a gradient",
+    ))
+
+
 for _reg in (_register_attention, _register_lstm, _register_encode,
              _register_int8_matmul, _register_conv,
-             _register_paged_attention):
+             _register_paged_attention, _register_moe_experts):
     _reg()

@@ -62,7 +62,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _body(W, H, Dh, blk, G, cap, scale,
+def _body(W, H, Dh, blk, G, cap, scale, Gq,
           layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
           kbuf, vbuf, sem, m_s, l_s, acc_s):
     T = G * blk
@@ -100,8 +100,9 @@ def _body(W, H, Dh, blk, G, cap, scale,
         each_copy(0, 0, "start")
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (acc_s.shape[0], T), 0)
-    # row w * H + h belongs to window position w, which sees n0 + w keys
-    limit = jnp.minimum(n0 + rows // H, last)
+    # row (w * Gq + g) * H + h belongs to window position w, which sees
+    # n0 + w keys (Gq query heads read one key-value head; 1 without groups)
+    limit = jnp.minimum(n0 + rows // (Gq * H), last)
 
     def step(gi, carry):
         slot = gi % 2
@@ -141,28 +142,32 @@ def _body(W, H, Dh, blk, G, cap, scale,
     r = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
     out = jnp.where(lane // Dh == r % H, out, 0.0)      # head h, own lanes
-    for w in range(W):
+    for w in range(W * Gq):
         o_ref[0, w:w + 1, :] = out[w * H:(w + 1) * H].sum(
             axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
 def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
-    """One device's heads: q [S,H,W,Dh], pools [L,nb,blk,H*Dh]; ``layer``
-    an int32 scalar. Jitted with the layer an OPERAND, so that a program
+    """One device's heads: q [S,Hq,W,Dh], pools [L,nb,blk,H*Dh] with
+    ``Hq = Gq * H`` (query head ``h * Gq + g`` reads key-value head ``h``;
+    Gq = 1 is plain multi-head attention); ``layer`` an int32 scalar. Jitted with the layer an OPERAND, so that a program
     of 24 layers traces this and lowers the kernel to Mosaic once, not 24
     times (7 s of every process's warm-up at the serving cells' shape)."""
-    S, H, W, Dh = q.shape
-    HD = H * Dh
+    S, Hq, W, Dh = q.shape
+    HD = k_pool.shape[3]
+    H = HD // Dh                          # key-value heads
+    Gq = Hq // H
     blk = k_pool.shape[2]
     mb = tables.shape[1]
     G = max(1, min(mb, _KEYS_PER_STEP // blk))
     T = G * blk
-    R = W * H
+    R = W * Hq
     Rp = -(-R // 16) * 16                 # whole sublane tiles, bf16 too
-    # block-diagonal queries [S, W*H, H*Dh]: row w*H+h holds q[s,h,w] in
-    # lanes h*Dh..(h+1)*Dh
-    qt = q.transpose(0, 2, 1, 3)[:, :, :, None, :]          # [S,W,H,1,Dh]
+    # block-diagonal queries [S, W*Gq*H, H*Dh]: row (w*Gq+g)*H+h holds
+    # q[s, h*Gq+g, w] in lanes h*Dh..(h+1)*Dh
+    qt = q.reshape(S, H, Gq, W, Dh).transpose(0, 3, 2, 1, 4)  # [S,W,Gq,H,Dh]
+    qt = qt.reshape(S, W * Gq, H, 1, Dh)
     own = jnp.eye(H, dtype=bool)[None, None, :, :, None]
     q_bd = jnp.where(own, qt, jnp.zeros((), q.dtype)).reshape(S, R, HD)
     q_bd = jnp.pad(q_bd, ((0, 0), (0, Rp - R), (0, 0)))
@@ -172,7 +177,7 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
         in_specs=[pl.BlockSpec((1, Rp, HD), lambda s, *_: (s, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, W, HD), lambda s, *_: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, W * Gq, HD), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2, T, HD), k_pool.dtype),
                         pltpu.VMEM((2, T, HD), v_pool.dtype),
                         pltpu.SemaphoreType.DMA((2, 2)),
@@ -182,25 +187,29 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
     with jax.named_scope(SCOPE):
         o = pl.pallas_call(
             functools.partial(_body, W, H, Dh, blk, G, mb * blk,
-                              1.0 / float(np.sqrt(Dh))),
+                              1.0 / float(np.sqrt(Dh)), Gq),
             name=KERNEL_NAME,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, W, HD), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((S, W * Gq, HD), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(layer.reshape(1), tables.astype(jnp.int32), lens.astype(jnp.int32),
           q_bd, k_pool, v_pool)
-    return o.reshape(S, W, H, Dh).transpose(0, 2, 1, 3)
+    # row w*Gq+g, lanes of head h -> query head h*Gq+g
+    return o.reshape(S, W, Gq, H, Dh).transpose(0, 3, 2, 1, 4).reshape(
+        S, Hq, W, Dh)
 
 
 def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
     """Softmax attention of a decode window over a paged cache.
 
-    q       [S, H, W, Dh]: W query rows a slot (1 in the decode step,
+    q       [S, Hq, W, Dh]: W query rows a slot (1 in the decode step,
             k + 1 in a speculative verify)
     k_pool, v_pool  [n_layers, num_blocks, block_len, H * Dh]; ``layer``
-            picks the layer inside the kernel's DMAs
+            picks the layer inside the kernel's DMAs. ``Hq`` is ``H`` or a
+            multiple of it: query head i reads key-value head
+            ``i // (Hq // H)`` (grouped-query attention)
     tables  [S, max_blocks] int32: position p of slot s lies in page
             ``tables[s, p // block_len]``
     lens    [S] int32: keys row 0 of the slot sees (``pos + 1``, the
@@ -210,7 +219,8 @@ def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
     Returns [S, H, W, Dh] in q's dtype. Under a mesh tracing context the
     heads split over the model axis with ``shard_map`` (a Mosaic call is
     not partitioned automatically); attention is head-local."""
-    S, H = q.shape[:2]
+    S = q.shape[0]
+    H = k_pool.shape[3] // q.shape[3]
     layer = jnp.asarray(layer, jnp.int32)
     kernel = functools.partial(_one_device, interpret=_interpret())
     split = _device_split(S, H)
@@ -231,11 +241,13 @@ def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens):
     into a dense context and attend under a mask. The parity pin of the
     kernel, and what the decode step did before it."""
     from ..models.decode import window_attention
-    S, H, W, Dh = q.shape
+    S, Hq, W, Dh = q.shape
+    H = k_pool.shape[3] // Dh
     ctx = tables.shape[1] * k_pool.shape[2]
 
     def dense(pool):
-        return pool[layer][tables].reshape(S, ctx, H, Dh).transpose(0, 2, 1, 3)
+        d = pool[layer][tables].reshape(S, ctx, H, Dh).transpose(0, 2, 1, 3)
+        return jnp.repeat(d, Hq // H, axis=1)
 
     limit = lens[:, None] + jnp.arange(W)[None, :]                # [S,W]
     mask = jnp.arange(ctx)[None, None, :] < limit[:, :, None]

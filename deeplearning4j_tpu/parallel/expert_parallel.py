@@ -6,6 +6,15 @@ axis (each device holds n_experts/n_devices expert parameter sets); tokens
 are routed to their top-1 expert with capacity-bounded dispatch and exchanged
 via ``all_to_all`` — the canonical TPU MoE pattern (dispatch/combine
 einsums + ICI all-to-all).
+
+This is NOT the layer a served model uses. The serving layer is
+``nn/layers/gated.py`` ``MixtureOfExpertsLayer`` (sigmoid router with a
+selection bias, top-k, no capacity and no dropped token, told which range
+of experts it ``held``s, its matmuls in ``ops/grouped_matmul.py``): on one
+chip it holds every expert and exchanges nothing. Over a mesh the exchange
+will wrap that layer (tokens to the chip that holds their expert and back,
+each chip computing the share ``held`` names); nothing here stands in for
+it today.
 """
 from __future__ import annotations
 

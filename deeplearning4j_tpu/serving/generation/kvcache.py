@@ -5,7 +5,8 @@ of pool arrays per model —
 
     k_pool / v_pool : [n_layers, num_blocks, block_len, n_heads * head_dim]
 
-(one page of one layer is ``block_len`` rows of all heads side by side:
+(``n_layers`` the model's ATTENTION layers and ``n_heads`` its key-value
+heads: with grouped-query attention fewer than its query heads; one page of one layer is ``block_len`` rows of all heads side by side:
 contiguous, lane-dense, one DMA for the attention kernel) — and a
 sequence's cache is the set of pool blocks its (host-side) block table
 points at. "Growing" a sequence's context is block *allocation*, a
@@ -238,6 +239,13 @@ def _pool_gather(pool: QuantizedPool, i, tables, dtype):
     return kv_dequantize(ctx, sc, dtype).transpose(0, 2, 1, 3)
 
 
+def repeat_heads(x, group: int):
+    """[S, Hkv, ...] -> [S, Hkv * group, ...]: each key-value head once for
+    every query head that reads it (grouped-query attention on the XLA
+    paths; the paged kernel needs no copy)."""
+    return x if group == 1 else jnp.repeat(x, group, axis=1)
+
+
 class QuantSimStore:
     """Full-prompt window store for the int8-KV PREFILL: records each
     layer's raw K/V (for the quantize-on-write scatter afterwards) and
@@ -272,7 +280,9 @@ class QuantSimStore:
         mask = (jnp.arange(W)[None, None, :]
                 <= jnp.arange(W)[None, :, None])
         mask = jnp.broadcast_to(mask, (B, W, W))
-        return window_attention(q, fakeq(k_win), fakeq(v_win), mask)
+        group = q.shape[1] // k_win.shape[2]
+        return window_attention(q, repeat_heads(fakeq(k_win), group),
+                                repeat_heads(fakeq(v_win), group), mask)
 
 
 class PagedWindowStore:
@@ -294,9 +304,14 @@ class PagedWindowStore:
     nothing and get zeros."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
-                 window: int):
+                 window: int, rec=None):
         self.k_pool = k_pool
         self.v_pool = v_pool
+        # per-slot state of the model's recurrent mixers, [layers,
+        # slots + 1, ...] (row ``slots`` is the prefill's trash row), or
+        # None for a model that keeps K/V alone
+        self.rec = rec
+        self._active = active
         self.tables = tables              # [S, max_blocks] int32
         mb = tables.shape[1]
         ctx_len = mb * block_len
@@ -322,23 +337,45 @@ class PagedWindowStore:
         self.k_pool = _pool_write(self.k_pool, i, self._bid, self._off, k_win)
         self.v_pool = _pool_write(self.v_pool, i, self._bid, self._off, v_win)
         if isinstance(self.k_pool, QuantizedPool):
+            group = q.shape[1] // k_win.shape[2]
             K = _pool_gather(self.k_pool, i, self.tables, k_win.dtype)
             V = _pool_gather(self.v_pool, i, self.tables, v_win.dtype)
-            return window_attention(q, K, V, self._mask)
+            return window_attention(q, repeat_heads(K, group),
+                                    repeat_heads(V, group), self._mask)
         return paged_attention_decode(q, self.k_pool, self.v_pool, i,
                                       self.tables, self._lens)
+
+    def state(self, j: int):
+        """Recurrent mixer ``j``'s state for the step's slots [S, ...]."""
+        return self.rec[j, :self.tables.shape[0]]
+
+    def set_state(self, j: int, new) -> None:
+        """Leave mixer ``j``'s state after the step; idle slots keep
+        theirs."""
+        S = self.tables.shape[0]
+        keep = self._active.reshape((S,) + (1,) * (new.ndim - 1))
+        self.rec = self.rec.at[j, :S].set(
+            jnp.where(keep, new.astype(self.rec.dtype), self.rec[j, :S]))
 
     @property
     def pools(self):
         return self.k_pool, self.v_pool
+
+    @property
+    def cache(self):
+        """The cache pytree a program hands back: the pools, and the
+        recurrent state behind them where the model has one."""
+        return self.pools + (() if self.rec is None else (self.rec,))
 
 
 class PagedStore(PagedWindowStore):
     """``models.decode.KVStore`` over the paged pools for ONE decode step:
     a window of one token a slot."""
 
-    def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int):
-        super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1)
+    def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
+                 rec=None):
+        super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1,
+                         rec)
 
     def attend(self, i: int, q, k_tok, v_tok):
         """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh]."""

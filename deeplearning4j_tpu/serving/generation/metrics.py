@@ -57,6 +57,7 @@ class GenerationMetrics:
         self.prefix_tokens_saved = 0
         self.cow_copies = 0
         self.prefix_evictions = 0
+        self.prefix_skipped_stateful = 0
         self._prefix_gauges: dict = {}
         # speculative decoding
         self._verify_ms = deque(maxlen=window)
@@ -188,6 +189,16 @@ class GenerationMetrics:
         reg = self.registry
         if reg.enabled:
             reg.counter(f"generation.{self.name}.prefix.cow_copies").inc()
+
+    def record_prefix_skipped_stateful(self, n: int = 1) -> None:
+        """Admissions that went past the prefix cache because the model's
+        sequences carry a recurrent state the cached pages do not hold."""
+        with self._lock:
+            self.prefix_skipped_stateful += n
+        reg = self.registry
+        if reg.enabled:
+            reg.counter(
+                f"generation.{self.name}.prefix_skipped_stateful").inc(n)
 
     def record_prefix_evictions(self, n: int) -> None:
         with self._lock:
@@ -375,6 +386,7 @@ class GenerationMetrics:
                     "tokens_saved": self.prefix_tokens_saved,
                     "cow_copies": self.cow_copies,
                     "evictions": self.prefix_evictions,
+                    "skipped_stateful": self.prefix_skipped_stateful,
                     "shared_blocks": self._prefix_gauges.get(
                         "shared_blocks", 0),
                     "cached_lru_blocks": self._prefix_gauges.get(

@@ -17,10 +17,16 @@ Params/state are arguments, not constants, so hot-swap reuses executables
 exactly as the forward-serving ProgramSet does (``with_params_from``).
 The PRNG key is carried through every program and split in-program.
 
-Model support is adapter-based: ``models.decode.TransformerDecodeSpec``
-(paged KV cache) and ``models.decode.LSTMDecodeSpec`` (the cache is the
-fixed-shape recurrent state; the block machinery degenerates to zero-block
-bookkeeping but the program/scheduler contract is identical).
+Model support is adapter-based: ``models.decode.GraphDecodeSpec`` (paged
+KV cache for a graph's attention layers and, beside the pools in the same
+cache pytree, a fixed-shape per-slot state for its recurrent mixers) and
+``models.decode.LSTMDecodeSpec`` (the cache is the fixed-shape recurrent
+state; the block machinery degenerates to zero-block bookkeeping but the
+program/scheduler contract is identical).
+
+A model with expert layers hands its routing counters back BEHIND the
+sampled tokens in the one array a program's caller reads back
+(``split_stats``): no dispatch, no sync and no program more.
 """
 from __future__ import annotations
 
@@ -35,7 +41,8 @@ import numpy as np
 from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ...models.decode import LSTMDecodeSpec, TransformerDecodeSpec
+from ...models.decode import (GraphDecodeSpec, LSTMDecodeSpec,
+                              StatefulDecodeUnsupportedError)
 from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
                                          model_axis_size, per_replica_bytes,
                                          shard_params)
@@ -195,8 +202,15 @@ class GenerationProgramSet:
         self.cost_path = cost_path    # e.g. "generation.<model>": enables
         # cost-index registration of the warmed executables (perf.py)
         self.adapter = self._resolve_adapter(net, adapter)
-        self.spec = (TransformerDecodeSpec(net) if self.adapter == "paged"
+        self.spec = (GraphDecodeSpec(net) if self.adapter == "paged"
                      else LSTMDecodeSpec(net))
+        # a model whose sequences carry more than K/V pages (a recurrent
+        # mixer's per-slot state): what does not carry that state refuses
+        # the model by name below, and the prefix cache is skipped
+        self.stateful = self.adapter == "paged" and self.spec.stateful
+        # int32 counters behind the tokens of a program's first result
+        self.stats_len = 2 if self.adapter == "paged" and self.spec.n_moe \
+            else 0
         # sharded decode (ISSUE 20): a ``(data, model)`` mesh with m > 1
         # shards the Q/K/V/O projections and the paged KV pools by HEAD
         # across the model axis — one decode step spans chips, the
@@ -226,11 +240,18 @@ class GenerationProgramSet:
                 lambda a: jax.device_put(a, rep), self.state)
         self.dtype = self.spec.dtype
         self.vocab = self.spec.vocab
-        # prefix-cache sharing only exists where there are blocks to share
-        self.prefix_enabled = (self.adapter == "paged"
-                               if config.prefix_cache is None
-                               else bool(config.prefix_cache)
-                               and self.adapter == "paged")
+        # prefix-cache sharing only exists where there are blocks to share,
+        # and where the blocks are ALL a sequence carries: a hit would
+        # resume a stateful model's suffix from pages alone, with a
+        # recurrent state nobody kept (counted, never served:
+        # ``prefix_skipped_stateful``)
+        self.prefix_enabled = ((self.adapter == "paged"
+                                if config.prefix_cache is None
+                                else bool(config.prefix_cache)
+                                and self.adapter == "paged")
+                               and not self.stateful)
+        self.prefix_skipped_stateful = (self.stateful
+                                        and config.prefix_cache is not False)
         # int8-quantized KV tier: paged pools only (the state adapter's
         # carry is recurrent state, not a token cache)
         self.kv_quantized = config.kv_cache_dtype == "int8"
@@ -238,12 +259,24 @@ class GenerationProgramSet:
             raise ValueError("kv_cache_dtype='int8' requires the paged "
                              "(transformer) adapter — the state adapter "
                              "has no KV block pool to quantize")
+        if self.kv_quantized and self.stateful:
+            raise StatefulDecodeUnsupportedError(
+                "kv_cache_dtype='int8' is refused for a model with "
+                f"recurrent mixers ({self.spec.recurrent_names}): its "
+                "prefill runs as a decode window, which does not carry "
+                "their state")
         # speculative decoding: active iff a draft model is attached
         self.draft_net = draft_net
         self.spec_k = 0
         self.draft_adapter: Optional[str] = None
         self.draft_spec = None
         if draft_net is not None:
+            if self.stateful:
+                raise StatefulDecodeUnsupportedError(
+                    "speculative decoding is refused for a model with "
+                    f"recurrent mixers ({self.spec.recurrent_names}): the "
+                    "verify window does not carry their state, and a "
+                    "rejected proposal could not be taken back out of it")
             if self.adapter != "paged":
                 raise ValueError(
                     "speculative decoding requires a paged (transformer) "
@@ -251,8 +284,12 @@ class GenerationProgramSet:
             self.spec_k = int(config.spec_k) or 4
             da = self._resolve_adapter(draft_net, "auto")
             self.draft_adapter = "dense" if da == "paged" else "state"
-            self.draft_spec = (TransformerDecodeSpec(draft_net)
+            self.draft_spec = (GraphDecodeSpec(draft_net)
                                if da == "paged" else LSTMDecodeSpec(draft_net))
+            if da == "paged" and self.draft_spec.stateful:
+                raise StatefulDecodeUnsupportedError(
+                    "a draft with recurrent mixers is refused: the dense "
+                    "draft cache keeps K/V alone")
             if self.draft_spec.vocab != self.vocab:
                 raise ValueError(
                     f"draft vocab {self.draft_spec.vocab} != target vocab "
@@ -310,8 +347,12 @@ class GenerationProgramSet:
             return "state"
         if adapter != "auto":
             raise ValueError(f"unknown adapter {adapter!r}")
-        # ComputationGraph transformer vs MultiLayerNetwork recurrent stack
-        if hasattr(net, "vertex_names") and "b0_attn" in net.vertex_names:
+        # a ComputationGraph with attention layers vs a MultiLayerNetwork
+        # recurrent stack: by the kind of the layers, not by their names
+        from ...nn.layers import SelfAttentionLayer
+        if hasattr(net, "vertex_names") and any(
+                isinstance(getattr(v, "layer_conf", None), SelfAttentionLayer)
+                for v in net.vertices):
             return "paged"
         return "state"
 
@@ -330,18 +371,27 @@ class GenerationProgramSet:
                              PartitionSpec(None, None, None, MODEL_AXIS))
 
     def make_cache(self):
-        """Fresh cache pytree: (k_pool, v_pool) for the paged adapter, the
-        zeroed recurrent-state carry (decode_slots + 1 rows, last row is
-        the prefill-padding trash slot) for the state adapter."""
+        """Fresh cache pytree: (k_pool, v_pool) for the paged adapter —
+        pools for the model's attention layers and key-value heads — with
+        the recurrent mixers' per-slot state behind them where the model
+        has any; the zeroed recurrent-state carry (decode_slots + 1 rows,
+        last row is the prefill-padding trash slot) for the state
+        adapter."""
         c = self.config
         if self.adapter == "paged":
             cache = make_pools(self.spec.n_blocks, c.num_blocks,
-                               c.block_len, self.spec.n_heads,
+                               c.block_len, self.spec.kv_heads,
                                self.spec.head_dim, self.dtype,
                                quantized=self.kv_quantized)
             sh = self._pool_sharding()
             if sh is not None:
                 cache = jax.tree.map(lambda a: jax.device_put(a, sh), cache)
+            if self.stateful:
+                # [recurrent layers, slots + 1, ...]: donated and updated
+                # by the same programs as the pools
+                cache = cache + (jnp.zeros(
+                    self.spec.recurrent_state_shape(c.decode_slots + 1),
+                    self.dtype),)
         else:
             cache = jax.tree.map(jnp.zeros_like, self._init_states)
         try:     # memprof owner hint: the block pool dominates live HBM
@@ -367,7 +417,24 @@ class GenerationProgramSet:
             per_head = s.head_dim * 1 + 4          # int8 codes + f32 scale
         else:
             per_head = s.head_dim * jnp.dtype(self.dtype).itemsize
-        return float(2 * s.n_blocks * s.n_heads * per_head)
+        return float(2 * s.n_blocks * s.kv_heads * per_head)
+
+    def recurrent_state_bytes(self) -> int:
+        """Device bytes of the recurrent mixers' per-slot state (every
+        slot, the trash row included); 0 for a model that keeps K/V
+        alone."""
+        if not self.stateful:
+            return 0
+        return int(math.prod(self.spec.recurrent_state_shape(
+            self.config.decode_slots + 1))) * jnp.dtype(self.dtype).itemsize
+
+    def split_stats(self, first):
+        """A program's first result as read back -> (tokens, counters):
+        the counters are the int32s a model with expert layers appends
+        (fullest expert's pairs, experts touched), else None."""
+        if not self.stats_len:
+            return first, None
+        return first[:-self.stats_len], first[-self.stats_len:]
 
     def make_draft_cache(self):
         """Fresh draft cache: dense per-slot K/V for a transformer draft,
@@ -404,10 +471,11 @@ class GenerationProgramSet:
             if self._trace_hook is not None:
                 self._trace_hook()
             if self.adapter == "paged":
-                k_pool, v_pool = cache
+                k_pool, v_pool = cache[:2]
                 # the head runs on the one row of each prompt that is
                 # sampled from, selected before it: [P, d], not [P, L, d]
                 rows = lengths - 1
+                states = []
                 if self.kv_quantized:
                     # int8 tier: compute the prefill logits through FAKE-
                     # QUANTIZED attention (QuantSimStore) so the first
@@ -416,18 +484,33 @@ class GenerationProgramSet:
                     # hit path replays the unmatched suffix through the
                     # decode program, and both must see identical K/V
                     store = QuantSimStore(spec.n_blocks)
-                    hidden = spec.window_hidden(
+                    live = (jnp.arange(tokens.shape[1])[None, :]
+                            < lengths[:, None]) if self.stats_len else None
+                    hidden, stats = spec.window_hidden_stats(
                         params, state, tokens,
-                        jnp.zeros((tokens.shape[0],), jnp.int32), store)
+                        jnp.zeros((tokens.shape[0],), jnp.int32), store, live)
                     last = spec.logits_at(params, hidden, rows)
                     ks, vs = store.ks, store.vs
                 else:
-                    last, ks, vs = spec.prefill_forward(params, state,
-                                                        tokens, rows)
-                k_pool = prefill_scatter(k_pool, ks, tables)
-                v_pool = prefill_scatter(v_pool, vs, tables)
+                    # with ``lengths`` the recurrent mixers' states come
+                    # back at each prompt's TRUE length and the expert
+                    # layers' counters over the live rows; a model that
+                    # has neither asks for neither
+                    last, ks, vs, states, stats = spec.prefill_full(
+                        params, state, tokens, rows,
+                        lengths if self.stateful or self.stats_len else None)
+                out = (prefill_scatter(k_pool, ks, tables),
+                       prefill_scatter(v_pool, vs, tables))
+                if self.stateful:
+                    # beside the pools, in the slots' rows (padding rows
+                    # carry slot S: the trash row)
+                    out += (cache[2].at[:, slots].set(
+                        jnp.stack(states).astype(cache[2].dtype)),)
                 tok, key = sample_tokens(last, key, temp, topk)
-                return tok, (k_pool, v_pool), key
+                if stats is not None:
+                    # the counters ride back behind the tokens
+                    tok = jnp.concatenate([tok, stats])
+                return tok, out, key
             P = tokens.shape[0]
             zero = jax.tree.map(
                 lambda c: jnp.zeros((P,) + c.shape[1:], c.dtype), cache)
@@ -448,10 +531,14 @@ class GenerationProgramSet:
                 self._trace_hook()
             if self.adapter == "paged":
                 store = PagedStore(cache[0], cache[1], tables, pos, active,
-                                   blk)
-                logits = spec.decode_step(params, state, tokens, pos, store)
+                                   blk, cache[2] if self.stateful else None)
+                logits, stats = spec.decode_step_stats(
+                    params, state, tokens, pos, store,
+                    active if self.stats_len else None)
                 tok, key = sample_tokens(logits, key, temp, topk)
-                return tok, store.pools, key
+                if stats is not None:
+                    tok = jnp.concatenate([tok, stats])
+                return tok, store.cache, key
             S = tokens.shape[0]
             cur = jax.tree.map(lambda c: c[:S], cache)
             logits, new = spec.decode_step(params, state, tokens, cur)
@@ -492,7 +579,7 @@ class GenerationProgramSet:
         def fn(cache, src, dst):
             if self._trace_hook is not None:
                 self._trace_hook()
-            return cow_copy(cache[0], cache[1], src, dst)
+            return cow_copy(cache[0], cache[1], src, dst) + tuple(cache[2:])
         return fn
 
     def _spec_fns(self):
@@ -721,7 +808,9 @@ class GenerationProgramSet:
     # ---------------------------------------------------------------- running
     def run_prefill(self, cache, tokens, lengths, tables, slots, key, temp,
                     topk):
-        """Returns (first_tokens np [P], cache', key')."""
+        """Returns (first_tokens np [P], cache', key'); a model with
+        expert layers appends its counters to the tokens
+        (``split_stats``)."""
         P, L = tokens.shape
         exe = self._compiled.get(("prefill", P, L))
         if exe is None:
@@ -736,7 +825,8 @@ class GenerationProgramSet:
 
     def run_decode(self, cache, tokens, pos, tables, active, key, temp,
                    topk):
-        """Returns (next_tokens np [S], cache', key')."""
+        """Returns (next_tokens np [S], cache', key'); a model with expert
+        layers appends its counters to the tokens (``split_stats``)."""
         exe = self._compiled.get(("decode",))
         if exe is None:
             from ..errors import ServingError

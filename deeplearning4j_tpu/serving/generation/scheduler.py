@@ -517,13 +517,27 @@ class ModelRuntime:
             temp[i] = r.temperature
             topk[i] = r.top_k
         self._phase("admit_batch", t_phase)
+        live_tokens = int(lengths[:len(cands)].sum())
         with span("generation.prefill", model=self.name, batch=len(cands),
-                  rung=L, rows=P, tokens=int(lengths[:len(cands)].sum()),
+                  rung=L, rows=P, tokens=live_tokens,
                   padded_tokens=P * L,
-                  head_rows=coh.ps.head_rows.get((P, L))):
+                  head_rows=coh.ps.head_rows.get((P, L))) as sp:
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
                 temp, topk)
+            first, stats = coh.ps.split_stats(first)
+            if stats is not None:
+                # the expert layers' routing, read back with the tokens:
+                # pairs from live and from padding positions (host
+                # integers), the fullest expert's pairs and the experts
+                # touched, over the model's expert layers
+                pairs = coh.ps.spec.n_moe * coh.ps.spec.moe_top_k
+                sp.set_attr("moe_pairs", live_tokens * pairs)
+                sp.set_attr("moe_pairs_padded", (P * L - live_tokens) * pairs)
+                sp.set_attr("moe_load_max", int(stats[0]))
+                sp.set_attr("experts_touched", int(stats[1]))
+        if coh.ps.prefix_skipped_stateful:
+            self.metrics.record_prefix_skipped_stateful(len(cands))
         t_phase = time.perf_counter()
         now = time.monotonic()
         emitted = 0
@@ -675,6 +689,11 @@ class ModelRuntime:
             nxt, coh.cache, self._key = coh.ps.run_decode(
                 coh.cache, self._tokens, self._pos, coh.tables, mask,
                 self._key, self._temp, self._topk)
+            nxt, stats = coh.ps.split_stats(nxt)
+            if stats is not None:
+                sp.set_attr("moe_pairs", len(live) * coh.ps.spec.n_moe
+                            * coh.ps.spec.moe_top_k)
+                sp.set_attr("experts_touched", int(stats[1]))
         t_phase = time.perf_counter()
         dt_ms = sp.dur_ms
         now = time.monotonic()

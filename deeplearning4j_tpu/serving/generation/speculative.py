@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...parallel.ring_attention import attention
+from .kvcache import repeat_heads
 
 
 # ------------------------------------------------------------- dense store
@@ -63,7 +64,9 @@ class DenseDraftStore:
         S = k_tok.shape[0]
         K = self.k_cache[i, :S].transpose(0, 2, 1, 3)   # [S,H,cap,Dh]
         V = self.v_cache[i, :S].transpose(0, 2, 1, 3)
-        return attention(q, K, V, causal=False, key_mask=self._mask)
+        group = q.shape[1] // K.shape[1]    # grouped-query drafts
+        return attention(q, repeat_heads(K, group), repeat_heads(V, group),
+                         causal=False, key_mask=self._mask)
 
     @property
     def caches(self):
@@ -73,7 +76,7 @@ class DenseDraftStore:
 def make_dense_draft_cache(draft_spec, slots: int, capacity: int):
     """Zero-filled (k_cache, v_cache) for the dense draft adapter."""
     shape = (draft_spec.n_blocks, slots + 1, capacity,
-             draft_spec.n_heads, draft_spec.head_dim)
+             draft_spec.kv_heads, draft_spec.head_dim)
     return (jnp.zeros(shape, draft_spec.dtype),
             jnp.zeros(shape, draft_spec.dtype))
 

@@ -27,7 +27,7 @@ from deeplearning4j_tpu.ops import kernels
 from deeplearning4j_tpu.ops.kernels import autotune, envutil
 
 BUILTINS = ("attention", "lstm", "threshold_encode", "int8_matmul",
-            "conv1x1_bias_relu", "paged_attention")
+            "conv1x1_bias_relu", "paged_attention", "moe_experts")
 
 
 # ----------------------------------------------------------------- registry

@@ -16,8 +16,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu.ops import (pallas_attention, pallas_compression,
-                                    pallas_lstm, pallas_paged_attention)
+from deeplearning4j_tpu.ops import (grouped_matmul, pallas_attention,
+                                    pallas_compression, pallas_lstm,
+                                    pallas_paged_attention)
 from deeplearning4j_tpu.ops import kernels
 from deeplearning4j_tpu.ops.kernels import conv, quantized
 
@@ -46,7 +47,7 @@ def _no_interpreter(monkeypatch):
     # the modules pick the interpreter from the (CPU) default backend;
     # the lowering here targets the TPU, so take the Mosaic path
     for mod in (pallas_attention, pallas_lstm, pallas_compression,
-                pallas_paged_attention, quantized, conv):
+                pallas_paged_attention, quantized, conv, grouped_matmul):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -75,7 +76,7 @@ def _instruction_names(text: str) -> set:
 def test_every_registered_kernel_is_compiled_here():
     assert set(kernels.names()) == {"attention", "lstm", "threshold_encode",
                                     "int8_matmul", "conv1x1_bias_relu",
-                                    "paged_attention"}, \
+                                    "paged_attention", "moe_experts"}, \
         "a kernel was registered without a TPU compile check in this file"
 
 
@@ -143,11 +144,13 @@ def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):
         _custom_calls(on_mesh, grad, qkv, qkv, qkv)
 
 
-def _paged_avals(S, H, W, Dh, blk, mb, L, dtype, sharding=None):
+def _paged_avals(S, H, W, Dh, blk, mb, L, dtype, sharding=None,
+                 q_heads=None):
     """(q, k_pool, v_pool, tables, lens) of a paged decode attention call:
-    the pool holds every slot's full table plus the trash block."""
+    the pool holds every slot's full table plus the trash block (``H``
+    key-value heads; ``q_heads`` query heads where they are more)."""
     pool = ((L, S * mb + 1, blk, H * Dh), dtype)
-    return (((S, H, W, Dh), dtype), pool, pool,
+    return (((S, q_heads or H, W, Dh), dtype), pool, pool,
             ((S, mb), jnp.int32), ((S,), jnp.int32))
 
 
@@ -156,15 +159,36 @@ def _paged_avals(S, H, W, Dh, blk, mb, L, dtype, sharding=None):
     (5, 2, 3, 64, 16, 8, 2, f32),            # and its verify window
     (16, 16, 1, 64, 16, 64, 24, bf16),       # the benchmark's serving cells
     (16, 16, 5, 64, 16, 64, 24, bf16),       # a verify window of k = 4 there
-    (4, 8, 1, 64, 16, 64, 12, bf16)])        # chip_smoke's LM
+    (4, 8, 1, 64, 16, 64, 12, bf16),         # chip_smoke's LM
+    (32, 8, 1, 64, 16, 132, 2, bf16)])       # lfm2moe-serve-extract (q: 32)
 def test_paged_attention_decode_compiles(v5e, S, H, W, Dh, blk, mb, L, dtype):
     text = _compiled_text(
         v5e, lambda q, k, v, t, n: pallas_paged_attention.
         paged_attention_decode(q, k, v, L - 1, t, n),
-        *_paged_avals(S, H, W, Dh, blk, mb, L, dtype))
+        *_paged_avals(S, H, W, Dh, blk, mb, L, dtype,
+                      q_heads=32 if L == 2 and S == 32 else None))
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert pallas_paged_attention.KERNEL_NAME in _instruction_names(text)
     assert not pallas_paged_attention.KERNEL_NAME[-1].isdigit()
+
+
+@pytest.mark.parametrize("N,d,F,E,k,dtype", [
+    (40, 128, 256, 8, 2, f32),               # the parity pin
+    (32, 2048, 1536, 64, 4, bf16),           # lfm2moe-serve-extract: decode,
+    (512, 2048, 1536, 64, 4, bf16),          # its smallest prefill
+    (8192, 2048, 1536, 64, 4, bf16)])        # and its largest (4 x 2048)
+def test_moe_experts_compile(v5e, monkeypatch, N, d, F, E, k, dtype):
+    """The grouped gated matmul's two kernels lower for the chip under
+    stable names (the benchmark's roofline metric reads them by name)."""
+    monkeypatch.setattr(grouped_matmul, "kernels_applicable",
+                        lambda *a: True)
+    text = _compiled_text(
+        v5e, grouped_matmul.expert_ffn, ((N, d), dtype), ((N, k), jnp.int32),
+        ((N, k), f32), ((E, d, F), dtype), ((E, d, F), dtype),
+        ((E, F, d), dtype))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert {grouped_matmul.KERNEL_GATE_UP, grouped_matmul.KERNEL_DOWN} <= \
+        _instruction_names(text)
 
 
 def test_paged_attention_decode_splits_heads_on_a_mesh(v5e_devices):

@@ -25,7 +25,10 @@ Pillars:
                     decode-step, cow, draft-prefill/propose/rewind and
                     verify executables, buffer-donated cache, jit-carried
                     PRNG
-  - sampling.py     greedy / temperature / top-k, in-program
+  - sampling.py     greedy / temperature / top-k, in-program: argmax
+                    alone for a batch of greedy rows; the draw, and a
+                    top-k threshold selected exactly without a sort,
+                    only when a row has a temperature
   - scheduler.py    continuous batching: step-boundary admission (prefix
                     matched, suffix replayed), slot backfill, verify-step
                     interleave, TokenStream per request, cohort-pinned

@@ -521,7 +521,8 @@ class ModelRuntime:
         with span("generation.prefill", model=self.name, batch=len(cands),
                   rung=L, rows=P, tokens=live_tokens,
                   padded_tokens=P * L,
-                  head_rows=coh.ps.head_rows.get((P, L))) as sp:
+                  head_rows=coh.ps.head_rows.get((P, L)),
+                  sampled=int(np.count_nonzero(temp > 0.0))) as sp:
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
                 temp, topk)
@@ -684,11 +685,16 @@ class ModelRuntime:
                      "gathered_tokens": S * cfg.capacity
                      if coh.ps.kv_quantized
                      else int((-(-seen // blk) * blk).sum())}
+        # a slot keeps its last request's temperature after it finishes,
+        # and another cohort's slots are not this step's: only the live
+        # rows may decide whether the program's sampler draws
+        temp = np.where(mask, self._temp, np.float32(0.0))
         with span("generation.decode_step", model=self.name,
-                  slots=len(live), **attrs) as sp:
+                  slots=len(live), sampled=int(np.count_nonzero(temp > 0.0)),
+                  **attrs) as sp:
             nxt, coh.cache, self._key = coh.ps.run_decode(
                 coh.cache, self._tokens, self._pos, coh.tables, mask,
-                self._key, self._temp, self._topk)
+                self._key, temp, self._topk)
             nxt, stats = coh.ps.split_stats(nxt)
             if stats is not None:
                 sp.set_attr("moe_pairs", len(live) * coh.ps.spec.n_moe

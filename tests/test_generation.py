@@ -351,6 +351,29 @@ def test_continuous_and_one_at_a_time_emit_same_greedy_tokens(shared_lm):
         serial.stop()
 
 
+def test_greedy_tokens_do_not_depend_on_a_sampling_neighbour(shared_lm):
+    """The sampler draws only when a row of its batch has a temperature,
+    and then for that row: a greedy request emits the same tokens alone,
+    beside greedy neighbours and beside a neighbour that samples (whose
+    batch takes the sampler's other branch at prefill and every step)."""
+    net, spec, eng = shared_lm
+    rt = eng._get("lm")
+    prompts = _prompts(53, (7, 12, 5), seed=39)
+    want = [naive_generate(net, p, 9, pad_to=64, spec=spec) for p in prompts]
+    for temps in ((0.0, 0.0, 0.0), (0.0, 1.0, 0.0)):
+        with rt._cond:                    # one admission pass, one batch
+            streams = [eng.generate(p, max_tokens=9, stream=True,
+                                    temperature=t)
+                       for p, t in zip(prompts, temps)]
+        outs = [s.result()[0] for s in streams]
+        for i, t in enumerate(temps):
+            if t == 0.0:
+                assert outs[i] == want[i], (temps, i)
+            else:
+                assert len(outs[i]) == 9
+                assert all(0 <= tok < 53 for tok in outs[i])
+
+
 # ------------------------------------------------------------- sampling
 def test_sampling_modes_and_stop_tokens(shared_lm):
     net, spec, eng = shared_lm
@@ -667,28 +690,46 @@ def test_generation_hammer_soak():
 # ------------------------------------- what the loop says about itself
 # (ISSUE 24: the program measures each of its layers where the work
 # happens, under the names the benchmark's readers key on)
-@pytest.fixture(scope="module")
-def pair_events(shared_lm):
+def _pair_of_requests(eng, temperatures=(0.0, 0.0), seed=2424):
     """The trace events of one hand-made pair of requests, prompts of 5
     and 11 tokens and 3 tokens out each, sent WITHOUT a trace context and
     admitted in one pass (the loop cannot take its lock between the two
     submissions): one prefill at rows 2 x rung 64, then two decode steps.
-    The engine has sat idle first, so the pass ends an idle period."""
-    _, _, eng = shared_lm
+    Another ``seed`` gives other prompts, which miss the prefix cache."""
     rt = eng._get("lm")
-    rng = np.random.default_rng(2424)
+    rng = np.random.default_rng(seed)
     a, b = (rng.integers(1, 53, size=n).tolist() for n in (5, 11))
-    time.sleep(0.1)                       # five idle wake-ups of the loop
     reg = get_registry()
     seq0 = reg.last_seq
     with rt._cond:
-        streams = [eng.generate(p, max_tokens=3, stream=True) for p in (a, b)]
+        streams = [eng.generate(p, max_tokens=3, stream=True, temperature=t)
+                   for p, t in zip((a, b), temperatures)]
     assert [len(s.result()[0]) for s in streams] == [3, 3]
     while rt.in_flight:                   # the emit after the last step
         time.sleep(0.005)
     time.sleep(0.05)
     events = reg.trace_events_since(seq0)
     return [e for e in events if e["name"].startswith("generation.")]
+
+
+@pytest.fixture(scope="module")
+def pair_events(shared_lm):
+    """A greedy pair. The engine has sat idle first, so the pass ends an
+    idle period."""
+    time.sleep(0.1)                       # five idle wake-ups of the loop
+    return _pair_of_requests(shared_lm[2])
+
+
+@pytest.fixture(scope="module")
+def sampling_pair_events(shared_lm):
+    """The same pair, the second request at a temperature of 1."""
+    return _pair_of_requests(shared_lm[2], (0.0, 1.0), seed=3939)
+
+
+@pytest.fixture(scope="module")
+def greedy_pair_after_sampling_events(shared_lm, sampling_pair_events):
+    """A greedy pair in slots of which one has just sampled."""
+    return _pair_of_requests(shared_lm[2], seed=3940)
 
 
 def _named(events, name, **match):
@@ -752,15 +793,19 @@ def test_admit_event_for_a_request_without_trace_context(pair_events):
     assert not _named(pair_events, "generation.decode_step", ph="i")
 
 
-def test_step_and_prefill_spans_count_their_tokens(pair_events):
-    (fill,) = _named(pair_events, "generation.prefill", ph="X")
+@pytest.mark.parametrize("which,sampled", [
+    ("pair_events", 0), ("sampling_pair_events", 1),
+    ("greedy_pair_after_sampling_events", 0)])
+def test_step_and_prefill_spans_count_their_tokens(request, which, sampled):
+    events = request.getfixturevalue(which)
+    (fill,) = _named(events, "generation.prefill", ph="X")
     a = fill["args"]
     assert (a["rows"], a["tokens"], a["padded_tokens"]) == (2, 16, 2 * 64)
     # the head ran on one row a prompt, not on the 2 x 64 padded positions
     # (counted from the prefill program's own matmuls, programs._head_rows)
     assert a["head_rows"] == 2
     assert (a["batch"], a["rung"]) == (2, 64)         # as before
-    steps = _named(pair_events, "generation.decode_step", ph="X")
+    steps = _named(events, "generation.decode_step", ph="X")
     # positions valid in the cache, this step's included: (5+1)+(11+1),
     # then one more each; the attention kernel reads the whole pages of 8
     # that hold them, 8 + 16 a layer (the gather read 4 slots x capacity
@@ -768,6 +813,11 @@ def test_step_and_prefill_spans_count_their_tokens(pair_events):
     assert [s["args"]["live_tokens"] for s in steps] == [18, 20]
     assert [s["args"]["gathered_tokens"] for s in steps] == [24, 24]
     assert all(s["args"]["slots"] == 2 for s in steps)
+    # live rows with a temperature: a call with none took the sampler's
+    # greedy branch. A slot that sampled last time and is greedy or empty
+    # now does not count (and is not handed to the program as sampling)
+    assert a["sampled"] == sampled
+    assert [s["args"]["sampled"] for s in steps] == [sampled, sampled]
 
 
 def test_metrics_show_queue_wait_and_host_phases(shared_lm, pair_events):
@@ -882,3 +932,66 @@ def test_head_rows_is_read_off_the_program_not_assumed():
     ps = GenerationProgramSet(_steady_char_lstm(), config=cfg).warm()
     assert ps.head_rows == {(1, 16): 16, (2, 16): 32,
                             (1, 64): 64, (2, 64): 128}
+
+
+# ------------- the sampler does the work its batch asks for (ISSUE 39)
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+    import jax
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("program", [("decode",)] + [
+    ("prefill", P, L) for P in _HEAD_BATCHES for L in _HEAD_RUNGS],
+    ids=lambda k: "-".join(str(p) for p in k))
+def test_serving_programs_sort_nothing_and_branch_on_sampling(head_rows_set,
+                                                              program):
+    """The executables the engine runs: no ``sort`` (the top-k threshold
+    is selected, not sorted for) and a ``conditional`` (a prefill has the
+    sampler's alone; off the TPU the decode program's attention kernel
+    runs in the Pallas interpreter, which brings its own)."""
+    _, ps = head_rows_set
+    text = ps._compiled[program].as_text()
+    assert not re.findall(r"\bsort\(", text)
+    found = len(re.findall(r"\bconditional\(", text))
+    assert found == 1 or (program[0] == "decode" and found > 1)
+
+
+@pytest.mark.parametrize("which", ["prefill", "decode"])
+def test_greedy_branch_draws_no_random_bits(head_rows_set, which):
+    """In the program as traced: the key's split sits outside the
+    ``cond``; of its two branches one draws (random bits, the selection's
+    loop) and the other holds no equation that makes or uses randomness."""
+    import jax
+    _, ps = head_rows_set
+    P, L = _HEAD_BATCHES[0], _HEAD_RUNGS[0]
+    cfg = ps.config
+    S, mb = cfg.decode_slots, cfg.blocks_per_seq
+    key = ps.fresh_key()
+    if which == "prefill":
+        args = (np.zeros((P, L), np.int32), np.ones(P, np.int32),
+                np.zeros((P, mb), np.int32), np.zeros(P, np.int32), key,
+                np.zeros(P, np.float32), np.zeros(P, np.int32))
+        fn = ps._prefill_fn()
+    else:
+        args = (np.zeros(S, np.int32), np.zeros(S, np.int32),
+                np.zeros((S, mb), np.int32), np.ones(S, np.bool_), key,
+                np.zeros(S, np.float32), np.zeros(S, np.int32))
+        fn = ps._decode_fn()
+    jaxpr = jax.make_jaxpr(fn)(ps.params, ps.state, ps.make_cache(),
+                               *args).jaxpr
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    greedy, draw = sorted((_primitives(b.jaxpr)
+                           for b in conds[0].params["branches"]), key=len)
+    assert not [p for p in greedy if "random" in p or "threefry" in p
+                or p in ("scan", "while", "sort")], greedy
+    # the other draws, and selects its threshold in a loop of passes
+    assert {"random_bits", "scan"} <= draw and "sort" not in draw
+    # the split that carries the key on is outside the branch
+    assert "random_split" in {e.primitive.name for e in jaxpr.eqns}

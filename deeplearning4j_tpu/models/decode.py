@@ -60,12 +60,21 @@ class KVStore(Protocol):
     paged store attends to its pages where they lie, a dense store hands
     its array to ``parallel.ring_attention.attention`` under a key mask."""
 
-    def attend(self, i: int, q, k_tok, v_tok) -> Any:
+    def attend(self, i: int, q, k_tok, v_tok, **latent) -> Any:
         """q: [B,H,1,Dh]; k_tok/v_tok: [B,H,Dh] for the current position.
         Returns the attention output [B,H,1,Dh]. (A window store, for
         ``decode_window``, takes q [B,H,W,Dh] and k/v [B,W,H,Dh].) With
         grouped-query attention q has the layer's query heads and k/v its
-        (fewer) key-value heads."""
+        (fewer) key-value heads.
+
+        A latent-attention layer writes ONE row a token and no value:
+        ``k_tok`` [B,1,row] is the token's cache row (the normed latent and
+        the shared rotated key part), ``v_tok`` None, q [B,H,1,row] the
+        queries absorbed into the rows' space, and the keywords ``scale``
+        (the softmax scale, which the row's width does not give) and
+        ``value_lanes`` (the leading lanes of a row that are its value)
+        say how to read the rows. Returns [B,H,1,value_lanes]. A store
+        that keeps K/V pairs alone takes no such call."""
         ...
 
     def state(self, j: int) -> Any:
@@ -105,6 +114,13 @@ class StatefulDecodeUnsupportedError(ValueError):
     token."""
 
 
+class LatentDecodeUnsupportedError(ValueError):
+    """A serving feature that keeps keys and values by head (the int8
+    tier's quantized pools, a speculative draft's dense cache, pools split
+    by head over a mesh) was asked of a model whose attention caches one
+    latent row a token. Refused by name, as a recurrent state is."""
+
+
 class GraphDecodeSpec:
     """A language-model ``ComputationGraph`` read by the KINDS of its
     layers, validated for the incremental decode path: token embedding
@@ -120,6 +136,7 @@ class GraphDecodeSpec:
 
     def __init__(self, net):
         from ..nn.layers import (EmbeddingSequenceLayer,
+                                 LatentAttentionLayer,
                                  MixtureOfExpertsLayer,
                                  PositionalEmbeddingLayer,
                                  SelfAttentionLayer)
@@ -166,8 +183,8 @@ class GraphDecodeSpec:
         if len(pos) > 1:
             raise ValueError(f"more than one position table: {pos}")
         self.pos_name = pos[0] if pos else None
-        self.attn_names = [n for n in names
-                           if isinstance(layer(n), SelfAttentionLayer)]
+        self.attn_names = [n for n in names if isinstance(
+            layer(n), (SelfAttentionLayer, LatentAttentionLayer))]
         self.recurrent_names = [n for n in names
                                 if getattr(self._v[n], "recurrent", False)]
         self.moe_names = [n for n in names
@@ -177,13 +194,19 @@ class GraphDecodeSpec:
                              "recurrent mixers alone has no pages to serve "
                              f"from; got vertices {names})")
         attn0 = layer(self.attn_names[0])
+        # the cache's KIND: K/V pages by head, or one latent row a token
+        self.latent = isinstance(attn0, LatentAttentionLayer)
+
+        def layout(a):
+            return (a.n_heads, a.n_out) + (
+                (a.row_lanes, a.kv_rank) if self.latent else (a.kv_heads,))
+
         for n in self.attn_names:
             a = layer(n)
             if not a.causal:
-                raise ValueError("decode requires causal SelfAttentionLayer "
+                raise ValueError("decode requires causal attention "
                                  f"blocks ({n} is not)")
-            if (a.n_heads, a.kv_heads, a.n_out) != (
-                    attn0.n_heads, attn0.kv_heads, attn0.n_out):
+            if type(a) is not type(attn0) or layout(a) != layout(attn0):
                 raise ValueError("the attention layers must share one "
                                  "head layout (one pool holds them all)")
         for n in self.recurrent_names:
@@ -196,9 +219,11 @@ class GraphDecodeSpec:
         self._rec_j = {n: j for j, n in enumerate(self.recurrent_names)}
         self.n_blocks = len(self.attn_names)       # layers the pools hold
         self.n_heads = attn0.n_heads
-        self.kv_heads = attn0.kv_heads
         self.d_model = attn0.n_out
-        self.head_dim = attn0.head_dim
+        # what a pool's row holds: ``kv_heads`` heads of ``head_dim``; a
+        # latent row is one "head" as wide as the row is laid out
+        self.kv_heads = 1 if self.latent else attn0.kv_heads
+        self.head_dim = attn0.row_lanes if self.latent else attn0.head_dim
         self.vocab = layer(self.head_name).n_out
         self.max_length = layer(self.pos_name).max_length \
             if self.pos_name else None
@@ -213,11 +238,18 @@ class GraphDecodeSpec:
         """Whether a sequence carries more than its K/V pages."""
         return bool(self.recurrent_names)
 
+    @property
+    def cache_kind(self) -> str:
+        return "latent" if self.latent else "kv"
+
     def supports_head_sharding(self, m: int) -> bool:
         """Whether the paged KV pools (and the Q/K/V/O projections) can
         split their head axis ``m`` ways: attention is head-local, so an
         even head split keeps every per-head row on one shard and decode
-        stays token-for-token identical to the single-chip program."""
+        stays token-for-token identical to the single-chip program. A
+        latent pool has no head axis: every head reads every row."""
+        if self.latent:
+            return m == 1
         return m >= 1 and self.kv_heads % m == 0 and self.n_heads % m == 0
 
     def recurrent_state_shape(self, rows: int):
@@ -313,9 +345,11 @@ class GraphDecodeSpec:
 
         Returns (logits [B,V] pre-activation or None, ks, vs, states,
         stats): ks[i]/vs[i] [B,L,Hkv,Dh] an attention layer, as a cache
-        keeps them (k normed and rotated where the layer does that);
-        states[j] a recurrent mixer's state after ``lengths`` rows; stats
-        int32 [2] (fullest expert's pairs, experts touched) or None."""
+        keeps them (k normed and rotated where the layer does that; a
+        latent layer's ks[i] is its cache rows [B,L,1,row] and vs is
+        empty: one pool); states[j] a recurrent mixer's state after
+        ``lengths`` rows; stats int32 [2] (fullest expert's pairs, experts
+        touched) or None."""
         x_in = tokens if self.token_input else \
             jax.nn.one_hot(tokens, self.vocab, dtype=self.dtype)
         acts, _ = self.net.apply_fn(params, state, [x_in], train=False)
@@ -324,6 +358,10 @@ class GraphDecodeSpec:
         ks, vs = [], []
         for n in self.attn_names:
             y = acts[self._inputs[n][0]]
+            if self.latent:
+                ks.append(self._v[n].layer_conf.cache_rows(
+                    self._p(params, n), y))
+                continue
             _, k, v = self._v[n].layer_conf.project_qkv(self._p(params, n), y)
             ks.append(k)
             vs.append(v)
@@ -391,9 +429,18 @@ class GraphDecodeSpec:
         layer = self._v[name].layer_conf
         ap = self._p(params, name)
         B, W, _ = y.shape
+        i = self._attn_i[name]
+        if self.latent:
+            # absorbed: attention over the cached rows themselves, then
+            # back to the heads' values
+            q = layer.absorbed_queries(ap, y, w_pos).transpose(0, 2, 1, 3)
+            rows = layer.cache_rows(ap, y, w_pos)             # [B,W,1,row]
+            out = store.attend(i, q, rows if window else rows[:, 0], None,
+                               scale=layer.scale, value_lanes=layer.kv_rank)
+            return layer.project_output(
+                ap, layer.unabsorb(ap, out.transpose(0, 2, 1, 3)))
         q, k, v = layer.project_qkv(ap, y, w_pos)
         q = q.transpose(0, 2, 1, 3)                            # [B,H,W,Dh]
-        i = self._attn_i[name]
         out = store.attend(i, q, k, v) if window else \
             store.attend(i, q, k[:, 0], v[:, 0])
         out = out.transpose(0, 2, 1, 3).reshape(B, W, self.d_model)

@@ -10,7 +10,7 @@ from .conv import (Convolution1DLayer, ConvolutionLayer, GlobalPoolingLayer,
 from .norm import (BatchNormalization, LayerNormalization,
                    LocalResponseNormalization, RMSNorm)
 from .gated import GatedMLP, GatedShortConvLayer, MixtureOfExpertsLayer
-from .attention import SelfAttentionLayer
+from .attention import LatentAttentionLayer, SelfAttentionLayer
 from .recurrent import (GravesBidirectionalLSTM, GravesLSTM, LSTM,
                         LastTimeStepLayer)
 from .variational import (BernoulliReconstructionDistribution,
@@ -20,7 +20,7 @@ from .variational import (BernoulliReconstructionDistribution,
                           LossFunctionWrapper, RBM, VariationalAutoencoder)
 
 __all__ = [
-    "SelfAttentionLayer",
+    "SelfAttentionLayer", "LatentAttentionLayer",
     "BernoulliReconstructionDistribution", "CompositeReconstructionDistribution",
     "ExponentialReconstructionDistribution", "GaussianReconstructionDistribution",
     "LossFunctionWrapper", "RBM", "VariationalAutoencoder",

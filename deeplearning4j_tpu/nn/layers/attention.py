@@ -154,3 +154,194 @@ class SelfAttentionLayer(LayerConf):
             out = attention(q, k, v, causal=self.causal, key_mask=mask)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
         return self.project_output(params, out), state
+
+
+@register
+@dataclass
+class LatentAttentionLayer(LayerConf):
+    """Multi-head latent attention, [B,T,F] -> [B,T,n_out]: keys and values
+    of every head are expanded from ONE low-rank latent a token, and a
+    cache keeps that latent (normed) beside one rotated key part that all
+    heads share, not the heads.
+
+        q = x Wq                    -> heads x [q_nope | q_rope]
+        [c' | r] = x Wkva;  c = rms(c'; kv_gain)
+        [k_nope_h | v_h] = c Wkvb   -> heads x (qk_nope_dim + v_dim)
+        s_h,t = (q_nope_h . k_nope_h,t + rope(q_rope_h) . rope(r_t)) * scale
+        out = [softmax(s_h) v_h]_h Wo
+
+    ``scale`` is 1 / sqrt(qk_nope_dim + qk_rope_dim). The rotation takes
+    interleaved pairs (x_2i, x_2i+1); only ``q_rope . r`` enters a score,
+    so the rotated halves are kept un-interleaved (first components, then
+    second) on both sides. No bias anywhere.
+
+    ``apply`` is this expanded form (flash attention with a score size of
+    ``qk_nope_dim + qk_rope_dim`` and a value size of ``v_dim`` where it
+    applies and the call is not training, else the XLA path). A decode
+    step takes the ABSORBED form, the same numbers with ``Wkvb`` moved
+    onto the query and the output so that attention runs over the cached
+    rows themselves: ``cache_rows`` is what the cache keeps of a token
+    (``[c | rope(r)]``, ``row_lanes`` wide), ``absorbed_queries`` the
+    queries in the rows' space, ``unabsorb`` the way back to the heads'
+    values."""
+    n_in: Optional[int] = None
+    n_out: int = 0
+    n_heads: int = 4
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_dim: int = 128
+    kv_rank: int = 512
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+    causal: bool = True
+
+    param_order: ClassVar[Tuple[str, ...]] = ("Wq", "Wkva", "kv_gain",
+                                              "Wkvb", "Wo")
+    weight_param_names: ClassVar[Tuple[str, ...]] = ("Wq", "Wkva", "Wkvb",
+                                                     "Wo")
+    expected_input: ClassVar[str] = "rnn"
+    accepts_mask: ClassVar[bool] = True
+
+    def output_type(self, itype):
+        t = itype.timestep_length if isinstance(itype, InputTypeRecurrent) else -1
+        return InputTypeRecurrent(self.n_out, t)
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def scale(self) -> float:
+        return float(self.qk_dim) ** -0.5
+
+    @property
+    def row_width(self) -> int:
+        """Values a cache row carries: the latent and the shared key part."""
+        return self.kv_rank + self.qk_rope_dim
+
+    @property
+    def row_lanes(self) -> int:
+        """A cache row as laid out: padded with zeros to whole 128-lane
+        tiles (576 -> 640). The TPU's compiler lays a 576-wide row out on
+        640 lanes whatever the shape says, and Mosaic refuses a DMA of a
+        partial tile, so the padding is in the shape, where the bytes can
+        be counted."""
+        return -(-self.row_width // 128) * 128
+
+    def init(self, rng, itype, dtype):
+        n_in = self.n_in or resolve_ff_size(itype)
+        self.n_in = n_in
+        self.n_out = d = self.n_out or n_in
+        if self.qk_rope_dim % 2:
+            raise ValueError("qk_rope_dim must be even (it rotates in pairs)")
+        H, r = self.n_heads, self.kv_rank
+        nq, nkva = H * self.qk_dim, r + self.qk_rope_dim
+        nkvb, no = H * (self.qk_nope_dim + self.v_dim), H * self.v_dim
+        ks = jax.random.split(rng, 4)
+        return {"Wq": self._winit(ks[0], (n_in, nq), n_in, nq, dtype),
+                "Wkva": self._winit(ks[1], (n_in, nkva), n_in, nkva, dtype),
+                "kv_gain": jnp.ones((r,), dtype),
+                "Wkvb": self._winit(ks[2], (r, nkvb), r, nkvb, dtype),
+                "Wo": self._winit(ks[3], (no, d), no, d, dtype)}, {}
+
+    def _rotate(self, x, positions):
+        """Rotary positions over interleaved pairs: x [B,T,...,R] with
+        positions [B,T]; pair i is (x_2i, x_2i+1) at angle ``pos *
+        theta^(-2i/R)``; returns [first components | second components].
+        Angles in float32."""
+        half = x.shape[-1] // 2
+        inv = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        ang = positions.astype(jnp.float32).reshape(
+            positions.shape + (1,) * (x.ndim - 2)) * inv
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    @staticmethod
+    def _positions(x, positions):
+        B, T = x.shape[:2]
+        return jnp.broadcast_to(jnp.arange(T)[None], (B, T)) \
+            if positions is None else positions
+
+    def project_q(self, params, x, positions=None):
+        """x [B,T,F] -> (q_nope [B,T,H,qk_nope_dim], q_rope
+        [B,T,H,qk_rope_dim] rotated)."""
+        B, T, _ = x.shape
+        q = (x @ params["Wq"]).reshape(B, T, self.n_heads, self.qk_dim)
+        return q[..., :self.qk_nope_dim], self._rotate(
+            q[..., self.qk_nope_dim:], self._positions(x, positions))
+
+    def latent(self, params, x, positions=None):
+        """x [B,T,F] -> (c [B,T,kv_rank] the normed latent, r
+        [B,T,qk_rope_dim] the rotated key part all heads share)."""
+        from .norm import rms_norm
+        cr = x @ params["Wkva"]
+        c = rms_norm(cr[..., :self.kv_rank], params["kv_gain"], self.norm_eps)
+        return c, self._rotate(cr[..., self.kv_rank:],
+                               self._positions(x, positions))
+
+    def _on_lanes(self, x):
+        """[..., row_width] -> [..., row_lanes], zeros behind the values."""
+        pad = [(0, 0)] * (x.ndim - 1) + [(0, self.row_lanes - self.row_width)]
+        return jnp.pad(x, pad)
+
+    def cache_rows(self, params, x, positions=None):
+        """What a cache keeps of each token: [B,T,1,row_lanes] = [c | r |
+        zeros], one "head" of ``row_lanes`` as a paged pool takes it."""
+        c, r = self.latent(params, x, positions)
+        return self._on_lanes(jnp.concatenate([c, r], axis=-1))[:, :, None, :]
+
+    def _wkvb(self, params):
+        """Wkvb by head: (W_UK [r,H,qk_nope_dim], W_UV [r,H,v_dim])."""
+        w = params["Wkvb"].reshape(self.kv_rank, self.n_heads,
+                                   self.qk_nope_dim + self.v_dim)
+        return w[..., :self.qk_nope_dim], w[..., self.qk_nope_dim:]
+
+    def absorbed_queries(self, params, x, positions=None):
+        """x [B,T,F] -> [B,T,H,row_lanes]: each head's query in the cache
+        rows' space, ``[W_UK,h^T q_nope_h | q_rope_h | zeros]``, so that
+        its dot with a cache row is the head's score before the scale."""
+        q_nope, q_rope = self.project_q(params, x, positions)
+        w_uk, _ = self._wkvb(params)
+        return self._on_lanes(jnp.concatenate(
+            [jnp.einsum("bthn,rhn->bthr", q_nope, w_uk), q_rope], axis=-1))
+
+    def unabsorb(self, params, o):
+        """o [B,T,H,kv_rank], each head's softmax-weighted sum of latents
+        -> the heads' values [B,T,H*v_dim]."""
+        _, w_uv = self._wkvb(params)
+        out = jnp.einsum("bthr,rhv->bthv", o, w_uv)
+        return out.reshape(out.shape[:2] + (self.n_heads * self.v_dim,))
+
+    def project_output(self, params, out):
+        """The heads' values [B,T,H*v_dim] through Wo and the activation."""
+        return self.act(out @ params["Wo"])
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from ...ops.pallas_attention import (flash_attention,
+                                             fused_attention_applicable)
+        from ...parallel.ring_attention import attention
+        x = maybe_dropout(x, self.dropout, rng, train)
+        B, T, _ = x.shape
+        H = self.n_heads
+        q_nope, q_rope = self.project_q(params, x)
+        c, r = self.latent(params, x)
+        kv = (c @ params["Wkvb"]).reshape(B, T, H,
+                                          self.qk_nope_dim + self.v_dim)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :self.qk_nope_dim],
+             jnp.broadcast_to(r[:, :, None, :], (B, T, H, self.qk_rope_dim))],
+            axis=-1)
+        v = kv[..., self.qk_nope_dim:]
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        # the kernels' backward pass takes one head size: training this
+        # layer runs the XLA path
+        fused = not train and fused_attention_applicable(
+            B, H, T, self.qk_dim, q.dtype, self.v_dim)
+        out = (flash_attention if fused else attention)(
+            q, k, v, causal=self.causal, scale=self.scale, key_mask=mask)
+        out = out.transpose(0, 2, 1, 3).reshape(B, T, H * self.v_dim)
+        return self.project_output(params, out), state

@@ -144,10 +144,14 @@ class MixtureOfExpertsLayer(LayerConf):
         s = sigmoid(x Wg)                       over all ``n_experts``
         chosen = top_k of (s + bias)            the bias takes part in the
                                                 choice only
-        w = s[chosen] / (sum s[chosen] + 1e-6)  (``norm_topk``) * scale
+        w = s[chosen] / (sum s[chosen] + eps)   (``norm_topk``) * scale
         out = sum_e w_e W2[e] (silu(x W1[e]) * (x W3[e]))
 
     Every chosen pair is computed: no capacity, no dropped token.
+    ``norm_eps`` is the family's: 1e-6 in one published code, 1e-20 in
+    another. A shared expert, where a model has one, is a ``GatedMLP``
+    vertex of the graph added to this layer's output: it belongs to no
+    ``held`` range and is counted once.
 
     ``held`` = (first, count) names the experts whose weights this layer
     holds (default all). The router always scores all ``n_experts``; the
@@ -165,6 +169,7 @@ class MixtureOfExpertsLayer(LayerConf):
     n_hidden: int = 0
     held: Optional[Tuple[int, int]] = None
     norm_topk: bool = True
+    norm_eps: float = 1e-6
     routed_scaling_factor: float = 1.0
 
     param_order: ClassVar[Tuple[str, ...]] = ("Wg", "bias", "W1", "W3", "W2")
@@ -213,7 +218,7 @@ class MixtureOfExpertsLayer(LayerConf):
         idx = idx.astype(jnp.int32)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if self.norm_topk:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + self.norm_eps)
         return idx, w * self.routed_scaling_factor
 
     def apply(self, params, state, x, *, train=False, rng=None):

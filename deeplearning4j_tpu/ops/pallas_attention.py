@@ -67,21 +67,25 @@ def _kernel_eligible(D: int, dtype) -> bool:
     dt = jnp.dtype(dtype)
     if dt not in (jnp.float32, jnp.dtype(jnp.bfloat16)):
         return False
-    if D % 128 != 0 and D not in (64, 96):
+    if D % 128 != 0 and D not in (64, 96, 192):
         # D is the lane dimension: multiples of the 128-lane tile are
-        # native; 64/96 (GPT-2-class head dims) ride Mosaic's minor-dim
-        # padding — the MXU pads the QK^T contraction to 128 either way,
-        # so the only cost is padded q/k/v/o tiles in VMEM
+        # native; 64/96 (GPT-2-class head dims) and 192 (latent
+        # attention's score size: 128 + a rotary part of 64) ride Mosaic's
+        # minor-dim padding — the MXU pads the QK^T contraction to whole
+        # tiles either way, so the only cost is padded q/k/v/o tiles in VMEM
         return False
     return kenv.backend_admits("attention", jax.default_backend(),
                                ("DL4J_TPU_FUSED_ATTN_INTERPRET",))
 
 
-def fused_attention_applicable(B: int, H: int, T: int, D: int, dtype) -> bool:
+def fused_attention_applicable(B: int, H: int, T: int, D: int, dtype,
+                               Dv: Optional[int] = None) -> bool:
     """Probe: can the fused kernels handle this call? (helper seam —
-    callers fall back to the XLA path when False)."""
+    callers fall back to the XLA path when False). ``Dv`` is the values'
+    head size where it differs from the scores' ``D`` (forward only)."""
     # tiny T isn't worth the pallas_call overhead vs one fused XLA softmax
-    return _kernel_eligible(D, dtype) and T % 128 == 0 and T >= 256
+    return _kernel_eligible(D, dtype) and T % 128 == 0 and T >= 256 \
+        and (Dv is None or Dv == D or _kernel_eligible(Dv, dtype))
 
 
 def _interpret() -> bool:
@@ -342,7 +346,8 @@ def _row_major_specs(causal, RQ, RK, D, mask_heads):
     """BlockSpecs of a (b, i, j) grid, k-blocks innermost: (q-side rows,
     k-side rows, per-query scalars [., 1, T], key mask [., T, 128] or
     None). Under ``causal`` the k side stops at the last block row block
-    i needs."""
+    i needs. ``D`` is the rows' width: the forward pass asks once for the
+    scores' size (q, k) and once for the values' (v, o)."""
     def col(i, j):
         return jnp.minimum(j, _last_col_block(i, RQ, RK)) if causal else j
     qspec = pl.BlockSpec((1, RQ, D), lambda b, i, j: (b, i, 0))
@@ -376,11 +381,15 @@ def _fwd_call(q3, k3, v3, mask2, *, causal, scale, tiles, interpret):
     process pays its tracing before it can even look in the compile cache
     (PR 27's lesson with the paged kernel, PERF.md §6)."""
     BH, T, D = q3.shape
+    Dv = v3.shape[2]                  # the values' size; D is the scores'
     BQ, BK, RQ, RK = tiles
     masked = mask2 is not None
-    qspec, kspec, lspec, mspec = _row_major_specs(
-        causal, RQ, RK, D, BH // mask2.shape[0] if masked else None)
-    in_specs = [qspec, kspec, kspec]
+    mask_heads = BH // mask2.shape[0] if masked else None
+    qspec, kspec, lspec, mspec = _row_major_specs(causal, RQ, RK, D,
+                                                  mask_heads)
+    ospec, vspec = (qspec, kspec) if Dv == D else _row_major_specs(
+        causal, RQ, RK, Dv, mask_heads)[:2]
+    in_specs = [qspec, kspec, vspec]
     args = [q3, k3, v3]
     if masked:
         in_specs.append(mspec)
@@ -391,10 +400,10 @@ def _fwd_call(q3, k3, v3, mask2, *, causal, scale, tiles, interpret):
             name=FWD_NAME,
             grid=(BH, T // RQ, T // RK),
             in_specs=in_specs,
-            out_specs=[qspec, lspec],
-            out_shape=[jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
+            out_specs=[ospec, lspec],
+            out_shape=[jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype),
                        jax.ShapeDtypeStruct((BH, 1, T), f32)],
-            scratch_shapes=[pltpu.VMEM((D, RQ), f32),
+            scratch_shapes=[pltpu.VMEM((Dv, RQ), f32),
                             pltpu.VMEM((1, RQ), f32),
                             pltpu.VMEM((1, RQ), f32)],
             compiler_params=pltpu.CompilerParams(
@@ -405,8 +414,8 @@ def _fwd_call(q3, k3, v3, mask2, *, causal, scale, tiles, interpret):
 
 
 def _fwd(q3, k3, v3, mask2, causal, scale):
-    """q3/k3/v3: [BH, T, D]; mask2: [B, T] or None. Returns (o, lse), lse
-    [BH, 1, T] float32."""
+    """q3/k3: [BH, T, D], v3: [BH, T, Dv]; mask2: [B, T] or None. Returns
+    (o [BH, T, Dv], lse), lse [BH, 1, T] float32."""
     return _fwd_call(q3, k3, v3, mask2, causal=causal, scale=scale,
                      tiles=_tiles(q3.shape[1], causal),
                      interpret=_interpret())
@@ -725,16 +734,21 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None, key_mask=None):
     """Fused softmax attention, [B,H,T,D] in/out — drop-in for
     parallel/ring_attention.attention when fused_attention_applicable.
-    ``key_mask`` [B,T] excludes padded timesteps as keys."""
+    ``key_mask`` [B,T] excludes padded timesteps as keys. ``v`` (and the
+    result) may have a head size ``Dv`` of its own (latent attention: 192
+    for the scores, 128 for the values): that call is forward only, the
+    backward kernels take one size."""
     B, H, T, D = q.shape
+    Dv = v.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
 
     def kernels(q, k, v, *mask):          # one device's [b,h,T,D] block
         b, h = q.shape[:2]
-        o = _flash(q.reshape(b * h, T, D), k.reshape(b * h, T, D),
-                   v.reshape(b * h, T, D), mask[0] if mask else None,
-                   causal, scale)
-        return o.reshape(b, h, T, D)
+        q3, k3, v3 = (a.reshape(b * h, T, a.shape[-1]) for a in (q, k, v))
+        m = mask[0] if mask else None
+        o = _flash(q3, k3, v3, m, causal, scale) if Dv == D else \
+            _fwd(q3, k3, v3, m, causal, scale)[0]
+        return o.reshape(b, h, T, Dv)
 
     args = (q, k, v) + (() if key_mask is None else (jnp.asarray(key_mask),))
     split = _device_split(B, H)

@@ -26,6 +26,16 @@ discarded). That spends H times the FLOPs the algorithm needs on an MXU
 that a one-row decode leaves idle anyway, and needs no per-head slice of a
 page, which the tiling would make a strided gather.
 
+A LATENT cache (multi-head latent attention) is ONE pool of rows with no
+head axis, ``[n_layers, num_blocks, block_len, row]``: every query head
+reads the same row as its key (all lanes) and as its value (the first
+``value_lanes`` lanes: the latent). ``paged_attention_decode`` takes it
+with ``v_pool=None``: the kernel fetches each page once and takes the
+values as a slice of the key rows it holds, under the name
+``paged_attention_latent_decode``. The queries arrive absorbed into the
+rows' space, so the softmax scale is the caller's (the layer's 1 /
+sqrt(192), not 1 / sqrt(row)).
+
 Precision: scores, the softmax recurrence (running max and sum) and both
 accumulations are float32; ``p`` is cast to the pool's dtype for ``p @ V``
 and the output is the pool's dtype, as on the XLA path.
@@ -49,6 +59,7 @@ f32 = jnp.float32
 # pallas_attention.SCOPE for why a call sits in two scopes)
 SCOPE = "paged_attention"
 KERNEL_NAME = "paged_attention_decode"
+LATENT_KERNEL_NAME = "paged_attention_latent_decode"
 
 # keys folded into the online softmax per step: 16 pages of 16, the DMAs of
 # one step in flight while the previous step's pages are computed on. On
@@ -56,15 +67,30 @@ KERNEL_NAME = "paged_attention_decode"
 # contexts (16 x 750 keys x 24 layers: 2.18 against 2.40 and 2.31 ms) and
 # tied them at short ones (PERF.md, PR 27)
 _KEYS_PER_STEP = 256
+# the same for a latent pool, whose rows are 640 lanes wide: on the v5e, 40
+# slots of 4k-17k rows x 6 layers took 7.48 ms at 256 keys a step and 5.57
+# at 512, in pages of 64 (5.56 in pages of 128, 5.83 in pages of 32;
+# PERF.md, PR 40): one step's matmuls are 32 query rows against the keys,
+# and longer steps amortize more of each
+_LATENT_KEYS_PER_STEP = 512
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _body(W, H, Dh, blk, G, cap, scale, Gq,
-          layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-          kbuf, vbuf, sem, m_s, l_s, acc_s):
+def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
+    """``Dv`` None: K and V pools, H heads of Dh side by side. ``Dv`` an
+    int: one latent pool (H = 1), the values are the first Dv lanes of
+    the key rows."""
+    if Dv is None:
+        (layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+         kbuf, vbuf, sem, m_s, l_s, acc_s) = refs
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
+    else:
+        (layer_ref, tables_ref, lens_ref, q_ref, k_hbm, o_ref,
+         kbuf, sem, m_s, l_s, acc_s) = refs
+        pools = ((k_hbm, kbuf),)
     T = G * blk
     s = pl.program_id(0)
     layer = layer_ref[0]
@@ -84,8 +110,7 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq,
             @pl.when(page < npages)
             def _():
                 bid = tables_ref[s, page]
-                for c, (pool, buf) in enumerate(((k_hbm, kbuf),
-                                                 (v_hbm, vbuf))):
+                for c, (pool, buf) in enumerate(pools):
                     getattr(pltpu.make_async_copy(
                         pool.at[layer, bid],
                         buf.at[slot, pl.ds(g * blk, blk)],
@@ -113,7 +138,7 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq,
 
         each_copy(gi, slot, "wait")
         k = kbuf[slot]
-        v = vbuf[slot]
+        v = vbuf[slot] if Dv is None else k[:, :Dv]
         sc = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32) * scale
         kpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -139,6 +164,9 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq,
 
     l = l_s[:, :1]
     out = acc_s[:] * jnp.where(l > 0, 1.0 / l, 0.0)     # idle slot: zeros
+    if Dv is not None:                 # one "head": every row is its own
+        o_ref[0] = out[:W * Gq].astype(o_ref.dtype)
+        return
     r = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
     out = jnp.where(lane // Dh == r % H, out, 0.0)      # head h, own lanes
@@ -187,7 +215,7 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
     with jax.named_scope(SCOPE):
         o = pl.pallas_call(
             functools.partial(_body, W, H, Dh, blk, G, mb * blk,
-                              1.0 / float(np.sqrt(Dh)), Gq),
+                              1.0 / float(np.sqrt(Dh)), Gq, None),
             name=KERNEL_NAME,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((S, W * Gq, HD), q.dtype),
@@ -201,7 +229,52 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
         S, Hq, W, Dh)
 
 
-def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
+@functools.partial(jax.jit, static_argnames=("scale", "value_lanes",
+                                             "interpret"))
+def _one_device_latent(layer, q, pool, tables, lens, *, scale, value_lanes,
+                       interpret):
+    """The latent pool's case: q [S,Hq,W,row] absorbed queries, pool
+    [L,nb,blk,row] with no head axis (every query head reads the whole
+    row as its key and its first ``value_lanes`` lanes as its value).
+    Returns [S,Hq,W,value_lanes]. One DMA a page."""
+    S, Hq, W, row = q.shape
+    blk = pool.shape[2]
+    mb = tables.shape[1]
+    G = max(1, min(mb, _LATENT_KEYS_PER_STEP // blk))
+    T = G * blk
+    R = W * Hq
+    Rp = -(-R // 16) * 16
+    # row w * Hq + g is query head g of window position w
+    qr = jnp.pad(q.transpose(0, 2, 1, 3).reshape(S, R, row),
+                 ((0, 0), (0, Rp - R), (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,            # layer, tables, lens
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, Rp, row), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, R, value_lanes), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, T, row), pool.dtype),
+                        pltpu.SemaphoreType.DMA((1, 2)),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, value_lanes), f32)])
+    with jax.named_scope(SCOPE):
+        o = pl.pallas_call(
+            functools.partial(_body, W, 1, row, blk, G, mb * blk, scale, Hq,
+                              value_lanes),
+            name=LATENT_KERNEL_NAME,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, R, value_lanes), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(layer.reshape(1), tables.astype(jnp.int32), lens.astype(jnp.int32),
+          qr, pool)
+    return o.reshape(S, W, Hq, value_lanes).transpose(0, 2, 1, 3)
+
+
+def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens, *,
+                           scale=None, value_lanes=None):
     """Softmax attention of a decode window over a paged cache.
 
     q       [S, Hq, W, Dh]: W query rows a slot (1 in the decode step,
@@ -216,9 +289,23 @@ def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
             step's own token already written); row w sees ``lens + w``.
             0 marks an idle slot: it fetches nothing and returns zeros.
 
+    A latent cache: ``v_pool`` None, ``k_pool`` [n_layers, num_blocks,
+    block_len, row] with no head axis, q [S, Hq, W, row] the queries
+    absorbed into the rows' space, ``scale`` the softmax scale (required:
+    it is not 1 / sqrt(row)) and ``value_lanes`` the leading lanes of a row
+    that are its value. Returns [S, Hq, W, value_lanes]. A latent pool has
+    no heads to split over a mesh.
+
     Returns [S, H, W, Dh] in q's dtype. Under a mesh tracing context the
     heads split over the model axis with ``shard_map`` (a Mosaic call is
     not partitioned automatically); attention is head-local."""
+    if v_pool is None:
+        if scale is None or value_lanes is None:
+            raise ValueError("a latent pool needs scale and value_lanes")
+        return _one_device_latent(
+            jnp.asarray(layer, jnp.int32), q, k_pool, tables, lens,
+            scale=float(scale), value_lanes=int(value_lanes),
+            interpret=_interpret())
     S = q.shape[0]
     H = k_pool.shape[3] // q.shape[3]
     layer = jnp.asarray(layer, jnp.int32)
@@ -236,12 +323,23 @@ def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens):
         check_vma=False)(layer, q, k_pool, v_pool, tables, lens)
 
 
-def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens):
+def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens,
+                              *, scale=None, value_lanes=None):
     """The same attention the plain way: gather every slot's whole table
     into a dense context and attend under a mask. The parity pin of the
     kernel, and what the decode step did before it."""
     from ..models.decode import window_attention
     S, Hq, W, Dh = q.shape
+    if v_pool is None:                 # a latent pool: one row, all heads
+        ctx = k_pool[layer][tables].reshape(S, 1, -1, Dh)
+        limit = lens[:, None] + jnp.arange(W)[None, :]
+        mask = jnp.arange(ctx.shape[2])[None, None, :] < limit[:, :, None]
+        # window_attention scales by 1 / sqrt(Dh): fold the rest into q
+        qs = (q.astype(f32) * (scale * np.sqrt(Dh))).astype(q.dtype)
+        ctx = jnp.broadcast_to(ctx, (S, Hq) + ctx.shape[2:])
+        out = window_attention(qs, ctx, ctx[..., :value_lanes], mask)
+        return jnp.where((lens > 0)[:, None, None, None], out,
+                         jnp.zeros((), out.dtype))
     H = k_pool.shape[3] // Dh
     ctx = tables.shape[1] * k_pool.shape[2]
 

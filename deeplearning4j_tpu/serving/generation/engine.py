@@ -198,6 +198,8 @@ class GenerationEngine:
             "prefix_cache": rt.active_ps.prefix_enabled,
             "kv_cache_dtype": rt.config.kv_cache_dtype,
             "kv_bytes_per_token": rt.active_ps.kv_bytes_per_token(),
+            "cache_kind": getattr(rt.active_ps.spec, "cache_kind", None),
+            "cache_bytes_per_token": rt.active_ps.kv_bytes_per_token(),
             "conv_state_bytes": rt.active_ps.recurrent_state_bytes(),
             "model_shards": rt.active_ps.model_shards,
             "kv_pool_bytes_per_chip": rt.active_ps.kv_pool_chip_bytes,

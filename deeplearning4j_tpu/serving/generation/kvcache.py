@@ -14,6 +14,13 @@ bookkeeping edit to an int32 table; no device array ever changes shape, so
 nothing ever recompiles (the vLLM PagedAttention idea fused with the
 repo's AOT-warmed-program discipline).
 
+A cache has a KIND. ``kv``: the pair above. ``latent`` (multi-head latent
+attention): ONE pool ``[n_layers, num_blocks, block_len, row]`` whose rows
+carry no head axis: a token's normed latent and the one rotated key part
+all heads share (576 values, laid out on 640 lanes), read by every query
+head as its key and, in its leading lanes, as its value. A latent cache is
+the 1-tuple ``(pool,)``; every helper below takes either kind.
+
 Block 0 is the reserved TRASH block: inactive decode slots and the unused
 tail of a prefill's table all point at it, so the fixed-shape scatter always
 has a legal destination and garbage lands where nothing ever reads it
@@ -156,10 +163,15 @@ def kv_dequantize(q, scale, dtype):
 
 def make_pools(n_layers: int, num_blocks: int, block_len: int,
                n_heads: int, head_dim: int, dtype,
-               quantized: bool = False) -> Tuple:
+               quantized: bool = False, latent: bool = False) -> Tuple:
     """Zero-filled (k_pool, v_pool) — plain arrays, or ``QuantizedPool``
-    pairs when ``quantized`` (the kv_cache_dtype="int8" tier)."""
+    pairs when ``quantized`` (the kv_cache_dtype="int8" tier); ``latent``:
+    the one pool ``(pool,)`` of rows ``head_dim`` wide (``n_heads`` 1)."""
     shape = (n_layers, num_blocks, block_len, n_heads, head_dim)
+    if latent:
+        if quantized or n_heads != 1:
+            raise ValueError("a latent pool is plain and has no head axis")
+        return (jnp.zeros(shape[:3] + (head_dim,), dtype),)
     if quantized:
         def qp():
             return QuantizedPool(jnp.zeros(shape, jnp.int8),
@@ -175,17 +187,16 @@ def pool_bytes(pool) -> int:
                for leaf in jax.tree.leaves(pool))
 
 
-def cow_copy(k_pool, v_pool, src, dst):
-    """Copy one block's content (every layer, K and V) from ``src`` to
-    ``dst`` — the copy-on-write primitive for prefix sharing. ``src``/
-    ``dst`` are runtime int32 scalars, so ONE compiled program serves every
-    copy; functional update keeps the read-before-write ordering a data
-    dependency. Generic over plain and quantized pools (a quantized COW
-    copies codes AND scales — bit-exact sharing)."""
+def cow_copy(pools, src, dst):
+    """Copy one block's content (every layer of every pool in ``pools``: K
+    and V, or the one latent pool) from ``src`` to ``dst`` — the
+    copy-on-write primitive for prefix sharing. ``src``/``dst`` are runtime
+    int32 scalars, so ONE compiled program serves every copy; functional
+    update keeps the read-before-write ordering a data dependency. Generic
+    over plain and quantized pools (a quantized COW copies codes AND
+    scales — bit-exact sharing). Returns the pools as a tuple."""
     copy = lambda p: p.at[:, dst].set(p[:, src])
-    k_pool = jax.tree.map(copy, k_pool)
-    v_pool = jax.tree.map(copy, v_pool)
-    return k_pool, v_pool
+    return tuple(jax.tree.map(copy, pool) for pool in pools)
 
 
 def prefill_scatter(pool, layer_kv, tables):
@@ -306,7 +317,7 @@ class PagedWindowStore:
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
                  window: int, rec=None):
         self.k_pool = k_pool
-        self.v_pool = v_pool
+        self.v_pool = v_pool              # None: a latent cache, one pool
         # per-slot state of the model's recurrent mixers, [layers,
         # slots + 1, ...] (row ``slots`` is the prefill's trash row), or
         # None for a model that keeps K/V alone
@@ -331,10 +342,17 @@ class PagedWindowStore:
             self._mask = (jnp.arange(ctx_len)[None, None, :]
                           <= w_pos[:, :, None])                  # [S, W, ctx]
 
-    def attend(self, i: int, q, k_win, v_win):
+    def attend(self, i: int, q, k_win, v_win, **latent):
         """q [S,H,W,Dh]; k_win/v_win [S,W,H,Dh] for the window. Returns
-        the attention output [S,H,W,Dh]."""
+        the attention output [S,H,W,Dh]. Over a latent cache: q
+        [S,H,W,row] absorbed, k_win [S,W,1,row] the tokens' cache rows,
+        v_win None, ``scale`` and ``value_lanes`` as
+        ``paged_attention_decode`` takes them; returns
+        [S,H,W,value_lanes]."""
         self.k_pool = _pool_write(self.k_pool, i, self._bid, self._off, k_win)
+        if self.v_pool is None:
+            return paged_attention_decode(q, self.k_pool, None, i,
+                                          self.tables, self._lens, **latent)
         self.v_pool = _pool_write(self.v_pool, i, self._bid, self._off, v_win)
         if isinstance(self.k_pool, QuantizedPool):
             group = q.shape[1] // k_win.shape[2]
@@ -359,7 +377,8 @@ class PagedWindowStore:
 
     @property
     def pools(self):
-        return self.k_pool, self.v_pool
+        return (self.k_pool,) if self.v_pool is None \
+            else (self.k_pool, self.v_pool)
 
     @property
     def cache(self):
@@ -377,6 +396,9 @@ class PagedStore(PagedWindowStore):
         super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1,
                          rec)
 
-    def attend(self, i: int, q, k_tok, v_tok):
-        """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh]."""
-        return super().attend(i, q, k_tok[:, None], v_tok[:, None])
+    def attend(self, i: int, q, k_tok, v_tok, **latent):
+        """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh] (v_tok None and the
+        ``latent`` keywords over a latent cache)."""
+        return super().attend(i, q, k_tok[:, None],
+                              None if v_tok is None else v_tok[:, None],
+                              **latent)

@@ -41,14 +41,14 @@ import numpy as np
 from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ...models.decode import (GraphDecodeSpec, LSTMDecodeSpec,
-                              StatefulDecodeUnsupportedError)
+from ...models.decode import (GraphDecodeSpec, LatentDecodeUnsupportedError,
+                              LSTMDecodeSpec, StatefulDecodeUnsupportedError)
 from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
                                          model_axis_size, per_replica_bytes,
                                          shard_params)
 from ...telemetry import span
 from ..programs import _arch_key, _tree_signature
-from .kvcache import (PagedStore, QuantSimStore, make_pools,
+from .kvcache import (PagedStore, QuantSimStore, cow_copy, make_pools,
                       prefill_scatter)
 from .sampling import sample_tokens
 
@@ -208,6 +208,10 @@ class GenerationProgramSet:
         # mixer's per-slot state): what does not carry that state refuses
         # the model by name below, and the prefix cache is skipped
         self.stateful = self.adapter == "paged" and self.spec.stateful
+        # the cache's kind: K/V pools by head, or ONE pool of latent rows
+        # (what keeps keys and values by head refuses such a model by name)
+        self.latent = self.adapter == "paged" and self.spec.latent
+        self.n_pools = 1 if self.latent else 2
         # int32 counters behind the tokens of a program's first result
         self.stats_len = 2 if self.adapter == "paged" and self.spec.n_moe \
             else 0
@@ -224,6 +228,11 @@ class GenerationProgramSet:
                     "model-sharded decode requires the paged (transformer) "
                     "adapter — the recurrent-state cache has no head axis "
                     "to split")
+            if self.latent:
+                raise LatentDecodeUnsupportedError(
+                    "model-sharded decode is refused for a model with a "
+                    f"latent cache ({self.spec.attn_names}): the pools "
+                    "shard by head, and a latent row has none")
             if not self.spec.supports_head_sharding(self.model_shards):
                 raise ValueError(
                     f"n_heads={self.spec.n_heads} does not divide by the "
@@ -265,6 +274,11 @@ class GenerationProgramSet:
                 f"recurrent mixers ({self.spec.recurrent_names}): its "
                 "prefill runs as a decode window, which does not carry "
                 "their state")
+        if self.kv_quantized and self.latent:
+            raise LatentDecodeUnsupportedError(
+                "kv_cache_dtype='int8' is refused for a model with a latent "
+                f"cache ({self.spec.attn_names}): the int8 tier quantizes "
+                "keys and values by head")
         # speculative decoding: active iff a draft model is attached
         self.draft_net = draft_net
         self.spec_k = 0
@@ -277,6 +291,11 @@ class GenerationProgramSet:
                     f"recurrent mixers ({self.spec.recurrent_names}): the "
                     "verify window does not carry their state, and a "
                     "rejected proposal could not be taken back out of it")
+            if self.latent:
+                raise LatentDecodeUnsupportedError(
+                    "speculative decoding is refused for a model with a "
+                    f"latent cache ({self.spec.attn_names}): the verify "
+                    "program and the dense draft cache keep K/V pairs")
             if self.adapter != "paged":
                 raise ValueError(
                     "speculative decoding requires a paged (transformer) "
@@ -290,6 +309,10 @@ class GenerationProgramSet:
                 raise StatefulDecodeUnsupportedError(
                     "a draft with recurrent mixers is refused: the dense "
                     "draft cache keeps K/V alone")
+            if da == "paged" and self.draft_spec.latent:
+                raise LatentDecodeUnsupportedError(
+                    "a draft with a latent cache is refused: the dense "
+                    "draft cache keeps K/V pairs")
             if self.draft_spec.vocab != self.vocab:
                 raise ValueError(
                     f"draft vocab {self.draft_spec.vocab} != target vocab "
@@ -349,9 +372,10 @@ class GenerationProgramSet:
             raise ValueError(f"unknown adapter {adapter!r}")
         # a ComputationGraph with attention layers vs a MultiLayerNetwork
         # recurrent stack: by the kind of the layers, not by their names
-        from ...nn.layers import SelfAttentionLayer
+        from ...nn.layers import LatentAttentionLayer, SelfAttentionLayer
         if hasattr(net, "vertex_names") and any(
-                isinstance(getattr(v, "layer_conf", None), SelfAttentionLayer)
+                isinstance(getattr(v, "layer_conf", None),
+                           (SelfAttentionLayer, LatentAttentionLayer))
                 for v in net.vertices):
             return "paged"
         return "state"
@@ -372,7 +396,8 @@ class GenerationProgramSet:
 
     def make_cache(self):
         """Fresh cache pytree: (k_pool, v_pool) for the paged adapter —
-        pools for the model's attention layers and key-value heads — with
+        pools for the model's attention layers and key-value heads, or the
+        one pool ``(pool,)`` of a latent cache — with
         the recurrent mixers' per-slot state behind them where the model
         has any; the zeroed recurrent-state carry (decode_slots + 1 rows,
         last row is the prefill-padding trash slot) for the state
@@ -382,7 +407,8 @@ class GenerationProgramSet:
             cache = make_pools(self.spec.n_blocks, c.num_blocks,
                                c.block_len, self.spec.kv_heads,
                                self.spec.head_dim, self.dtype,
-                               quantized=self.kv_quantized)
+                               quantized=self.kv_quantized,
+                               latent=self.latent)
             sh = self._pool_sharding()
             if sh is not None:
                 cache = jax.tree.map(lambda a: jax.device_put(a, sh), cache)
@@ -407,7 +433,8 @@ class GenerationProgramSet:
 
     def kv_bytes_per_token(self) -> Optional[float]:
         """Block-pool device bytes per token SLOT (K + V, all layers/
-        heads) — the capacity-per-byte currency the quantized tier
+        heads; a latent cache's one row a layer, as laid out) — the
+        capacity-per-byte currency the quantized tier
         moves; published as ``generation.<m>.kv_bytes_per_token``.
         None for the state adapter (no token-addressed pool)."""
         if self.adapter != "paged":
@@ -417,7 +444,14 @@ class GenerationProgramSet:
             per_head = s.head_dim * 1 + 4          # int8 codes + f32 scale
         else:
             per_head = s.head_dim * jnp.dtype(self.dtype).itemsize
-        return float(2 * s.n_blocks * s.kv_heads * per_head)
+        return float(self.n_pools * s.n_blocks * s.kv_heads * per_head)
+
+    def cache_row_bytes(self) -> Optional[int]:
+        """Bytes of ONE layer's cache row of one token as laid out (every
+        pool): what a decode step reads per live row and layer."""
+        per_token = self.kv_bytes_per_token()
+        return None if per_token is None else int(per_token
+                                                  // self.spec.n_blocks)
 
     def recurrent_state_bytes(self) -> int:
         """Device bytes of the recurrent mixers' per-slot state (every
@@ -471,7 +505,7 @@ class GenerationProgramSet:
             if self._trace_hook is not None:
                 self._trace_hook()
             if self.adapter == "paged":
-                k_pool, v_pool = cache[:2]
+                pools = cache[:self.n_pools]
                 # the head runs on the one row of each prompt that is
                 # sampled from, selected before it: [P, d], not [P, L, d]
                 rows = lengths - 1
@@ -499,13 +533,15 @@ class GenerationProgramSet:
                     last, ks, vs, states, stats = spec.prefill_full(
                         params, state, tokens, rows,
                         lengths if self.stateful or self.stats_len else None)
-                out = (prefill_scatter(k_pool, ks, tables),
-                       prefill_scatter(v_pool, vs, tables))
+                # K and V, or a latent cache's one pool of rows (``ks``)
+                out = tuple(prefill_scatter(pool, kv, tables)
+                            for pool, kv in zip(pools, (ks, vs)))
                 if self.stateful:
                     # beside the pools, in the slots' rows (padding rows
                     # carry slot S: the trash row)
-                    out += (cache[2].at[:, slots].set(
-                        jnp.stack(states).astype(cache[2].dtype)),)
+                    rec = cache[self.n_pools]
+                    out += (rec.at[:, slots].set(
+                        jnp.stack(states).astype(rec.dtype)),)
                 tok, key = sample_tokens(last, key, temp, topk)
                 if stats is not None:
                     # the counters ride back behind the tokens
@@ -530,8 +566,10 @@ class GenerationProgramSet:
             if self._trace_hook is not None:
                 self._trace_hook()
             if self.adapter == "paged":
-                store = PagedStore(cache[0], cache[1], tables, pos, active,
-                                   blk, cache[2] if self.stateful else None)
+                store = PagedStore(
+                    cache[0], None if self.latent else cache[1], tables, pos,
+                    active, blk,
+                    cache[self.n_pools] if self.stateful else None)
                 logits, stats = spec.decode_step_stats(
                     params, state, tokens, pos, store,
                     active if self.stats_len else None)
@@ -574,12 +612,12 @@ class GenerationProgramSet:
         return jax.ShapeDtypeStruct(k.shape, k.dtype)
 
     def _cow_fn(self):
-        from .kvcache import cow_copy
+        n = self.n_pools
 
         def fn(cache, src, dst):
             if self._trace_hook is not None:
                 self._trace_hook()
-            return cow_copy(cache[0], cache[1], src, dst) + tuple(cache[2:])
+            return cow_copy(cache[:n], src, dst) + tuple(cache[n:])
         return fn
 
     def _spec_fns(self):

@@ -517,10 +517,14 @@ class ModelRuntime:
             temp[i] = r.temperature
             topk[i] = r.top_k
         self._phase("admit_batch", t_phase)
-        live_tokens = int(lengths[:len(cands)].sum())
+        plens = lengths[:len(cands)].astype(np.int64)
+        live_tokens = int(plens.sum())
         with span("generation.prefill", model=self.name, batch=len(cands),
                   rung=L, rows=P, tokens=live_tokens,
                   padded_tokens=P * L,
+                  # keys causal attention needs a layer: row t of a prompt
+                  # sees t + 1 of them
+                  attn_key_rows=int((plens * (plens + 1) // 2).sum()),
                   head_rows=coh.ps.head_rows.get((P, L)),
                   sampled=int(np.count_nonzero(temp > 0.0))) as sp:
             first, coh.cache, self._key = coh.ps.run_prefill(
@@ -684,7 +688,9 @@ class ModelRuntime:
             attrs = {"live_tokens": int(seen.sum()),
                      "gathered_tokens": S * cfg.capacity
                      if coh.ps.kv_quantized
-                     else int((-(-seen // blk) * blk).sum())}
+                     else int((-(-seen // blk) * blk).sum()),
+                     # one layer's row of one token, as the pools lay it out
+                     "cache_row_bytes": coh.ps.cache_row_bytes()}
         # a slot keeps its last request's temperature after it finishes,
         # and another cohort's slots are not this step's: only the live
         # rows may decide whether the program's sampler draws

@@ -124,6 +124,20 @@ def test_flash_attention_fwd_bwd_compiles(v5e, B, H, T, D, dtype, backward):
     assert n == (3 if backward else 1)  # forward, dq pass, dk/dv pass
 
 
+@pytest.mark.parametrize("T", [6144, 16384])
+def test_flash_forward_with_a_value_size_of_its_own_compiles(v5e, T):
+    """kanana2-serve-longdoc's prefills: scores over 192 values a head
+    (128 + a rotary part of 64), values of 128, one whole prompt of its
+    smallest and its largest rung, 6 and 16 resident blocks a side."""
+    text = _compiled_text(
+        v5e, lambda q, k, v: pallas_attention.flash_attention(
+            q, k, v, causal=True, scale=192 ** -0.5),
+        ((1, 32, T, 192), bf16), ((1, 32, T, 192), bf16),
+        ((1, 32, T, 128), bf16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert pallas_attention.FWD_NAME in _instruction_names(text)
+
+
 def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):
     """Mosaic calls cannot be partitioned automatically: a program over
     four devices lowers the kernels only because flash_attention splits
@@ -172,11 +186,33 @@ def test_paged_attention_decode_compiles(v5e, S, H, W, Dh, blk, mb, L, dtype):
     assert not pallas_paged_attention.KERNEL_NAME[-1].isdigit()
 
 
+@pytest.mark.parametrize("S,Hq,W,row,lanes,blk,mb,L,dtype", [
+    (4, 4, 1, 128, 64, 8, 6, 2, f32),        # the CPU tests' shape
+    (40, 32, 1, 640, 512, 64, 272, 6, bf16),  # kanana2-serve-longdoc
+    (48, 32, 1, 640, 512, 128, 136, 6, bf16)])
+def test_paged_attention_over_a_latent_pool_compiles(v5e, S, Hq, W, row,
+                                                     lanes, blk, mb, L, dtype):
+    """One pool of rows with no head axis, one DMA a page, values the
+    first lanes of the key rows, under its own name in the program (and so
+    in a device trace)."""
+    text = _compiled_text(
+        v5e, lambda q, pool, t, n: pallas_paged_attention.
+        paged_attention_decode(q, pool, None, L - 1, t, n, scale=192 ** -0.5,
+                               value_lanes=lanes),
+        ((S, Hq, W, row), dtype), ((L, S * mb + 1, blk, row), dtype),
+        ((S, mb), jnp.int32), ((S,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    name = pallas_paged_attention.LATENT_KERNEL_NAME
+    assert name in _instruction_names(text) and not name[-1].isdigit()
+
+
 @pytest.mark.parametrize("N,d,F,E,k,dtype", [
     (40, 128, 256, 8, 2, f32),               # the parity pin
     (32, 2048, 1536, 64, 4, bf16),           # lfm2moe-serve-extract: decode,
     (512, 2048, 1536, 64, 4, bf16),          # its smallest prefill
-    (8192, 2048, 1536, 64, 4, bf16)])        # and its largest (4 x 2048)
+    (8192, 2048, 1536, 64, 4, bf16),         # and its largest (4 x 2048)
+    (40, 2048, 768, 128, 6, bf16),           # kanana2-serve-longdoc: decode
+    (16384, 2048, 768, 128, 6, bf16)])       # and its largest prefill
 def test_moe_experts_compile(v5e, monkeypatch, N, d, F, E, k, dtype):
     """The grouped gated matmul's two kernels lower for the chip under
     stable names (the benchmark's roofline metric reads them by name)."""

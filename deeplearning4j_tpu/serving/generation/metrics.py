@@ -41,6 +41,10 @@ class GenerationMetrics:
         self.prefill_rows = 0
         self.decode_steps = 0
         self.decode_slot_steps = 0              # active slots summed per step
+        # the decode pipeline: steps launched while the device still held
+        # an unread one, and tokens a late ``stop`` threw away
+        self.decode_steps_overlapped = 0
+        self.overrun_tokens_dropped = 0
         self.finished: Dict[str, int] = {}
         self.rejected: Dict[str, int] = {"full": 0, "exhausted": 0,
                                          "draining": 0, "deadline": 0,
@@ -127,10 +131,19 @@ class GenerationMetrics:
     def record_decode_step(self, step_ms: float, active_slots: int,
                            emitted: int, *, slots: int,
                            blocks_used: int, blocks_total: int,
-                           queue_depth: int) -> None:
+                           queue_depth: int, overlapped: int = 0,
+                           overrun: int = 0) -> None:
+        """One decode step, where it is READ. ``step_ms`` is the loop's
+        pass that read it (the launch of the next step and this step's
+        read-back), not launch-to-result: with a step always in flight
+        it is the interval at which steps complete. ``overlapped``: the
+        device still held an unread step when this one was launched;
+        ``overrun``: tokens of slots a ``stop`` had already finished."""
         now = time.monotonic()
         with self._lock:
             self.decode_steps += 1
+            self.decode_steps_overlapped += overlapped
+            self.overrun_tokens_dropped += overrun
             self.decode_slot_steps += active_slots
             self.tokens_out += emitted
             self._step_ms.append(step_ms)
@@ -140,6 +153,11 @@ class GenerationMetrics:
         reg = self.registry
         if reg.enabled:
             reg.counter(f"generation.{self.name}.decode_steps").inc()
+            # incremented by 0 too: both read 0 from the first step on
+            reg.counter(f"generation.{self.name}."
+                        "decode_steps_overlapped").inc(overlapped)
+            reg.counter(f"generation.{self.name}."
+                        "overrun_tokens_dropped").inc(overrun)
             reg.counter(f"generation.{self.name}.tokens_out").inc(emitted)
             reg.histogram(
                 f"generation.{self.name}.decode_step_ms").observe(step_ms)
@@ -359,6 +377,8 @@ class GenerationMetrics:
                 "prefills": self.prefills,
                 "prefill_rows": self.prefill_rows,
                 "decode_steps": self.decode_steps,
+                "decode_steps_overlapped": self.decode_steps_overlapped,
+                "overrun_tokens_dropped": self.overrun_tokens_dropped,
                 "ttft_ms": {"p50": round(_percentile(ttft, 0.50), 3),
                             "p99": round(_percentile(ttft, 0.99), 3)},
                 "decode_step_ms": {"p50": round(_percentile(step, 0.50), 3),

@@ -11,7 +11,10 @@ every program the steady-state loop can ever need is lowered and compiled at
   - one **decode-step** executable: one token per in-flight slot, scatter
     the step's K/V, attend to the slot's pages in place through the block
     table (``ops.pallas_paged_attention``), sample the next token — cache
-    buffers donated so the pool updates in place.
+    buffers donated so the pool updates in place. A row's token comes
+    from the host where the host knows it and else from the step before,
+    as that step left it on the device: the loop launches a step before
+    it has read the last one (``launch_decode`` / ``read_decode``).
 
 Params/state are arguments, not constants, so hot-swap reuses executables
 exactly as the forward-serving ProgramSet does (``with_params_from``).
@@ -170,18 +173,31 @@ def _head_rows(jaxpr, vocab: int) -> int:
     return count(live)
 
 
-def _launch_and_read(program: str, exe, *args):
-    """Call a compiled executable and read its FIRST result back, the two
-    halves of the blocking ``generation.prefill`` / ``decode_step`` /
-    ``verify`` span the caller holds open: ``generation.dispatch`` is the
-    host-to-device transfer of the arguments and the launch (the call
-    returns with the results still pending), ``generation.readback`` the
-    ``np.asarray`` that blocks until the device has them."""
+def _launch(program: str, exe, *args):
+    """Call a compiled executable: ``generation.dispatch`` is the
+    host-to-device transfer of the arguments and the launch. The call
+    returns with the results still pending; the copy of the FIRST one to
+    the host is asked for at once, so that ``_read`` finds it done."""
     with span("generation.dispatch", program=program):
         first, *rest = exe(*args)
-    with span("generation.readback", program=program):
-        first = np.asarray(first)
+        first.copy_to_host_async()
     return (first, *rest)
+
+
+def _read(program: str, first) -> np.ndarray:
+    """``generation.readback``: the ``np.asarray`` that blocks until the
+    device has a launch's first result."""
+    with span("generation.readback", program=program):
+        return np.asarray(first)
+
+
+def _launch_and_read(program: str, exe, *args):
+    """Launch and read the first result back, the two halves of the
+    blocking ``generation.prefill`` / ``verify`` span the caller holds
+    open (a ``generation.decode_step`` span holds the launch of one step
+    and the read of the step before: ``launch_decode``)."""
+    first, *rest = _launch(program, exe, *args)
+    return (_read(program, first), *rest)
 
 
 class GenerationProgramSet:
@@ -361,6 +377,9 @@ class GenerationProgramSet:
         self.head_rows: Dict[Tuple[int, int], int] = {}
         if self.adapter == "state":
             self._init_states = self.spec.init_states(config.decode_slots + 1)
+        # what a decode step takes for "the step before" when there is none
+        self._no_prev = np.zeros(config.decode_slots + self.stats_len,
+                                 np.int32)
 
     @staticmethod
     def _resolve_adapter(net, adapter: str) -> str:
@@ -561,10 +580,15 @@ class GenerationProgramSet:
     def _decode_fn(self):
         spec, blk = self.spec, self.config.block_len
 
-        def fn(params, state, cache, tokens, pos, tables, active, key,
-               temp, topk):
+        def fn(params, state, cache, tokens, prev, host_known, pos, tables,
+               active, key, temp, topk):
             if self._trace_hook is not None:
                 self._trace_hook()
+            # a row's token: the host's where the host knows it (a slot's
+            # first token after its prefill, a replayed prompt token), else
+            # what the step before sampled, never read by the host before
+            # this launch (its counters ride behind the tokens)
+            tokens = jnp.where(host_known, tokens, prev[:tokens.shape[0]])
             if self.adapter == "paged":
                 store = PagedStore(
                     cache[0], None if self.latent else cache[1], tables, pos,
@@ -575,7 +599,9 @@ class GenerationProgramSet:
                     active if self.stats_len else None)
                 tok, key = sample_tokens(logits, key, temp, topk)
                 if stats is not None:
-                    tok = jnp.concatenate([tok, stats])
+                    # in the tokens' own type: the next step takes this
+                    # array back as ``prev``
+                    tok = jnp.concatenate([tok, stats.astype(tok.dtype)])
                 return tok, store.cache, key
             S = tokens.shape[0]
             cur = jax.tree.map(lambda c: c[:S], cache)
@@ -687,6 +713,8 @@ class GenerationProgramSet:
         self._compiled[("decode",)] = self._aot(
             decode, _DONATE_CACHE, self.params, self.state, cache_spec,
             jax.ShapeDtypeStruct((S,), i32),
+            jax.ShapeDtypeStruct((S + self.stats_len,), i32),
+            jax.ShapeDtypeStruct((S,), jnp.bool_),
             jax.ShapeDtypeStruct((S,), i32),
             jax.ShapeDtypeStruct((S, mb), i32),
             jax.ShapeDtypeStruct((S,), jnp.bool_),
@@ -861,18 +889,34 @@ class GenerationProgramSet:
                                 cache, tokens, lengths, tables, slots, key,
                                 temp, topk)
 
+    def launch_decode(self, cache, tokens, prev, host_known, pos, tables,
+                      active, key, temp, topk):
+        """Launch one decode step and return (next_tokens ON THE DEVICE
+        [S + stats_len], cache', key') without waiting for it. A row's
+        token is ``tokens`` where ``host_known``, else the row of ``prev``:
+        the step before's first result as it left the device (None: every
+        row is the host's). ``read_decode`` reads the tokens back."""
+        if prev is None:
+            prev = self._no_prev
+        return _launch("decode", self._exe(("decode",)), self.params,
+                       self.state, cache, tokens, prev, host_known, pos,
+                       tables, active, key, temp, topk)
+
+    @staticmethod
+    def read_decode(first) -> np.ndarray:
+        """Block until a launched step's tokens (and counters,
+        ``split_stats``) are on the host."""
+        return _read("decode", first)
+
     def run_decode(self, cache, tokens, pos, tables, active, key, temp,
                    topk):
-        """Returns (next_tokens np [S], cache', key'); a model with expert
-        layers appends its counters to the tokens (``split_stats``)."""
-        exe = self._compiled.get(("decode",))
-        if exe is None:
-            from ..errors import ServingError
-            raise ServingError("no warmed decode program — call warm() "
-                               "before serving")
-        return _launch_and_read("decode", exe, self.params, self.state,
-                                cache, tokens, pos, tables, active, key,
-                                temp, topk)
+        """One blocking decode step on the host's tokens. Returns
+        (next_tokens np [S], cache', key'); a model with expert layers
+        appends its counters to the tokens (``split_stats``)."""
+        first, cache, key = self.launch_decode(
+            cache, tokens, None, np.ones(tokens.shape, np.bool_), pos,
+            tables, active, key, temp, topk)
+        return self.read_decode(first), cache, key
 
     def _exe(self, key):
         exe = self._compiled.get(key)

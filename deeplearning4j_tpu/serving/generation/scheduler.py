@@ -4,11 +4,27 @@ admission, per-token streams, and cohort-pinned hot-swap.
 One dispatch thread per model owns the decode loop:
 
     loop:  admit (bucketed prefill for queued requests, into free slots)
-           -> one decode step per live cohort (ALL in-flight sequences
-              advance one token)
-           -> emit tokens to per-request TokenStreams, retire finished
-              slots (stop token / max_tokens / deadline / cancel), which
-              frees their cache blocks for the next admission
+           -> per live cohort, LAUNCH decode step k+1 (ALL in-flight
+              sequences advance one token), then READ step k
+           -> emit step k's tokens to per-request TokenStreams, retire
+              finished slots (stop token / max_tokens / deadline /
+              cancel), which frees their cache blocks for the next
+              admission
+
+The decode pipeline is one step deep: a cohort keeps at most one launched
+and unread step between passes (``_Cohort.unread``), so the host's
+dispatch and emission run while the device works and the device's next
+step is queued when the current one ends. Step k+1 takes its tokens from
+step k's result ON THE DEVICE, except the rows whose token the host knows
+(a slot's first token after its prefill, a prefix hit's replayed prompt
+token: ``_host_known``). Positions advance at the launch. A slot whose
+last token by count is in flight gives its slot and pages back at that
+launch (device order queues whatever reuses them behind the step that
+still reads them) and is delivered at the read; a ``stop`` token is seen
+one step late and the token the slot ran over is dropped at the next read
+and counted. Deadline and cancellation are host facts and act at the
+read. A cohort that speculates keeps the synchronous order (launch k,
+read k): ``_spec_step`` reads the host's tokens.
 
 The loop's host phases between the blocking program spans are recorded as
 complete events of category ``phase`` (``generation.admit_batch``,
@@ -112,8 +128,8 @@ class _GenRequest:
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "stop",
                  "deadline", "stream", "slot", "blocks", "shared_blocks",
                  "replay", "replaying", "matched_tokens", "spec", "emitted",
-                 "cancelled", "cancel_reason", "enqueue_t", "cohort",
-                 "trace_id")
+                 "unread", "cancelled", "cancel_reason", "enqueue_t",
+                 "cohort", "trace_id")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: int, stop: frozenset, deadline: float,
@@ -137,6 +153,7 @@ class _GenRequest:
         self.spec = bool(speculative) and temperature <= 0.0
         self.cohort = None                  # set at admission
         self.emitted = 0
+        self.unread = 0        # tokens of its launched steps still to read
         self.cancelled = False
         self.cancel_reason = "cancelled"
         self.enqueue_t = time.monotonic()
@@ -149,13 +166,33 @@ class _GenRequest:
         self.cancelled = True
 
 
+# what the read of a launched step does with a slot's sampled token
+_EMIT = 0           # a generated token
+_REPLAY = 1         # a prompt token was fed mid-replay: the sample is dropped
+_REPLAY_LAST = 2    # the final prompt token was fed: a hit's FIRST token
+
+
+class _Step:
+    """A launched decode step whose tokens the host has not read: the
+    device's result, the (slot, request, what to do with its token)
+    triples live at the launch, and the step's span attributes."""
+    __slots__ = ("tokens", "pairs", "attrs")
+
+    def __init__(self, tokens, pairs, attrs):
+        self.tokens = tokens
+        self.pairs = pairs
+        self.attrs = attrs
+
+
 class _Cohort:
     """In-flight sequences pinned to one program set (one model version):
     their cache pool, block allocator, block tables, prefix cache and
     draft cache live and die with the cohort — shared prefix K/V and draft
-    proposals can never cross a hot-swap boundary."""
+    proposals can never cross a hot-swap boundary. ``unread`` is the
+    cohort's launched and unread decode step between two passes of the
+    loop (None: nothing of its is in flight)."""
     __slots__ = ("ps", "cache", "allocator", "tables", "slots", "version",
-                 "prefix", "draft_cache")
+                 "prefix", "draft_cache", "unread")
 
     def __init__(self, ps: GenerationProgramSet, version: int):
         self.ps = ps
@@ -169,6 +206,7 @@ class _Cohort:
             PrefixCache(self.allocator, ps.config.block_len)
             if ps.prefix_enabled else None)
         self.draft_cache = ps.make_draft_cache()
+        self.unread: Optional[_Step] = None
 
 
 class ModelRuntime:
@@ -189,7 +227,11 @@ class ModelRuntime:
         self._cond = threading.Condition()
         self._slots_free: Set[int] = set(range(S))
         self._slot_req: Dict[int, _GenRequest] = {}
+        # released at the launch of their last step, delivered at its read
+        self._early: Set[_GenRequest] = set()
         self._tokens = np.zeros(S, np.int32)
+        # rows whose next token is the host's (``_tokens``), not the device's
+        self._host_known = np.ones(S, np.bool_)
         self._pos = np.zeros(S, np.int32)
         self._temp = np.zeros(S, np.float32)
         self._topk = np.zeros(S, np.int32)
@@ -211,7 +253,17 @@ class ModelRuntime:
 
     @property
     def in_flight(self) -> int:
-        return len(self._slot_req)
+        """Requests admitted and not finished: those that hold a slot and
+        those whose last token is launched and still to be delivered."""
+        return len(self._slot_req) + len(self._early)
+
+    def _admitted(self) -> List[_GenRequest]:
+        """Every request admitted and not finished (under ``_cond``)."""
+        return list(self._slot_req.values()) + list(self._early)
+
+    def _work_left(self) -> bool:
+        return bool(self._queue or self._slot_req
+                    or any(c.unread is not None for c in self._cohorts))
 
     @property
     def draining(self) -> bool:
@@ -231,12 +283,12 @@ class ModelRuntime:
             else cfg.num_blocks
         m = self.metrics
         lookups = m.prefix_hits + m.prefix_misses
-        in_flight = len(self._slot_req)
         return {
             "queue_depth": len(self._queue),
-            "in_flight": in_flight,
+            "in_flight": self.in_flight,
             "decode_slots": cfg.decode_slots,
-            "slot_occupancy": round(in_flight / cfg.decode_slots, 4),
+            "slot_occupancy": round(len(self._slot_req) / cfg.decode_slots,
+                                    4),
             "block_len": cfg.block_len,
             "blocks_total": cfg.num_blocks,
             "block_pool_free_frac": (round(free / cfg.num_blocks, 4)
@@ -318,7 +370,7 @@ class ModelRuntime:
                 with self._cond:
                     if self._stopped:
                         break
-                    if not self._queue and not self._slot_req:
+                    if not self._work_left():
                         # one event per idle PERIOD, not per 20 ms wake-up:
                         # an idle engine must not fill the trace ring
                         if idle_t0 is None:
@@ -553,6 +605,7 @@ class ModelRuntime:
             self._pos[s] = len(r.prompt)
             self._temp[s] = r.temperature
             self._topk[s] = r.top_k
+            self._host_known[s] = True      # the first token, read just now
             if coh.prefix is not None:
                 self.metrics.record_prefix_miss()
                 # the prompt's full blocks are immutable from here on:
@@ -613,6 +666,7 @@ class ModelRuntime:
             self._temp[s] = r.temperature
             self._topk[s] = r.top_k
             self._tokens[s] = int(r.prompt[start])
+            self._host_known[s] = True
             self._active[s] = True
             r.replay = deque(int(t) for t in r.prompt[start + 1:])
             r.replaying = True
@@ -644,11 +698,9 @@ class ModelRuntime:
                                                    lengths, slots)
 
     def _step(self):
-        cfg = self.config
-        S = cfg.decode_slots
         for coh in list(self._cohorts):
             live = [s for s in sorted(coh.slots) if self._active[s]]
-            if not live:
+            if not live and coh.unread is None:
                 continue
             # speculative slots (greedy, past replay) advance through
             # draft-propose + one batched verify; everything else —
@@ -659,24 +711,103 @@ class ModelRuntime:
                      if not spec_on or not self._slot_req[s].spec
                      or self._slot_req[s].replaying]
             specs = [s for s in live if s not in set(plain)]
-            if plain:
+            if plain or coh.unread is not None:
                 self._plain_step(coh, plain)
             if specs:
                 self._spec_step(coh, specs)
         if self._det is not None:
             self.metrics.record_recompile(self._det.count)
-        # drop drained cohorts (old params/pools released)
+        # drop drained cohorts (old params/pools released); one whose last
+        # step is still to be read has not drained
         self._cohorts = [c for c in self._cohorts
-                         if c.slots or c.ps is self.active_ps]
+                         if c.slots or c.unread is not None
+                         or c.ps is self.active_ps]
         if not self._slot_req:
             self._check_quiesce()
 
     def _plain_step(self, coh: _Cohort, live: List[int]):
+        """One pass of a cohort's decode pipeline: launch the next step
+        for ``live``, THEN read the step launched a pass ago and emit it.
+        The ``generation.decode_step`` span covers the pass and carries
+        the attributes of the step it READS. With nothing unread (the
+        first step after an idle period or a drain; every step of a
+        cohort that speculates) the step to read is launched here first,
+        and a speculating cohort launches nothing behind it."""
+        step, coh.unread = coh.unread, None
+        with span("generation.decode_step", model=self.name) as sp:
+            if step is None:
+                step = self._launch_step(coh, live, None)
+                live = [s for s in live if s in coh.slots]
+            if live and not coh.ps.spec_k:
+                coh.unread = self._launch_step(coh, live, step)
+            for k, v in step.attrs.items():
+                sp.set_attr(k, v)
+            nxt, stats = coh.ps.split_stats(
+                coh.ps.read_decode(step.tokens))
+            if stats is not None:
+                # the experts' routing arrives with the read, so all of a
+                # step's attributes sit on the one span
+                sp.set_attr("moe_pairs", len(step.pairs) * coh.ps.spec.n_moe
+                            * coh.ps.spec.moe_top_k)
+                sp.set_attr("experts_touched", int(stats[1]))
+        t_phase = time.perf_counter()
+        dt_ms = sp.dur_ms
+        now = time.monotonic()
+        emitted = overrun = 0
+        for s, r, what in step.pairs:
+            if what != _REPLAY:
+                r.unread -= 1
+            if r.stream.done:
+                # finished at the read before this one, by a fact the
+                # host learned after this step's launch: the token the
+                # slot ran over is dropped (its write lies inside the
+                # slot's own reserved pages, at a position no one reads)
+                if what != _REPLAY and r.stream.finish_reason == "stop":
+                    overrun += 1
+                continue
+            if r.trace_id is not None:
+                # one event per decode step the request participated
+                # in — the per-request timeline's heartbeat
+                event("generation.decode_step", trace_id=r.trace_id,
+                      model=self.name, slot=s, token_index=r.emitted,
+                      step_ms=round(dt_ms, 3))
+            if what == _REPLAY:
+                # a mid-prompt prediction: discarded (the next prompt
+                # token was teacher-forced at the launch)
+                self._closed_by_host(coh, r, now, "while replaying the "
+                                     "prompt suffix")
+                continue
+            if what == _REPLAY_LAST:
+                self.metrics.record_cached_first_token(
+                    (now - r.enqueue_t) * 1e3)
+            did_emit, cont = self._slot_emit(coh, r, int(nxt[s]), now)
+            emitted += did_emit
+            if cont and coh.unread is None:
+                # nothing launched behind this step: the next one feeds
+                # this token from the host
+                self._host_known[s] = True
+        self.metrics.record_decode_step(
+            dt_ms, len(step.pairs), emitted,
+            slots=self.config.decode_slots,
+            blocks_used=coh.allocator.used_blocks,
+            blocks_total=coh.allocator.total_usable,
+            queue_depth=len(self._queue),
+            overlapped=step.attrs["overlapped"], overrun=overrun)
+        self._phase("emit", t_phase)
+
+    def _launch_step(self, coh: _Cohort, live: List[int],
+                     prev: Optional[_Step]) -> _Step:
+        """Launch one decode step for ``live`` behind ``prev`` (the
+        cohort's unread step, whose tokens the rows the host does not know
+        take on the device) and advance the host's state to where the
+        NEXT launch starts: positions, replayed prompt tokens, and the
+        slots whose last token by count this step samples."""
         cfg = self.config
         S = cfg.decode_slots
         mask = np.zeros(S, np.bool_)
         mask[live] = True
-        attrs = {}
+        # ``overlapped``: the device still held an unread step at this launch
+        attrs = {"slots": len(live), "overlapped": int(prev is not None)}
         if coh.ps.adapter == "paged":
             # what the step attends to against what it reads per layer:
             # each live slot's valid positions (this step's included), and
@@ -685,91 +816,61 @@ class ModelRuntime:
             # slot's whole table
             seen = self._pos[live] + 1
             blk = cfg.block_len
-            attrs = {"live_tokens": int(seen.sum()),
-                     "gathered_tokens": S * cfg.capacity
-                     if coh.ps.kv_quantized
-                     else int((-(-seen // blk) * blk).sum()),
-                     # one layer's row of one token, as the pools lay it out
-                     "cache_row_bytes": coh.ps.cache_row_bytes()}
+            attrs.update(
+                live_tokens=int(seen.sum()),
+                gathered_tokens=S * cfg.capacity if coh.ps.kv_quantized
+                else int((-(-seen // blk) * blk).sum()),
+                # one layer's row of one token, as the pools lay it out
+                cache_row_bytes=coh.ps.cache_row_bytes())
         # a slot keeps its last request's temperature after it finishes,
         # and another cohort's slots are not this step's: only the live
         # rows may decide whether the program's sampler draws
         temp = np.where(mask, self._temp, np.float32(0.0))
-        with span("generation.decode_step", model=self.name,
-                  slots=len(live), sampled=int(np.count_nonzero(temp > 0.0)),
-                  **attrs) as sp:
-            nxt, coh.cache, self._key = coh.ps.run_decode(
-                coh.cache, self._tokens, self._pos, coh.tables, mask,
-                self._key, temp, self._topk)
-            nxt, stats = coh.ps.split_stats(nxt)
-            if stats is not None:
-                sp.set_attr("moe_pairs", len(live) * coh.ps.spec.n_moe
-                            * coh.ps.spec.moe_top_k)
-                sp.set_attr("experts_touched", int(stats[1]))
-        t_phase = time.perf_counter()
-        dt_ms = sp.dur_ms
-        now = time.monotonic()
-        emitted = 0
+        attrs["sampled"] = int(np.count_nonzero(temp > 0.0))
+        # the launch may still read a host array after it returns, and
+        # this loop writes these before the step has run: hand it copies
+        tokens, coh.cache, self._key = coh.ps.launch_decode(
+            coh.cache, self._tokens.copy(),
+            None if prev is None else prev.tokens, self._host_known.copy(),
+            self._pos.copy(), coh.tables.copy(), mask, self._key, temp,
+            self._topk.copy())
+        pairs = []
         for s in live:
             r = self._slot_req[s]
-            if r.trace_id is not None:
-                # one event per decode step the request participated
-                # in — the per-request timeline's heartbeat
-                event("generation.decode_step", trace_id=r.trace_id,
-                      model=self.name, slot=s, token_index=r.emitted,
-                      step_ms=round(dt_ms, 3))
-            if r.replaying:
-                emitted += self._replay_advance(coh, r, int(nxt[s]), now)
+            self._pos[s] += 1        # whatever the token's value
+            if r.replay:
+                self._tokens[s] = r.replay.popleft()    # stays the host's
+                pairs.append((s, r, _REPLAY))
                 continue
-            did_emit, cont = self._slot_emit(coh, r, int(nxt[s]), now)
-            emitted += did_emit
-            if cont:
-                self._pos[s] += 1
-        self.metrics.record_decode_step(
-            dt_ms, len(live), emitted, slots=S,
-            blocks_used=coh.allocator.used_blocks,
-            blocks_total=coh.allocator.total_usable,
-            queue_depth=len(self._queue))
-        self._phase("emit", t_phase)
+            r.unread += 1
+            self._host_known[s] = False
+            what = _EMIT
+            if r.replaying:
+                what = _REPLAY_LAST
+                self._replay_done(coh, r)
+            pairs.append((s, r, what))
+            if r.emitted + r.unread >= r.max_new:
+                # the token in flight is the slot's last by count: the
+                # next launch leaves it out and its slot and pages go
+                # back NOW (what reuses them is queued behind this step);
+                # the stream finishes where the token is delivered
+                self._release(coh, r, early=True)
+        return _Step(tokens, pairs, attrs)
 
-    def _replay_advance(self, coh: _Cohort, r: "_GenRequest", sampled: int,
-                        now: float) -> int:
-        """One replay step for a cache-hit admission: the decode program
-        just fed prompt[pos]. While suffix tokens remain the sample is a
-        mid-prompt prediction — discarded, teacher-force the next prompt
-        token. The step that fed the FINAL prompt token produced the first
-        generated token: record the cached TTFT and emit. Returns tokens
-        emitted (0 or 1)."""
-        s = r.slot
-        if r.cancelled or now > r.deadline:
-            if r.cancelled:
-                err = GenerationClosedError("engine stopped mid-generation") \
-                    if r.cancel_reason == "shutdown" else None
-                self._finish_slot(coh, r, r.cancel_reason, err)
-            else:
-                self._finish_slot(coh, r, "deadline", DeadlineExceededError(
-                    "deadline expired while replaying the prompt suffix"))
-            return 0
-        if r.replay:
-            self._tokens[s] = r.replay.popleft()
-            self._pos[s] += 1
-            return 0
+    def _replay_done(self, coh: _Cohort, r: "_GenRequest") -> None:
+        """The step that feeds a cache hit's FINAL prompt token is
+        launched: full prompt blocks beyond the matched span are valid for
+        whatever is queued behind it. Index them so the NEXT request
+        extends the cached chain."""
         r.replaying = False
-        self.metrics.record_cached_first_token(
-            (now - r.enqueue_t) * 1e3)
-        if coh.prefix is not None:
-            # full prompt blocks beyond the matched span are now valid:
-            # index them so the NEXT request extends the cached chain
-            managed = coh.prefix.register(r.prompt, coh.tables[s], r.blocks)
-            if managed:
-                drop = set(managed)
-                r.blocks = [b for b in r.blocks if b not in drop]
-                r.shared_blocks.extend(managed)
-            self.metrics.set_prefix_gauges(coh.prefix.stats())
-        did_emit, cont = self._slot_emit(coh, r, sampled, now)
-        if cont:
-            self._pos[s] += 1
-        return did_emit
+        if coh.prefix is None:
+            return
+        managed = coh.prefix.register(r.prompt, coh.tables[r.slot], r.blocks)
+        if managed:
+            drop = set(managed)
+            r.blocks = [b for b in r.blocks if b not in drop]
+            r.shared_blocks.extend(managed)
+        self.metrics.set_prefix_gauges(coh.prefix.stats())
 
     def _spec_step(self, coh: _Cohort, specs: List[int]):
         """Draft proposes k tokens per slot; ONE batched target pass
@@ -832,14 +933,16 @@ class ModelRuntime:
         self._phase("emit", t_phase)
 
     def _check_quiesce(self):
-        """Block-accounting invariant at quiesce (no in-flight requests):
-        every allocated block is exactly a cached block (refcounted owner
+        """Block-accounting invariant at quiesce (no in-flight requests;
+        an unread step counts as in flight): every allocated block is
+        exactly a cached block (refcounted owner
         refs are gone, so cached == prefix index incl. its LRU). A
         violation is a leak or a double-custody bug — fail loudly (the
         loop's defensive except turns this into _fail_all + a flight
         dump) rather than serving corrupt shared state."""
         for coh in self._cohorts:
-            if coh.ps.adapter != "paged" or coh.slots:
+            if coh.ps.adapter != "paged" or coh.slots \
+                    or coh.unread is not None:
                 continue
             alloc = set(coh.allocator.allocated)
             cached = (coh.prefix.cached_block_ids()
@@ -854,18 +957,9 @@ class ModelRuntime:
                    now: float):
         """Handle one sampled token for a slot: emit/terminate. Returns
         (emitted, continuing)."""
-        if r.cancelled:
-            # a shutdown-cancel must surface as an ERROR to blocking
-            # callers (engine stopped under them); a consumer cancel is a
-            # normal close
-            err = GenerationClosedError("engine stopped mid-generation") \
-                if r.cancel_reason == "shutdown" else None
-            return self._finish_slot(coh, r, r.cancel_reason, err)
-        if now > r.deadline:
-            return self._finish_slot(
-                coh, r, "deadline",
-                DeadlineExceededError("deadline expired mid-generation "
-                                      f"after {r.emitted} tokens"))
+        if self._closed_by_host(coh, r, now, "mid-generation after "
+                                f"{r.emitted} tokens"):
+            return (0, False)
         if tok in r.stop:
             return self._finish_slot(coh, r, "stop")
         r.stream._put(tok)
@@ -877,15 +971,46 @@ class ModelRuntime:
         self._active[r.slot] = True
         return (1, True)
 
+    def _closed_by_host(self, coh: _Cohort, r: _GenRequest, now: float,
+                        where: str) -> bool:
+        """Cancellation and the deadline are facts of the host: they act
+        where a step is read. True when one of them finished ``r``."""
+        if r.cancelled:
+            # a shutdown-cancel must surface as an ERROR to blocking
+            # callers (engine stopped under them); a consumer cancel is a
+            # normal close
+            err = GenerationClosedError("engine stopped mid-generation") \
+                if r.cancel_reason == "shutdown" else None
+            self._finish_slot(coh, r, r.cancel_reason, err)
+            return True
+        if now > r.deadline:
+            self._finish_slot(coh, r, "deadline", DeadlineExceededError(
+                f"deadline expired {where}"))
+            return True
+        return False
+
     def _finish_slot(self, coh: _Cohort, r: _GenRequest, reason: str,
                      error: Optional[BaseException] = None):
-        s = r.slot
+        """Deliver the end of a request's stream and, unless the launch of
+        its last step already did, give its slot and pages back."""
         r.stream._finish(reason, error)
         if r.trace_id is not None:
             event("generation.finish", trace_id=r.trace_id,
-                  model=self.name, slot=s, reason=reason,
+                  model=self.name, slot=r.slot, reason=reason,
                   tokens=r.emitted)
         self.metrics.record_finish(reason)
+        if r in self._early:
+            with self._cond:
+                self._early.discard(r)
+                self._cond.notify_all()
+        else:
+            self._release(coh, r)
+        return (0, False)
+
+    def _release(self, coh: _Cohort, r: _GenRequest, early: bool = False):
+        """Give a request's slot and pages back. ``early``: at the launch
+        of its last step, with the token still to be delivered."""
+        s = r.slot
         if r.blocks:
             coh.allocator.free(r.blocks)
             r.blocks = []
@@ -902,12 +1027,16 @@ class ModelRuntime:
         with self._cond:
             del self._slot_req[s]
             self._slots_free.add(s)
+            if early:
+                self._early.add(r)
             self._cond.notify_all()
-        return (0, False)
 
     def _fail_all(self, exc: BaseException):
         """A dispatch-side failure must resolve every caller (the batcher
-        contract): fail queued + in-flight, release blocks/slots.
+        contract): fail queued + in-flight (the callers of an unread step
+        whose slot went back at its launch among them: a device error
+        surfaces at the read of step k with k+1 launched, and fails
+        both), release blocks/slots.
         Iterates ``_slot_req`` (not cohort slot sets) so requests whose
         PREFILL raised — admitted but never added to a cohort's slots —
         are failed too instead of hanging their callers. Every cohort is
@@ -918,7 +1047,7 @@ class ModelRuntime:
         with self._cond:
             queued = list(self._queue)
             self._queue.clear()
-            reqs = list(self._slot_req.values())
+            reqs = self._admitted()
         in_flight = len(reqs)
         for r in queued:
             r.stream._finish("error", exc)
@@ -938,7 +1067,7 @@ class ModelRuntime:
         with self._cond:
             queued = list(self._queue)
             self._queue.clear()
-            reqs = list(self._slot_req.values())
+            reqs = self._admitted()
         for r in queued:
             r.stream._finish("shutdown", err)
             self.metrics.record_finish("shutdown")
@@ -961,13 +1090,13 @@ class ModelRuntime:
                     r.stream._finish("shutdown", DrainingError(
                         f"model '{self.name}' shut down before admission"))
                 self._queue.clear()
-                for r in self._slot_req.values():
+                for r in self._admitted():
                     r.cancelled = True
                     r.cancel_reason = "shutdown"
             self._cond.notify_all()
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline and \
-                (self._queue or self._slot_req):
+                (self._queue or self.in_flight):
             time.sleep(0.005)
         with self._cond:
             self._stopped = True

@@ -415,13 +415,13 @@ def test_block_pool_exhaustion_and_queue_taxonomy():
         # slow decode down so r1 deterministically holds its blocks for
         # the whole submit sequence below (un-slowed it finishes in ms)
         rt = eng._get("lm")
-        orig_decode = rt.active_ps.run_decode
+        orig_decode = rt.active_ps.launch_decode    # the loop's launch
 
         def slow_decode(*a, **k):
             time.sleep(0.01)
             return orig_decode(*a, **k)
 
-        rt.active_ps.run_decode = slow_decode
+        rt.active_ps.launch_decode = slow_decode
         # r1 takes both usable blocks (plen 2 + 14 new = 16 = 2 blocks)
         s1 = eng.generate([1, 2], max_tokens=14, stream=True)
         # wait until r1 is admitted (blocks held) before probing the queue
@@ -748,11 +748,13 @@ def test_program_spans_split_into_dispatch_and_readback(pair_events, parent,
         inner = [e for e in _named(pair_events, child, ph="X", cat="span")
                  if e["args"]["program"] == program]
         assert len(inner) == calls
-        for o, i in zip(outer, inner):
-            # nested in the parent: by path, and in time
+        for i in inner:
+            # nested in a parent: by path, and in time (a decode span
+            # holds the launch of one step and the read of the step
+            # before, so a launch need not sit in its own step's span)
             assert i["args"]["path"] == parent + "/" + child
-            assert o["ts"] <= i["ts"]
-            assert i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1
+            assert [o for o in outer if o["ts"] <= i["ts"] and
+                    i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1]
     # launch first, then the blocking read
     d, r = (_named(pair_events, n)[0] for n in
             ("generation.dispatch", "generation.readback"))
@@ -979,7 +981,8 @@ def test_greedy_branch_draws_no_random_bits(head_rows_set, which):
                 np.zeros(P, np.float32), np.zeros(P, np.int32))
         fn = ps._prefill_fn()
     else:
-        args = (np.zeros(S, np.int32), np.zeros(S, np.int32),
+        args = (np.zeros(S, np.int32), np.zeros(S + ps.stats_len, np.int32),
+                np.ones(S, np.bool_), np.zeros(S, np.int32),
                 np.zeros((S, mb), np.int32), np.ones(S, np.bool_), key,
                 np.zeros(S, np.float32), np.zeros(S, np.int32))
         fn = ps._decode_fn()
@@ -995,3 +998,322 @@ def test_greedy_branch_draws_no_random_bits(head_rows_set, which):
     assert {"random_bits", "scan"} <= draw and "sort" not in draw
     # the split that carries the key on is outside the branch
     assert "random_split" in {e.primitive.name for e in jaxpr.eqns}
+
+
+# ------------------- the decode pipeline, one step deep (ISSUE 41): the
+# loop launches step k+1 before it reads step k, k+1 takes its tokens
+# from k's result on the device, and a slot whose last token by count is
+# in flight goes back at that launch
+def _pipe_lm():
+    return _lm(seed=41, vocab=47, d_model=16, n_blocks=1, max_length=64)
+
+
+@pytest.fixture(scope="module")
+def pipe_lm():
+    """One engine for the pipeline's read-only tests: 3 slots, a pool that
+    holds two whole sequences beside the prefix cache's blocks."""
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=3, prefill_batches=(1, 2),
+                           prompt_rungs=(64,))
+    yield net, TransformerDecodeSpec(net), eng
+    eng.stop()
+
+
+def _steps_since(seq0):
+    return [e for e in get_registry().trace_events_since(seq0)
+            if e["name"] == "generation.decode_step" and e["ph"] == "X"]
+
+
+def _settle(eng):
+    rt = eng._get("lm")
+    deadline = time.monotonic() + 10.0
+    while rt._work_left():
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    time.sleep(0.02)
+
+
+@pytest.mark.parametrize("replay", ["miss", "partial_hit", "aligned_hit"])
+def test_pipelined_greedy_tokens_are_the_full_recomputes(pipe_lm, replay):
+    """More requests than slots, output lengths 1-17 mixed, so that steps
+    hold slots at their first, middle and last token at once, admissions
+    land behind an unread step and slots are reused at once: every token
+    is the cache-free recompute's (what the synchronous loop served).
+    With a hit, prompts replay their suffix through host-known rows."""
+    net, spec, eng = pipe_lm
+    seed = {"miss": 411, "partial_hit": 412, "aligned_hit": 413}[replay]
+    base = _prompts(47, (16,), seed=seed)[0]
+    if replay == "miss":
+        prompts = _prompts(47, (3, 5, 9, 12, 17, 20, 6), seed=seed)
+    else:
+        # the first request leaves two full blocks behind; the others
+        # match them and replay 1-5 prompt tokens (aligned: the whole
+        # prompt is cached, its last block is copied on write)
+        tails = (0, 0, 0) if replay == "aligned_hit" else (1, 3, 5)
+        assert eng.generate(base, max_tokens=2)[0] == \
+            naive_generate(net, base, 2, pad_to=64, spec=spec)
+        prompts = [base + _prompts(47, (n,), seed=seed + n)[0]
+                   if n else list(base) for n in tails] * 2
+    lengths = [1, 2, 17, 3, 9, 1, 5][:len(prompts)]
+    refs = [naive_generate(net, p, n, pad_to=64, spec=spec)
+            for p, n in zip(prompts, lengths)]
+    hits0 = eng.metrics()["lm"]["prefix"]["hits"]
+    streams = [eng.generate(p, max_tokens=n, stream=True)
+               for p, n in zip(prompts, lengths)]
+    for st, want in zip(streams, refs):
+        assert st.result() == (want, "length")
+    _settle(eng)
+    snap = eng.metrics()["lm"]
+    assert (snap["prefix"]["hits"] - hits0 > 0) == (replay != "miss")
+    assert eng.models()["lm"]["in_flight"] == 0
+    eng._get("lm")._check_quiesce()
+
+
+def test_overlapped_is_0_on_the_first_step_after_idle_and_1_after(pipe_lm):
+    """One request, five tokens: four decode steps, the first launched
+    with nothing unread, each later one behind its predecessor."""
+    _, _, eng = pipe_lm
+    _settle(eng)
+    names = ("generation.lm.decode_steps_overlapped",
+             "generation.lm.overrun_tokens_dropped")
+    counted = lambda: [get_registry().snapshot()["counters"][n]
+                       for n in names]
+    seq0 = get_registry().last_seq
+    before, counted0 = eng.metrics()["lm"], counted()
+    toks, _ = eng.generate(_prompts(47, (7,), seed=415)[0], max_tokens=5)
+    _settle(eng)
+    steps = _steps_since(seq0)
+    assert [s["args"]["overlapped"] for s in steps] == [0, 1, 1, 1]
+    assert all(s["args"]["slots"] == 1 for s in steps)
+    after = eng.metrics()["lm"]
+    assert after["decode_steps"] - before["decode_steps"] == 4
+    assert after["decode_steps_overlapped"] \
+        - before["decode_steps_overlapped"] == 3
+    # the registry's counters move with them (every engine of this
+    # process called "lm" counts there: deltas)
+    assert [b - a for a, b in zip(counted0, counted())] == [3, 0]
+
+
+@pytest.mark.parametrize("budget,overrun", [(8, 1), (4, 0)])
+def test_a_stop_token_is_seen_one_step_late_and_the_overrun_dropped(
+        pipe_lm, budget, overrun):
+    """The fourth token is a stop token. With budget left the slot was in
+    the next launch when the host saw it: neither the stop token nor the
+    token the slot ran over is emitted, and the overrun is counted. Where
+    the stop token is the slot's last by count, nothing ran over."""
+    net, spec, eng = pipe_lm
+    for seed in range(4160, 4260):       # a prompt whose fourth token is
+        prompt = _prompts(47, (6,), seed=seed)[0]       # new to its output
+        greedy = naive_generate(net, prompt, 8, pad_to=64, spec=spec)
+        if greedy[3] not in greedy[:3]:
+            break
+    stop = greedy[3]
+    _settle(eng)
+    before = eng.metrics()["lm"]["overrun_tokens_dropped"]
+    toks, reason = eng.generate(prompt, max_tokens=budget, stop=[stop])
+    assert (toks, reason) == (greedy[:3], "stop")
+    _settle(eng)
+    assert eng.metrics()["lm"]["overrun_tokens_dropped"] - before == overrun
+    # the slot and its pages are back, and serve the next request rightly
+    assert eng.generate(prompt, max_tokens=8)[0] == greedy
+    eng._get("lm")._check_quiesce()
+
+
+@pytest.fixture(scope="module")
+def last_step_watch():
+    """One slot, pages for one sequence: A (4 tokens: a prefill and three
+    decode steps) and B are submitted together, so B can only run in A's
+    slot and pages. Every read of a decode step notes, on the loop's own
+    thread, what the host held at that moment."""
+    net = _pipe_lm()
+    spec = TransformerDecodeSpec(net)
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=32,
+                           decode_slots=1, prefill_batches=(1,),
+                           prompt_rungs=(32,), num_blocks=3,
+                           prefix_cache=False)
+    rt = eng._get("lm")
+    ps = rt.active_ps
+    seen, checks = [], []
+    orig, orig_check = ps.read_decode, rt._check_quiesce
+
+    def watching(first):
+        coh = rt._cohorts[-1]
+        seen.append({"slot_req": len(rt._slot_req), "early": len(rt._early),
+                     "free_blocks": coh.allocator.free_blocks,
+                     "slots_free": len(rt._slots_free),
+                     "in_flight": rt.in_flight,
+                     "prefills": rt.metrics.prefills})
+        return orig(first)
+
+    def checking():
+        # the loop's own call, at the end of a pass that left no request
+        # in a slot
+        checks.append({"early": len(rt._early),
+                       "unread": [c.unread is not None
+                                  for c in rt._cohorts],
+                       "work_left": rt._work_left()})
+        orig_check()
+
+    ps.read_decode, rt._check_quiesce = watching, checking
+    a, b = _prompts(47, (5, 7), seed=418)
+    want = [naive_generate(net, p, n, pad_to=32, spec=spec)
+            for p, n in ((a, 4), (b, 6))]
+    with rt._cond:
+        streams = [eng.generate(a, max_tokens=4, stream=True),
+                   eng.generate(b, max_tokens=6, stream=True)]
+    got = [st.result() for st in streams]
+    _settle(eng)
+    yield seen, got, want, checks
+    eng.stop()
+
+
+def test_a_length_finish_frees_slot_and_pages_at_its_last_launch(
+        last_step_watch):
+    seen, _, _, _ = last_step_watch
+    # read 1 (step 1; steps 1 and 2 are launched): A holds slot and pages
+    assert (seen[0]["slot_req"], seen[0]["early"]) == (1, 0)
+    assert seen[0]["free_blocks"] == 0 and seen[0]["slots_free"] == 0
+    # read 2 (step 2): step 3, A's last by count, is launched, and slot
+    # and pages went back at that launch, before any token of it is read
+    assert (seen[1]["slot_req"], seen[1]["early"]) == (0, 1)
+    assert seen[1]["free_blocks"] == 2 and seen[1]["slots_free"] == 1
+    # ... but A still counts as in flight until its last token is read
+    assert seen[1]["in_flight"] == 1
+
+
+def test_the_next_request_decodes_rightly_in_the_freed_slot_and_pages(
+        last_step_watch):
+    seen, got, want, _ = last_step_watch
+    assert got == [(want[0], "length"), (want[1], "length")]
+    # B's prefill ran BEFORE A's last token was read: read 3 is step 3's,
+    # and B already holds the slot and both pages
+    assert seen[2]["prefills"] == 2
+    assert (seen[2]["slot_req"], seen[2]["early"]) == (1, 1)
+    assert seen[2]["free_blocks"] == 0
+    assert len(seen) == 3 + 5
+
+
+def test_quiesce_holds_with_a_last_token_still_unread(last_step_watch):
+    """The pass that launched A's last step ends with no request in a
+    slot (B is still queued) and A's last token unread: the loop's
+    accounting check runs and passes (an unread step counts as in flight)
+    and the loop does not go idle. The last check finds nothing left."""
+    _, _, _, checks = last_step_watch
+    assert checks[0] == {"early": 1, "unread": [True], "work_left": True}
+    assert checks[-1] == {"early": 0, "unread": [False], "work_left": False}
+
+
+def _engine_with_an_unread_last_token(on_read):
+    """Two slots; A's last token by count (of 3) and B's 3rd of 30 are in
+    one unread step when ``on_read`` runs in place of its read, on the
+    loop's thread: A's slot is back already, B's is held."""
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=2, prefill_batches=(2,),
+                           prompt_rungs=(64,))
+    rt = eng._get("lm")
+    ps = rt.active_ps
+    orig = ps.read_decode
+    reads = {"n": 0}
+
+    def reading(first):
+        reads["n"] += 1
+        if reads["n"] == 2:                 # step 2: A's last
+            assert len(rt._early) == 1 and len(rt._slot_req) == 1
+            on_read()
+        return orig(first)
+
+    ps.read_decode = reading
+    a, b = _prompts(47, (5, 7), seed=419)
+    with rt._cond:
+        streams = [eng.generate(a, max_tokens=3, stream=True),
+                   eng.generate(b, max_tokens=30, stream=True)]
+    return net, eng, streams
+
+
+def test_a_failing_step_fails_the_callers_of_the_unread_step_too():
+    """The device's error surfaces at a read with the next step launched:
+    the request whose slot went back at its last launch, the one that
+    holds a slot, and the step behind all fail; the engine recovers."""
+    def boom():
+        raise RuntimeError("injected device failure")
+
+    net, eng, streams = _engine_with_an_unread_last_token(boom)
+    try:
+        for st in streams:
+            toks, reason = st.result(raise_on_error=False)   # must NOT hang
+            assert reason == "error" and isinstance(st.error, RuntimeError)
+            assert 1 <= len(toks) <= 2
+        _settle(eng)
+        rt = eng._get("lm")
+        assert rt.in_flight == 0 and not rt._early
+        assert len(rt._slots_free) == 2
+        p = _prompts(47, (6,), seed=420)[0]
+        assert eng.generate(p, max_tokens=5)[0] == naive_generate(
+            net, p, 5, pad_to=64, spec=TransformerDecodeSpec(net))
+        assert eng.metrics()["lm"]["finished"].get("error") == 2
+    finally:
+        eng.stop()
+
+
+def test_stop_without_drain_resolves_the_callers_of_the_unread_step():
+    """``stop(drain=False)`` arrives while the loop is in the read of a
+    step that holds A's last token: both streams end as ``shutdown``,
+    A's too, whose slot had gone back."""
+    at_read, go = threading.Event(), threading.Event()
+
+    def wait():
+        at_read.set()
+        assert go.wait(10.0)
+
+    _, eng, streams = _engine_with_an_unread_last_token(wait)
+    assert at_read.wait(10.0)
+    stopper = threading.Thread(
+        target=lambda: eng.stop(drain=False, timeout=10.0))
+    stopper.start()
+    rt = eng._get("lm")
+    deadline = time.monotonic() + 10.0
+    while not rt.draining:
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    time.sleep(0.02)                 # stop() has marked what is in flight
+    go.set()
+    stopper.join(20.0)
+    assert not stopper.is_alive()
+    for st in streams:
+        toks, reason = st.result(raise_on_error=False)
+        assert reason == "shutdown" and len(toks) <= 2
+    assert rt.in_flight == 0
+
+
+def test_a_speculating_cohort_keeps_the_synchronous_order():
+    """A program set that speculates reads the host's tokens in
+    ``_spec_step``: its plain steps (a request that opts out, a sampling
+    one) launch nothing behind the step they read."""
+    net = _lm(seed=43)
+    spec = TransformerDecodeSpec(net)
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=2, prefill_batches=(1,),
+                           prompt_rungs=(64,), draft=truncated_draft(net, 1),
+                           spec_k=3)
+    try:
+        rt = eng._get("lm")
+        assert rt.active_ps.spec_k == 3
+        p, q = _prompts(53, (6, 9), seed=421)
+        seq0 = get_registry().last_seq
+        streams = [eng.generate(p, max_tokens=9, stream=True,
+                                speculative=False),
+                   eng.generate(q, max_tokens=9, stream=True)]
+        assert [st.result()[0] for st in streams] == [
+            naive_generate(net, x, 9, pad_to=64, spec=spec) for x in (p, q)]
+        _settle(eng)
+        steps = _steps_since(seq0)
+        assert len(steps) == 8              # the opted-out request's
+        assert all(s["args"]["overlapped"] == 0 for s in steps)
+        assert all(c.unread is None for c in rt._cohorts)
+        snap = eng.metrics()["lm"]
+        assert snap["decode_steps_overlapped"] == 0
+        assert snap["speculative"]["verify_steps"] >= 1
+    finally:
+        eng.stop()

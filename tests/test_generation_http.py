@@ -123,13 +123,13 @@ def test_http_generation_error_taxonomy():
         # submit sequence (the un-slowed window is a few ms — flaky under
         # suite load).
         rt = eng._get("lm")
-        orig_decode = rt.active_ps.run_decode
+        orig_decode = rt.active_ps.launch_decode    # the loop's launch
 
         def slow_decode(*a, **k):
             time.sleep(0.01)
             return orig_decode(*a, **k)
 
-        rt.active_ps.run_decode = slow_decode
+        rt.active_ps.launch_decode = slow_decode
         results = {}
 
         def bg(i):

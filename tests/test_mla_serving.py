@@ -208,6 +208,50 @@ def test_prefill_at_a_padded_rung_then_twenty_decode_steps(toy):
     assert res["tokens"] == 42 and res["widest_gap"] <= 1e-3, res
 
 
+def test_a_row_fed_from_the_device_gives_the_logits_of_the_host_fed_token(
+        toy, monkeypatch):
+    """The decode program takes a row's token from the step before, as it
+    left the device, where the host does not know it (ISSUE 41): the
+    step's LOGITS for such a row are those for the same token fed from
+    the host, and the rows written to the latent pages are the same. The
+    program as traced, with the sampler handing the logits through (and
+    no counters behind them)."""
+    from deeplearning4j_tpu.serving.generation import programs
+    net, _ = toy
+    cfg = GenerationConfig(block_len=8, max_seq_len=CAP, decode_slots=3,
+                           prompt_rungs=(32,), prefill_batches=(2,))
+    ps = GenerationProgramSet(net, config=cfg)
+    ps.stats_len = 0
+    monkeypatch.setattr(programs, "sample_tokens",
+                        lambda logits, key, temp, topk: (logits, key))
+    step = jax.jit(ps._decode_fn())
+    S, mb = 3, cfg.blocks_per_seq
+    tables = np.zeros((S, mb), np.int32)
+    for i in range(S):
+        tables[i] = 1 + i * mb + np.arange(mb)
+    toks = np.asarray([17, 201, 5], np.int32)
+    pos = np.asarray([0, 3, 9], np.int32)
+    rest = (pos, tables, np.ones(S, np.bool_), ps.fresh_key(),
+            np.zeros(S, np.float32), np.zeros(S, np.int32))
+    host, (pool_h,), _ = step(ps.params, ps.state, ps.make_cache(), toks,
+                              np.zeros(S, np.int32), np.ones(S, np.bool_),
+                              *rest)
+    # rows 0 and 2 from the device (the host's copy of them is stale), row
+    # 1 from the host (the device's is another token)
+    prev = jnp.asarray([17, 99, 5], jnp.int32)
+    dev, (pool_d,), _ = step(ps.params, ps.state, ps.make_cache(),
+                             np.asarray([3, 201, 250], np.int32), prev,
+                             np.asarray([False, True, False]), *rest)
+    assert host.shape == (S, TOY["vocab_size"])
+    assert jnp.array_equal(host, dev) and jnp.array_equal(pool_h, pool_d)
+    # and the host's stale copy, had it been fed, gives other logits
+    stale, _, _ = step(ps.params, ps.state, ps.make_cache(),
+                       np.asarray([3, 201, 250], np.int32), prev,
+                       np.ones(S, np.bool_), *rest)
+    assert not jnp.array_equal(stale[0], host[0])
+    assert jnp.array_equal(stale[1], host[1])
+
+
 # ------------------------------------------------------------ the kernels
 @pytest.mark.parametrize("W", [1, 3])
 def test_the_latent_paged_kernel_is_the_dense_gather(W):
@@ -449,15 +493,21 @@ LFM2 = {
     "hyperparameters": TOY["hyperparameters"], "precision": TOY["precision"],
 }
 # sha256 (first 16 hex digits) of the jaxpr text of each program as traced
-# at commit 7936418 (PR 40's parent) by this very function under this
-# suite's conftest (x64 on): what this PR
-# adds to the kernels, the stores and the specification are new cases, and
-# the calls these two families make trace to what they traced to before
+# by this very function under this suite's conftest (x64 on). The two
+# ``prefill`` hashes are those of commit 7936418 (PR 40's parent): what PR
+# 40 added to the kernels, the stores and the specification are new cases,
+# and the calls these two families make trace to what they traced to
+# before. The two ``decode`` hashes were taken anew at PR 41 (parent
+# a13c243), which MEANT to change the program: it takes the step before's
+# result and a mask of the rows the host knows beside ``tokens`` and
+# selects between them on ``[S]`` (and hands lfm2's counters back in the
+# tokens' own type, so that the result can be fed to the next step);
+# nothing else of the step's text differs from the parent's
 PARENT_PROGRAMS = {
     ("gpt2", "prefill"): "c5416f549b9a0e76",
-    ("gpt2", "decode"): "9efe110173668cef",
+    ("gpt2", "decode"): "0f7b970879784f8b",
     ("lfm2", "prefill"): "0da8642ba6a73b96",
-    ("lfm2", "decode"): "bd0db33b499dd30f",
+    ("lfm2", "decode"): "46c233dfc02c1801",
 }
 
 
@@ -475,8 +525,9 @@ def _program_text(net, which):
             sds((P,), i32))
     else:
         jaxpr = jax.make_jaxpr(ps._decode_fn())(
-            ps.params, ps.state, cache, sds((S,), i32), sds((S,), i32),
-            sds((S, mb), i32), sds((S,), jnp.bool_), key,
+            ps.params, ps.state, cache, sds((S,), i32),
+            sds((S + ps.stats_len,), i32), sds((S,), jnp.bool_),
+            sds((S,), i32), sds((S, mb), i32), sds((S,), jnp.bool_), key,
             sds((S,), jnp.float32), sds((S,), i32))
     return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 

@@ -296,10 +296,11 @@ class GraphDecodeSpec:
         """Pre-activation logits of the head over the final norm's output:
         y [B,T,d] -> [B,T,V]."""
         head_v = self._v[self.head_name]
-        if head_v.preprocessor is not None:
-            y = head_v.preprocessor.apply(y)
-        return head_v.layer_conf.pre_output(self._p(params, self.head_name),
-                                            y)
+        with jax.named_scope(self.head_name):
+            if head_v.preprocessor is not None:
+                y = head_v.preprocessor.apply(y)
+            return head_v.layer_conf.pre_output(
+                self._p(params, self.head_name), y)
 
     def logits_at(self, params, y, rows):
         """The head on ONE position of each sequence: y [B,T,d] is the
@@ -397,28 +398,30 @@ class GraphDecodeSpec:
             if name == self.head_name:
                 continue
             ins = self._inputs[name]
-            if name == self.embed_name:
-                out = self.embed_tokens(params, tokens)
-            elif name == self.pos_name:
-                P = self._p(params, name)["P"]
-                out = v.layer_conf.act(
-                    acts[ins[0]] + P[jnp.clip(w_pos, 0, P.shape[0] - 1)])
-            elif name in self._attn_i:
-                out = self._attend(params, name, acts[ins[0]], w_pos, store,
-                                   window)
-            elif name in self._rec_j:
-                if window:
-                    raise StatefulDecodeUnsupportedError(
-                        f"a decode window does not carry {name}'s state")
-                j = self._rec_j[name]
-                out, new = v.apply_with_final_state(
-                    self._p(params, name), state[self._idx[name]],
-                    [acts[ins[0]]], train=False, rng=None,
-                    initial_state=store.state(j))
-                store.set_state(j, new)
-            else:
-                out = self._apply(params, state, name,
-                                  [acts[i] for i in ins])
+            # under the vertex's name, as the graph's own forward runs it
+            with jax.named_scope(name):
+                if name == self.embed_name:
+                    out = self.embed_tokens(params, tokens)
+                elif name == self.pos_name:
+                    P = self._p(params, name)["P"]
+                    out = v.layer_conf.act(
+                        acts[ins[0]] + P[jnp.clip(w_pos, 0, P.shape[0] - 1)])
+                elif name in self._attn_i:
+                    out = self._attend(params, name, acts[ins[0]], w_pos,
+                                       store, window)
+                elif name in self._rec_j:
+                    if window:
+                        raise StatefulDecodeUnsupportedError(
+                            f"a decode window does not carry {name}'s state")
+                    j = self._rec_j[name]
+                    out, new = v.apply_with_final_state(
+                        self._p(params, name), state[self._idx[name]],
+                        [acts[ins[0]]], train=False, rng=None,
+                        initial_state=store.state(j))
+                    store.set_state(j, new)
+                else:
+                    out = self._apply(params, state, name,
+                                      [acts[i] for i in ins])
             if live is not None and name in self.moe_names:
                 stats.append(self._moe_stats(params, name, acts[ins[0]],
                                              live))

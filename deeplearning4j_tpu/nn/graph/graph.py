@@ -125,39 +125,45 @@ class ComputationGraph:
             vin = [acts[i] for i in in_names]
             in_mask = next((masks[i] for i in in_names if i in masks), None)
             rng, sub = jax.random.split(rng)
-            if isinstance(v, LastTimeStepVertex):
-                mask = masks.get(v.mask_input) if v.mask_input else in_mask
-                if mask is not None and getattr(vin[0], "ndim", 0) == 3 and \
-                        mask.shape[1] != vin[0].shape[1]:
-                    mask = None   # sequence length changed upstream
-                out, s = v.apply(params[idx], state[idx], vin, train=train,
-                                 rng=sub, mask=mask)
-            elif isinstance(v, DuplicateToTimeSeriesVertex):
-                t = None
-                if v.reference_input is not None:
-                    t = acts[v.reference_input].shape[1]
-                out, s = v.apply(params[idx], state[idx], vin, train=train,
-                                 rng=sub, timesteps=t)
-            elif isinstance(v, LayerVertex) and v.recurrent and \
-                    (collect_rnn_states or (rnn_states is not None
-                                            and rnn_states[idx] is not None)):
-                init = rnn_states[idx] if rnn_states is not None else None
-                out, final = v.apply_with_final_state(
-                    params[idx], state[idx], vin, train=train, rng=sub,
-                    mask=in_mask, initial_state=init)
-                s = state[idx]
-                rnn_out[idx] = final
-            elif isinstance(v, LayerVertex) and \
-                    getattr(self.conf, "gradient_checkpointing", False):
-                fn = jax.checkpoint(
-                    lambda p, s_, xx, key, _v=v, _m=in_mask:
-                    _v.apply(p, s_, xx, train=train, rng=key, mask=_m))
-                out, s = fn(params[idx], state[idx], vin, sub)
-            elif isinstance(v, LayerVertex):
-                out, s = v.apply(params[idx], state[idx], vin, train=train,
-                                 rng=sub, mask=in_mask)
-            else:
-                out, s = v.apply(params[idx], state[idx], vin, train=train, rng=sub)
+            # each vertex under its own name: the name rides every
+            # operation's metadata into the compiled program, where a
+            # device trace reads it (the kernels open theirs inside)
+            with jax.named_scope(name):
+                if isinstance(v, LastTimeStepVertex):
+                    mask = masks.get(v.mask_input) if v.mask_input \
+                        else in_mask
+                    if mask is not None and getattr(vin[0], "ndim", 0) == 3 \
+                            and mask.shape[1] != vin[0].shape[1]:
+                        mask = None   # sequence length changed upstream
+                    out, s = v.apply(params[idx], state[idx], vin, train=train,
+                                     rng=sub, mask=mask)
+                elif isinstance(v, DuplicateToTimeSeriesVertex):
+                    t = None
+                    if v.reference_input is not None:
+                        t = acts[v.reference_input].shape[1]
+                    out, s = v.apply(params[idx], state[idx], vin, train=train,
+                                     rng=sub, timesteps=t)
+                elif isinstance(v, LayerVertex) and v.recurrent and \
+                        (collect_rnn_states
+                         or (rnn_states is not None
+                             and rnn_states[idx] is not None)):
+                    init = rnn_states[idx] if rnn_states is not None else None
+                    out, final = v.apply_with_final_state(
+                        params[idx], state[idx], vin, train=train, rng=sub,
+                        mask=in_mask, initial_state=init)
+                    s = state[idx]
+                    rnn_out[idx] = final
+                elif isinstance(v, LayerVertex) and \
+                        getattr(self.conf, "gradient_checkpointing", False):
+                    fn = jax.checkpoint(
+                        lambda p, s_, xx, key, _v=v, _m=in_mask:
+                        _v.apply(p, s_, xx, train=train, rng=key, mask=_m))
+                    out, s = fn(params[idx], state[idx], vin, sub)
+                elif isinstance(v, LayerVertex):
+                    out, s = v.apply(params[idx], state[idx], vin, train=train,
+                                     rng=sub, mask=in_mask)
+                else:
+                    out, s = v.apply(params[idx], state[idx], vin, train=train, rng=sub)
             acts[name] = out
             new_state.append(s)
             # propagate only while the time axis is unchanged — a vertex that
@@ -225,8 +231,10 @@ class ComputationGraph:
             if cd:
                 head_params = cast_floats(head_params, cd)
                 feed = cast_floats(feed, cd)
-            per_ex = v.layer_conf.compute_loss_per_example(
-                head_params, feed, labels[k], lmasks[k], train=train, rng=sub)
+            with jax.named_scope(out_name):
+                per_ex = v.layer_conf.compute_loss_per_example(
+                    head_params, feed, labels[k], lmasks[k], train=train,
+                    rng=sub)
             if cd:
                 per_ex = per_ex.astype(jnp.dtype(self.conf.dtype))
             lm = lmasks[k]

@@ -57,9 +57,12 @@ def train_step_math(net, params, state, opt_state, it, rng, x, y,
     local grads, so watching costs zero extra dispatches and zero host
     syncs (the watch materializes it on its own worker thread at window
     boundaries). The params/opt math is untouched either way."""
+    # ``loss`` and ``updater`` are the top-level scopes of the step's
+    # operations in a device trace; the graph's vertices nest under ``loss``
     def lf(p):
-        return net.loss_fn(p, state, x, y, train=True, rng=rng,
-                           labels_mask=lmask, features_mask=fmask)
+        with jax.named_scope("loss"):
+            return net.loss_fn(p, state, x, y, train=True, rng=rng,
+                               labels_mask=lmask, features_mask=fmask)
     (loss, new_state), grads = jax.value_and_grad(lf, has_aux=True)(params)
     health = None
     if with_health:
@@ -68,7 +71,8 @@ def train_step_math(net, params, state, opt_state, it, rng, x, y,
     if grad_sync is not None:
         grads = grad_sync(grads)
     update = net.updater.update if update_fn is None else update_fn
-    new_params, new_opt = update(grads, opt_state, params, it)
+    with jax.named_scope("updater"):
+        new_params, new_opt = update(grads, opt_state, params, it)
     if with_health:
         return new_params, new_state, new_opt, loss, health
     return new_params, new_state, new_opt, loss

@@ -23,6 +23,15 @@ from ...telemetry.registry import _percentile
 # loop phase (scheduler.py) -> its histogram and snapshot key
 _PHASE_METRIC = {"admit_batch": "admit_ms", "emit": "emit_ms"}
 
+# what a decode pass or a verify window writes to the registry: the names
+# are built once per ``GenerationMetrics`` (``_step_names``), not by
+# f-string on every pass
+_STEP_KEYS = ("decode_steps", "decode_steps_overlapped",
+              "overrun_tokens_dropped", "tokens_out", "decode_step_ms",
+              "slot_occupancy", "queue_depth", "tokens_per_sec",
+              "spec.verify_steps", "spec.proposed", "spec.accepted",
+              "verify_step_ms", "spec.accepted_per_verify")
+
 
 class GenerationMetrics:
     def __init__(self, window: int = 4096, name: str = "default",
@@ -30,6 +39,7 @@ class GenerationMetrics:
         self._lock = threading.Lock()
         self.name = name
         self._registry = registry
+        self._step_names = {k: f"generation.{name}.{k}" for k in _STEP_KEYS}
         self._ttft_ms = deque(maxlen=window)
         self._step_ms = deque(maxlen=window)
         self._tok_t = deque(maxlen=window)       # emission timestamps
@@ -52,7 +62,6 @@ class GenerationMetrics:
         self.swaps = 0
         self.decode_recompiles = 0
         self.slots = 0
-        self.blocks_total = 0
         self.kv_bytes_per_token = None          # quantized-KV tier (ISSUE 17)
         # prefix-cache economics (ISSUE 14)
         self._ttft_cached_ms = deque(maxlen=window)
@@ -130,7 +139,6 @@ class GenerationMetrics:
 
     def record_decode_step(self, step_ms: float, active_slots: int,
                            emitted: int, *, slots: int,
-                           blocks_used: int, blocks_total: int,
                            queue_depth: int, overlapped: int = 0,
                            overrun: int = 0) -> None:
         """One decode step, where it is READ. ``step_ms`` is the loop's
@@ -149,29 +157,30 @@ class GenerationMetrics:
             self._step_ms.append(step_ms)
             self._tok_t.extend([now] * emitted)
             self.slots = slots
-            self.blocks_total = blocks_total
         reg = self.registry
         if reg.enabled:
-            reg.counter(f"generation.{self.name}.decode_steps").inc()
+            n = self._step_names
+            reg.counter(n["decode_steps"]).inc()
             # incremented by 0 too: both read 0 from the first step on
-            reg.counter(f"generation.{self.name}."
-                        "decode_steps_overlapped").inc(overlapped)
-            reg.counter(f"generation.{self.name}."
-                        "overrun_tokens_dropped").inc(overrun)
-            reg.counter(f"generation.{self.name}.tokens_out").inc(emitted)
-            reg.histogram(
-                f"generation.{self.name}.decode_step_ms").observe(step_ms)
-            reg.gauge(f"generation.{self.name}.slot_occupancy").set(
-                active_slots / slots if slots else 0.0)
-            reg.gauge(f"generation.{self.name}.blocks_in_use").set(
-                blocks_used)
-            reg.gauge(f"generation.{self.name}.queue_depth").set(queue_depth)
-            # throttled: the rate scan over the timestamp ring is not free
-            # and the decode step is the serving hot loop
-            if now - self._rate_t >= 0.5:
-                self._rate_t = now
-                reg.gauge(f"generation.{self.name}.tokens_per_sec").set(
-                    self._recent_tokens_per_sec(now))
+            reg.counter(n["decode_steps_overlapped"]).inc(overlapped)
+            reg.counter(n["overrun_tokens_dropped"]).inc(overrun)
+            reg.counter(n["tokens_out"]).inc(emitted)
+            reg.histogram(n["decode_step_ms"]).observe(step_ms)
+            self._loop_gauges(reg, now, active_slots, slots, queue_depth)
+
+    def _loop_gauges(self, reg, now: float, active_slots: int, slots: int,
+                     queue_depth: int) -> None:
+        """What the fleet's collector steers by, after every pass."""
+        n = self._step_names
+        reg.gauge(n["slot_occupancy"]).set(
+            active_slots / slots if slots else 0.0)
+        reg.gauge(n["queue_depth"]).set(queue_depth)
+        # throttled: the rate scan over the timestamp ring is not free
+        # and the decode step is the serving hot loop
+        if now - self._rate_t >= 0.5:
+            self._rate_t = now
+            reg.gauge(n["tokens_per_sec"]).set(
+                self._recent_tokens_per_sec(now))
 
     # -------------------------------------------------- prefix cache (hits)
     def record_prefix_hit(self, tokens_saved: int) -> None:
@@ -251,8 +260,7 @@ class GenerationMetrics:
     # ------------------------------------------------- speculative decoding
     def record_verify(self, step_ms: float, active_slots: int, *,
                       proposed: int, accepted: int, emitted: int,
-                      slots: int, blocks_used: int, blocks_total: int,
-                      queue_depth: int) -> None:
+                      slots: int, queue_depth: int) -> None:
         """One draft-propose + verify window: ``accepted`` draft tokens
         matched the target's greedy choice; ``emitted`` includes each
         slot's correction token (the per-target-dispatch yield)."""
@@ -269,29 +277,19 @@ class GenerationMetrics:
             self._verify_ms.append(step_ms)
             self._tok_t.extend([now] * emitted)
             self.slots = slots
-            self.blocks_total = blocks_total
             per_verify = (self.spec_emitted / self.verify_slot_steps
                           if self.verify_slot_steps else 0.0)
         reg = self.registry
         if reg.enabled:
-            reg.counter(f"generation.{self.name}.spec.verify_steps").inc()
-            reg.counter(f"generation.{self.name}.spec.proposed").inc(proposed)
-            reg.counter(f"generation.{self.name}.spec.accepted").inc(accepted)
-            reg.counter(f"generation.{self.name}.tokens_out").inc(emitted)
-            reg.histogram(
-                f"generation.{self.name}.verify_step_ms").observe(step_ms)
-            reg.gauge(
-                f"generation.{self.name}.spec.accepted_per_verify").set(
+            n = self._step_names
+            reg.counter(n["spec.verify_steps"]).inc()
+            reg.counter(n["spec.proposed"]).inc(proposed)
+            reg.counter(n["spec.accepted"]).inc(accepted)
+            reg.counter(n["tokens_out"]).inc(emitted)
+            reg.histogram(n["verify_step_ms"]).observe(step_ms)
+            reg.gauge(n["spec.accepted_per_verify"]).set(
                 round(per_verify, 3))
-            reg.gauge(f"generation.{self.name}.slot_occupancy").set(
-                active_slots / slots if slots else 0.0)
-            reg.gauge(f"generation.{self.name}.blocks_in_use").set(
-                blocks_used)
-            reg.gauge(f"generation.{self.name}.queue_depth").set(queue_depth)
-            if now - self._rate_t >= 0.5:
-                self._rate_t = now
-                reg.gauge(f"generation.{self.name}.tokens_per_sec").set(
-                    self._recent_tokens_per_sec(now))
+            self._loop_gauges(reg, now, active_slots, slots, queue_depth)
 
     def record_finish(self, reason: str) -> None:
         with self._lock:

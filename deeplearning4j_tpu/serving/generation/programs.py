@@ -173,21 +173,30 @@ def _head_rows(jaxpr, vocab: int) -> int:
     return count(live)
 
 
-def _launch(program: str, exe, *args):
+def _program_span(name: str, program: str, step: Optional[int]):
+    """A launch's or a read's span. A decode step's carries the step's
+    number (``scheduler._Step``), on the event and as the metadata of its
+    ``TraceAnnotation``: the pair of spans of one step is found by it."""
+    if step is None:
+        return span(name, program=program)
+    return span(name, annotate=("step",), program=program, step=step)
+
+
+def _launch(program: str, exe, *args, step: Optional[int] = None):
     """Call a compiled executable: ``generation.dispatch`` is the
     host-to-device transfer of the arguments and the launch. The call
     returns with the results still pending; the copy of the FIRST one to
     the host is asked for at once, so that ``_read`` finds it done."""
-    with span("generation.dispatch", program=program):
+    with _program_span("generation.dispatch", program, step):
         first, *rest = exe(*args)
         first.copy_to_host_async()
     return (first, *rest)
 
 
-def _read(program: str, first) -> np.ndarray:
+def _read(program: str, first, step: Optional[int] = None) -> np.ndarray:
     """``generation.readback``: the ``np.asarray`` that blocks until the
     device has a launch's first result."""
-    with span("generation.readback", program=program):
+    with _program_span("generation.readback", program, step):
         return np.asarray(first)
 
 
@@ -890,23 +899,25 @@ class GenerationProgramSet:
                                 temp, topk)
 
     def launch_decode(self, cache, tokens, prev, host_known, pos, tables,
-                      active, key, temp, topk):
+                      active, key, temp, topk, step: Optional[int] = None):
         """Launch one decode step and return (next_tokens ON THE DEVICE
         [S + stats_len], cache', key') without waiting for it. A row's
         token is ``tokens`` where ``host_known``, else the row of ``prev``:
         the step before's first result as it left the device (None: every
-        row is the host's). ``read_decode`` reads the tokens back."""
+        row is the host's). ``read_decode`` reads the tokens back. ``step``
+        is the loop's number for the step: it rides the launch's span and
+        the read's."""
         if prev is None:
             prev = self._no_prev
         return _launch("decode", self._exe(("decode",)), self.params,
                        self.state, cache, tokens, prev, host_known, pos,
-                       tables, active, key, temp, topk)
+                       tables, active, key, temp, topk, step=step)
 
     @staticmethod
-    def read_decode(first) -> np.ndarray:
+    def read_decode(first, step: Optional[int] = None) -> np.ndarray:
         """Block until a launched step's tokens (and counters,
         ``split_stats``) are on the host."""
-        return _read("decode", first)
+        return _read("decode", first, step)
 
     def run_decode(self, cache, tokens, pos, tables, active, key, temp,
                    topk):

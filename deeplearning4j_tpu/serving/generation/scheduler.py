@@ -32,6 +32,22 @@ complete events of category ``phase`` (``generation.admit_batch``,
 reader that lines the device's clock up with the host's does so on spans
 that BLOCK on the device, and these cover the rest of the loop.
 
+The loop accounts for itself (ISSUE 42). Every launched decode step has a
+number; the launch's span, the read's and the pass's carry it, so a
+launch is paired with its read. A pass says what it waited for: its
+children's own durations (``launch_ms``, ``read_wait_ms``) and the time
+inside a garbage collection of generation 1 or 2; every sixteenth decode
+pass also samples the loop thread's CPU time and involuntary context
+switches so far (``loop_cpu_ms``, ``nivcsw``: ONE system call, because
+on a sandboxed host each costs microseconds; a prefill or a verify
+window, rare and long, takes its own ``cpu_ms``). A pass far longer than
+its kind's recent median leaves one ``generation.stall`` instant event
+with all of that and the thread's usage since the last sample (at most
+one a second: what an operator pages on). Every request that ends,
+however it ends, leaves ONE ``generation.request`` complete event of
+category ``request`` from its submission to its end, written by the
+thread that ends it.
+
 Admission happens at step boundaries only — a new request never stalls
 in-flight decode, it just lands in the next step's batch (freed slots are
 backfilled from the queue; idle slots ride along masked). All device work
@@ -47,6 +63,8 @@ each step runs one decode program per live cohort.
 """
 from __future__ import annotations
 
+import gc
+import itertools
 import queue as _queue
 import threading
 import time
@@ -55,8 +73,10 @@ from typing import Dict, List, Optional, Sequence, Set
 
 import numpy as np
 
-from ...telemetry import RecompileDetector, record_external_span, span
+from ...telemetry import (RecompileDetector, get_registry,
+                          record_external_span, span)
 from ...telemetry.flightrec import get_flight_recorder
+from ...telemetry.spans import wall_us
 from ...telemetry.tracecontext import current_trace_id, event
 from ..errors import (BlockPoolExhaustedError, DeadlineExceededError,
                       DrainingError, GenerationClosedError, QueueFullError,
@@ -67,13 +87,72 @@ from .prefix import PrefixCache
 from .programs import GenerationProgramSet
 
 
+try:
+    from resource import RUSAGE_THREAD, getrusage
+
+    def _thread_usage():
+        """(CPU milliseconds, involuntary context switches) of the calling
+        thread so far, in one system call."""
+        ru = getrusage(RUSAGE_THREAD)
+        return (ru.ru_utime + ru.ru_stime) * 1e3, ru.ru_nivcsw
+except ImportError:                # a platform without per-thread usage
+    def _thread_usage():
+        return None
+
+# a decode pass in this many samples the loop thread's usage: on the chip
+# tool's host a system call costs 6-7 us alone and about 20 us in the
+# serving process, and a pass of 1.9 ms has 10 us to spare (PERF.md §5)
+_USAGE_EVERY = 16
+
+
+class _GcWatch:
+    """Nanoseconds spent so far inside garbage collections of generation 1
+    or 2, whichever thread ran them (the interpreter lock holds the loop
+    meanwhile): one ``gc.callbacks`` hook, installed with a model's
+    runtime and removed with it. A pass reads ``total_ns`` before and
+    after."""
+
+    def __init__(self):
+        self.total_ns = 0
+        self._t0 = 0
+        gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if not info["generation"]:
+            return
+        if phase == "start":
+            self._t0 = time.perf_counter_ns()
+        elif self._t0:
+            self.total_ns += time.perf_counter_ns() - self._t0
+            self._t0 = 0
+
+    def close(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+
+# a pass stalls where it takes longer than the larger of _STALL_MS and
+# _STALL_X times the median of its kind's last _STALL_HISTORY passes (once
+# the kind has _STALL_LEAST of them); at most one event in _STALL_EVERY_S
+_STALL_MS = 50.0
+_STALL_X = 20.0
+_STALL_HISTORY = 256
+_STALL_LEAST = 8
+_STALL_EVERY_S = 1.0
+
+_request_ids = itertools.count(1)      # unique in the process
+
+
 class TokenStream:
     """Per-request token stream: the scheduler produces, ONE consumer
     iterates (or calls ``result()`` — not both). Always terminates: every
     admitted request is finished with a reason (or failed) exactly once,
-    so iterating callers can never hang."""
+    so iterating callers can never hang. ``request_id`` is the integer the
+    request's ``generation.admit`` and ``generation.request`` events
+    carry."""
 
-    def __init__(self):
+    def __init__(self, request_id: Optional[int] = None):
+        self.request_id = request_id
         self._q: "_queue.Queue" = _queue.Queue()
         self._done = threading.Event()
         self.finish_reason: Optional[str] = None
@@ -129,7 +208,8 @@ class _GenRequest:
                  "deadline", "stream", "slot", "blocks", "shared_blocks",
                  "replay", "replaying", "matched_tokens", "spec", "emitted",
                  "unread", "cancelled", "cancel_reason", "enqueue_t",
-                 "cohort", "trace_id")
+                 "cohort", "trace_id", "id", "queue_ms", "first_t", "last_t",
+                 "steps", "rung", "batch")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: int, stop: frozenset, deadline: float,
@@ -140,7 +220,8 @@ class _GenRequest:
         self.top_k = top_k
         self.stop = stop
         self.deadline = deadline
-        self.stream = TokenStream()
+        self.id = next(_request_ids)
+        self.stream = TokenStream(self.id)
         self.stream._cancel_cb = self._cancel
         self.slot: Optional[int] = None
         self.blocks: List[int] = []          # owned (freed at finish)
@@ -157,6 +238,15 @@ class _GenRequest:
         self.cancelled = False
         self.cancel_reason = "cancelled"
         self.enqueue_t = time.monotonic()
+        # what the request's one record says of its life
+        # (``_record_request``): the wait for its slot, the loop's clock at
+        # its first and last emission, the passes it rode, its prefill
+        self.queue_ms: Optional[float] = None
+        self.first_t: Optional[float] = None
+        self.last_t: Optional[float] = None
+        self.steps = 0
+        self.rung = 0
+        self.batch = 0
         # the submitter's trace id rides the request across the queue
         # handoff into the decode loop thread (None = untraced: the
         # per-token trace events are skipped entirely)
@@ -173,12 +263,14 @@ _REPLAY_LAST = 2    # the final prompt token was fed: a hit's FIRST token
 
 
 class _Step:
-    """A launched decode step whose tokens the host has not read: the
-    device's result, the (slot, request, what to do with its token)
-    triples live at the launch, and the step's span attributes."""
-    __slots__ = ("tokens", "pairs", "attrs")
+    """A launched decode step whose tokens the host has not read: its
+    number (counted up per model at the launch), the device's result, the
+    (slot, request, what to do with its token) triples live at the
+    launch, and the step's span attributes."""
+    __slots__ = ("number", "tokens", "pairs", "attrs")
 
-    def __init__(self, tokens, pairs, attrs):
+    def __init__(self, number, tokens, pairs, attrs):
+        self.number = number
         self.tokens = tokens
         self.pairs = pairs
         self.attrs = attrs
@@ -240,6 +332,14 @@ class ModelRuntime:
         self._key = ps.fresh_key()
         self._draining = False
         self._stopped = False
+        self._step_no = 0                 # the last launched step's number
+        self._gc = _GcWatch()
+        self._recent = {kind: deque(maxlen=_STALL_HISTORY)
+                        for kind in ("decode_step", "prefill", "verify")}
+        self._stall_t = 0.0
+        # the loop thread's last two usage samples: (monotonic clock, CPU
+        # ms, involuntary context switches), taken on the loop's own thread
+        self._usage = self._usage_before = None
         self._det = RecompileDetector(allowed=0, warn=False) \
             if watch_recompiles else None
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -364,6 +464,7 @@ class ModelRuntime:
     def _loop(self):
         if self._det is not None:
             self._det.__enter__()
+        self._sample_usage()
         idle_t0 = None
         try:
             while True:
@@ -401,6 +502,109 @@ class ModelRuntime:
                              model=self.name)
         self.metrics.record_phase(name, ms)
         return now
+
+    # ------------------------------------------- the loop's own accounting
+    def _pass_begin(self, cpu: bool = True):
+        """Inside a pass's span, first: the collector's total and, for the
+        rare and long kinds of pass, the loop thread's CPU clock."""
+        return (time.thread_time_ns() if cpu else None), self._gc.total_ns
+
+    def _pass_end(self, sp, begun) -> dict:
+        """Inside a pass's span, last: what the pass waited for.
+        ``launch_ms`` and ``read_wait_ms`` are the child spans' own
+        durations (the span is the stopwatch); ``gc_ms`` is there on a
+        pass inside which a collection of generation 1 or 2 ran. Set on
+        the span and returned for ``_note_pass``."""
+        cpu0, gc0 = begun
+        kids = sp.child_ms or {}
+        cost = {"launch_ms": round(kids.get("generation.dispatch", 0.0), 3),
+                "read_wait_ms": round(kids.get("generation.readback", 0.0),
+                                      3)}
+        if self._gc.total_ns != gc0:
+            cost["gc_ms"] = round((self._gc.total_ns - gc0) / 1e6, 3)
+        if cpu0 is not None:
+            cost["cpu_ms"] = round((time.thread_time_ns() - cpu0) / 1e6, 3)
+        for k, v in cost.items():
+            sp.set_attr(k, v)
+        return cost
+
+    def _sample_usage(self, sp=None):
+        """The loop thread's CPU time and involuntary context switches so
+        far (cumulative, one system call; the loop's thread only), kept
+        for a stall's account and set on ``sp``."""
+        usage = _thread_usage()
+        if usage is not None:
+            self._usage_before = self._usage
+            self._usage = (time.monotonic(),) + usage
+            if sp is not None:
+                sp.set_attr("loop_cpu_ms", round(usage[0], 3))
+                sp.set_attr("nivcsw", usage[1])
+        return usage
+
+    def _note_pass(self, kind: str, sp, cost: dict, step: int = -1) -> None:
+        """After a pass's span has closed: one ``generation.stall`` event
+        where it took far longer than its kind's recent passes, with what
+        it waited for and what the loop's thread did since its last usage
+        sample (at most ``_USAGE_EVERY`` decode passes back)."""
+        recent = self._recent[kind]
+        wall_ms = sp.dur_ms
+        if wall_ms > _STALL_MS and len(recent) >= _STALL_LEAST \
+                and wall_ms > _STALL_X * sorted(recent)[len(recent) // 2]:
+            now = time.monotonic()
+            if now - self._stall_t >= _STALL_EVERY_S:
+                self._stall_t = now
+                inside = {}
+                # against the last sample taken before this pass began
+                # (the pass may have taken the newest one itself)
+                before = self._usage
+                if before is not None and \
+                        (now - before[0]) * 1e3 < wall_ms:
+                    before = self._usage_before
+                usage = self._sample_usage()
+                if before is not None and usage is not None:
+                    inside = {
+                        "since_sample_ms": round((now - before[0]) * 1e3, 3),
+                        "cpu_since_sample_ms": round(usage[0] - before[1],
+                                                     3),
+                        "nivcsw_since_sample": usage[1] - before[2]}
+                if self._det is not None:
+                    # against what the loop's last iteration recorded
+                    inside["compiles"] = self._det.count \
+                        - self.metrics.decode_recompiles
+                event("generation.stall", model=self.name,
+                      span="generation." + kind, step=step,
+                      wall_ms=round(wall_ms, 3), **cost, **inside)
+        recent.append(wall_ms)
+
+    def _end(self, r: _GenRequest, reason: str,
+             error: Optional[BaseException] = None) -> None:
+        """Finish a request's stream, once, and leave its ONE record: a
+        complete event from its submission to now. Category ``request``,
+        not ``span``: it blocks on nothing, and a reader that fits the
+        device's clock takes every ``span``. ``first_token_us`` and
+        ``last_token_us`` are on the events' clock (``ts``)."""
+        if r.stream.done:
+            return
+        r.stream._finish(reason, error)
+        if not get_registry().enabled:
+            return
+        now, now_us = time.monotonic(), wall_us()
+        attrs = {"model": self.name, "request": r.id,
+                 "prompt_len": len(r.prompt), "tokens": r.emitted,
+                 "matched_tokens": int(r.matched_tokens), "steps": r.steps,
+                 "slot": -1 if r.slot is None else r.slot, "rung": r.rung,
+                 "batch": r.batch, "reason": reason}
+        if r.queue_ms is not None:
+            attrs["queue_ms"] = r.queue_ms
+        if r.first_t is not None:
+            attrs["ttft_ms"] = round((r.first_t - r.enqueue_t) * 1e3, 3)
+            attrs["first_token_us"] = now_us - round((now - r.first_t) * 1e6)
+            attrs["last_token_us"] = now_us - round((now - r.last_t) * 1e6)
+        if r.trace_id is not None:
+            attrs["trace_id"] = r.trace_id
+        record_external_span("generation.request",
+                             (now - r.enqueue_t) * 1e3, cat="request",
+                             **attrs)
 
     def _cohort_for_admission(self) -> _Cohort:
         ps = self.active_ps
@@ -475,11 +679,11 @@ class ModelRuntime:
             while q:
                 r = q.popleft()
                 if r.cancelled:
-                    r.stream._finish(r.cancel_reason)
+                    self._end(r, r.cancel_reason)
                     self.metrics.record_finish(r.cancel_reason)
                 elif now > r.deadline:
                     self.metrics.record_rejection("deadline")
-                    r.stream._finish("deadline", DeadlineExceededError(
+                    self._end(r, "deadline", DeadlineExceededError(
                         "deadline expired while queued for admission"))
                 else:
                     keep.append(r)
@@ -524,9 +728,10 @@ class ModelRuntime:
             # loop thread has no context of its own); the wait is taken on
             # one clock by the one thread that knows both ends
             queue_ms = (now - r.enqueue_t) * 1e3
+            r.queue_ms = round(queue_ms, 3)
             event("generation.admit", trace_id=r.trace_id, model=self.name,
-                  slot=r.slot, prompt_len=len(r.prompt),
-                  queue_ms=round(queue_ms, 3))
+                  request=r.id, slot=r.slot, prompt_len=len(r.prompt),
+                  queue_ms=r.queue_ms)
             self.metrics.record_admission(queue_ms)
         hits = [r for r in cands if r.matched_tokens]
         misses = [r for r in cands if not r.matched_tokens]
@@ -579,6 +784,7 @@ class ModelRuntime:
                   attn_key_rows=int((plens * (plens + 1) // 2).sum()),
                   head_rows=coh.ps.head_rows.get((P, L)),
                   sampled=int(np.count_nonzero(temp > 0.0))) as sp:
+            begun = self._pass_begin()
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
                 temp, topk)
@@ -593,6 +799,8 @@ class ModelRuntime:
                 sp.set_attr("moe_pairs_padded", (P * L - live_tokens) * pairs)
                 sp.set_attr("moe_load_max", int(stats[0]))
                 sp.set_attr("experts_touched", int(stats[1]))
+            cost = self._pass_end(sp, begun)
+        self._note_pass("prefill", sp, cost)
         if coh.ps.prefix_skipped_stateful:
             self.metrics.record_prefix_skipped_stateful(len(cands))
         t_phase = time.perf_counter()
@@ -600,6 +808,7 @@ class ModelRuntime:
         emitted = 0
         for i, r in enumerate(cands):
             s = r.slot
+            r.rung, r.batch = int(L), len(cands)
             coh.slots.add(s)
             coh.tables[s] = tables_p[i]
             self._pos[s] = len(r.prompt)
@@ -732,9 +941,17 @@ class ModelRuntime:
         the attributes of the step it READS. With nothing unread (the
         first step after an idle period or a drain; every step of a
         cohort that speculates) the step to read is launched here first,
-        and a speculating cohort launches nothing behind it."""
+        and a speculating cohort launches nothing behind it. The span
+        also says what the pass waited for: ``step`` and ``launched`` (the
+        step read, and the one launched behind it or -1), its children's
+        durations and, every ``_USAGE_EVERY``-th step, the loop thread's
+        CPU time and involuntary context switches so far."""
         step, coh.unread = coh.unread, None
-        with span("generation.decode_step", model=self.name) as sp:
+        # the step this pass reads: the unread one, or the next to launch
+        number = self._step_no + 1 if step is None else step.number
+        with span("generation.decode_step", annotate=("step",),
+                  model=self.name, step=number) as sp:
+            begun = self._pass_begin(cpu=False)
             if step is None:
                 step = self._launch_step(coh, live, None)
                 live = [s for s in live if s in coh.slots]
@@ -742,19 +959,26 @@ class ModelRuntime:
                 coh.unread = self._launch_step(coh, live, step)
             for k, v in step.attrs.items():
                 sp.set_attr(k, v)
+            sp.set_attr("launched",
+                        -1 if coh.unread is None else coh.unread.number)
             nxt, stats = coh.ps.split_stats(
-                coh.ps.read_decode(step.tokens))
+                coh.ps.read_decode(step.tokens, step.number))
             if stats is not None:
                 # the experts' routing arrives with the read, so all of a
                 # step's attributes sit on the one span
                 sp.set_attr("moe_pairs", len(step.pairs) * coh.ps.spec.n_moe
                             * coh.ps.spec.moe_top_k)
                 sp.set_attr("experts_touched", int(stats[1]))
+            if step.number % _USAGE_EVERY == 0:
+                self._sample_usage(sp)
+            cost = self._pass_end(sp, begun)
+        self._note_pass("decode_step", sp, cost, step.number)
         t_phase = time.perf_counter()
         dt_ms = sp.dur_ms
         now = time.monotonic()
         emitted = overrun = 0
         for s, r, what in step.pairs:
+            r.steps += 1
             if what != _REPLAY:
                 r.unread -= 1
             if r.stream.done:
@@ -789,8 +1013,6 @@ class ModelRuntime:
         self.metrics.record_decode_step(
             dt_ms, len(step.pairs), emitted,
             slots=self.config.decode_slots,
-            blocks_used=coh.allocator.used_blocks,
-            blocks_total=coh.allocator.total_usable,
             queue_depth=len(self._queue),
             overlapped=step.attrs["overlapped"], overrun=overrun)
         self._phase("emit", t_phase)
@@ -827,13 +1049,15 @@ class ModelRuntime:
         # rows may decide whether the program's sampler draws
         temp = np.where(mask, self._temp, np.float32(0.0))
         attrs["sampled"] = int(np.count_nonzero(temp > 0.0))
+        self._step_no += 1
+        number = self._step_no
         # the launch may still read a host array after it returns, and
         # this loop writes these before the step has run: hand it copies
         tokens, coh.cache, self._key = coh.ps.launch_decode(
             coh.cache, self._tokens.copy(),
             None if prev is None else prev.tokens, self._host_known.copy(),
             self._pos.copy(), coh.tables.copy(), mask, self._key, temp,
-            self._topk.copy())
+            self._topk.copy(), number)
         pairs = []
         for s in live:
             r = self._slot_req[s]
@@ -855,7 +1079,7 @@ class ModelRuntime:
                 # back NOW (what reuses them is queued behind this step);
                 # the stream finishes where the token is delivered
                 self._release(coh, r, early=True)
-        return _Step(tokens, pairs, attrs)
+        return _Step(number, tokens, pairs, attrs)
 
     def _replay_done(self, coh: _Cohort, r: "_GenRequest") -> None:
         """The step that feeds a cache hit's FINAL prompt token is
@@ -884,6 +1108,7 @@ class ModelRuntime:
         mask[specs] = True
         with span("generation.verify", model=self.name, slots=len(specs),
                   k=k) as sp:
+            begun = self._pass_begin()
             props, aux = coh.ps.run_propose(
                 coh.draft_cache, self._tokens, self._pos, mask)
             if coh.ps.draft_adapter == "dense":
@@ -892,6 +1117,8 @@ class ModelRuntime:
                 [self._tokens[:, None], props], axis=1).astype(np.int32)
             targets, coh.cache = coh.ps.run_verify(
                 coh.cache, feeds, self._pos, coh.tables, mask)
+            cost = self._pass_end(sp, begun)
+        self._note_pass("verify", sp, cost)
         t_phase = time.perf_counter()
         dt_ms = sp.dur_ms
         counts, emitted_toks = accept_greedy(props, targets)
@@ -902,6 +1129,7 @@ class ModelRuntime:
         rewind_idx = np.ones(S, np.int32)
         for s in specs:
             r = self._slot_req[s]
+            r.steps += 1
             if r.trace_id is not None:
                 event("generation.verify", trace_id=r.trace_id,
                       model=self.name, slot=s, token_index=r.emitted,
@@ -927,8 +1155,6 @@ class ModelRuntime:
         self.metrics.record_verify(
             dt_ms, len(specs), proposed=k * len(specs), accepted=accepted,
             emitted=emitted, slots=S,
-            blocks_used=coh.allocator.used_blocks,
-            blocks_total=coh.allocator.total_usable,
             queue_depth=len(self._queue))
         self._phase("emit", t_phase)
 
@@ -964,6 +1190,9 @@ class ModelRuntime:
             return self._finish_slot(coh, r, "stop")
         r.stream._put(tok)
         r.emitted += 1
+        if r.first_t is None:
+            r.first_t = now
+        r.last_t = now
         if r.emitted >= r.max_new:
             out = self._finish_slot(coh, r, "length")
             return (1, out[1])
@@ -993,7 +1222,7 @@ class ModelRuntime:
                      error: Optional[BaseException] = None):
         """Deliver the end of a request's stream and, unless the launch of
         its last step already did, give its slot and pages back."""
-        r.stream._finish(reason, error)
+        self._end(r, reason, error)
         if r.trace_id is not None:
             event("generation.finish", trace_id=r.trace_id,
                   model=self.name, slot=r.slot, reason=reason,
@@ -1050,7 +1279,7 @@ class ModelRuntime:
             reqs = self._admitted()
         in_flight = len(reqs)
         for r in queued:
-            r.stream._finish("error", exc)
+            self._end(r, "error", exc)
         for r in reqs:
             self._finish_slot(r.cohort, r, "error", exc)
         self._cohorts = []
@@ -1069,7 +1298,7 @@ class ModelRuntime:
             self._queue.clear()
             reqs = self._admitted()
         for r in queued:
-            r.stream._finish("shutdown", err)
+            self._end(r, "shutdown", err)
             self.metrics.record_finish("shutdown")
         for r in reqs:
             self._finish_slot(r.cohort, r, "shutdown",
@@ -1087,7 +1316,7 @@ class ModelRuntime:
             self._draining = True
             if not drain:
                 for r in list(self._queue):
-                    r.stream._finish("shutdown", DrainingError(
+                    self._end(r, "shutdown", DrainingError(
                         f"model '{self.name}' shut down before admission"))
                 self._queue.clear()
                 for r in self._admitted():
@@ -1103,3 +1332,4 @@ class ModelRuntime:
             self._cond.notify_all()
         self._thread.join(timeout=5.0)
         self._shutdown_flush()    # belt-and-braces if the thread wedged
+        self._gc.close()

@@ -19,13 +19,20 @@ Every span is also a ``jax.profiler.TraceAnnotation`` named by its path:
 while a profile is being taken (``jax.profiler.start_trace``, an
 operator's capture) the spans are events of the host plane of the same
 ``.xplane.pb``, on the profiler's clock, beside the runtime's launch
-events. With no profile active the annotation is a flag check.
+events. With no profile active the annotation is a flag check. The
+attributes a span names in ``annotate`` ride the annotation as its
+metadata, so a reader of the ``.xplane.pb`` finds them on the event
+(the serving loop's ``step``).
+
+A span that ends inside another adds its duration to the parent's
+``child_ms[name]``: a caller that wants its callee's time reads the
+callee's own stopwatch, not the clock again.
 """
 from __future__ import annotations
 
 import threading
 import time
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 from jax.profiler import TraceAnnotation
 
@@ -33,7 +40,7 @@ from .registry import MetricsRegistry, get_registry
 from .tracecontext import current_trace_id
 
 __all__ = ["Span", "span", "current_span", "current_span_path",
-           "record_external_span"]
+           "record_external_span", "wall_us"]
 
 # Chrome-trace timestamps are microseconds; anchor perf_counter_ns to the
 # unix epoch once so every event in a process shares one clock domain.
@@ -56,6 +63,7 @@ class _NoopSpan:
     __slots__ = ()
     name = path = "<disabled>"
     dur_ms = 0.0
+    child_ms = None
 
     def __enter__(self):
         return self
@@ -82,17 +90,23 @@ class Span:
     (e.g. ProfilerListener's capture window opens in one listener callback
     and closes in a later one). ``dur_ms`` holds the duration once the
     span has ended, for a caller that feeds a metric of its own from the
-    interval the span already timed."""
+    interval the span already timed; ``child_ms`` (None until a child
+    has ended) the summed durations of the spans that ended inside it, by
+    name."""
 
-    __slots__ = ("name", "attrs", "path", "registry", "dur_ms", "_t0",
-                 "_tid", "_ended", "_trace_id", "_annotation")
+    __slots__ = ("name", "attrs", "path", "registry", "dur_ms", "child_ms",
+                 "_annotate", "_t0", "_tid", "_ended", "_trace_id",
+                 "_annotation")
 
-    def __init__(self, name: str, registry: MetricsRegistry, attrs: dict):
+    def __init__(self, name: str, registry: MetricsRegistry, attrs: dict,
+                 annotate: Sequence[str] = ()):
         self.name = name
         self.attrs = attrs
         self.registry = registry
         self.path = name          # parent path resolved at start()
         self.dur_ms = 0.0
+        self.child_ms: Optional[dict] = None
+        self._annotate = annotate
         self._annotation = None
         self._t0 = 0
         self._tid = 0
@@ -116,7 +130,9 @@ class Span:
         # closed event is keyed by trace id alongside its span path
         self._trace_id = current_trace_id()
         self._tid = threading.get_ident() & 0xFFFFFFFF
-        self._annotation = TraceAnnotation(self.path)
+        attrs = self.attrs
+        self._annotation = TraceAnnotation(
+            self.path, **{k: attrs[k] for k in self._annotate})
         self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
@@ -137,6 +153,12 @@ class Span:
                 pass
         dur_ns = t1 - self._t0
         self.dur_ms = dur_ns / 1e6
+        if stack:
+            parent = stack[-1]
+            if parent.child_ms is None:
+                parent.child_ms = {}
+            parent.child_ms[self.name] = \
+                parent.child_ms.get(self.name, 0.0) + self.dur_ms
         reg = self.registry
         if reg.enabled:
             args = self.attrs
@@ -164,10 +186,12 @@ class Span:
 _hook_ready = False
 
 
-def span(name: str, **attrs):
+def span(name: str, annotate: Sequence[str] = (), **attrs):
     """Open a structured span (context manager). ``attrs`` must be
     host-side values (ints/strs) — passing a device array would force the
-    readback this layer exists to avoid."""
+    readback this layer exists to avoid. ``annotate`` names the attributes
+    (given here, not set later) that also go to the span's
+    ``TraceAnnotation`` as metadata."""
     reg = get_registry()
     if not reg.enabled:
         return _NOOP
@@ -176,7 +200,12 @@ def span(name: str, **attrs):
         from . import jaxsignals
         jaxsignals.ensure_monitoring_hook()   # compiles attribute to spans
         _hook_ready = True
-    return Span(name, reg, attrs)
+    return Span(name, reg, attrs, annotate)
+
+
+def wall_us() -> int:
+    """Now, on the clock of every trace event's ``ts`` (microseconds)."""
+    return (time.perf_counter_ns() + _EPOCH_NS) // 1000
 
 
 def record_external_span(name: str, dur_ms: float, cat: str = "external",

@@ -1137,14 +1137,14 @@ def last_step_watch():
     seen, checks = [], []
     orig, orig_check = ps.read_decode, rt._check_quiesce
 
-    def watching(first):
+    def watching(first, *step):
         coh = rt._cohorts[-1]
         seen.append({"slot_req": len(rt._slot_req), "early": len(rt._early),
                      "free_blocks": coh.allocator.free_blocks,
                      "slots_free": len(rt._slots_free),
                      "in_flight": rt.in_flight,
                      "prefills": rt.metrics.prefills})
-        return orig(first)
+        return orig(first, *step)
 
     def checking():
         # the loop's own call, at the end of a pass that left no request
@@ -1217,12 +1217,12 @@ def _engine_with_an_unread_last_token(on_read):
     orig = ps.read_decode
     reads = {"n": 0}
 
-    def reading(first):
+    def reading(first, *step):
         reads["n"] += 1
         if reads["n"] == 2:                 # step 2: A's last
             assert len(rt._early) == 1 and len(rt._slot_req) == 1
             on_read()
-        return orig(first)
+        return orig(first, *step)
 
     ps.read_decode = reading
     a, b = _prompts(47, (5, 7), seed=419)
@@ -1311,9 +1311,278 @@ def test_a_speculating_cohort_keeps_the_synchronous_order():
         steps = _steps_since(seq0)
         assert len(steps) == 8              # the opted-out request's
         assert all(s["args"]["overlapped"] == 0 for s in steps)
+        # nothing is launched behind the step a pass reads, and the steps
+        # are numbered all the same
+        assert all(s["args"]["launched"] == -1 for s in steps)
+        numbers = [s["args"]["step"] for s in steps]
+        assert numbers == list(range(numbers[0], numbers[0] + 8))
+        assert all("cpu_ms" not in s["args"] for s in steps)
+        verifies = [e for e in get_registry().trace_events_since(seq0)
+                    if e["name"] == "generation.verify" and e["ph"] == "X"]
+        assert verifies and all(v["args"]["cpu_ms"] >= 0.0
+                                and v["args"]["read_wait_ms"] >= 0.0
+                                for v in verifies)
         assert all(c.unread is None for c in rt._cohorts)
         snap = eng.metrics()["lm"]
         assert snap["decode_steps_overlapped"] == 0
         assert snap["speculative"]["verify_steps"] >= 1
     finally:
         eng.stop()
+
+
+# ------------- the loop accounts for itself (ISSUE 42): every decode pass
+# says which step it launched and read and what it waited for, every
+# request leaves one record, a pass far out of line leaves one stall event
+def _generation_events(seq0):
+    return [e for e in get_registry().trace_events_since(seq0)
+            if e["name"].startswith("generation.")]
+
+
+STEPS = 19
+
+
+@pytest.fixture(scope="module")
+def numbered_events(pipe_lm):
+    """One request of twenty tokens on an idle engine: a prefill, then
+    nineteen decode steps in eighteen overlapped passes and a last one
+    that launches nothing."""
+    _, _, eng = pipe_lm
+    _settle(eng)
+    seq0 = get_registry().last_seq
+    prompt = _prompts(47, (6,), seed=4242)[0]
+    assert len(eng.generate(prompt, max_tokens=STEPS + 1)[0]) == STEPS + 1
+    _settle(eng)
+    return _generation_events(seq0)
+
+
+def _decode_spans(events, name):
+    return [e for e in _named(events, name, ph="X", cat="span")
+            if e["args"].get("program", "decode") == "decode"]
+
+
+@pytest.mark.parametrize("name", [
+    "generation.decode_step", "generation.dispatch", "generation.readback"])
+def test_step_numbers_count_up_without_a_hole(numbered_events, name):
+    numbers = [e["args"]["step"] for e in _decode_spans(numbered_events, name)]
+    assert len(numbers) == STEPS
+    assert numbers == list(range(numbers[0], numbers[0] + STEPS))
+    # the other programs' launches and reads belong to no step
+    assert all("step" not in e["args"] for e in numbered_events
+               if e["args"].get("program") == "prefill")
+
+
+def test_a_steps_launch_ends_before_its_read_starts(numbered_events):
+    launch = {e["args"]["step"]: e
+              for e in _decode_spans(numbered_events, "generation.dispatch")}
+    reads = _decode_spans(numbered_events, "generation.readback")
+    assert len(reads) == STEPS
+    for r in reads:
+        d = launch[r["args"]["step"]]
+        assert d["ts"] + d["dur"] <= r["ts"] + 1
+
+
+def test_launched_is_the_step_behind_the_one_a_pass_reads(numbered_events):
+    passes = _decode_spans(numbered_events, "generation.decode_step")
+    for p in passes[:-1]:
+        assert p["args"]["launched"] == p["args"]["step"] + 1
+    # the first pass after idle holds two launches, overlapped or not
+    assert [p["args"]["overlapped"] for p in passes] == [0] + [1] * (STEPS - 1)
+    assert passes[-1]["args"]["launched"] == -1
+
+
+@pytest.mark.parametrize("name,steps", [
+    ("generation.decode_step", STEPS), ("generation.prefill", 1)])
+def test_a_pass_says_what_it_waited_for(numbered_events, name, steps):
+    found = _named(numbered_events, name, ph="X", cat="span")
+    assert len(found) == steps
+    for e in found:
+        a = e["args"]
+        # the children's own stopwatches, rounded to the microsecond
+        assert 0.0 <= a["launch_ms"] and 0.0 <= a["read_wait_ms"]
+        assert a["launch_ms"] + a["read_wait_ms"] <= e["dur"] / 1e3 + 0.003
+    if name == "generation.prefill":
+        # a rare and long pass takes its own CPU time: there, and not
+        # negative (no tier-1 test holds a CPU time to a size)
+        assert found[0]["args"]["cpu_ms"] >= 0.0
+        return
+    # a pass with no launch has waited for none
+    assert found[-1]["args"]["launch_ms"] == 0.0
+    # a decode pass makes no system call of its own, but one in sixteen
+    # samples the loop thread's usage so far
+    assert all("cpu_ms" not in e["args"] for e in found)
+    sampled = [e["args"] for e in found if "loop_cpu_ms" in e["args"]]
+    assert [a["step"] for a in sampled] == [
+        e["args"]["step"] for e in found if e["args"]["step"] % 16 == 0]
+    assert 1 <= len(sampled) <= 2
+    assert all(a["loop_cpu_ms"] >= 0.0 and a["nivcsw"] >= 0
+               for a in sampled)
+
+
+@pytest.fixture(scope="module")
+def record_lm():
+    """An engine of the record tests' own: they patch its program set."""
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=2, prefill_batches=(1, 2),
+                           prompt_rungs=(64,), prefix_cache=False)
+    yield net, TransformerDecodeSpec(net), eng
+    eng.stop()
+
+
+def _end_as(reason, net, spec, eng):
+    """Drive one request of the engine to end as ``reason``; returns its
+    stream and the tokens it gave."""
+    rt = eng._get("lm")
+    ps = rt.active_ps
+    launch, read = ps.launch_decode, ps.read_decode
+    prompt = _prompts(47, (6,), seed=4300)[0]
+    try:
+        if reason == "length":
+            st = eng.generate(prompt, max_tokens=5, stream=True)
+            return st, list(st)
+        if reason == "stop":
+            greedy = naive_generate(net, prompt, 8, pad_to=64, spec=spec)
+            stop = next(t for t in greedy[1:] if t != greedy[0])
+            st = eng.generate(prompt, max_tokens=8, stop=[stop], stream=True)
+            return st, list(st)
+
+        def slow(*a, **k):
+            time.sleep(0.02)
+            return launch(*a, **k)
+
+        def boom(first, *step):
+            raise RuntimeError("injected device failure")
+
+        if reason == "error":
+            ps.read_decode = boom
+        else:
+            ps.launch_decode = slow
+        st = eng.generate(prompt, max_tokens=50, stream=True,
+                          timeout=0.3 if reason == "deadline" else 30.0)
+        toks = []
+        for tok in st:
+            toks.append(tok)
+            if reason == "cancelled" and len(toks) == 2:
+                st.cancel()
+        return st, toks
+    finally:
+        _settle(eng)
+        ps.launch_decode, ps.read_decode = launch, read
+
+
+@pytest.mark.parametrize("reason", ["length", "stop", "cancelled",
+                                    "deadline", "error"])
+def test_every_end_leaves_exactly_one_request_record(record_lm, reason):
+    net, spec, eng = record_lm
+    _settle(eng)
+    seq0 = get_registry().last_seq
+    st, toks = _end_as(reason, net, spec, eng)
+    assert st.finish_reason == reason
+    events = _generation_events(seq0)
+    (rec,) = _named(events, "generation.request")
+    a = rec["args"]
+    # a complete event from the submission on, and never a ``span``: the
+    # benchmark fits the device's clock on every span
+    assert (rec["ph"], rec["cat"]) == ("X", "request")
+    assert a["request"] == st.request_id and a["reason"] == reason
+    assert a["tokens"] == len(toks) == st.emitted
+    assert (a["prompt_len"], a["matched_tokens"], a["model"]) == (6, 0, "lm")
+    assert (a["rung"], a["batch"]) == (64, 1) and a["slot"] in (0, 1)
+    (admit,) = [e for e in _named(events, "generation.admit", ph="i")
+                if e["args"]["request"] == st.request_id]
+    assert a["queue_ms"] == admit["args"]["queue_ms"]
+    assert toks and a["ttft_ms"] >= a["queue_ms"]
+    assert rec["ts"] <= a["first_token_us"] <= a["last_token_us"] \
+        <= rec["ts"] + rec["dur"] + 1
+    # the passes it rode: one a token after the first, and the steps that
+    # were in flight when the host learned of its end
+    assert a["steps"] >= len(toks) - 1
+    if reason == "length":
+        assert a["steps"] == 4
+
+
+def test_a_request_that_ends_in_the_queue_leaves_its_record_too(record_lm):
+    net, spec, eng = record_lm
+    rt = eng._get("lm")
+    _settle(eng)
+    seq0 = get_registry().last_seq
+    with rt._cond:                # the loop cannot admit before the cancel
+        st = eng.generate([1, 2, 3], max_tokens=4, stream=True)
+        st.cancel()
+    assert st.result() == ([], "cancelled")
+    _settle(eng)
+    (rec,) = _named(_generation_events(seq0), "generation.request")
+    a = rec["args"]
+    assert (a["request"], a["reason"], a["tokens"], a["slot"]) == (
+        st.request_id, "cancelled", 0, -1)
+    assert not {"queue_ms", "ttft_ms", "first_token_us"} & set(a)
+
+
+def test_a_slow_read_leaves_one_stall_event_a_second():
+    """Two reads of one second sleep 400 ms each, far over 20 times the
+    median pass and over the floor of 50 ms: the first leaves the event,
+    with the sleep in ``read_wait_ms`` and the thread's usage since its
+    last sample; the second, inside the same second, none. A collection the read runs shows as the pass's
+    ``gc_ms``, and the collector's hook goes with the engine."""
+    import gc
+    hooks = len(gc.callbacks)
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=1, prefill_batches=(1,),
+                           prompt_rungs=(64,), prefix_cache=False)
+    try:
+        assert len(gc.callbacks) == hooks + 1
+        rt = eng._get("lm")
+        ps = rt.active_ps
+        prompt = _prompts(47, (6,), seed=4400)[0]
+        eng.generate(prompt, max_tokens=12)      # the median's history
+        _settle(eng)
+        orig, reads = ps.read_decode, {"n": 0, "slept": []}
+
+        class Slow:
+            """A result whose copy to the host takes 400 ms more: the
+            wait lies inside the read's own span."""
+
+            def __init__(self, first):
+                self.first = first
+
+            def __array__(self, dtype=None, copy=None):
+                time.sleep(0.4)
+                return np.asarray(self.first)
+
+        def reading(first, *step):
+            reads["n"] += 1
+            if reads["n"] in (3, 5):
+                reads["slept"].append(step[0])
+                first = Slow(first)
+            if reads["n"] == 7:
+                gc.collect(1)
+            return orig(first, *step)
+
+        ps.read_decode = reading
+        seq0 = get_registry().last_seq
+        assert len(eng.generate(prompt, max_tokens=10)[0]) == 10
+        _settle(eng)
+        events = _generation_events(seq0)
+        (stall,) = _named(events, "generation.stall", ph="i")
+        a = stall["args"]
+        assert (a["span"], a["step"], a["model"]) == (
+            "generation.decode_step", reads["slept"][0], "lm")
+        assert 400.0 <= a["read_wait_ms"] <= a["wall_ms"]
+        assert a["launch_ms"] >= 0.0 and a["compiles"] == 0
+        # what the loop's thread did since its last usage sample, at most
+        # sixteen passes back: the sleep is in the wall, not in the CPU
+        assert a["since_sample_ms"] >= a["wall_ms"]
+        assert 0.0 <= a["cpu_since_sample_ms"] and \
+            a["nivcsw_since_sample"] >= 0
+        # both slow passes are on their spans all the same
+        passes = {p["args"]["step"]: p["args"] for p in
+                  _named(events, "generation.decode_step", ph="X")}
+        assert all(passes[n]["read_wait_ms"] >= 400.0
+                   for n in reads["slept"])
+        collected = [n for n, p in passes.items() if "gc_ms" in p]
+        assert reads["slept"][0] + 4 in collected
+        assert all(passes[n]["gc_ms"] > 0.0 for n in collected)
+    finally:
+        eng.stop()
+    assert len(gc.callbacks) == hooks

@@ -295,6 +295,63 @@ def test_spans_are_events_of_the_profilers_host_plane(fresh_registry,
                                        "manual24"]
 
 
+def test_a_spans_named_attributes_ride_its_annotation(fresh_registry,
+                                                        tmp_path):
+    """``annotate`` names the attributes that also go to the span's
+    ``TraceAnnotation`` as metadata: a reader of the ``.xplane.pb`` finds
+    them as the event's stats (the serving loop's ``step``), and the
+    event keeps the span's path as its name."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+
+    def body():
+        with span("pass42", annotate=("step",), step=7, slots=3):
+            with span("read42", annotate=("step",), step=6):
+                pass
+        with span("plain42", step=9):
+            pass
+    assert {"pass42", "pass42/read42", "plain42"} <= _profiled(tmp_path, body)
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    stats = {e.name: dict(e.stats)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.endswith("42")}
+    assert stats["pass42"] == {"step": 7}         # not ``slots``
+    assert stats["pass42/read42"] == {"step": 6}
+    assert stats["plain42"] == {}
+    # the registry's events carry the attributes as they always did
+    args = {e["name"]: e["args"] for e in fresh_registry.trace_events()}
+    assert (args["pass42"]["step"], args["pass42"]["slots"]) == (7, 3)
+
+
+def test_a_parent_span_sums_its_childrens_durations(fresh_registry):
+    """``child_ms``: a caller that wants its callee's time reads the
+    callee's own stopwatch."""
+    with span("outer42") as outer:
+        assert outer.child_ms is None
+        with span("launch42") as a:
+            pass
+        with span("launch42") as b:
+            pass
+        with span("read42") as c:
+            with span("deep42"):
+                pass
+    assert outer.child_ms == {
+        "launch42": pytest.approx(a.dur_ms + b.dur_ms),
+        "read42": pytest.approx(c.dur_ms)}
+    assert set(c.child_ms) == {"deep42"} and a.child_ms is None
+    assert sum(outer.child_ms.values()) <= outer.dur_ms
+    fresh_registry.enabled = False
+    with span("outer42") as off:
+        with span("launch42"):
+            pass
+    fresh_registry.enabled = True
+    assert off.child_ms is None
+
+
 def test_disabled_registry_opens_no_annotation(fresh_registry, tmp_path):
     fresh_registry.enabled = False
 

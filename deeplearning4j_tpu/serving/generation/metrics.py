@@ -46,6 +46,7 @@ class GenerationMetrics:
         self._queue_wait_ms = deque(maxlen=window)
         self._phase_ms = {p: deque(maxlen=window) for p in _PHASE_METRIC}
         self.requests = 0
+        self.admits_jumped = 0      # admissions ahead of an earlier arrival
         self.tokens_out = 0
         self.prefills = 0
         self.prefill_rows = 0
@@ -94,14 +95,20 @@ class GenerationMetrics:
         if reg.enabled:
             reg.counter(f"generation.{self.name}.requests").inc()
 
-    def record_admission(self, queue_ms: float) -> None:
-        """One request's wait from submission to its slot."""
+    def record_admission(self, queue_ms: float, jumped: int = 0) -> None:
+        """One request's wait from submission to its slot, and how many
+        earlier-queued requests still waited when it got it (0 under
+        arrival order)."""
         with self._lock:
             self._queue_wait_ms.append(queue_ms)
+            if jumped:
+                self.admits_jumped += 1
         reg = self.registry
         if reg.enabled:
             reg.histogram(
                 f"generation.{self.name}.queue_wait_ms").observe(queue_ms)
+            if jumped:
+                reg.counter(f"generation.{self.name}.admits_jumped").inc()
 
     def record_phase(self, phase: str, ms: float) -> None:
         """A host phase of the loop between two program calls: the
@@ -371,6 +378,7 @@ class GenerationMetrics:
             lookups = self.prefix_hits + self.prefix_misses
             out = {
                 "requests": self.requests,
+                "admits_jumped": self.admits_jumped,
                 "tokens_out": self.tokens_out,
                 "prefills": self.prefills,
                 "prefill_rows": self.prefill_rows,

@@ -36,7 +36,7 @@ from __future__ import annotations
 import contextlib
 import math
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +58,13 @@ from .sampling import sample_tokens
 
 def _ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+# how far down the queue an admission pass looks for partners of its
+# head's rung (``GenerationConfig.admission_choice``): integer compares on
+# a rung stamped at submission, so the bound is on the worst case of a
+# long queue of other rungs, not on the common one
+ADMIT_LOOKAHEAD = 32
 
 
 @dataclass
@@ -141,6 +148,42 @@ class GenerationConfig:
                 return r
         raise ValueError(f"prompt length {plen} exceeds the largest prompt "
                          f"rung {self.prompt_rungs[-1]}")
+
+    def filled_batch(self, n: int) -> int:
+        """The largest batch rung that ``n`` requests fill with no empty
+        row; ``n`` itself where it fills none (fewer requests than the
+        smallest rung: the rows are padded, as for any lone request)."""
+        return max((b for b in self.prefill_batches if b <= n), default=n)
+
+    def admission_choice(self, rungs: Sequence[int], waiting: int,
+                         free_slots: int) -> List[int]:
+        """Which waiting requests one admission pass takes, as indices
+        into the queue, ascending. ``rungs`` are the prompt rungs of the
+        queue's first requests in queue order (no more than
+        ``ADMIT_LOOKAHEAD`` of them are read), ``waiting`` is how many
+        requests wait in all.
+
+        When everything that waits fits into the pass (``waiting`` <= the
+        smaller of ``free_slots`` and the largest batch rung) the pass
+        takes all of it in arrival order, whatever the rungs: holding a
+        request back would add a launch to its first token on a chip that
+        has time to spare. When more wait than the pass can take, the
+        order among them is free and padded positions are what the chip is
+        short of: the pass takes the head, then the next waiting requests
+        of the HEAD'S rung, and cuts the batch back to the largest batch
+        rung they fill, so that the prefill program it launches has no row
+        of another rung's width and no empty row. Requests of another rung
+        are never pulled in. The head always goes first, so a request is
+        admitted within as many admitting passes as its place in the queue
+        when it arrived."""
+        room = min(free_slots, self.prefill_batches[-1])
+        if room < 1 or waiting < 1:
+            return []
+        if waiting <= room:
+            return list(range(waiting))
+        chosen = [i for i, rung in enumerate(rungs[:ADMIT_LOOKAHEAD])
+                  if rung == rungs[0]][:room]
+        return chosen[:self.filled_batch(len(chosen))]
 
 
 # Every cache-carrying program donates its cache argument (argnum 2): the

@@ -50,10 +50,25 @@ thread that ends it.
 
 Admission happens at step boundaries only — a new request never stalls
 in-flight decode, it just lands in the next step's batch (freed slots are
-backfilled from the queue; idle slots ride along masked). All device work
-goes through the cohort's AOT-warmed ``GenerationProgramSet``; the host
-side is numpy-only, so steady state never traces (a ``RecompileDetector``
-stays armed on the loop to prove it).
+backfilled from the queue; idle slots ride along masked). Which waiting
+requests a pass admits is ``GenerationConfig.admission_choice``: when all
+that waits fits into the pass, all of it in arrival order; when more wait
+than a pass takes, the head of the queue first, then the next waiting
+requests OF THE HEAD'S PROMPT RUNG (inside ``ADMIT_LOOKAHEAD``), cut back
+to the largest batch rung they fill, so that the prefill launched pads no
+row to another request's rung and has no empty row. Order among waiting
+requests therefore changes under load, within a bound: the head leaves at
+every admitting pass, so a request is admitted within as many admitting
+passes as its place in the queue when it arrived, and only requests of
+the current head's rung ever pass it. A head whose blocks do not fit
+admits nobody. ``generation.admit`` carries ``jumped``, the number of
+earlier-queued requests still waiting when the request got its slot (0
+in arrival order), counted as ``generation.<m>.admits_jumped``.
+
+All device work goes through the cohort's AOT-warmed
+``GenerationProgramSet``; the host side is numpy-only, so steady state
+never traces (a ``RecompileDetector`` stays armed on the loop to prove
+it).
 
 Hot-swap cutover rule: a request is pinned to the program set (params) it
 was admitted under. After ``hot_swap``, new admissions form a NEW cohort on
@@ -84,7 +99,7 @@ from ..errors import (BlockPoolExhaustedError, DeadlineExceededError,
 from .kvcache import BlockAllocator
 from .metrics import GenerationMetrics
 from .prefix import PrefixCache
-from .programs import GenerationProgramSet
+from .programs import ADMIT_LOOKAHEAD, GenerationProgramSet
 
 
 try:
@@ -209,12 +224,17 @@ class _GenRequest:
                  "replay", "replaying", "matched_tokens", "spec", "emitted",
                  "unread", "cancelled", "cancel_reason", "enqueue_t",
                  "cohort", "trace_id", "id", "queue_ms", "first_t", "last_t",
-                 "steps", "rung", "batch")
+                 "steps", "rung", "batch", "prompt_rung", "jumped")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: int, stop: frozenset, deadline: float,
-                 speculative: bool = True):
+                 speculative: bool = True, prompt_rung: int = 0):
         self.prompt = prompt
+        # the rung its prompt pads to, stamped here so that an admission
+        # pass chooses its batch by integer compares
+        self.prompt_rung = prompt_rung
+        # earlier-queued requests still waiting when it was admitted
+        self.jumped = 0
         self.max_new = max_new
         self.temperature = temperature
         self.top_k = top_k
@@ -430,7 +450,8 @@ class ModelRuntime:
         req = _GenRequest(prompt, int(max_new), float(temperature),
                           int(top_k), frozenset(int(s) for s in stop),
                           time.monotonic() + timeout,
-                          speculative=speculative)
+                          speculative=speculative,
+                          prompt_rung=cfg.prompt_rung(plen))
         with self._cond:
             if self._draining or self._stopped:
                 self.metrics.record_rejection("draining")
@@ -660,13 +681,50 @@ class ModelRuntime:
         r.shared_blocks = shared
         r.matched_tokens = matched
 
+    def _blocks_fit(self, coh: _Cohort, r: _GenRequest) -> bool:
+        """Whether the pool can hold ``r`` now (under the cond lock): the
+        blocks it needs beyond its cached prefix, against the free blocks
+        and what the prefix cache could evict for it."""
+        if coh.ps.adapter == "state":
+            return True
+        cfg, plen = self.config, len(r.prompt)
+        fresh = cfg.blocks_needed(plen, r.max_new)
+        budget = coh.allocator.free_blocks
+        if coh.prefix is not None:
+            m = coh.prefix.probe(r.prompt)
+            if not self._worth_replaying(m, plen):
+                m = 0                # short match -> plain miss
+            # a match of the whole prompt copies its last block on write
+            fresh += (1 if m and m * cfg.block_len == plen else 0) - m
+            budget += coh.prefix.evictable_for(r.prompt)
+        return fresh <= budget
+
+    def _put_back(self, coh: _Cohort, r: _GenRequest, place: int) -> None:
+        """Undo an admission of this pass before anything was launched for
+        it (under the cond lock): blocks and slot go back, and the request
+        to ``place`` in the queue, where it waited."""
+        if r.blocks:
+            coh.allocator.free(r.blocks)
+            r.blocks = []
+        if r.shared_blocks:
+            coh.prefix.release(r.shared_blocks)
+            r.shared_blocks = []
+        r.matched_tokens = 0
+        del self._slot_req[r.slot]
+        self._slots_free.add(r.slot)
+        r.slot = r.cohort = None
+        self._queue.insert(place, r)
+
     def _admit(self):
         """One admission pass; what of it is host work between program
         calls is recorded as ``generation.admit_batch`` phases."""
         self._phase("admit_batch", self._admit_pass(time.perf_counter()))
 
     def _admit_pass(self, t_phase: float) -> float:
-        """``t_phase`` is where the pass began; returns the clock from
+        """Expire what was cancelled or ran out of time in the queue, then
+        admit what ``GenerationConfig.admission_choice`` takes of the
+        waiting requests, each under the block budget, and prefill it.
+        ``t_phase`` is where the pass began; returns the clock from
         which its host work is not recorded yet (a prefill on the way
         records the part before its launch and its own emission)."""
         cfg = self.config
@@ -691,35 +749,40 @@ class ModelRuntime:
             if not self._queue or not self._slots_free:
                 return t_phase
             coh = self._cohort_for_admission()
-            max_p = cfg.prefill_batches[-1]
-            blk = cfg.block_len
-            while self._queue and self._slots_free and len(cands) < max_p:
-                r = self._queue[0]
-                if coh.ps.adapter != "state":
-                    total = cfg.blocks_needed(len(r.prompt), r.max_new)
-                    budget = coh.allocator.free_blocks
-                    fresh = total
-                    if coh.prefix is not None:
-                        m = coh.prefix.probe(r.prompt)
-                        if not self._worth_replaying(m, len(r.prompt)):
-                            m = 0                # short match -> plain miss
-                        fresh = total - m + \
-                            (1 if m and m * blk == len(r.prompt) else 0)
-                        budget += coh.prefix.evictable_for(r.prompt)
-                    if fresh > budget:
-                        break        # head-of-line: wait for blocks to free
-                self._queue.popleft()
+            waiting = len(self._queue)
+            chosen = cfg.admission_choice(
+                [r.prompt_rung for r in itertools.islice(
+                    self._queue, ADMIT_LOOKAHEAD)],
+                waiting, len(self._slots_free))
+            for i in chosen:
+                # ``len(cands)`` requests before it have left the queue
+                r = self._queue[i - len(cands)]
+                if not self._blocks_fit(coh, r):
+                    # wait for blocks to free: a head that does not fit
+                    # admits nobody (head-of-line: a large request is not
+                    # starved by smaller ones), a partner ends the search
+                    break
+                del self._queue[i - len(cands)]
                 # register the request for failure delivery BEFORE block
                 # setup: if _setup_blocks raises (an accounting bug —
-                # the head-of-line budget above should prevent it), the
+                # the budget above should prevent it), the
                 # loop's _fail_all resolves this caller instead of
                 # leaving a popped-but-unregistered stream hanging
                 r.slot = self._slots_free.pop()
                 r.cohort = coh
+                r.jumped = i - len(cands)
                 self._slot_req[r.slot] = r
                 if coh.ps.adapter != "state":
                     self._setup_blocks(coh, r)
                 cands.append(r)
+            if len(chosen) < waiting:
+                # more wait than a pass takes, so a later pass launches
+                # anyway: no empty row either where a partner's blocks
+                # did not fit. The last admitted go back to their places
+                keep = cfg.filled_batch(len(cands))
+                while len(cands) > keep:
+                    r = cands.pop()
+                    self._put_back(coh, r, chosen[len(cands)] - len(cands))
         if not cands:
             return t_phase
         now = time.monotonic()
@@ -731,8 +794,8 @@ class ModelRuntime:
             r.queue_ms = round(queue_ms, 3)
             event("generation.admit", trace_id=r.trace_id, model=self.name,
                   request=r.id, slot=r.slot, prompt_len=len(r.prompt),
-                  queue_ms=r.queue_ms)
-            self.metrics.record_admission(queue_ms)
+                  queue_ms=r.queue_ms, jumped=r.jumped)
+            self.metrics.record_admission(queue_ms, r.jumped)
         hits = [r for r in cands if r.matched_tokens]
         misses = [r for r in cands if not r.matched_tokens]
         if misses:

@@ -171,7 +171,10 @@ def test_events_lost_at_under_and_past_the_capacity(handed, first_at, lost):
 
 def test_every_new_metric_is_appended_with_its_reader_and_its_cells():
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    rows = bench["per_layer"][-len(NEW):]
+    # one stretch of the list, as PR 42 appended it (later PRs append behind)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = min(names.index(n) for n in NEW)
+    rows = bench["per_layer"][first:first + len(NEW)]
     assert sorted(m["name"] for m in rows) == sorted(NEW)
     tput = [w["name"] for w in bench["workloads"]
             if w["name"] in {"gpt2m-serve-longprompt",
@@ -188,14 +191,57 @@ def test_every_new_metric_is_appended_with_its_reader_and_its_cells():
                 "serve_tokens_per_s", tput)
 
 
+# ISSUE 43: how often the admission pass went ahead of an earlier arrival
+JUMPED = "sched.admit_jumped_pct.tput"
+
+
+def an_admit(at, **args):
+    return instant("generation.admit", at, request=1, queue_ms=1.0, **args)
+
+
+@pytest.mark.parametrize("kind,jumped,want", [
+    # four admissions inside the window, two ahead of somebody; the one
+    # before the window opened is not counted
+    ("closed_loop", [0, 3, 0, 1], 50.0),
+    ("closed_loop", [0, 0, 0], 0.0),           # arrival order throughout
+    ("closed_loop", [2], 100.0),
+    ("closed_loop", [], None),                 # nothing admitted
+    ("open_loop", [0, 1], None)])              # not this reader's cell
+def test_the_share_of_admissions_that_jumped(kind, jumped, want):
+    events = [an_admit(99.0, jumped=5)] + \
+        [an_admit(101.0 + i, jumped=j) for i, j in enumerate(jumped)]
+    got = read(JUMPED, obs_of(kind, events))
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_the_parents_admissions_carry_no_jumped_and_read_as_nothing():
+    events = [an_admit(101.0 + i) for i in range(4)]
+    assert read(JUMPED, obs_of("closed_loop", events)) is None
+    assert read(JUMPED, {"kind": "closed_loop", "window_perf": (T0, T1),
+                         "epoch_ns": 0}) is None
+
+
+def test_the_jumped_share_is_appended_behind_pr_42s_with_its_reader():
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index(JUMPED) > max(names.index(n) for n in NEW)
+    assert bench["per_layer"][names.index(JUMPED)] == {
+        "name": JUMPED, "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "scheduler",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["gpt2m-serve-longprompt", "lfm2moe-serve-extract",
+                      "kanana2-serve-longdoc"]}
+    assert os.path.isfile(os.path.join(BENCH, "layer_metrics", JUMPED + ".py"))
+
+
 @pytest.mark.parametrize("cell,stands_for", [
     ("toy-serve-chat", "gpt2m-serve-chat"),
     ("toy-serve-longprompt", "gpt2m-serve-longprompt")])
 def test_the_rehearsal_reads_every_new_metric_of_its_cell(cell, stands_for):
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     want = {m["name"] for m in bench["per_layer"]
-            if m["name"] in NEW and stands_for in m["workloads"]}
-    assert len(want) == (9 if cell == "toy-serve-chat" else 6)
+            if m["name"] in NEW + [JUMPED] and stands_for in m["workloads"]}
+    assert len(want) == (9 if cell == "toy-serve-chat" else 7)
     p = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
          "--seed", str(2**31 + 42), "--seconds", "2", "--trace", "1"],

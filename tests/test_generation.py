@@ -39,6 +39,7 @@ from deeplearning4j_tpu.serving import (BlockPoolExhaustedError,
                                         ShapeMismatchError,
                                         xla_compile_count)
 from deeplearning4j_tpu.serving.generation import BlockAllocator
+from deeplearning4j_tpu.serving.generation.programs import ADMIT_LOOKAHEAD
 from deeplearning4j_tpu.telemetry import RecompileDetector, get_registry
 
 R = np.random.default_rng(99)
@@ -1586,3 +1587,350 @@ def test_a_slow_read_leaves_one_stall_event_a_second():
     finally:
         eng.stop()
     assert len(gc.callbacks) == hooks
+
+
+# ------------- admission chooses its batch (ISSUE 43): when more requests
+# wait than a pass can admit, the pass takes the head of the queue and the
+# next waiting requests of the head's prompt rung, cut back to a batch rung
+# they fill; when all that waits fits into the pass, it is arrival order
+@pytest.mark.parametrize("rungs,free,batches,want", [
+    # all that waits fits into the pass: all of it, in arrival order,
+    # mixed rungs and a batch rung it does not fill
+    ([16, 64, 16], 4, (1, 2, 4), [0, 1, 2]),
+    ([64, 16], 2, (1, 2), [0, 1]),
+    ([64], 1, (1, 2, 4), [0]),
+    # more wait than the pass takes: the head, then its rung's next
+    ([64, 16, 64, 16, 64, 64], 4, (1, 2, 4), [0, 2, 4, 5]),
+    ([16, 64, 64, 16, 64], 4, (1, 2, 4), [0, 3]),
+    # three of a rung: cut back to two, not three and an empty row
+    ([16, 64, 16, 64, 16, 64], 4, (1, 2, 4), [0, 2]),
+    # nobody of the head's rung waits: alone, never another rung's row
+    ([16, 64, 64, 64, 64], 4, (1, 2, 4), [0]),
+    # the free slots bound the batch, and three slots make a batch of two
+    ([64, 64, 64], 2, (1, 2, 4), [0, 1]),
+    ([64, 64, 64, 64, 64], 3, (1, 2, 4), [0, 1]),
+    # no batch rung of one: two fill the smallest, a lone head still goes
+    ([16, 16, 16], 2, (2, 4), [0, 1]),
+    ([16, 64, 64], 2, (2, 4), [0]),
+    # partners are looked for inside the lookahead only
+    ([64] + [16] * (ADMIT_LOOKAHEAD - 2) + [64, 64], 4, (1, 2, 4),
+     [0, ADMIT_LOOKAHEAD - 1]),
+    ([64] + [16] * (ADMIT_LOOKAHEAD - 1) + [64, 64], 4, (1, 2, 4), [0]),
+    # no free slot, nobody waiting
+    ([16, 16, 16], 0, (1, 2), []),
+    ([], 4, (1, 2), [])])
+def test_admission_choice(rungs, free, batches, want):
+    cfg = GenerationConfig(block_len=8, max_seq_len=64,
+                           prefill_batches=batches, prompt_rungs=(16, 64))
+    assert cfg.admission_choice(rungs, len(rungs), free) == want
+    # the scheduler hands over the first rungs only and says how many wait
+    assert cfg.admission_choice(rungs[:ADMIT_LOOKAHEAD], len(rungs),
+                                free) == want
+
+
+@pytest.mark.parametrize("seed,batches", [(1, (1, 2, 4)), (2, (1, 2, 4)),
+                                          (3, (1, 2)), (4, (2, 4)),
+                                          (5, (1, 4, 8))])
+def test_a_deep_mixed_queue_leaves_in_whole_batches_of_one_rung(seed,
+                                                                batches):
+    """The rule replayed without an engine: sixty requests of three rungs
+    arriving while the first forty leave, 1-9 slots free a pass. Every
+    pass of a deep queue shares one rung and fills a batch rung; a queue
+    that fits into the pass goes whole, in arrival order; the head goes at
+    every pass, so a request leaves within as many admitting passes as its
+    place in the queue when it arrived."""
+    cfg = GenerationConfig(block_len=8, max_seq_len=64,
+                           prefill_batches=batches, prompt_rungs=(16, 32, 64))
+    rng = np.random.default_rng(seed)
+    queue, due, passes = [], {}, 0
+
+    def arrive(n):
+        for _ in range(n):
+            rid = len(due)
+            queue.append((rid, int(rng.choice(cfg.prompt_rungs))))
+            due[rid] = passes + len(queue)       # its place, in passes
+    arrive(24)
+    while queue:
+        free = int(rng.integers(1, 10))
+        room = min(free, batches[-1])
+        took = cfg.admission_choice([r for _, r in queue], len(queue), free)
+        passes += 1
+        assert took and took[0] == 0 and took == sorted(set(took))
+        if len(queue) > room:
+            assert len({queue[i][1] for i in took}) == 1
+            assert len(took) <= room
+            assert len(took) in batches or len(took) < batches[0]
+        else:
+            assert took == list(range(len(queue)))
+        for i in took:
+            assert passes <= due[queue[i][0]]
+        for i in reversed(took):
+            del queue[i]
+        if len(due) < 60:
+            arrive(int(rng.integers(0, 5)))
+
+
+_ADMIT_PLAN = dict(block_len=8, max_seq_len=64, decode_slots=4,
+                   prefill_batches=(1, 2, 4), prompt_rungs=(16, 64))
+
+
+@pytest.fixture(scope="module")
+def admit_lm():
+    """One engine for the admission tests: two prompt rungs, batches of
+    one, two and four, four slots, no prefix cache (every request
+    prefills)."""
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", prefix_cache=False,
+                           **_ADMIT_PLAN)
+    yield net, TransformerDecodeSpec(net), eng
+    eng.stop()
+
+
+class _Prefills:
+    """Every prefill an engine launches while the block runs, recorded on
+    the loop's thread: its (P, L), the prompt lengths and slots of its
+    live rows, and what waited and what was free BEFORE its pass (nobody
+    submits meanwhile). ``draft`` holds the draft's prefills."""
+
+    def __init__(self, eng):
+        self.rt = eng._get("lm")
+        self.ps = self.rt.active_ps
+        self.seen, self.draft = [], []
+
+    def __enter__(self):
+        rt, ps, S = self.rt, self.ps, self.rt.config.decode_slots
+        self.orig = ps.run_prefill, ps.run_draft_prefill
+
+        def live(lengths, slots):
+            return ([int(n) for n, s in zip(lengths, slots) if s != S],
+                    [int(s) for s in slots if s != S])
+
+        def run(cache, tokens, lengths, tables, slots, *rest):
+            lens, rows = live(lengths, slots)
+            self.seen.append({
+                "P": tokens.shape[0], "L": tokens.shape[1], "lens": lens,
+                "slots": rows, "waiting": len(rt._queue) + len(lens),
+                "free": len(rt._slots_free) + len(lens)})
+            return self.orig[0](cache, tokens, lengths, tables, slots, *rest)
+
+        def draft(cache, tokens, lengths, slots):
+            lens, rows = live(lengths, slots)
+            self.draft.append({"P": tokens.shape[0], "L": tokens.shape[1],
+                               "lens": lens, "slots": rows})
+            return self.orig[1](cache, tokens, lengths, slots)
+
+        ps.run_prefill, ps.run_draft_prefill = run, draft
+        return self
+
+    def __exit__(self, *exc):
+        self.ps.run_prefill, self.ps.run_draft_prefill = self.orig
+
+
+def _serve_at_once(eng, prompts, lengths):
+    """Submit everything before the loop can admit any of it; returns the
+    tokens of each and the generation events of the run."""
+    rt = eng._get("lm")
+    _settle(eng)
+    seq0 = get_registry().last_seq
+    with rt._cond:
+        streams = [eng.generate(p, max_tokens=n, stream=True)
+                   for p, n in zip(prompts, lengths)]
+    outs = [st.result() for st in streams]
+    _settle(eng)
+    assert all(reason == "length" for _, reason in outs)
+    return [toks for toks, _ in outs], _generation_events(seq0)
+
+
+# twelve prompts of distinct lengths (a launch names its rows by them):
+# six pad to rung 16, six to rung 64, mixed in arrival order
+_MIXED = (5, 30, 9, 41, 12, 22, 7, 50, 3, 33, 14, 19)
+_MIXED_OUT = (3, 1, 6, 2, 5, 4, 1, 3, 2, 6, 4, 5)
+
+
+def test_a_deep_mixed_queue_prefills_whole_batches_of_one_rung(admit_lm):
+    """Twelve requests of two rungs wait for four slots. While more wait
+    than a pass can take, every prefill holds prompts of ONE rung in a
+    batch rung they fill; the passes at the end, which take all that
+    waits, are arrival order. Every request is admitted within as many
+    passes as its place at arrival, its ``jumped`` counts the earlier
+    arrivals it went ahead of, and every token is the full recompute's."""
+    net, spec, eng = admit_lm
+    cfg = eng._get("lm").config
+    prompts = _prompts(47, _MIXED, seed=4343)
+    refs = [naive_generate(net, p, n, pad_to=64, spec=spec)
+            for p, n in zip(prompts, _MIXED_OUT)]
+    jumped0 = eng.metrics()["lm"]["admits_jumped"]
+    with _Prefills(eng) as rec:
+        outs, events = _serve_at_once(eng, prompts, _MIXED_OUT)
+    assert outs == refs
+    waiting = list(_MIXED)
+    deep = 0
+    for launch in rec.seen:
+        lens = launch["lens"]
+        assert launch["waiting"] == len(waiting)
+        room = min(launch["free"], cfg.prefill_batches[-1])
+        if len(waiting) > room:
+            deep += 1
+            assert lens[0] == waiting[0]                 # the head, first
+            assert {cfg.prompt_rung(n) for n in lens} == {launch["L"]}
+            assert len(lens) == launch["P"]              # no empty row
+        else:
+            assert lens == waiting
+            assert launch["L"] == cfg.prompt_rung(max(lens))
+        waiting = [n for n in waiting if n not in lens]
+    assert not waiting and deep >= 2
+    # the first pass had four slots and four waiting prompts of the head's
+    # rung: one (4, 16) program
+    assert (rec.seen[0]["P"], rec.seen[0]["L"]) == (4, 16)
+    assert rec.seen[0]["lens"] == [5, 9, 12, 7]
+    went = {n: k for k, launch in enumerate(rec.seen)
+            for n in launch["lens"]}
+    admits = {e["args"]["prompt_len"]: e["args"]["jumped"]
+              for e in _named(events, "generation.admit", ph="i")}
+    for place, n in enumerate(_MIXED):
+        assert went[n] <= place
+        assert admits[n] == sum(went[m] > went[n] for m in _MIXED[:place])
+    assert sum(v > 0 for v in admits.values()) >= 3
+    assert eng.metrics()["lm"]["admits_jumped"] - jumped0 == \
+        sum(v > 0 for v in admits.values())
+    assert get_registry().snapshot()["counters"][
+        "generation.lm.admits_jumped"] >= sum(v > 0 for v in admits.values())
+
+
+@pytest.mark.parametrize("sizes,program", [
+    ((5, 30, 9), (4, 64)),        # an empty row, two prompts in a wide one
+    ((30, 5), (2, 64)),
+    ((7,), (1, 16)),
+    ((9, 5, 30, 12), (4, 64))])
+def test_a_queue_that_fits_into_one_pass_is_admitted_as_it_arrived(
+        admit_lm, sizes, program):
+    """The pass of an engine with time to spare: all that waits, in
+    arrival order, padded to the longest prompt's rung and the next batch
+    rung, and nobody went ahead of anybody."""
+    net, spec, eng = admit_lm
+    prompts = _prompts(47, sizes, seed=4344)
+    refs = [naive_generate(net, p, 3, pad_to=64, spec=spec) for p in prompts]
+    with _Prefills(eng) as rec:
+        outs, events = _serve_at_once(eng, prompts, [3] * len(sizes))
+    assert outs == refs
+    (launch,) = rec.seen
+    assert (launch["P"], launch["L"]) == program
+    assert launch["lens"] == list(sizes)
+    admits = _named(events, "generation.admit", ph="i")
+    assert [e["args"]["prompt_len"] for e in admits] == list(sizes)
+    assert all(e["args"]["jumped"] == 0 for e in admits)
+    (sp,) = _named(events, "generation.prefill", ph="X", cat="span")
+    assert (sp["args"]["batch"], sp["args"]["rows"], sp["args"]["rung"]) == (
+        len(sizes), program[0], program[1])
+
+
+def test_grouped_admission_emits_the_one_at_a_time_greedy_tokens(admit_lm):
+    """Beside ``test_continuous_and_one_at_a_time_emit_same_greedy_tokens``:
+    the order in which a deep queue is admitted is not a numerics choice
+    either. Twelve clients at once over four slots and two rungs, and one
+    caller at a time on a one-slot engine, emit the same tokens."""
+    net, _, eng = admit_lm
+    prompts = _prompts(47, _MIXED, seed=4345)
+    outs, events = _serve_at_once(eng, prompts, [7] * len(prompts))
+    assert any(e["args"]["jumped"] for e in
+               _named(events, "generation.admit", ph="i"))
+    serial = GenerationEngine(net, model_name="lm", block_len=8,
+                              max_seq_len=64, decode_slots=1,
+                              prefill_batches=(1,), prompt_rungs=(16, 64),
+                              prefix_cache=False)
+    try:
+        for i, p in enumerate(prompts):
+            assert serial.generate(p, max_tokens=7)[0] == outs[i], \
+                f"prompt {i} diverged under the grouped order"
+    finally:
+        serial.stop()
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["no_prefix_cache", "prefix_cache"])
+def tight_lm(request):
+    """The admission tests' plan over a pool of twelve usable blocks (a
+    sequence of 64 tokens takes eight): admission is short of blocks, not
+    of slots."""
+    net = _pipe_lm()
+    eng = GenerationEngine(net, model_name="lm", num_blocks=13,
+                           prefix_cache=request.param, **_ADMIT_PLAN)
+    yield net, TransformerDecodeSpec(net), eng
+    eng.stop()
+
+
+def test_a_head_that_does_not_fit_admits_nobody(tight_lm):
+    """A (six blocks) is admitted; its partner by rung, B (seven), does
+    not fit beside it, which ends that pass's search. Then B heads the
+    queue and five small requests of the other rung wait behind it with
+    three slots and six blocks free: nobody is admitted until A's blocks
+    come back, and B goes first."""
+    net, spec, eng = tight_lm
+    sizes, lengths = (40, 50, 5, 7, 3, 9, 11), (8, 6, 3, 1, 5, 2, 4)
+    prompts = _prompts(47, sizes, seed=4346)
+    refs = [naive_generate(net, p, n, pad_to=64, spec=spec)
+            for p, n in zip(prompts, lengths)]
+    with _Prefills(eng) as rec:
+        outs, events = _serve_at_once(eng, prompts, lengths)
+    assert outs == refs
+    assert [launch["lens"] for launch in rec.seen[:2]] == [[40], [50]]
+    assert rec.seen[1]["free"] == 4         # A had ended: B waited for it
+    assert all(e["args"]["jumped"] == 0 for e in
+               _named(events, "generation.admit", ph="i")
+               if e["args"]["prompt_len"] in (40, 50))
+    eng._get("lm")._check_quiesce()
+
+
+def test_a_partner_that_does_not_fit_is_cut_back_to_a_whole_batch(tight_lm):
+    """Six requests of one rung, four blocks each, four slots free and
+    twelve blocks: three fit, the fourth ends the search, and three would
+    launch a (4, 16) program with an empty row while others wait. The
+    pass takes two; the third goes back to its place with its blocks and
+    heads the next pass, so the order of arrival holds."""
+    net, spec, eng = tight_lm
+    sizes = (16, 15, 14, 13, 12, 11)
+    lengths = [26 - n for n in sizes]
+    prompts = _prompts(47, sizes, seed=4347)
+    refs = [naive_generate(net, p, n, pad_to=64, spec=spec)
+            for p, n in zip(prompts, lengths)]
+    with _Prefills(eng) as rec:
+        outs, events = _serve_at_once(eng, prompts, lengths)
+    assert outs == refs
+    assert rec.seen[0]["lens"] == [16, 15] and rec.seen[0]["P"] == 2
+    assert [n for launch in rec.seen for n in launch["lens"]] == list(sizes)
+    assert all(len(launch["lens"]) == launch["P"] for launch in rec.seen)
+    admits = _named(events, "generation.admit", ph="i")
+    assert [e["args"]["prompt_len"] for e in admits] == list(sizes)
+    assert all(e["args"]["jumped"] == 0 for e in admits)
+    rt = eng._get("lm")
+    rt._check_quiesce()
+    assert len(rt._slots_free) == 4 and eng.models()["lm"]["in_flight"] == 0
+
+
+def test_a_speculating_cohort_drafts_the_candidates_the_pass_chose():
+    """The draft's prefill takes the requests the admission pass chose:
+    the same rows in the same (P, L) as the target's prefill of that pass,
+    whatever the order the queue was admitted in, and speculation over
+    the grouped order still emits the full recompute's tokens."""
+    net = _lm()
+    eng = GenerationEngine(net, model_name="lm", block_len=8, max_seq_len=64,
+                           decode_slots=2, prefill_batches=(1, 2),
+                           prompt_rungs=(16, 64), prefix_cache=False,
+                           draft=truncated_draft(net, 1), spec_k=2)
+    try:
+        spec = TransformerDecodeSpec(net)
+        sizes, lengths = (5, 30, 9, 41, 12, 22), (6, 3, 5, 4, 7, 2)
+        prompts = _prompts(53, sizes, seed=4348)
+        refs = [naive_generate(net, p, n, pad_to=64, spec=spec)
+                for p, n in zip(prompts, lengths)]
+        with _Prefills(eng) as rec:
+            outs, events = _serve_at_once(eng, prompts, lengths)
+        assert outs == refs
+        assert rec.seen[0]["lens"] == [5, 9]         # grouped by rung
+        assert [{k: launch[k] for k in ("P", "L", "lens", "slots")}
+                for launch in rec.seen] == rec.draft
+        assert any(e["args"]["jumped"] for e in
+                   _named(events, "generation.admit", ph="i"))
+        assert eng.metrics()["lm"]["speculative"]["verify_steps"] > 0
+    finally:
+        eng.stop()

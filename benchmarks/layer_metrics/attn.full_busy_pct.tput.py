@@ -1,0 +1,18 @@
+"""Share of the device's busy time inside the attention kernels of the
+layers that keep the whole context (``flash_attention_fwd`` in a prefill,
+``paged_attention_decode`` in a decode step), in a model that also has
+sliding-window layers. Read beside ``attn.window_busy_pct.tput``: which
+kind of layer carries the cell."""
+import importlib
+
+
+def read(obs):
+    tr, cfg = obs.get("trace"), obs.get("config", {})
+    if not tr or "sliding_window" not in cfg or not tr.get("busy_s"):
+        return None
+    names = importlib.import_module(
+        f"benchmarks.families.{cfg['family']}.kernel_costs").FULL_KERNELS
+    ops = tr.get("by_op_s", {})
+    if not any(n in ops for n in names):
+        return None
+    return 100.0 * sum(ops.get(n, 0.0) for n in names) / tr["busy_s"]

@@ -8,7 +8,9 @@ zoo models:
 - ``GraphDecodeSpec`` (``TransformerDecodeSpec`` is its older name) — reads
   a language-model ``ComputationGraph`` by the kinds of its layers, not by
   one builder's vertex names: causal attention mixers go through a
-  ``KVStore``, recurrent mixers (the gated short convolution) through a
+  ``KVStore`` (those that keep the whole context through its pages, those
+  with a sliding window through its rings; the layers share one key-value
+  row layout and may differ in query heads), recurrent mixers (the gated short convolution) through a
   per-slot state the store carries, norms / MLPs / expert layers / adds
   replay their own ``apply``. ``models.transformer_lm`` is one case. It
   exposes:
@@ -74,7 +76,14 @@ class KVStore(Protocol):
         (the softmax scale, which the row's width does not give) and
         ``value_lanes`` (the leading lanes of a row that are its value)
         say how to read the rows. Returns [B,H,1,value_lanes]. A store
-        that keeps K/V pairs alone takes no such call."""
+        that keeps K/V pairs alone takes no such call.
+
+        A layer with a sliding window calls with the keyword ``window``
+        (its width): ``i`` then counts the WINDOW layers, whose rows the
+        store keeps apart from the pages (no more than the window and a
+        page a sequence), and the row sees the keys ``pos - window <
+        s <= pos``. A store that keeps whole contexts alone takes no such
+        call."""
         ...
 
     def state(self, j: int) -> Any:
@@ -121,6 +130,15 @@ class LatentDecodeUnsupportedError(ValueError):
     latent row a token. Refused by name, as a recurrent state is."""
 
 
+class WindowDecodeUnsupportedError(ValueError):
+    """A serving feature that keeps or shares a sequence's WHOLE context
+    per layer (the prefix cache's shared pages, the int8 tier, a
+    speculative verify window or draft, pools split over a mesh) was asked
+    of a model with sliding-window attention layers, whose cache keeps no
+    more than the window's rows a sequence. Refused by name, as a
+    recurrent state and a latent cache are."""
+
+
 class GraphDecodeSpec:
     """A language-model ``ComputationGraph`` read by the KINDS of its
     layers, validated for the incremental decode path: token embedding
@@ -132,7 +150,16 @@ class GraphDecodeSpec:
     head. Every other vertex (norms, dense and gated MLPs,
     mixture-of-experts layers, residual adds) is position-wise and
     replays its own ``apply``. ``models.transformer_lm`` (GPT-2) is one
-    case, the hybrid convolution / attention / expert models another."""
+    case, the hybrid convolution / attention / expert models another.
+
+    What the attention layers must share is the cache's ROW: all latent
+    (one ``row_lanes`` and ``kv_rank``) or all K/V with one ``kv_heads`` x
+    ``head_dim``. Query heads may differ by layer (a layer's own count
+    shapes its projections), and so may what a layer keeps: the whole
+    context (``window`` None: the paged pools, ``full_names``) or a
+    sliding window's rows (``window_names``, all of one width: a ring a
+    slot beside the pages). At least one layer keeps the whole context:
+    the pages' tables are what admission counts."""
 
     def __init__(self, net):
         from ..nn.layers import (EmbeddingSequenceLayer,
@@ -197,27 +224,56 @@ class GraphDecodeSpec:
         # the cache's KIND: K/V pages by head, or one latent row a token
         self.latent = isinstance(attn0, LatentAttentionLayer)
 
-        def layout(a):
-            return (a.n_heads, a.n_out) + (
-                (a.row_lanes, a.kv_rank) if self.latent else (a.kv_heads,))
+        def row(a):
+            return (a.row_lanes, a.kv_rank) if self.latent \
+                else (a.kv_heads, a.head_dim)
 
         for n in self.attn_names:
             a = layer(n)
             if not a.causal:
                 raise ValueError("decode requires causal attention "
                                  f"blocks ({n} is not)")
-            if type(a) is not type(attn0) or layout(a) != layout(attn0):
-                raise ValueError("the attention layers must share one "
-                                 "head layout (one pool holds them all)")
+            if type(a) is not type(attn0) or row(a) != row(attn0):
+                raise ValueError(
+                    "the attention layers must share one cache row (all "
+                    "latent with one row width and rank, or all K/V with "
+                    f"one kv_heads x head size): {n} keeps {row(a)}, "
+                    f"{self.attn_names[0]} {row(attn0)}; query heads and "
+                    "windows may differ by layer")
+            if self.latent and a.n_heads != attn0.n_heads:
+                raise ValueError("latent attention layers must share their "
+                                 "query heads")
+        # what a layer KEEPS: the whole context (the paged pools) or a
+        # sliding window's rows (a ring a slot)
+        self.window_names = [n for n in self.attn_names
+                             if getattr(layer(n), "window", None)]
+        self.full_names = [n for n in self.attn_names
+                           if n not in self.window_names]
+        widths = sorted({layer(n).window for n in self.window_names})
+        if len(widths) > 1:
+            raise ValueError("the sliding-window layers must share one "
+                             f"window (one ring a slot), got {widths}")
+        self.window = widths[0] if widths else None
+        if not self.full_names:
+            raise ValueError(
+                "every attention layer has a sliding window "
+                f"({self.window_names}): at least one must keep the whole "
+                "context (admission counts the pages of those layers)")
         for n in self.recurrent_names:
             if not hasattr(layer(n), "state_at"):
                 raise ValueError(
                     f"recurrent mixer {n} ({type(layer(n)).__name__}) has "
                     f"no state_at: its state at a padded prompt's true "
                     f"length cannot be read from one batched forward")
-        self._attn_i = {n: i for i, n in enumerate(self.attn_names)}
+        # a layer's index among the layers of its kind of cache
+        self._attn_i = {n: i for names in (self.full_names,
+                                           self.window_names)
+                        for i, n in enumerate(names)}
         self._rec_j = {n: j for j, n in enumerate(self.recurrent_names)}
-        self.n_blocks = len(self.attn_names)       # layers the pools hold
+        self.n_blocks = len(self.full_names)       # layers the pools hold
+        self.n_window_layers = len(self.window_names)
+        # the FIRST attention layer's; a layer's own count is what its
+        # projections and its attention use
         self.n_heads = attn0.n_heads
         self.d_model = attn0.n_out
         # what a pool's row holds: ``kv_heads`` heads of ``head_dim``; a
@@ -248,9 +304,10 @@ class GraphDecodeSpec:
         even head split keeps every per-head row on one shard and decode
         stays token-for-token identical to the single-chip program. A
         latent pool has no head axis: every head reads every row."""
-        if self.latent:
+        if self.latent or self.window is not None:
             return m == 1
-        return m >= 1 and self.kv_heads % m == 0 and self.n_heads % m == 0
+        return m >= 1 and self.kv_heads % m == 0 and all(
+            self._v[n].layer_conf.n_heads % m == 0 for n in self.attn_names)
 
     def recurrent_state_shape(self, rows: int):
         """[recurrent layers, rows, ...]: the per-slot state of the
@@ -345,7 +402,9 @@ class GraphDecodeSpec:
         the rung, and the expert counters count live rows only.
 
         Returns (logits [B,V] pre-activation or None, ks, vs, states,
-        stats): ks[i]/vs[i] [B,L,Hkv,Dh] an attention layer, as a cache
+        stats): ks[i]/vs[i] [B,L,Hkv,Dh] an attention layer in
+        ``attn_names``' order (``split_kinds`` parts the layers that keep
+        the whole context from those that keep a window), as a cache
         keeps them (k normed and rotated where the layer does that; a
         latent layer's ks[i] is its cache rows [B,L,1,row] and vs is
         empty: one pool); states[j] a recurrent mixer's state after
@@ -375,6 +434,14 @@ class GraphDecodeSpec:
             stats = [self._moe_stats(params, n, acts[self._inputs[n][0]],
                                      live) for n in self.moe_names]
         return logits, ks, vs, states, self._fold_stats(stats)
+
+    def split_kinds(self, per_layer):
+        """A list an attention layer (``attn_names``' order) -> (the
+        full-context layers', the window layers'), each in its pool's
+        order."""
+        by_name = dict(zip(self.attn_names, per_layer))
+        return ([by_name[n] for n in self.full_names],
+                [by_name[n] for n in self.window_names])
 
     def prefill_forward(self, params, state, tokens, rows):
         """``prefill_full`` for a model that keeps K/V alone: (logits, ks,
@@ -444,10 +511,19 @@ class GraphDecodeSpec:
                 ap, layer.unabsorb(ap, out.transpose(0, 2, 1, 3)))
         q, k, v = layer.project_qkv(ap, y, w_pos)
         q = q.transpose(0, 2, 1, 3)                            # [B,H,W,Dh]
-        out = store.attend(i, q, k, v) if window else \
-            store.attend(i, q, k[:, 0], v[:, 0])
-        out = out.transpose(0, 2, 1, 3).reshape(B, W, self.d_model)
-        return layer.project_output(ap, out)
+        if layer.window is not None:
+            if window:
+                raise WindowDecodeUnsupportedError(
+                    f"a decode window does not carry {name}'s ring of "
+                    f"{layer.window} rows")
+            out = store.attend(i, q, k[:, 0], v[:, 0], window=layer.window)
+        else:
+            out = store.attend(i, q, k, v) if window else \
+                store.attend(i, q, k[:, 0], v[:, 0])
+        # the layer's OWN heads: layers of one model may differ in them
+        out = out.transpose(0, 2, 1, 3).reshape(
+            B, W, layer.n_heads * layer.head_dim)
+        return layer.project_output(ap, out, y)
 
     # ---------------------------------------------------------- decode step
     def decode_step(self, params, state, tokens, pos, store: KVStore):

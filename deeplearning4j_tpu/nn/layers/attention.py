@@ -15,6 +15,7 @@ from typing import Any, ClassVar, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..conf.serde import register
 from ..inputs import InputTypeRecurrent
@@ -26,11 +27,21 @@ from .base import LayerConf, maybe_dropout, resolve_ff_size
 class SelfAttentionLayer(LayerConf):
     """Multi-head self-attention, [B,T,F] -> [B,T,n_out].
 
-    ``n_out`` must be divisible by ``n_heads``. With ``causal`` each position
-    attends only to itself and earlier steps. A [B,T] feature mask excludes
-    padded timesteps as attention KEYS (queries at masked positions produce
+    ``n_out`` must be divisible by ``n_heads`` unless the heads have a size
+    of their own (``head_size``). With ``causal`` each position attends
+    only to itself and earlier steps. A [B,T] feature mask excludes padded
+    timesteps as attention KEYS (queries at masked positions produce
     outputs that downstream masked losses ignore, matching the framework's
     masking convention).
+
+    The fields below ``bias`` are off by default and leave a layer that
+    sets none of them its parameters, its program and its numbers:
+
+        q = x Wq -> [H, Dh];  k = x Wk, v = x Wv -> [Hkv, Dh]
+        rotate the first ``rotary_dim`` values of each head of q and k
+        o_h = softmax over the keys s with t - window < s <= t
+        g = sigmoid(x Wg) -> [H]              (``head_gate``)
+        out = concat_h(g_h o_h) Wo            ([H * Dh, n_out])
     """
     n_in: Optional[int] = None
     n_out: int = 0
@@ -49,10 +60,28 @@ class SelfAttentionLayer(LayerConf):
     # (the positions then come from a PositionalEmbeddingLayer, or nowhere)
     rope_theta: Optional[float] = None
     bias: bool = True                  # the output projection's bias
+    # a head size apart from ``n_out // n_heads``: the projections are then
+    # ``n_in -> H x head_size`` (q), ``n_in -> Hkv x head_size`` (k, v) and
+    # ``H x head_size -> n_out`` (Wo)
+    head_size: Optional[int] = None
+    # sliding window: position t sees the keys s with t - window < s <= t
+    # (itself included); causal only
+    window: Optional[int] = None
+    # one sigmoid gate a head and token from the layer's input (``Wg:
+    # n_in -> H``, no bias), on the head's output before ``Wo``
+    head_gate: bool = False
+    # rotate the first ``rotary_dim`` values of a head (rotate-half form
+    # within them) and pass the rest through; None = the whole head
+    rotary_dim: Optional[int] = None
+    # how the rotation's frequencies are scaled; today the YaRN rule:
+    # {"rope_type": "yarn", "factor", "original_max_position_embeddings",
+    # "beta_fast", "beta_slow"[, "attention_factor"]}
+    rope_scaling: Optional[dict] = None
 
     param_order: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wo", "b",
-                                              "q_gain", "k_gain")
-    weight_param_names: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wo")
+                                              "q_gain", "k_gain", "Wg")
+    weight_param_names: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wo",
+                                                     "Wg")
     expected_input: ClassVar[str] = "rnn"
     accepts_mask: ClassVar[bool] = True
 
@@ -66,44 +95,108 @@ class SelfAttentionLayer(LayerConf):
 
     @property
     def head_dim(self) -> int:
-        return self.n_out // self.n_heads
+        return self.head_size or self.n_out // self.n_heads
 
     def init(self, rng, itype, dtype):
         n_in = self.n_in or resolve_ff_size(itype)
         self.n_in = n_in
-        if self.n_out % self.n_heads:
+        if self.head_size is None and self.n_out % self.n_heads:
             raise ValueError(f"n_out={self.n_out} must be divisible by "
                              f"n_heads={self.n_heads}")
         if self.n_heads % self.kv_heads:
             raise ValueError(f"n_heads={self.n_heads} must be divisible by "
                              f"n_kv_heads={self.kv_heads}")
-        ks = jax.random.split(rng, 4)
+        if self.window is not None and (self.window < 1 or not self.causal):
+            raise ValueError("a sliding window is positive and causal")
+        if (self.rotary_dim or 0) % 2 or \
+                (self.rotary_dim or 0) > self.head_dim:
+            raise ValueError(f"rotary_dim={self.rotary_dim} must be even "
+                             f"and at most the head size {self.head_dim}")
+        if self.rope_theta is not None:
+            self.rope_frequencies()        # a rule it does not know: now
+        elif self.rotary_dim or self.rope_scaling:
+            raise ValueError("rotary_dim and rope_scaling need a rope_theta")
+        ks = jax.random.split(rng, 5 if self.head_gate else 4)
         d = self.n_out
+        dq = self.n_heads * self.head_dim  # == d unless head_size is set
         dkv = self.kv_heads * self.head_dim
         params = {
-            "Wq": self._winit(ks[0], (n_in, d), n_in, d, dtype),
+            "Wq": self._winit(ks[0], (n_in, dq), n_in, dq, dtype),
             "Wk": self._winit(ks[1], (n_in, dkv), n_in, dkv, dtype),
             "Wv": self._winit(ks[2], (n_in, dkv), n_in, dkv, dtype),
-            "Wo": self._winit(ks[3], (d, d), d, d, dtype),
+            "Wo": self._winit(ks[3], (dq, d), dq, d, dtype),
         }
         if self.bias:
             params["b"] = self._binit((d,), dtype)
         if self.qk_norm:
             params["q_gain"] = jnp.ones((self.head_dim,), dtype)
             params["k_gain"] = jnp.ones((self.head_dim,), dtype)
+        if self.head_gate:
+            params["Wg"] = self._winit(ks[4], (n_in, self.n_heads), n_in,
+                                       self.n_heads, dtype)
         return params, {}
+
+    def rope_frequencies(self):
+        """(inv_freq float32 numpy [rotated / 2], the factor on cos and
+        sin): computed once, on the host, in float64 rounded to float32.
+        The default is ``theta^(-2i/R)`` and 1; the YaRN rule blends, pair
+        by pair, those frequencies with the same divided by ``factor``:
+        pairs that turn more than ``beta_fast`` times over the original
+        length keep theirs, pairs that turn fewer than ``beta_slow`` times
+        are interpolated, a linear ramp between, and cos and sin carry
+        ``0.1 ln(factor) + 1``."""
+        R = self.rotary_dim or self.head_dim
+        f = float(self.rope_theta) ** (-np.arange(R // 2, dtype=np.float64)
+                                       / (R // 2))
+        sc = self.rope_scaling
+        if not sc:
+            return f.astype(np.float32), 1.0
+        kind = sc.get("rope_type", sc.get("type"))
+        if kind != "yarn":
+            raise ValueError(f"rope_scaling of type {kind!r} is not "
+                             "supported (yarn is)")
+        factor = float(sc["factor"])
+        orig = float(sc["original_max_position_embeddings"])
+
+        def turns_at(n_rot):             # the pair that turns n_rot times
+            return R * np.log(orig / (n_rot * 2 * np.pi)) \
+                / (2 * np.log(float(self.rope_theta)))
+        low = max(int(np.floor(turns_at(float(sc.get("beta_fast", 32))))), 0)
+        high = min(int(np.ceil(turns_at(float(sc.get("beta_slow", 1))))),
+                   R - 1)
+        ramp = np.clip((np.arange(R // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv = ramp * f / factor + (1.0 - ramp) * f
+        att = sc.get("attention_factor")
+        att = 0.1 * np.log(factor) + 1.0 if att is None else float(att)
+        return inv.astype(np.float32), float(att)
 
     def _rotate(self, x, positions):
         """Rotary positions, rotate-half form: x [B,T,H,Dh], positions
         [B,T]; angles in float32."""
-        half = self.head_dim // 2
-        inv = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-        ang = positions.astype(jnp.float32)[:, :, None, None] * inv
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        if self.rotary_dim is None and self.rope_scaling is None:
+            # the default form as it always traced (the frequencies made in
+            # the program): the accepted families' programs are held to it
+            half = self.head_dim // 2
+            inv = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32)
+                                      / half)
+            ang = positions.astype(jnp.float32)[:, :, None, None] * inv
+            cos, sin = jnp.cos(ang), jnp.sin(ang)
+            xf = x.astype(jnp.float32)
+            x1, x2 = xf[..., :half], xf[..., half:]
+            return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                                   axis=-1).astype(x.dtype)
+        # part of the head, scaled frequencies, or both: rotate-half within
+        # the first ``R`` values, the rest passes through
+        R = self.rotary_dim or self.head_dim
+        inv, factor = self.rope_frequencies()
+        ang = positions.astype(jnp.float32)[:, :, None, None] * jnp.asarray(inv)
+        cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
         xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1).astype(x.dtype)
+        x1, x2 = xf[..., :R // 2], xf[..., R // 2:R]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., R:]],
+            axis=-1).astype(x.dtype)
 
     def project_qkv(self, params, x, positions=None):
         """x [B,T,F] -> (q [B,T,Hq,Dh], k [B,T,Hkv,Dh], v [B,T,Hkv,Dh]) as
@@ -125,9 +218,15 @@ class SelfAttentionLayer(LayerConf):
             q, k = self._rotate(q, positions), self._rotate(k, positions)
         return q, k, v
 
-    def project_output(self, params, out):
+    def project_output(self, params, out, x=None):
         """The heads' output [B,T,H*Dh] through Wo (and its bias) and the
-        layer's activation."""
+        layer's activation. ``x`` [B,T,F], the layer's input, is what a
+        ``head_gate`` is computed from."""
+        if self.head_gate:
+            g = jax.nn.sigmoid(x @ params["Wg"])               # [B,T,H]
+            B, T, _ = out.shape
+            out = (out.reshape(B, T, self.n_heads, self.head_dim)
+                   * g[..., None]).reshape(B, T, -1)
         if self.project_out:
             out = out @ params["Wo"]
             if self.bias:
@@ -140,6 +239,9 @@ class SelfAttentionLayer(LayerConf):
         from ...parallel.ring_attention import attention
         x = maybe_dropout(x, self.dropout, rng, train)
         q, k, v = self.project_qkv(params, x)
+        if self.window is not None:
+            return self.project_output(
+                params, self._windowed(q, k, v, train, mask), x), state
         group = self.n_heads // self.kv_heads
         if group > 1:
             # the kernels take equal heads: each key-value head repeated
@@ -153,7 +255,29 @@ class SelfAttentionLayer(LayerConf):
         else:
             out = attention(q, k, v, causal=self.causal, key_mask=mask)
         out = out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
-        return self.project_output(params, out), state
+        return self.project_output(params, out, x), state
+
+    def _windowed(self, q, k, v, train, mask):
+        """Attention under the sliding window: q [B,T,H,Dh], k/v
+        [B,T,Hkv,Dh] -> [B,T,H*Dh]. The windowed flash kernel is forward
+        only, takes no key mask and reads grouped key-value heads in
+        place; a training step or a masked batch runs the XLA path with
+        the window in its mask."""
+        from ...ops.pallas_attention import (flash_attention,
+                                             fused_attention_applicable)
+        from ...parallel.ring_attention import attention
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        B, H, T, Dh = q.shape
+        if not train and mask is None and \
+                fused_attention_applicable(B, H, T, Dh, q.dtype):
+            out = flash_attention(q, k, v, causal=True, window=self.window)
+        else:
+            group = H // k.shape[1]
+            if group > 1:
+                k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+            out = attention(q, k, v, causal=True, key_mask=mask,
+                            window=self.window)
+        return out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
 
 
 @register

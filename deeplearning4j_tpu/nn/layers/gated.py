@@ -139,9 +139,12 @@ class GatedShortConvLayer(LayerConf):
 @register
 @dataclass
 class MixtureOfExpertsLayer(LayerConf):
-    """Mixture of gated-MLP experts with a sigmoid router, [..., d] -> [..., d].
+    """Mixture of gated-MLP experts with a sigmoid (or, by ``score``, a
+    softmax) router, [..., d] -> [..., d].
 
         s = sigmoid(x Wg)                       over all ``n_experts``
+                                                (``score="softmax"``:
+                                                softmax over them)
         chosen = top_k of (s + bias)            the bias takes part in the
                                                 choice only
         w = s[chosen] / (sum s[chosen] + eps)   (``norm_topk``) * scale
@@ -171,6 +174,10 @@ class MixtureOfExpertsLayer(LayerConf):
     norm_topk: bool = True
     norm_eps: float = 1e-6
     routed_scaling_factor: float = 1.0
+    # the router's score function over the logits x Wg, by name: "sigmoid"
+    # (each expert for itself) or "softmax" (over all ``n_experts``); the
+    # choice, the normalisation and the scale that follow are the same
+    score: str = "sigmoid"
 
     param_order: ClassVar[Tuple[str, ...]] = ("Wg", "bias", "W1", "W3", "W2")
     weight_param_names: ClassVar[Tuple[str, ...]] = ("Wg", "W1", "W3", "W2")
@@ -197,6 +204,9 @@ class MixtureOfExpertsLayer(LayerConf):
                 and 1 <= self.top_k <= self.n_experts):
             raise ValueError("MixtureOfExpertsLayer needs n_experts, "
                              "n_hidden and 1 <= top_k <= n_experts")
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score={self.score!r}: the router scores by "
+                             "'sigmoid' or 'softmax'")
         _, E = self.held_range()
         F = self.n_hidden
         kg, k1, k2, k3 = jax.random.split(rng, 4)
@@ -211,8 +221,10 @@ class MixtureOfExpertsLayer(LayerConf):
         """x [N, d] -> (idx [N, top_k] int32 expert ids, w [N, top_k]
         float32 weights). The scores are accumulated in float32: a choice
         between near-equal experts should not hang on a bfloat16 sum."""
-        s = jax.nn.sigmoid(jnp.matmul(x, params["Wg"],
-                                      preferred_element_type=jnp.float32))
+        logits = jnp.matmul(x, params["Wg"],
+                            preferred_element_type=jnp.float32)
+        s = jax.nn.softmax(logits, axis=-1) if self.score == "softmax" \
+            else jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(s + params["bias"].astype(jnp.float32),
                                self.top_k)
         idx = idx.astype(jnp.int32)

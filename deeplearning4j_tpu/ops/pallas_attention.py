@@ -26,6 +26,12 @@ Design (same helper-probe-with-fallback seam as ops/pallas_lstm.py):
     it is computed without a mask, and only a tile the diagonal crosses
     pays the iota/compare/select. ``tile_schedule`` counts what that comes
     to for the tiles ``_blocks`` picks (T=1024: 10 of 16 tiles, 4 masked).
+  - a sliding WINDOW (``flash_attention(..., window=W)``) is a case of the
+    same rule: tiles wholly older than the window are skipped, tiles its
+    trailing edge crosses are masked. It runs a forward kernel of its own
+    (``flash_attention_window_fwd``) whose grid spans, per row block, only
+    the resident key blocks the window reaches, and which reads grouped
+    key-value heads in place. Forward only.
   - ``scale`` is folded into a [rows, D] operand (q; dq's accumulator),
     never multiplied over a score tile.
   - masking uses a large negative (-1e30) everywhere, matching the XLA
@@ -165,35 +171,47 @@ def _resident(T: int, BQ: int, BK: int, causal: bool) -> tuple:
 SKIP, FULL, DIAG = "skip", "full", "diag"
 
 
-def _tile_rule(row0, rows, col0, cols):
+def _tile_rule(row0, rows, col0, cols, window=None):
     """THE rule of which score tiles causal attention visits, for the tile
     of query rows [row0, row0 + rows) and key columns [col0, col0 + cols):
     ``(skip, full)``. skip: every column lies after every row, the tile
     holds no position with col <= row. full: every column is at or before
     every row, no position is masked. Neither: the diagonal crosses it.
     Python ints give bools (the static tile loops, ``tile_schedule``);
-    program ids give traced predicates (the grid). A sliding window or
-    segment mask changes this function and ``_scores``' mask, nothing
-    else."""
-    return col0 > row0 + rows - 1, col0 + cols - 1 <= row0
+    program ids give traced predicates (the grid).
+
+    A sliding ``window`` (row t sees the columns s with t - window < s <=
+    t, itself included) adds its trailing edge: skip also where every
+    column lies at or before ``row0 - window`` (not even the tile's first
+    row sees its last column), full only where the tile's last row still
+    sees its first column; a tile either edge crosses, or both, is masked.
+    A segment mask would change this function and ``_scores``' mask,
+    nothing else."""
+    skip, full = col0 > row0 + rows - 1, col0 + cols - 1 <= row0
+    if window is None:
+        return skip, full
+    return (skip | (col0 + cols - 1 <= row0 - window),
+            full & (col0 > row0 + rows - 1 - window))
 
 
 def tile_kind(row0: int, rows: int, col0: int, cols: int,
-              causal: bool = True) -> str:
-    """SKIP, FULL or DIAG for one tile at static offsets."""
+              causal: bool = True, window: Optional[int] = None) -> str:
+    """SKIP, FULL or DIAG for one tile at static offsets (DIAG: masked, by
+    the diagonal, by a window's trailing edge, or by both)."""
     if not causal:
         return FULL
-    skip, full = _tile_rule(row0, rows, col0, cols)
+    skip, full = _tile_rule(row0, rows, col0, cols, window)
     return SKIP if skip else FULL if full else DIAG
 
 
-def tile_schedule(T: int, causal: bool) -> tuple:
+def tile_schedule(T: int, causal: bool, window: Optional[int] = None) -> tuple:
     """(visited, masked, total) score tiles a head's [T,T] square costs
     with the tiles ``_blocks`` picks: how many the kernels compute, how
-    many of those pay the causal mask, how many the square has. A count
-    from the same rule the kernels run, so it can be tested on a CPU."""
+    many of those pay a mask (the causal one, a window's, or both), how
+    many the square has. A count from the same rule the kernels run, so it
+    can be tested on a CPU."""
     BQ, BK = _blocks(T, causal)
-    kinds = [tile_kind(r, BQ, c, BK, causal)
+    kinds = [tile_kind(r, BQ, c, BK, causal, window)
              for r in range(0, T, BQ) for c in range(0, T, BK)]
     return (sum(k != SKIP for k in kinds), sum(k == DIAG for k in kinds),
             len(kinds))
@@ -291,6 +309,21 @@ SCOPE = "flash_attention"
 FWD_NAME = "flash_attention_fwd"
 
 
+def _fold_scores(s, vT, at, acc, m, l):
+    """One pass of the online-softmax recurrence: the scores s [nk, n] of a
+    key tile under the queries ``at`` and the tile's values vT [D, nk]
+    folded into the carry (acc [D, RQ], m and l [1, RQ])."""
+    m_prev = m[:, at]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l[:, at] = l[:, at] * corr + jnp.sum(p, axis=0, keepdims=True)
+    acc[:, at] = acc[:, at] * corr + jax.lax.dot_general(
+        vT, p.astype(vT.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=f32)
+    m[:, at] = m_new
+
+
 def _softmax_block(diagonal, scale, BQ, BK, q_ref, k_ref, v_ref, mask_ref,
                    acc, m, l):
     """Fold one resident block into the online-softmax carry in VMEM
@@ -304,15 +337,7 @@ def _softmax_block(diagonal, scale, BQ, BK, q_ref, k_ref, v_ref, mask_ref,
         at = slice(r_lo, RQ)
         s = _scores(k_ref[0, c0:c0 + BK, :], q[at], r_lo, c0, masked,
                     None if mask_ref is None else mask_ref[0, c0:c0 + BK, :])
-        m_prev = m[:, at]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l[:, at] = l[:, at] * corr + jnp.sum(p, axis=0, keepdims=True)
-        acc[:, at] = acc[:, at] * corr + jax.lax.dot_general(
-            vT[:, c0:c0 + BK], p.astype(vT.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=f32)
-        m[:, at] = m_new
+        _fold_scores(s, vT[:, c0:c0 + BK], at, acc, m, l)
 
 
 def _fwd_body(causal, masked, scale, BQ, BK, *refs):
@@ -419,6 +444,126 @@ def _fwd(q3, k3, v3, mask2, causal, scale):
     return _fwd_call(q3, k3, v3, mask2, causal=causal, scale=scale,
                      tiles=_tiles(q3.shape[1], causal),
                      interpret=_interpret())
+
+
+# ------------------------------------------------- windowed forward
+WINDOW_FWD_NAME = "flash_attention_window_fwd"
+
+
+def _window_visits(behind, RQ, RK, BQ, BK, window):
+    """The walk of a resident block whose rows begin ``behind`` positions
+    after its columns (0 on the diagonal, a multiple of the block behind
+    it), one pass a key tile: yields ``(c0, r_lo, r_hi, masked)`` for the
+    BK keys at c0, visited by the queries [r_lo, r_hi) (the window cuts
+    the visitors off at BOTH ends: queries before r_lo lie before the keys,
+    queries from r_hi on no longer reach back to them); ``masked``: some
+    tile of the strip is crossed by the diagonal or by the window's edge."""
+    for c0 in range(0, RK, BK):
+        kinds = [tile_kind(behind + r0, BQ, c0, BK, True, window)
+                 for r0 in range(0, RQ, BQ)]
+        seen = [n for n, kind in enumerate(kinds) if kind != SKIP]
+        if seen:
+            lo, hi = seen[0], seen[-1] + 1
+            assert SKIP not in kinds[lo:hi], kinds
+            yield c0, lo * BQ, hi * BQ, DIAG in kinds[lo:hi]
+
+
+def _softmax_block_window(behind, window, scale, BQ, BK, q_ref, k_ref, v_ref,
+                          acc, m, l):
+    """``_softmax_block`` for a sliding window: the same recurrence over
+    the strips ``_window_visits`` names. A query whose strip is all masked
+    (the last row of a tile the edge crosses) folds in uniform weights
+    under a running max of NEG; the first real key it meets rescales them
+    to exactly 0, and every query meets its own position."""
+    RQ, RK = q_ref.shape[1], k_ref.shape[1]
+    q = _scaled(q_ref[0], scale)
+    vT = v_ref[0].T                       # [Dv, RK]
+    for c0, r_lo, r_hi, masked in _window_visits(behind, RQ, RK, BQ, BK,
+                                                 window):
+        at = slice(r_lo, r_hi)
+        s = jax.lax.dot_general(k_ref[0, c0:c0 + BK, :], q[at],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=f32)
+        if masked:
+            # key c0 + a under query behind + r_lo + b: b - a + their
+            # distance is how far back the key lies, kept in [0, window)
+            back = ((behind + r_lo - c0)
+                    + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                    - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            s = jnp.where((back >= 0) & (back < window), s, NEG)
+        _fold_scores(s, vT[:, c0:c0 + BK], at, acc, m, l)
+
+
+def _fwd_window_body(window, blocks_back, scale, BQ, BK, q_ref, k_ref, v_ref,
+                     o_ref, acc, m, l):
+    """Grid step (b, i, j): row block i against the resident key block
+    ``i - blocks_back + j``. How far a block lies behind the diagonal is
+    static per j, so every tile's place in the window is too."""
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    RQ = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, NEG)
+        l[:] = jnp.zeros_like(l)
+
+    for jp in range(blocks_back + 1):
+        behind = blocks_back - jp         # blocks between this and row block i
+
+        @pl.when((j == jp) & (i >= behind))   # a block before the sequence
+        def _block(behind=behind):            # began is not computed
+            _softmax_block_window(behind * RQ, window, scale, BQ, BK, q_ref,
+                                  k_ref, v_ref, acc, m, l)
+
+    @pl.when(j == blocks_back)
+    def _finalize():
+        o_ref[0] = (acc[:] / l[:]).T.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "tiles",
+                                             "group", "interpret"))
+def _fwd_window_call(q3, k3, v3, *, window, scale, tiles, group, interpret):
+    """Causal attention under a sliding window, forward only: q3 [BH, T,
+    D], k3 / v3 [BH / group, T, D]: query head b reads key-value head ``b
+    // group`` through the index map (no repeated copy). The grid's key
+    axis spans only the resident blocks a row block's window reaches
+    (``blocks_back`` behind the diagonal's and that one), so the blocks
+    older than the window cost no grid step and no copy: at T = 16,384 and
+    a window of 512 a head runs 32 steps, not the 136 of its causal half.
+    Jitted for the reason ``_fwd_call`` is."""
+    BH, T, D = q3.shape
+    Dv = v3.shape[2]
+    BQ, BK, RQ, RK = tiles
+    assert RQ == RK, "causal resident blocks are square"
+    blocks_back = min(-(-(window - 1) // RK), T // RK - 1)
+
+    def col(i, j):                 # clamped: a skipped step copies nothing
+        return jnp.maximum(i - blocks_back + j, 0)
+
+    def kv(b):
+        return b if group == 1 else b // group
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            functools.partial(_fwd_window_body, window, blocks_back, scale,
+                              BQ, BK),
+            name=WINDOW_FWD_NAME,
+            grid=(BH, T // RQ, blocks_back + 1),
+            in_specs=[
+                pl.BlockSpec((1, RQ, D), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, RK, D), lambda b, i, j: (kv(b), col(i, j), 0)),
+                pl.BlockSpec((1, RK, Dv),
+                             lambda b, i, j: (kv(b), col(i, j), 0))],
+            out_specs=pl.BlockSpec((1, RQ, Dv), lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype),
+            scratch_shapes=[pltpu.VMEM((Dv, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32),
+                            pltpu.VMEM((1, RQ), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q3, k3, v3)
 
 
 # ------------------------------------------------------------------ dq pass
@@ -731,16 +876,28 @@ def _device_split(B: int, H: int):
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: Optional[float] = None, key_mask=None):
+                    scale: Optional[float] = None, key_mask=None,
+                    window: Optional[int] = None):
     """Fused softmax attention, [B,H,T,D] in/out — drop-in for
     parallel/ring_attention.attention when fused_attention_applicable.
     ``key_mask`` [B,T] excludes padded timesteps as keys. ``v`` (and the
     result) may have a head size ``Dv`` of its own (latent attention: 192
     for the scores, 128 for the values): that call is forward only, the
-    backward kernels take one size."""
+    backward kernels take one size.
+
+    ``window``: causal attention in which position t sees the keys s with
+    ``t - window < s <= t`` (``flash_attention_window_fwd``). Forward only,
+    no ``key_mask``; k and v may carry fewer heads than q ([B,Hkv,T,D],
+    query head i reading head ``i // (H // Hkv)``), which the kernel reads
+    in place."""
     B, H, T, D = q.shape
     Dv = v.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
+    if window is not None:
+        if not causal or key_mask is not None:
+            raise ValueError("a sliding window is causal and takes no "
+                             "key_mask")
+        return _flash_window(q, k, v, int(window), scale)
 
     def kernels(q, k, v, *mask):          # one device's [b,h,T,D] block
         b, h = q.shape[:2]
@@ -758,3 +915,32 @@ def flash_attention(q, k, v, *, causal: bool = False,
     return jax.shard_map(
         kernels, in_specs=(spec,) * 3 + (P(spec[0], None),) * (len(args) - 3),
         out_specs=spec, axis_names=axis_names, check_vma=False)(*args)
+
+
+def _flash_window(q, k, v, window: int, scale: float):
+    """``flash_attention``'s windowed case."""
+    B, H, T, _ = q.shape
+    if window < 1 or H % k.shape[1]:
+        raise ValueError(f"window={window} must be positive and the "
+                         f"{k.shape[1]} key-value heads divide the {H} "
+                         "query heads")
+
+    def kernels(q, k, v):                 # one device's heads
+        b, h, hkv = q.shape[0], q.shape[1], k.shape[1]
+        o = _fwd_window_call(
+            q.reshape(b * h, T, q.shape[-1]),
+            k.reshape(b * hkv, T, k.shape[-1]),
+            v.reshape(b * hkv, T, v.shape[-1]), window=window, scale=scale,
+            tiles=_tiles(T, True), group=h // hkv, interpret=_interpret())
+        return o.reshape(b, h, T, v.shape[-1])
+
+    split = _device_split(B, H)
+    if split is None:
+        return kernels(q, k, v)
+    # over a mesh every device takes whole query heads with their own copy
+    # of the key-value heads they read
+    group = H // k.shape[1]
+    k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
+    spec, axis_names = split
+    return jax.shard_map(kernels, in_specs=(spec,) * 3, out_specs=spec,
+                         axis_names=axis_names, check_vma=False)(q, k, v)

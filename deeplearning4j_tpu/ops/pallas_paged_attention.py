@@ -36,6 +36,13 @@ values as a slice of the key rows it holds, under the name
 rows' space, so the softmax scale is the caller's (the layer's 1 /
 sqrt(192), not 1 / sqrt(row)).
 
+A SLIDING WINDOW (``starts``: the first key position a slot sees) walks
+the pages from that position's page on and masks the keys before it, under
+the name ``paged_attention_window_decode``; the tables stay logical, so a
+cache that keeps a window's rows in a ring maps page j to ring page ``j
+mod ring_pages`` in the table it hands in
+(``serving/generation/kvcache.py``).
+
 Precision: scores, the softmax recurrence (running max and sum) and both
 accumulations are float32; ``p`` is cast to the pool's dtype for ``p @ V``
 and the output is the pool's dtype, as on the XLA path.
@@ -60,6 +67,7 @@ f32 = jnp.float32
 SCOPE = "paged_attention"
 KERNEL_NAME = "paged_attention_decode"
 LATENT_KERNEL_NAME = "paged_attention_latent_decode"
+WINDOW_KERNEL_NAME = "paged_attention_window_decode"
 
 # keys folded into the online softmax per step: 16 pages of 16, the DMAs of
 # one step in flight while the previous step's pages are computed on. On
@@ -79,10 +87,14 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
+def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs, windowed=False):
     """``Dv`` None: K and V pools, H heads of Dh side by side. ``Dv`` an
     int: one latent pool (H = 1), the values are the first Dv lanes of
-    the key rows."""
+    the key rows. ``windowed``: a fourth prefetched scalar a slot, the
+    first key position it sees; the walk starts at that position's page
+    and the keys before it in that page are masked."""
+    if windowed:
+        starts_ref, refs = refs[3], refs[:3] + refs[4:]
     if Dv is None:
         (layer_ref, tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
          kbuf, vbuf, sem, m_s, l_s, acc_s) = refs
@@ -97,7 +109,14 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
     n0 = lens_ref[s]                      # keys row 0 sees; 0 = idle slot
     last = jnp.where(n0 > 0, jnp.minimum(n0 + (W - 1), cap), 0)
     npages = (last + (blk - 1)) // blk
-    ngroups = (npages + (G - 1)) // G
+    if windowed:
+        # groups count from the page that holds the first key seen: the
+        # pages before it are never fetched
+        start = jnp.minimum(starts_ref[s], last)
+        page0 = start // blk
+        ngroups = (npages - page0 + (G - 1)) // G
+    else:
+        ngroups = (npages + (G - 1)) // G
 
     def each_copy(group, slot, act):
         """``act`` ("start" or "wait") on the DMA of every live page of
@@ -106,6 +125,8 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
         are not fetched."""
         for g in range(G):
             page = group * G + g
+            if windowed:
+                page = page + page0
 
             @pl.when(page < npages)
             def _():
@@ -142,7 +163,11 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
         sc = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=f32) * scale
         kpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(kpos < limit, sc, NEG)
+        if windowed:
+            kpos = kpos + page0 * blk
+            sc = jnp.where((kpos < limit) & (kpos >= start), sc, NEG)
+        else:
+            sc = jnp.where(kpos < limit, sc, NEG)
         m_prev = m_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         p = jnp.exp(sc - m_new)
@@ -153,6 +178,8 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
         # are the unwritten tail of its last page): whatever they hold,
         # 0 * it must stay 0
         vpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        if windowed:
+            vpos = vpos + page0 * blk
         v = jnp.where(vpos < last, v, jnp.zeros_like(v))
         acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -176,12 +203,16 @@ def _body(W, H, Dh, blk, G, cap, scale, Gq, Dv, *refs):
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
-def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
+def _one_device(layer, q, k_pool, v_pool, tables, lens, starts=None, *,
+                interpret):
     """One device's heads: q [S,Hq,W,Dh], pools [L,nb,blk,H*Dh] with
     ``Hq = Gq * H`` (query head ``h * Gq + g`` reads key-value head ``h``;
     Gq = 1 is plain multi-head attention); ``layer`` an int32 scalar. Jitted with the layer an OPERAND, so that a program
     of 24 layers traces this and lowers the kernel to Mosaic once, not 24
-    times (7 s of every process's warm-up at the serving cells' shape)."""
+    times (7 s of every process's warm-up at the serving cells' shape).
+    ``starts`` [S]: the windowed case, the first key position a slot sees
+    (``paged_attention_window_decode``)."""
+    windowed = starts is not None
     S, Hq, W, Dh = q.shape
     HD = k_pool.shape[3]
     H = HD // Dh                          # key-value heads
@@ -199,8 +230,12 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
     own = jnp.eye(H, dtype=bool)[None, None, :, :, None]
     q_bd = jnp.where(own, qt, jnp.zeros((), q.dtype)).reshape(S, R, HD)
     q_bd = jnp.pad(q_bd, ((0, 0), (0, Rp - R), (0, 0)))
+    scalars = (layer.reshape(1), tables.astype(jnp.int32),
+               lens.astype(jnp.int32))
+    if windowed:
+        scalars += (starts.astype(jnp.int32),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,            # layer, tables, lens
+        num_scalar_prefetch=len(scalars),  # layer, tables, lens (, starts)
         grid=(S,),
         in_specs=[pl.BlockSpec((1, Rp, HD), lambda s, *_: (s, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -215,15 +250,15 @@ def _one_device(layer, q, k_pool, v_pool, tables, lens, *, interpret):
     with jax.named_scope(SCOPE):
         o = pl.pallas_call(
             functools.partial(_body, W, H, Dh, blk, G, mb * blk,
-                              1.0 / float(np.sqrt(Dh)), Gq, None),
-            name=KERNEL_NAME,
+                              1.0 / float(np.sqrt(Dh)), Gq, None,
+                              windowed=windowed),
+            name=WINDOW_KERNEL_NAME if windowed else KERNEL_NAME,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((S, W * Gq, HD), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
-        )(layer.reshape(1), tables.astype(jnp.int32), lens.astype(jnp.int32),
-          q_bd, k_pool, v_pool)
+        )(*scalars, q_bd, k_pool, v_pool)
     # row w*Gq+g, lanes of head h -> query head h*Gq+g
     return o.reshape(S, W, Gq, H, Dh).transpose(0, 3, 2, 1, 4).reshape(
         S, Hq, W, Dh)
@@ -274,7 +309,7 @@ def _one_device_latent(layer, q, pool, tables, lens, *, scale, value_lanes,
 
 
 def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens, *,
-                           scale=None, value_lanes=None):
+                           scale=None, value_lanes=None, starts=None):
     """Softmax attention of a decode window over a paged cache.
 
     q       [S, Hq, W, Dh]: W query rows a slot (1 in the decode step,
@@ -296,9 +331,24 @@ def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens, *,
     that are its value. Returns [S, Hq, W, value_lanes]. A latent pool has
     no heads to split over a mesh.
 
+    A sliding window: ``starts`` [S] int32, the first key position a slot
+    sees (``max(0, pos + 1 - window)`` in a decode step); the walk begins
+    at the page ``tables[s, starts // block_len]``, the pages before it
+    are not fetched and the keys before ``starts`` in that page are
+    masked. The tables stay LOGICAL (page j holds positions j * block_len
+    ...): a cache that keeps a window's rows in a ring hands in a table
+    that maps page j to ring page ``j mod ring_pages``. Runs under the
+    name ``paged_attention_window_decode``; K/V pools on one device only.
+
     Returns [S, H, W, Dh] in q's dtype. Under a mesh tracing context the
     heads split over the model axis with ``shard_map`` (a Mosaic call is
     not partitioned automatically); attention is head-local."""
+    if starts is not None:
+        if v_pool is None or _device_split(q.shape[0], 1) is not None:
+            raise ValueError("a windowed decode reads K/V pools on one "
+                             "device")
+        return _one_device(jnp.asarray(layer, jnp.int32), q, k_pool, v_pool,
+                           tables, lens, starts, interpret=_interpret())
     if v_pool is None:
         if scale is None or value_lanes is None:
             raise ValueError("a latent pool needs scale and value_lanes")
@@ -324,7 +374,7 @@ def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens, *,
 
 
 def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens,
-                              *, scale=None, value_lanes=None):
+                              *, scale=None, value_lanes=None, starts=None):
     """The same attention the plain way: gather every slot's whole table
     into a dense context and attend under a mask. The parity pin of the
     kernel, and what the decode step did before it."""
@@ -349,6 +399,9 @@ def paged_attention_reference(q, k_pool, v_pool, layer: int, tables, lens,
 
     limit = lens[:, None] + jnp.arange(W)[None, :]                # [S,W]
     mask = jnp.arange(ctx)[None, None, :] < limit[:, :, None]
+    if starts is not None:
+        mask = mask & (jnp.arange(ctx)[None, None, :]
+                       >= starts[:, None, None])
     out = window_attention(q, dense(k_pool), dense(v_pool), mask)
     return jnp.where((lens > 0)[:, None, None, None], out,
                      jnp.zeros((), out.dtype))

@@ -50,11 +50,13 @@ def _block_update(acc, m, l, q, k, v, scale, mask=None):
 
 
 def attention(q, k, v, *, causal: bool = False,
-              scale: Optional[float] = None, key_mask=None):
+              scale: Optional[float] = None, key_mask=None,
+              window: Optional[int] = None):
     """Plain softmax attention, [B,H,T,D] in/out (single-device reference
     semantics for the ring version). ``key_mask`` [B,Tk] excludes padded
     timesteps as keys (large-negative rather than -inf so a fully-masked
-    query row yields a uniform distribution instead of NaN)."""
+    query row yields a uniform distribution instead of NaN). ``window``
+    (causal only): query t sees the keys s with t - window < s <= t."""
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * jnp.asarray(scale, q.dtype)
     if key_mask is not None:
@@ -63,6 +65,9 @@ def attention(q, k, v, *, causal: bool = False,
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((tq, tk), bool),
+                                    k=tk - tq - window)
         s = jnp.where(mask, s, -1e30 if key_mask is not None else -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)

@@ -201,6 +201,15 @@ class GenerationEngine:
             "cache_kind": getattr(rt.active_ps.spec, "cache_kind", None),
             "cache_bytes_per_token": rt.active_ps.kv_bytes_per_token(),
             "conv_state_bytes": rt.active_ps.recurrent_state_bytes(),
+            # sliding-window layers: their width (None: the model has
+            # none), how many there are, and what one slot's rings hold
+            # (K and V, all of them); ``cache_bytes_per_token`` counts the
+            # layers that keep the whole context alone
+            "window": getattr(rt.active_ps.spec, "window", None),
+            "window_layers": getattr(rt.active_ps.spec, "n_window_layers",
+                                     0),
+            "window_cache_bytes_per_slot":
+                rt.active_ps.window_cache_bytes_per_slot(),
             "model_shards": rt.active_ps.model_shards,
             "kv_pool_bytes_per_chip": rt.active_ps.kv_pool_chip_bytes,
             "speculative": {

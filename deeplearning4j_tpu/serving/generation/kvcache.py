@@ -21,6 +21,22 @@ all heads share (576 values, laid out on 640 lanes), read by every query
 head as its key and, in its leading lanes, as its value. A latent cache is
 the 1-tuple ``(pool,)``; every helper below takes either kind.
 
+A model may also have attention layers with a SLIDING WINDOW beside those
+that keep the whole context. Such a layer's rows are not paged: each decode
+slot owns a RING of ``ring_pages = window / block_len + 1`` pages a window
+layer (``make_rings``: ``[n_window_layers, slots + 1, ring_pages,
+block_len, H * Dh]`` for K and for V, the last slot the prefill's trash
+row), position p of a sequence lying in ring page ``(p // block_len) mod
+ring_pages`` at row ``p mod block_len``. A span of ``window`` positions
+touches at most ``ring_pages`` whole pages, so the ring always holds the
+window and a write never lands on a row the window still needs. Nothing is
+allocated or freed as a sequence grows and no table is kept on the host:
+the table that maps a sequence's logical pages onto its ring is a constant
+(``ring_tables``), and the windowed decode kernel walks it from the page
+of the window's first key. The full-context layers keep the pools, the
+tables and the allocator below as they are; the rings ride behind them in
+the same cache pytree.
+
 Block 0 is the reserved TRASH block: inactive decode slots and the unused
 tail of a prefill's table all point at it, so the fixed-shape scatter always
 has a legal destination and garbage lands where nothing ever reads it
@@ -181,6 +197,66 @@ def make_pools(n_layers: int, num_blocks: int, block_len: int,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+def ring_pages(window: int, block_len: int) -> int:
+    """Pages of a slot's ring for a sliding window: the most whole pages a
+    span of ``window`` positions can touch."""
+    return -(-window // block_len) + 1
+
+
+def make_rings(n_layers: int, slots: int, window: int, block_len: int,
+               n_heads: int, head_dim: int, dtype) -> Tuple:
+    """Zero-filled (k_ring, v_ring) for a model's sliding-window layers:
+    ``[n_layers, slots + 1, ring_pages, block_len, n_heads * head_dim]``;
+    row ``slots`` of the slot axis is the trash ring idle slots and a
+    prefill's padding rows write to."""
+    shape = (n_layers, slots + 1, ring_pages(window, block_len), block_len,
+             n_heads * head_dim)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def ring_as_pool(ring):
+    """A ring as the attention kernel takes a pool: ``[n_layers, (slots +
+    1) * ring_pages, block_len, H * Dh]`` (a reshape of contiguous axes: no
+    copy)."""
+    L, S1, rp, blk, hd = ring.shape
+    return ring.reshape(L, S1 * rp, blk, hd)
+
+
+def ring_tables(slots: int, pages: int, max_blocks: int):
+    """[slots, max_blocks] int32: logical page j of slot s lies in ring
+    page ``s * pages + j mod pages`` of ``ring_as_pool``'s view. A
+    constant: a ring needs no table kept anywhere."""
+    return (jnp.arange(slots, dtype=jnp.int32)[:, None] * pages
+            + jnp.arange(max_blocks, dtype=jnp.int32)[None, :] % pages)
+
+
+def ring_prefill_fill(ring, layer_kv, lengths, slots):
+    """Leave each prompt's LAST rows in its slot's ring after a prefill:
+    ring row r of a sequence of true length n takes the newest position p
+    < n with ``p mod rows == r`` (``rows`` the ring's rows: its pages x
+    block_len). Rows no position of the prompt maps to keep whatever the
+    gather finds; they lie outside [first key, length) of every later
+    step and are masked there.
+
+    ring      [n_layers, slots + 1, ring_pages, blk, H*Dh]
+    layer_kv  list of [P, L, H, Dh] a window layer (the rung's L rows)
+    lengths   [P] the prompts' true lengths (not the rung)
+    slots     [P] the slots the prompts were admitted to (padding rows
+              carry the trash slot)"""
+    _, _, rp, blk, hd = ring.shape
+    rows = rp * blk
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    n = lengths.astype(jnp.int32)[:, None]
+    src = r + rows * ((n - 1 - r) // rows)                    # [P, rows]
+    for i, kv in enumerate(layer_kv):
+        P, L, H, Dh = kv.shape
+        took = jnp.take_along_axis(
+            kv.reshape(P, L, H * Dh), jnp.clip(src, 0, L - 1)[:, :, None],
+            axis=1)
+        ring = ring.at[i, slots].set(took.reshape(P, rp, blk, hd))
+    return ring
+
+
 def pool_bytes(pool) -> int:
     """Total device bytes of one pool entry (plain array or QuantizedPool)."""
     return sum(leaf.size * leaf.dtype.itemsize
@@ -315,9 +391,12 @@ class PagedWindowStore:
     nothing and get zeros."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
-                 window: int, rec=None):
+                 window: int, rec=None, rings=None):
         self.k_pool = k_pool
         self.v_pool = v_pool              # None: a latent cache, one pool
+        # (k_ring, v_ring) of the model's sliding-window layers, or None
+        self.rings = rings
+        self._pos, self._blk = pos, block_len
         # per-slot state of the model's recurrent mixers, [layers,
         # slots + 1, ...] (row ``slots`` is the prefill's trash row), or
         # None for a model that keeps K/V alone
@@ -342,13 +421,39 @@ class PagedWindowStore:
             self._mask = (jnp.arange(ctx_len)[None, None, :]
                           <= w_pos[:, :, None])                  # [S, W, ctx]
 
-    def attend(self, i: int, q, k_win, v_win, **latent):
+    def _attend_ring(self, i: int, q, k_tok, v_tok, window: int):
+        """A sliding-window layer's step: write the token at its place in
+        the slot's ring (idle slots write to the trash ring), then attend
+        to the ring's pages from the window's first key on."""
+        k_ring, v_ring = self.rings
+        S = self.tables.shape[0]
+        rp = k_ring.shape[2]
+        slot = jnp.where(self._active, jnp.arange(S), S)
+        page = (self._pos // self._blk) % rp
+        off = self._pos % self._blk
+        flat = lambda t: t.reshape(S, -1)
+        k_ring = k_ring.at[i, slot, page, off].set(flat(k_tok))
+        v_ring = v_ring.at[i, slot, page, off].set(flat(v_tok))
+        self.rings = (k_ring, v_ring)
+        starts = jnp.maximum(self._lens - window, 0)
+        return paged_attention_decode(
+            q, ring_as_pool(k_ring), ring_as_pool(v_ring), i,
+            ring_tables(S, rp, self.tables.shape[1]), self._lens,
+            starts=starts)
+
+    def attend(self, i: int, q, k_win, v_win, window=None, **latent):
         """q [S,H,W,Dh]; k_win/v_win [S,W,H,Dh] for the window. Returns
         the attention output [S,H,W,Dh]. Over a latent cache: q
         [S,H,W,row] absorbed, k_win [S,W,1,row] the tokens' cache rows,
         v_win None, ``scale`` and ``value_lanes`` as
         ``paged_attention_decode`` takes them; returns
-        [S,H,W,value_lanes]."""
+        [S,H,W,value_lanes]. ``window``: layer ``i`` of the sliding-window
+        layers, one token a slot, through the slot's ring."""
+        if window is not None:
+            if k_win.shape[1] != 1 or self.rings is None:
+                raise ValueError("a sliding-window layer takes one token "
+                                 "a slot and a store that carries rings")
+            return self._attend_ring(i, q, k_win[:, 0], v_win[:, 0], window)
         self.k_pool = _pool_write(self.k_pool, i, self._bid, self._off, k_win)
         if self.v_pool is None:
             return paged_attention_decode(q, self.k_pool, None, i,
@@ -384,7 +489,8 @@ class PagedWindowStore:
     def cache(self):
         """The cache pytree a program hands back: the pools, and the
         recurrent state behind them where the model has one."""
-        return self.pools + (() if self.rec is None else (self.rec,))
+        return self.pools + (() if self.rec is None else (self.rec,)) \
+            + (() if self.rings is None else tuple(self.rings))
 
 
 class PagedStore(PagedWindowStore):
@@ -392,13 +498,14 @@ class PagedStore(PagedWindowStore):
     a window of one token a slot."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
-                 rec=None):
+                 rec=None, rings=None):
         super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1,
-                         rec)
+                         rec, rings)
 
-    def attend(self, i: int, q, k_tok, v_tok, **latent):
-        """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh] (v_tok None and the
-        ``latent`` keywords over a latent cache)."""
+    def attend(self, i: int, q, k_tok, v_tok, **kw):
+        """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh] (v_tok None and the latent
+        keywords over a latent cache; ``window`` for a sliding-window
+        layer)."""
         return super().attend(i, q, k_tok[:, None],
                               None if v_tok is None else v_tok[:, None],
-                              **latent)
+                              **kw)

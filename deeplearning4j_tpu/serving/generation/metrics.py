@@ -72,6 +72,7 @@ class GenerationMetrics:
         self.cow_copies = 0
         self.prefix_evictions = 0
         self.prefix_skipped_stateful = 0
+        self.prefix_skipped_windowed = 0
         self._prefix_gauges: dict = {}
         # speculative decoding
         self._verify_ms = deque(maxlen=window)
@@ -233,6 +234,17 @@ class GenerationMetrics:
         if reg.enabled:
             reg.counter(
                 f"generation.{self.name}.prefix_skipped_stateful").inc(n)
+
+    def record_prefix_skipped_windowed(self, n: int = 1) -> None:
+        """Admissions that went past the prefix cache because the model has
+        sliding-window layers, whose rings do not keep the window's rows
+        at a matched boundary."""
+        with self._lock:
+            self.prefix_skipped_windowed += n
+        reg = self.registry
+        if reg.enabled:
+            reg.counter(
+                f"generation.{self.name}.prefix_skipped_windowed").inc(n)
 
     def record_prefix_evictions(self, n: int) -> None:
         with self._lock:
@@ -413,6 +425,7 @@ class GenerationMetrics:
                     "cow_copies": self.cow_copies,
                     "evictions": self.prefix_evictions,
                     "skipped_stateful": self.prefix_skipped_stateful,
+                    "skipped_windowed": self.prefix_skipped_windowed,
                     "shared_blocks": self._prefix_gauges.get(
                         "shared_blocks", 0),
                     "cached_lru_blocks": self._prefix_gauges.get(

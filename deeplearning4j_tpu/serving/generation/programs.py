@@ -45,14 +45,16 @@ from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ...models.decode import (GraphDecodeSpec, LatentDecodeUnsupportedError,
-                              LSTMDecodeSpec, StatefulDecodeUnsupportedError)
+                              LSTMDecodeSpec, StatefulDecodeUnsupportedError,
+                              WindowDecodeUnsupportedError)
 from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
                                          model_axis_size, per_replica_bytes,
                                          shard_params)
 from ...telemetry import span
 from ..programs import _arch_key, _tree_signature
 from .kvcache import (PagedStore, QuantSimStore, cow_copy, make_pools,
-                      prefill_scatter)
+                      make_rings, prefill_scatter, ring_pages,
+                      ring_prefill_fill)
 from .sampling import sample_tokens
 
 
@@ -280,6 +282,11 @@ class GenerationProgramSet:
         # (what keeps keys and values by head refuses such a model by name)
         self.latent = self.adapter == "paged" and self.spec.latent
         self.n_pools = 1 if self.latent else 2
+        # sliding-window layers beside those that keep the whole context:
+        # their rows live in a ring a slot behind the pools (what shares or
+        # re-reads a sequence's whole context refuses the model by name)
+        self.windowed = self.adapter == "paged" \
+            and self.spec.window is not None
         # int32 counters behind the tokens of a program's first result
         self.stats_len = 2 if self.adapter == "paged" and self.spec.n_moe \
             else 0
@@ -301,6 +308,11 @@ class GenerationProgramSet:
                     "model-sharded decode is refused for a model with a "
                     f"latent cache ({self.spec.attn_names}): the pools "
                     "shard by head, and a latent row has none")
+            if self.windowed:
+                raise WindowDecodeUnsupportedError(
+                    "model-sharded decode is refused for a model with "
+                    f"sliding-window layers ({self.spec.window_names}): "
+                    "their rings are not split over a mesh")
             if not self.spec.supports_head_sharding(self.model_shards):
                 raise ValueError(
                     f"n_heads={self.spec.n_heads} does not divide by the "
@@ -326,8 +338,14 @@ class GenerationProgramSet:
                                 if config.prefix_cache is None
                                 else bool(config.prefix_cache)
                                 and self.adapter == "paged")
-                               and not self.stateful)
+                               and not self.stateful and not self.windowed)
         self.prefix_skipped_stateful = (self.stateful
+                                        and config.prefix_cache is not False)
+        # the same for sliding-window layers: a hit resumes from shared
+        # pages at the matched boundary and would need the window's rows
+        # as they stood THERE, which a ring does not keep
+        # (``prefix_skipped_windowed``)
+        self.prefix_skipped_windowed = (self.windowed
                                         and config.prefix_cache is not False)
         # int8-quantized KV tier: paged pools only (the state adapter's
         # carry is recurrent state, not a token cache)
@@ -347,6 +365,12 @@ class GenerationProgramSet:
                 "kv_cache_dtype='int8' is refused for a model with a latent "
                 f"cache ({self.spec.attn_names}): the int8 tier quantizes "
                 "keys and values by head")
+        if self.kv_quantized and self.windowed:
+            raise WindowDecodeUnsupportedError(
+                "kv_cache_dtype='int8' is refused for a model with "
+                f"sliding-window layers ({self.spec.window_names}): its "
+                "prefill runs as a decode window, which does not carry "
+                "their rings")
         # speculative decoding: active iff a draft model is attached
         self.draft_net = draft_net
         self.spec_k = 0
@@ -364,6 +388,12 @@ class GenerationProgramSet:
                     "speculative decoding is refused for a model with a "
                     f"latent cache ({self.spec.attn_names}): the verify "
                     "program and the dense draft cache keep K/V pairs")
+            if self.windowed:
+                raise WindowDecodeUnsupportedError(
+                    "speculative decoding is refused for a model with "
+                    f"sliding-window layers ({self.spec.window_names}): "
+                    "the verify window does not carry their rings, and a "
+                    "rejected proposal could not be taken back out of one")
             if self.adapter != "paged":
                 raise ValueError(
                     "speculative decoding requires a paged (transformer) "
@@ -377,6 +407,10 @@ class GenerationProgramSet:
                 raise StatefulDecodeUnsupportedError(
                     "a draft with recurrent mixers is refused: the dense "
                     "draft cache keeps K/V alone")
+            if da == "paged" and self.draft_spec.window is not None:
+                raise WindowDecodeUnsupportedError(
+                    "a draft with sliding-window layers is refused: the "
+                    "dense draft cache keeps every layer's whole context")
             if da == "paged" and self.draft_spec.latent:
                 raise LatentDecodeUnsupportedError(
                     "a draft with a latent cache is refused: the dense "
@@ -489,6 +523,13 @@ class GenerationProgramSet:
                 cache = cache + (jnp.zeros(
                     self.spec.recurrent_state_shape(c.decode_slots + 1),
                     self.dtype),)
+            if self.windowed:
+                # a ring a slot for the sliding-window layers, K and V,
+                # last in the pytree
+                cache = cache + make_rings(
+                    self.spec.n_window_layers, c.decode_slots,
+                    self.spec.window, c.block_len, self.spec.kv_heads,
+                    self.spec.head_dim, self.dtype)
         else:
             cache = jax.tree.map(jnp.zeros_like, self._init_states)
         try:     # memprof owner hint: the block pool dominates live HBM
@@ -523,6 +564,17 @@ class GenerationProgramSet:
         per_token = self.kv_bytes_per_token()
         return None if per_token is None else int(per_token
                                                   // self.spec.n_blocks)
+
+    def window_cache_bytes_per_slot(self) -> int:
+        """Device bytes one decode slot's rings hold (K and V, every
+        sliding-window layer): ``window`` rows and a page a layer whatever
+        the sequence's length; 0 for a model without such layers."""
+        if not self.windowed:
+            return 0
+        s, c = self.spec, self.config
+        return int(2 * s.n_window_layers * ring_pages(s.window, c.block_len)
+                   * c.block_len * s.kv_heads * s.head_dim
+                   * jnp.dtype(self.dtype).itemsize)
 
     def recurrent_state_bytes(self) -> int:
         """Device bytes of the recurrent mixers' per-slot state (every
@@ -604,6 +656,11 @@ class GenerationProgramSet:
                     last, ks, vs, states, stats = spec.prefill_full(
                         params, state, tokens, rows,
                         lengths if self.stateful or self.stats_len else None)
+                if self.windowed:
+                    # the layers that keep the whole context go to the
+                    # pages, the sliding-window layers to the rings
+                    (ks, wks), (vs, wvs) = (spec.split_kinds(ks),
+                                            spec.split_kinds(vs))
                 # K and V, or a latent cache's one pool of rows (``ks``)
                 out = tuple(prefill_scatter(pool, kv, tables)
                             for pool, kv in zip(pools, (ks, vs)))
@@ -613,6 +670,11 @@ class GenerationProgramSet:
                     rec = cache[self.n_pools]
                     out += (rec.at[:, slots].set(
                         jnp.stack(states).astype(rec.dtype)),)
+                if self.windowed:
+                    # each prompt's last rows at its TRUE length, in its
+                    # slot's rings (padding rows: the trash ring)
+                    out += tuple(ring_prefill_fill(ring, kv, lengths, slots)
+                                 for ring, kv in zip(cache[-2:], (wks, wvs)))
                 tok, key = sample_tokens(last, key, temp, topk)
                 if stats is not None:
                     # the counters ride back behind the tokens
@@ -645,7 +707,8 @@ class GenerationProgramSet:
                 store = PagedStore(
                     cache[0], None if self.latent else cache[1], tables, pos,
                     active, blk,
-                    cache[self.n_pools] if self.stateful else None)
+                    cache[self.n_pools] if self.stateful else None,
+                    cache[-2:] if self.windowed else None)
                 logits, stats = spec.decode_step_stats(
                     params, state, tokens, pos, store,
                     active if self.stats_len else None)

@@ -839,6 +839,13 @@ class ModelRuntime:
         self._phase("admit_batch", t_phase)
         plens = lengths[:len(cands)].astype(np.int64)
         live_tokens = int(plens.sum())
+        extra = {}
+        if coh.ps.windowed:
+            # keys a sliding-window layer needs: row t sees min(t + 1, W)
+            inside = np.minimum(plens, coh.ps.spec.window)
+            extra["attn_window_key_rows"] = int(
+                (inside * (inside + 1) // 2
+                 + (plens - inside) * coh.ps.spec.window).sum())
         with span("generation.prefill", model=self.name, batch=len(cands),
                   rung=L, rows=P, tokens=live_tokens,
                   padded_tokens=P * L,
@@ -846,7 +853,7 @@ class ModelRuntime:
                   # sees t + 1 of them
                   attn_key_rows=int((plens * (plens + 1) // 2).sum()),
                   head_rows=coh.ps.head_rows.get((P, L)),
-                  sampled=int(np.count_nonzero(temp > 0.0))) as sp:
+                  sampled=int(np.count_nonzero(temp > 0.0)), **extra) as sp:
             begun = self._pass_begin()
             first, coh.cache, self._key = coh.ps.run_prefill(
                 coh.cache, tokens, lengths, tables_p, slots, self._key,
@@ -866,6 +873,8 @@ class ModelRuntime:
         self._note_pass("prefill", sp, cost)
         if coh.ps.prefix_skipped_stateful:
             self.metrics.record_prefix_skipped_stateful(len(cands))
+        if coh.ps.prefix_skipped_windowed:
+            self.metrics.record_prefix_skipped_windowed(len(cands))
         t_phase = time.perf_counter()
         now = time.monotonic()
         emitted = 0
@@ -1107,6 +1116,10 @@ class ModelRuntime:
                 else int((-(-seen // blk) * blk).sum()),
                 # one layer's row of one token, as the pools lay it out
                 cache_row_bytes=coh.ps.cache_row_bytes())
+            if coh.ps.windowed:
+                # the rows a sliding-window layer attends to this step
+                attrs["window_tokens"] = int(
+                    np.minimum(seen, coh.ps.spec.window).sum())
         # a slot keeps its last request's temperature after it finishes,
         # and another cohort's slots are not this step's: only the live
         # rows may decide whether the program's sampler draws

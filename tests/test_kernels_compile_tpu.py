@@ -138,6 +138,24 @@ def test_flash_forward_with_a_value_size_of_its_own_compiles(v5e, T):
     assert pallas_attention.FWD_NAME in _instruction_names(text)
 
 
+@pytest.mark.parametrize("T,H,Hkv,window", [
+    (6144, 64, 8, 512), (16384, 64, 8, 512),     # laguna-serve-codebase
+    (2048, 8, 8, 300), (512, 4, 2, 512)])        # odd window; one block
+def test_flash_forward_under_a_sliding_window_compiles(v5e, T, H, Hkv,
+                                                       window):
+    """laguna-serve-codebase's sliding-window prefills: 64 query heads
+    reading 8 key-value heads in place, a window of 512, one whole prompt
+    of its smallest and its largest rung; under a name of its own."""
+    text = _compiled_text(
+        v5e, lambda q, k, v: pallas_attention.flash_attention(
+            q, k, v, causal=True, window=window),
+        ((1, H, T, 128), bf16), ((1, Hkv, T, 128), bf16),
+        ((1, Hkv, T, 128), bf16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert pallas_attention.WINDOW_FWD_NAME in _instruction_names(text)
+    assert pallas_attention.FWD_NAME not in _instruction_names(text)
+
+
 def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):
     """Mosaic calls cannot be partitioned automatically: a program over
     four devices lowers the kernels only because flash_attention splits
@@ -206,7 +224,41 @@ def test_paged_attention_over_a_latent_pool_compiles(v5e, S, Hq, W, row,
     assert name in _instruction_names(text) and not name[-1].isdigit()
 
 
+@pytest.mark.parametrize("S,Hq,H,blk,rp,mb,L,dtype", [
+    (5, 8, 2, 8, 5, 12, 2, f32),             # the CPU tests' shape
+    (40, 64, 8, 64, 9, 272, 3, bf16),        # laguna-serve-codebase
+    (48, 64, 8, 64, 9, 272, 3, bf16)])
+def test_paged_attention_under_a_sliding_window_compiles(v5e, S, Hq, H, blk,
+                                                         rp, mb, L, dtype):
+    """The windowed decode over a ring a slot (``rp`` pages of ``blk``
+    rows, S + 1 rings a layer viewed as one pool): a fourth prefetched
+    scalar a slot, the walk from the window's first page; under a name of
+    its own."""
+    pool = ((L, (S + 1) * rp, blk, H * 128), dtype)
+    text = _compiled_text(
+        v5e, lambda q, k, v, t, n, s0: pallas_paged_attention.
+        paged_attention_decode(q, k, v, L - 1, t, n, starts=s0),
+        ((S, Hq, 1, 128), dtype), pool, pool, ((S, mb), jnp.int32),
+        ((S,), jnp.int32), ((S,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    name = pallas_paged_attention.WINDOW_KERNEL_NAME
+    assert name in _instruction_names(text) and not name[-1].isdigit()
+    assert pallas_paged_attention.KERNEL_NAME not in _instruction_names(text)
+
+
+def test_paged_attention_of_48_query_heads_over_8_compiles(v5e):
+    """laguna-serve-codebase's full-attention layers: 48 query heads of
+    128 over 8 key-value heads, pages of 64, 40 slots of 272 pages."""
+    text = _compiled_text(
+        v5e, lambda q, k, v, t, n: pallas_paged_attention.
+        paged_attention_decode(q, k, v, 1, t, n),
+        *_paged_avals(40, 8, 1, 128, 64, 272, 2, bf16, q_heads=48))
+    assert pallas_paged_attention.KERNEL_NAME in _instruction_names(text)
+
+
 @pytest.mark.parametrize("N,d,F,E,k,dtype", [
+    (40, 2048, 512, 256, 8, bf16),           # laguna-serve-codebase: decode
+    (16384, 2048, 512, 256, 8, bf16),        # and its largest prefill
     (40, 128, 256, 8, 2, f32),               # the parity pin
     (32, 2048, 1536, 64, 4, bf16),           # lfm2moe-serve-extract: decode,
     (512, 2048, 1536, 64, 4, bf16),          # its smallest prefill

@@ -20,6 +20,13 @@ Params/state are arguments, not constants, so hot-swap reuses executables
 exactly as the forward-serving ProgramSet does (``with_params_from``).
 The PRNG key is carried through every program and split in-program.
 
+What a launch brings from the HOST is one int32 array, so one transfer:
+a row a slot (decode) or a prompt (prefill) holding every per-row
+argument, the temperature's float32 bit for bit (``pack_decode`` /
+``unpack_decode``, ``pack_prefill`` / ``unpack_prefill``). A program
+unpacks it as its first act; everything else it takes (parameters,
+state, cache, key, the step before's tokens) is on the device already.
+
 Model support is adapter-based: ``models.decode.GraphDecodeSpec`` (paged
 KV cache for a graph's attention layers and, beside the pools in the same
 cache pytree, a fixed-shape per-slot state for its recurrent mixers) and
@@ -41,6 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -218,21 +226,106 @@ def _head_rows(jaxpr, vocab: int) -> int:
     return count(live)
 
 
-def _program_span(name: str, program: str, step: Optional[int]):
+# ---- a launch's host arguments: ONE int32 array, one transfer. The column
+# order is written down here and nowhere else: a ``pack_*`` on the host and
+# its ``unpack_*`` as the program's first act, every caller through them.
+DECODE_COLS = 6     # a row a slot; the slot's table row behind them
+PREFILL_COLS = 4    # a row a prompt; its table row, then its tokens, behind
+
+
+def _f32(col):
+    """An int32 column as the float32 it carries bit for bit: a view on the
+    host, a bitcast in a program."""
+    if isinstance(col, np.ndarray):
+        return col.view(np.float32)
+    return lax.bitcast_convert_type(col, jnp.float32)
+
+
+def pack_decode(tokens, host_known, pos, tables, active, temp,
+                topk) -> np.ndarray:
+    """A decode step's host arguments as one FRESH ``[S, DECODE_COLS +
+    blocks_per_seq]`` int32 array (the caller may write its own arrays as
+    soon as this returns)."""
+    packed = np.empty((len(tokens), DECODE_COLS + tables.shape[1]), np.int32)
+    for col, a in enumerate((tokens, host_known, pos, active,
+                             np.asarray(temp, np.float32).view(np.int32),
+                             topk)):
+        packed[:, col] = a
+    packed[:, DECODE_COLS:] = tables
+    return packed
+
+
+def unpack_decode(packed):
+    """``pack_decode``'s array, in a program or on the host -> (tokens,
+    host_known, pos, tables, active, temp, topk), each value what was
+    packed, to the bit."""
+    tokens, host_known, pos, active, temp, topk = (
+        packed[:, col] for col in range(DECODE_COLS))
+    return (tokens, host_known != 0, pos, packed[:, DECODE_COLS:],
+            active != 0, _f32(temp), topk)
+
+
+def unpack_prefill(packed, blocks_per_seq: int):
+    """A prefill's ``[P, PREFILL_COLS + blocks_per_seq + L]`` int32 array,
+    in a program or on the host -> (tokens [P, L], lengths, tables, slots,
+    temp, topk). Of a numpy array these are writable VIEWS: the scheduler
+    fills a fresh array's rows through them."""
+    lengths, slots, temp, topk = (
+        packed[:, col] for col in range(PREFILL_COLS))
+    at = PREFILL_COLS + blocks_per_seq
+    return (packed[:, at:], lengths, packed[:, PREFILL_COLS:at], slots,
+            _f32(temp), topk)
+
+
+def padding_prefill(P: int, L: int, blocks_per_seq: int,
+                    trash_slot: int) -> np.ndarray:
+    """A fresh (P, L) prefill array whose every row is padding: one token,
+    the trash slot, no pages, greedy."""
+    packed = np.zeros((P, PREFILL_COLS + blocks_per_seq + L), np.int32)
+    _, lengths, _, slots, _, _ = unpack_prefill(packed, blocks_per_seq)
+    lengths[:], slots[:] = 1, trash_slot
+    return packed
+
+
+def pack_prefill(tokens, lengths, tables, slots, temp, topk) -> np.ndarray:
+    """A prefill's host arguments as one fresh int32 array."""
+    mb = tables.shape[1]
+    packed = np.empty((tokens.shape[0], PREFILL_COLS + mb + tokens.shape[1]),
+                      np.int32)
+    for view, a in zip(unpack_prefill(packed, mb),
+                       (tokens, lengths, tables, slots, temp, topk)):
+        view[...] = a
+    return packed
+
+
+def _host_args(args) -> Tuple[int, int]:
+    """What of a call's arguments comes from the host: (numpy arrays,
+    their bytes)."""
+    arrays = [a for a in jax.tree.leaves(args) if isinstance(a, np.ndarray)]
+    return len(arrays), sum(a.nbytes for a in arrays)
+
+
+def _program_span(name: str, program: str, step: Optional[int], **attrs):
     """A launch's or a read's span. A decode step's carries the step's
     number (``scheduler._Step``), on the event and as the metadata of its
     ``TraceAnnotation``: the pair of spans of one step is found by it."""
     if step is None:
-        return span(name, program=program)
-    return span(name, annotate=("step",), program=program, step=step)
+        return span(name, program=program, **attrs)
+    return span(name, annotate=("step",), program=program, step=step,
+                **attrs)
 
 
-def _launch(program: str, exe, *args, step: Optional[int] = None):
+def _launch(program: str, exe, *args, host: Tuple[int, int],
+            step: Optional[int] = None):
     """Call a compiled executable: ``generation.dispatch`` is the
-    host-to-device transfer of the arguments and the launch. The call
-    returns with the results still pending; the copy of the FIRST one to
-    the host is asked for at once, so that ``_read`` finds it done."""
-    with _program_span("generation.dispatch", program, step):
+    host-to-device transfer of the call's ONE host array (``host``: the
+    numpy arrays among ``args`` and their bytes, counted once an
+    executable, on the span as ``host_args`` / ``host_bytes``) and the
+    launch. The call returns with the results still pending; the copy of
+    the FIRST one to the host is asked for at once, so that ``_read``
+    finds it done."""
+    with _program_span("generation.dispatch", program, step,
+                       host_args=host[0], host_bytes=host[1]):
         first, *rest = exe(*args)
         first.copy_to_host_async()
     return (first, *rest)
@@ -245,12 +338,12 @@ def _read(program: str, first, step: Optional[int] = None) -> np.ndarray:
         return np.asarray(first)
 
 
-def _launch_and_read(program: str, exe, *args):
+def _launch_and_read(program: str, exe, *args, host: Tuple[int, int]):
     """Launch and read the first result back, the two halves of the
     blocking ``generation.prefill`` / ``verify`` span the caller holds
     open (a ``generation.decode_step`` span holds the launch of one step
     and the read of the step before: ``launch_decode``)."""
-    first, *rest = _launch(program, exe, *args)
+    first, *rest = _launch(program, exe, *args, host=host)
     return (_read(program, first), *rest)
 
 
@@ -461,11 +554,15 @@ class GenerationProgramSet:
         # (P, L) -> rows the head runs on in that prefill program, read off
         # its jaxpr by warm(); the ``generation.prefill`` span carries it
         self.head_rows: Dict[Tuple[int, int], int] = {}
+        # executable -> (numpy arrays among a launch's arguments, their
+        # bytes), counted at its first launch, which is warm()'s touch
+        self.host_args: Dict[Any, Tuple[int, int]] = {}
         if self.adapter == "state":
             self._init_states = self.spec.init_states(config.decode_slots + 1)
-        # what a decode step takes for "the step before" when there is none
-        self._no_prev = np.zeros(config.decode_slots + self.stats_len,
-                                 np.int32)
+        # what a decode step takes for "the step before" when there is
+        # none: on the device, as a step's result would be
+        self._no_prev = jnp.zeros(config.decode_slots + self.stats_len,
+                                  jnp.int32)
 
     @staticmethod
     def _resolve_adapter(net, adapter: str) -> str:
@@ -621,12 +718,13 @@ class GenerationProgramSet:
 
     # ------------------------------------------------------------- programs
     def _prefill_fn(self):
-        spec = self.spec
+        spec, mb = self.spec, self.config.blocks_per_seq
 
-        def fn(params, state, cache, tokens, lengths, tables, slots, key,
-               temp, topk):
+        def fn(params, state, cache, packed, key):
             if self._trace_hook is not None:
                 self._trace_hook()
+            tokens, lengths, tables, slots, temp, topk = unpack_prefill(
+                packed, mb)
             if self.adapter == "paged":
                 pools = cache[:self.n_pools]
                 # the head runs on the one row of each prompt that is
@@ -694,10 +792,11 @@ class GenerationProgramSet:
     def _decode_fn(self):
         spec, blk = self.spec, self.config.block_len
 
-        def fn(params, state, cache, tokens, prev, host_known, pos, tables,
-               active, key, temp, topk):
+        def fn(params, state, cache, packed, prev, key):
             if self._trace_hook is not None:
                 self._trace_hook()
+            tokens, host_known, pos, tables, active, temp, topk = \
+                unpack_decode(packed)
             # a row's token: the host's where the host knows it (a slot's
             # first token after its prefill, a replayed prompt token), else
             # what the step before sampled, never read by the host before
@@ -752,6 +851,23 @@ class GenerationProgramSet:
         k = self.fresh_key()
         return jax.ShapeDtypeStruct(k.shape, k.dtype)
 
+    def _prefill_avals(self, P: int, L: int):
+        """What a (P, L) prefill program takes behind (params, state,
+        cache): the packed host array and the key."""
+        return (jax.ShapeDtypeStruct(
+            (P, PREFILL_COLS + self.config.blocks_per_seq + L), jnp.int32),
+            self._key_spec())
+
+    def _decode_avals(self):
+        """The same for the decode step: the packed host array, the step
+        before's result and the key."""
+        c = self.config
+        return (jax.ShapeDtypeStruct(
+            (c.decode_slots, DECODE_COLS + c.blocks_per_seq), jnp.int32),
+            jax.ShapeDtypeStruct((c.decode_slots + self.stats_len,),
+                                 jnp.int32),
+            self._key_spec())
+
     def _cow_fn(self):
         n = self.n_pools
 
@@ -805,37 +921,22 @@ class GenerationProgramSet:
         the decode hot path."""
         c = self.config
         i32 = jnp.int32
-        cache_spec, key_spec = self._cache_spec(), self._key_spec()
+        cache_spec = self._cache_spec()
         mb = c.blocks_per_seq
         prefill = self._prefill_fn()
         decode = self._decode_fn()
         for P in c.prefill_batches:
             for L in c.prompt_rungs:
                 traced = self._traced(
-                    prefill, _DONATE_CACHE,
-                    self.params, self.state, cache_spec,
-                    jax.ShapeDtypeStruct((P, L), i32),
-                    jax.ShapeDtypeStruct((P,), i32),
-                    jax.ShapeDtypeStruct((P, mb), i32),
-                    jax.ShapeDtypeStruct((P,), i32),
-                    key_spec,
-                    jax.ShapeDtypeStruct((P,), jnp.float32),
-                    jax.ShapeDtypeStruct((P,), i32))
+                    prefill, _DONATE_CACHE, self.params, self.state,
+                    cache_spec, *self._prefill_avals(P, L))
                 self.head_rows[(P, L)] = _head_rows(traced.jaxpr.jaxpr,
                                                     self.spec.vocab)
                 self._compiled[("prefill", P, L)] = traced.lower().compile()
         S = c.decode_slots
         self._compiled[("decode",)] = self._aot(
             decode, _DONATE_CACHE, self.params, self.state, cache_spec,
-            jax.ShapeDtypeStruct((S,), i32),
-            jax.ShapeDtypeStruct((S + self.stats_len,), i32),
-            jax.ShapeDtypeStruct((S,), jnp.bool_),
-            jax.ShapeDtypeStruct((S,), i32),
-            jax.ShapeDtypeStruct((S, mb), i32),
-            jax.ShapeDtypeStruct((S,), jnp.bool_),
-            key_spec,
-            jax.ShapeDtypeStruct((S,), jnp.float32),
-            jax.ShapeDtypeStruct((S,), i32))
+            *self._decode_avals())
         if self.prefix_enabled:
             # the copy-on-write block copy: src/dst are runtime scalars, so
             # ONE executable serves every copy
@@ -851,10 +952,7 @@ class GenerationProgramSet:
         for P in c.prefill_batches:
             for L in c.prompt_rungs:
                 _, cache, key = self.run_prefill(
-                    cache, np.zeros((P, L), np.int32),
-                    np.ones((P,), np.int32), np.zeros((P, mb), np.int32),
-                    np.full((P,), S, np.int32), key,
-                    np.zeros((P,), np.float32), np.zeros((P,), np.int32))
+                    cache, padding_prefill(P, L, mb, S), key)
         _, cache, key = self.run_decode(
             cache, np.zeros((S,), np.int32), np.zeros((S,), np.int32),
             np.zeros((S, mb), np.int32), np.zeros((S,), np.bool_), key,
@@ -987,12 +1085,21 @@ class GenerationProgramSet:
         return want <= set(self._compiled)
 
     # ---------------------------------------------------------------- running
-    def run_prefill(self, cache, tokens, lengths, tables, slots, key, temp,
-                    topk):
-        """Returns (first_tokens np [P], cache', key'); a model with
-        expert layers appends its counters to the tokens
-        (``split_stats``)."""
-        P, L = tokens.shape
+    def _host_of(self, which, *args) -> Tuple[int, int]:
+        """``_host_args`` of executable ``which``'s launches, counted at
+        its first one."""
+        host = self.host_args.get(which)
+        if host is None:
+            host = self.host_args[which] = _host_args(args)
+        return host
+
+    def run_prefill(self, cache, packed, key):
+        """One blocking prefill of ``packed`` (``pack_prefill``: the (P,
+        L) program is read off its shape). Returns (first_tokens np [P],
+        cache', key'); a model with expert layers appends its counters to
+        the tokens (``split_stats``)."""
+        P = packed.shape[0]
+        L = packed.shape[1] - PREFILL_COLS - self.config.blocks_per_seq
         exe = self._compiled.get(("prefill", P, L))
         if exe is None:
             from ..errors import ServingError
@@ -1000,24 +1107,25 @@ class GenerationProgramSet:
                 f"no warmed prefill program for (batch={P}, rung={L}) — "
                 f"call warm() before serving (warmed: "
                 f"{sorted(k for k in self._compiled if k[0] == 'prefill')})")
-        return _launch_and_read("prefill", exe, self.params, self.state,
-                                cache, tokens, lengths, tables, slots, key,
-                                temp, topk)
+        args = (self.params, self.state, cache, packed, key)
+        return _launch_and_read("prefill", exe, *args,
+                                host=self._host_of(("prefill", P, L), *args))
 
-    def launch_decode(self, cache, tokens, prev, host_known, pos, tables,
-                      active, key, temp, topk, step: Optional[int] = None):
-        """Launch one decode step and return (next_tokens ON THE DEVICE
-        [S + stats_len], cache', key') without waiting for it. A row's
-        token is ``tokens`` where ``host_known``, else the row of ``prev``:
-        the step before's first result as it left the device (None: every
-        row is the host's). ``read_decode`` reads the tokens back. ``step``
-        is the loop's number for the step: it rides the launch's span and
-        the read's."""
+    def launch_decode(self, cache, packed, prev, key,
+                      step: Optional[int] = None):
+        """Launch one decode step on ``packed`` (``pack_decode``) and
+        return (next_tokens ON THE DEVICE [S + stats_len], cache', key')
+        without waiting for it. A row's token is the packed one where the
+        row is ``host_known``, else the row of ``prev``: the step before's
+        first result as it left the device (None: every row is the
+        host's). ``read_decode`` reads the tokens back. ``step`` is the
+        loop's number for the step: it rides the launch's span and the
+        read's."""
         if prev is None:
             prev = self._no_prev
-        return _launch("decode", self._exe(("decode",)), self.params,
-                       self.state, cache, tokens, prev, host_known, pos,
-                       tables, active, key, temp, topk, step=step)
+        args = (self.params, self.state, cache, packed, prev, key)
+        return _launch("decode", self._exe(("decode",)), *args, step=step,
+                       host=self._host_of(("decode",), *args))
 
     @staticmethod
     def read_decode(first, step: Optional[int] = None) -> np.ndarray:
@@ -1031,8 +1139,8 @@ class GenerationProgramSet:
         (next_tokens np [S], cache', key'); a model with expert layers
         appends its counters to the tokens (``split_stats``)."""
         first, cache, key = self.launch_decode(
-            cache, tokens, None, np.ones(tokens.shape, np.bool_), pos,
-            tables, active, key, temp, topk)
+            cache, pack_decode(tokens, np.ones(tokens.shape, np.bool_), pos,
+                               tables, active, temp, topk), None, key)
         return self.read_decode(first), cache, key
 
     def _exe(self, key):
@@ -1080,9 +1188,9 @@ class GenerationProgramSet:
     def run_verify(self, cache, feeds, pos, tables, active):
         """One batched target pass over [S, k+1] fed tokens. Returns
         (greedy targets np [S,k+1], cache')."""
-        return _launch_and_read("verify", self._exe(("verify",)),
-                                self.params, self.state, cache, feeds, pos,
-                                tables, active)
+        args = (self.params, self.state, cache, feeds, pos, tables, active)
+        return _launch_and_read("verify", self._exe(("verify",)), *args,
+                                host=self._host_of(("verify",), *args))
 
     # --------------------------------------------------------------- hot-swap
     def with_params_from(self, net, draft_net=None) -> "GenerationProgramSet":
@@ -1102,4 +1210,5 @@ class GenerationProgramSet:
         new._compiled = self._compiled
         new.kv_pool_chip_bytes = self.kv_pool_chip_bytes
         new.head_rows = self.head_rows
+        new.host_args = self.host_args
         return new

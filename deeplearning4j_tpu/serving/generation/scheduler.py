@@ -99,7 +99,8 @@ from ..errors import (BlockPoolExhaustedError, DeadlineExceededError,
 from .kvcache import BlockAllocator
 from .metrics import GenerationMetrics
 from .prefix import PrefixCache
-from .programs import ADMIT_LOOKAHEAD, GenerationProgramSet
+from .programs import (ADMIT_LOOKAHEAD, GenerationProgramSet, pack_decode,
+                       padding_prefill, unpack_prefill)
 
 
 try:
@@ -822,12 +823,11 @@ class ModelRuntime:
         S, mb = cfg.decode_slots, cfg.blocks_per_seq
         P = cfg.prefill_rung(len(cands))
         L = cfg.prompt_rung(max(len(r.prompt) for r in cands))
-        tokens = np.zeros((P, L), np.int32)
-        lengths = np.ones(P, np.int32)
-        tables_p = np.zeros((P, mb), np.int32)
-        slots = np.full(P, S, np.int32)          # padding rows -> trash slot
-        temp = np.zeros(P, np.float32)
-        topk = np.zeros(P, np.int32)
+        # the launch's one host array, filled a row a candidate through
+        # views of its columns (padding rows -> the trash slot)
+        packed = padding_prefill(P, L, mb, S)
+        tokens, lengths, tables_p, slots, temp, topk = unpack_prefill(
+            packed, mb)
         for i, r in enumerate(cands):
             plen = len(r.prompt)
             tokens[i, :plen] = r.prompt
@@ -856,8 +856,7 @@ class ModelRuntime:
                   sampled=int(np.count_nonzero(temp > 0.0)), **extra) as sp:
             begun = self._pass_begin()
             first, coh.cache, self._key = coh.ps.run_prefill(
-                coh.cache, tokens, lengths, tables_p, slots, self._key,
-                temp, topk)
+                coh.cache, packed, self._key)
             first, stats = coh.ps.split_stats(first)
             if stats is not None:
                 # the expert layers' routing, read back with the tokens:
@@ -1093,9 +1092,11 @@ class ModelRuntime:
                      prev: Optional[_Step]) -> _Step:
         """Launch one decode step for ``live`` behind ``prev`` (the
         cohort's unread step, whose tokens the rows the host does not know
-        take on the device) and advance the host's state to where the
-        NEXT launch starts: positions, replayed prompt tokens, and the
-        slots whose last token by count this step samples."""
+        take on the device): pack the host's arrays as they stand into
+        the step's one host array, launch, and advance the host's state
+        to where the NEXT launch starts: positions, replayed prompt
+        tokens, and the slots whose last token by count this step
+        samples."""
         cfg = self.config
         S = cfg.decode_slots
         mask = np.zeros(S, np.bool_)
@@ -1127,13 +1128,14 @@ class ModelRuntime:
         attrs["sampled"] = int(np.count_nonzero(temp > 0.0))
         self._step_no += 1
         number = self._step_no
-        # the launch may still read a host array after it returns, and
-        # this loop writes these before the step has run: hand it copies
+        # the launch may still read its host array after it returns, and
+        # this loop writes its own arrays before the step has run: the
+        # packed array is fresh, and nobody writes it
         tokens, coh.cache, self._key = coh.ps.launch_decode(
-            coh.cache, self._tokens.copy(),
-            None if prev is None else prev.tokens, self._host_known.copy(),
-            self._pos.copy(), coh.tables.copy(), mask, self._key, temp,
-            self._topk.copy(), number)
+            coh.cache,
+            pack_decode(self._tokens, self._host_known, self._pos,
+                        coh.tables, mask, temp, self._topk),
+            None if prev is None else prev.tokens, self._key, number)
         pairs = []
         for s in live:
             r = self._slot_req[s]

@@ -39,7 +39,9 @@ from deeplearning4j_tpu.serving import (BlockPoolExhaustedError,
                                         ShapeMismatchError,
                                         xla_compile_count)
 from deeplearning4j_tpu.serving.generation import BlockAllocator
-from deeplearning4j_tpu.serving.generation.programs import ADMIT_LOOKAHEAD
+from deeplearning4j_tpu.serving.generation.programs import (
+    ADMIT_LOOKAHEAD, pack_decode, pack_prefill, padding_prefill, unpack_decode,
+    unpack_prefill)
 from deeplearning4j_tpu.telemetry import RecompileDetector, get_registry
 
 R = np.random.default_rng(99)
@@ -882,8 +884,8 @@ def test_prefill_head_runs_on_the_rows_it_reads(head_rows_set, L, P):
         slots[i] = i
     zf, zi = np.zeros(P, np.float32), np.zeros(P, np.int32)
     first, (k_pool, v_pool), _ = ps.run_prefill(
-        ps.make_cache(), tokens, lengths, tables, slots, ps.fresh_key(),
-        zf, zi)
+        ps.make_cache(), pack_prefill(tokens, lengths, tables, slots, zf, zi),
+        ps.fresh_key())
 
     # (a) the first token, and the logits it is sampled from, are
     # net.output's row lengths - 1 of every live row
@@ -977,16 +979,9 @@ def test_greedy_branch_draws_no_random_bits(head_rows_set, which):
     S, mb = cfg.decode_slots, cfg.blocks_per_seq
     key = ps.fresh_key()
     if which == "prefill":
-        args = (np.zeros((P, L), np.int32), np.ones(P, np.int32),
-                np.zeros((P, mb), np.int32), np.zeros(P, np.int32), key,
-                np.zeros(P, np.float32), np.zeros(P, np.int32))
-        fn = ps._prefill_fn()
+        args, fn = ps._prefill_avals(P, L), ps._prefill_fn()
     else:
-        args = (np.zeros(S, np.int32), np.zeros(S + ps.stats_len, np.int32),
-                np.ones(S, np.bool_), np.zeros(S, np.int32),
-                np.zeros((S, mb), np.int32), np.ones(S, np.bool_), key,
-                np.zeros(S, np.float32), np.zeros(S, np.int32))
-        fn = ps._decode_fn()
+        args, fn = ps._decode_avals(), ps._decode_fn()
     jaxpr = jax.make_jaxpr(fn)(ps.params, ps.state, ps.make_cache(),
                                *args).jaxpr
     conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
@@ -1705,13 +1700,15 @@ class _Prefills:
             return ([int(n) for n, s in zip(lengths, slots) if s != S],
                     [int(s) for s in slots if s != S])
 
-        def run(cache, tokens, lengths, tables, slots, *rest):
+        def run(cache, packed, key):
+            tokens, lengths, _, slots, _, _ = unpack_prefill(
+                packed, rt.config.blocks_per_seq)
             lens, rows = live(lengths, slots)
             self.seen.append({
                 "P": tokens.shape[0], "L": tokens.shape[1], "lens": lens,
                 "slots": rows, "waiting": len(rt._queue) + len(lens),
                 "free": len(rt._slots_free) + len(lens)})
-            return self.orig[0](cache, tokens, lengths, tables, slots, *rest)
+            return self.orig[0](cache, packed, key)
 
         def draft(cache, tokens, lengths, slots):
             lens, rows = live(lengths, slots)
@@ -1934,3 +1931,210 @@ def test_a_speculating_cohort_drafts_the_candidates_the_pass_chose():
         assert eng.metrics()["lm"]["speculative"]["verify_steps"] > 0
     finally:
         eng.stop()
+
+
+# ------------- a launch takes ONE host array (ISSUE 45): every per-row
+# argument of a decode step or a prefill rides one packed int32 array, a
+# transfer a launch, and the program unpacks it as its first act
+def _decode_rows(S=5, mb=3):
+    rng = np.random.default_rng(4500)
+    return (rng.integers(1, 50000, S).astype(np.int32),          # tokens
+            np.asarray([True, False, True, False, False]),       # host_known
+            rng.integers(0, 1000, S).astype(np.int32),           # pos
+            rng.integers(1, 999, (S, mb)).astype(np.int32),      # tables
+            np.asarray([False, True, True, False, True]),        # active
+            np.asarray([0.0, 0.7, 1.3, 1e-3, 2.5], np.float32),  # temp
+            np.asarray([0, 40, 1, 0, 50256], np.int32))          # topk
+
+
+def _same_to_the_bit(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("where", ["host", "program"])
+def test_decode_pack_unpack_round_trip(where):
+    """Temperatures no integer holds, both masks and every slot's table
+    row come back as they went in, on the host and out of a program."""
+    import jax
+    rows = _decode_rows()
+    packed = pack_decode(*rows)
+    assert packed.dtype == np.int32 and packed.shape == (5, 6 + 3)
+    back = unpack_decode(packed) if where == "host" \
+        else jax.jit(unpack_decode)(packed)
+    for got, want in zip(back, rows):
+        _same_to_the_bit(got, want)
+    # a fresh array: writing what was packed does not reach it
+    rows[0][:] = 0
+    rows[3][:] = 0
+    assert unpack_decode(packed)[0].all() and unpack_decode(packed)[3].all()
+
+
+@pytest.mark.parametrize("where", ["host", "program"])
+def test_prefill_pack_unpack_round_trip(where):
+    """Prompts shorter than the rung beside one that fills it and a
+    padding row: tokens, lengths, table rows, slots and a sampled row's
+    temperature and top-k come back as they went in."""
+    import jax
+    P, L, mb, S = 4, 16, 2, 7
+    rng = np.random.default_rng(4501)
+    tokens = np.zeros((P, L), np.int32)
+    lengths = np.asarray([3, 16, 9, 1], np.int32)
+    for i, n in enumerate(lengths[:3]):
+        tokens[i, :n] = rng.integers(1, 50000, n)
+    tables = np.asarray([[4, 0], [9, 2], [5, 6], [0, 0]], np.int32)
+    slots = np.asarray([2, 0, 5, S], np.int32)
+    temp = np.asarray([0.0, 0.7, 1.3, 0.0], np.float32)
+    topk = np.asarray([0, 40, 3, 0], np.int32)
+    rows = (tokens, lengths, tables, slots, temp, topk)
+    packed = pack_prefill(*rows)
+    assert packed.dtype == np.int32 and packed.shape == (P, 4 + mb + L)
+    back = unpack_prefill(packed, mb) if where == "host" \
+        else jax.jit(unpack_prefill, static_argnums=1)(packed, mb)
+    for got, want in zip(back, rows):
+        _same_to_the_bit(got, want)
+    # the scheduler's way in: a fresh array of padding rows, filled a row
+    # a prompt through the host's views, is the same array
+    filled = padding_prefill(P, L, mb, S)
+    views = unpack_prefill(filled, mb)
+    assert int(views[1][3]) == 1 and int(views[3][3]) == S
+    for i in range(3):
+        for view, a in zip(views, rows):
+            view[i] = a[i]
+    assert filled.tobytes() == packed.tobytes()
+
+
+class _Launches:
+    """Every call of ``programs._launch`` while the block runs: the
+    program, how many numpy arrays the executable was handed and their
+    bytes, and the arrays themselves."""
+
+    def __init__(self, monkeypatch):
+        from deeplearning4j_tpu.serving.generation import programs
+        self.seen = []
+        orig = programs._launch
+
+        def launch(program, exe, *args, **kw):
+            import jax
+            arrays = [a for a in jax.tree.leaves(args)
+                      if isinstance(a, np.ndarray)]
+            self.seen.append((program, arrays, args))
+            return orig(program, exe, *args, **kw)
+
+        monkeypatch.setattr(programs, "_launch", launch)
+
+    def of(self, program):
+        return [(arrays, args) for name, arrays, args in self.seen
+                if name == program]
+
+
+@pytest.mark.parametrize("sizes,program", [
+    ((7,), (1, 16)), ((5, 9), (2, 16)), ((5, 9, 12), (4, 16)),
+    ((30,), (1, 64)), ((30, 5), (2, 64)), ((5, 30, 9), (4, 64))])
+def test_a_launch_hands_the_executable_one_host_array(admit_lm, monkeypatch,
+                                                      sizes, program):
+    """Every warmed (P, L) prefill and every decode step behind it, the
+    first after the idle period included (its ``prev`` is a device array
+    too): ONE numpy array a call, of the packed shape, and the tokens are
+    the full recompute's."""
+    net, spec, eng = admit_lm
+    cfg = eng._get("lm").config
+    S, mb = cfg.decode_slots, cfg.blocks_per_seq
+    prompts = _prompts(47, sizes, seed=4502)
+    refs = [naive_generate(net, p, 4, pad_to=64, spec=spec) for p in prompts]
+    rec = _Launches(monkeypatch)
+    outs, _ = _serve_at_once(eng, prompts, [4] * len(sizes))
+    assert outs == refs
+    P, L = program
+    ((arrays, _),) = rec.of("prefill")
+    assert [a.shape for a in arrays] == [(P, 4 + mb + L)]
+    steps = rec.of("decode")
+    assert len(steps) == 3
+    for arrays, args in steps:
+        assert [(a.shape, a.dtype) for a in arrays] == [
+            ((S, 6 + mb), np.dtype(np.int32))]
+        assert len(args) == 6            # params, state, cache, packed, prev, key
+
+
+def test_a_step_does_not_see_what_the_loop_writes_after_its_launch(admit_lm):
+    """``launch_decode`` may read its host array after it returns and the
+    loop writes its own arrays at once (positions, replayed tokens, the
+    next admission's table): the packed array is fresh at every launch
+    and shares no memory with the loop's arrays; and at the program set,
+    a step whose sources are overwritten the moment the launch returns
+    samples what the untouched step samples."""
+    net, spec, eng = admit_lm
+    rt = eng._get("lm")
+    ps = rt.active_ps
+    launch, seen = ps.launch_decode, []
+
+    def spy(cache, packed, *rest):
+        out = launch(cache, packed, *rest)
+        coh = rt._cohorts[-1]
+        for own in (rt._tokens, rt._host_known, rt._pos, rt._temp, rt._topk,
+                    rt._active, coh.tables):
+            assert not np.shares_memory(packed, own)
+        seen.append(packed)
+        return out
+
+    prompts = _prompts(47, (5, 30, 9), seed=4503)
+    refs = [naive_generate(net, p, 6, pad_to=64, spec=spec) for p in prompts]
+    ps.launch_decode = spy
+    try:
+        outs, _ = _serve_at_once(eng, prompts, [6] * 3)
+    finally:
+        ps.launch_decode = launch
+    assert outs == refs
+    assert len(seen) == 5 and len({id(p) for p in seen}) == 5
+
+    cfg = rt.config
+    S, mb = cfg.decode_slots, cfg.blocks_per_seq
+    tokens = np.zeros((1, 16), np.int32)
+    tokens[0, :5] = prompts[0]
+    table = np.zeros((1, mb), np.int32)
+    table[0, :2] = [1, 2]
+    results = []
+    for scribble in (False, True):
+        first, cache, key = ps.run_prefill(
+            ps.make_cache(),
+            pack_prefill(tokens, np.asarray([5], np.int32), table,
+                         np.asarray([0], np.int32), np.zeros(1, np.float32),
+                         np.zeros(1, np.int32)), ps.fresh_key())
+        rows = [np.zeros(S, np.int32), np.ones(S, np.bool_),
+                np.zeros(S, np.int32), np.zeros((S, mb), np.int32),
+                np.zeros(S, np.bool_), np.zeros(S, np.float32),
+                np.zeros(S, np.int32)]
+        rows[0][0], rows[2][0], rows[4][0] = first[0], 5, True
+        rows[3][0] = table[0]
+        nxt, cache, key = ps.launch_decode(cache, pack_decode(*rows), None,
+                                           key)
+        if scribble:
+            for a in rows:
+                a[...] = 1
+        results.append(int(ps.read_decode(nxt)[0]))
+    assert results[0] == results[1] == refs[0][1]
+
+
+def test_the_dispatch_span_says_what_came_from_the_host(admit_lm):
+    """``generation.dispatch`` carries ``host_args`` = 1 and the packed
+    array's bytes, for the prefill's launch and for every decode step's."""
+    net, _, eng = admit_lm
+    cfg = eng._get("lm").config
+    S, mb = cfg.decode_slots, cfg.blocks_per_seq
+    _, events = _serve_at_once(eng, _prompts(47, (30, 5), seed=4504), [4, 4])
+    spans = _named(events, "generation.dispatch", ph="X", cat="span")
+    by_program = {}
+    for e in spans:
+        by_program.setdefault(e["args"]["program"], []).append(e["args"])
+    assert sorted(by_program) == ["decode", "prefill"]
+    assert [(a["host_args"], a["host_bytes"])
+            for a in by_program["prefill"]] == [(1, 2 * (4 + mb + 64) * 4)]
+    assert len(by_program["decode"]) == 3
+    assert all((a["host_args"], a["host_bytes"]) == (1, S * (6 + mb) * 4)
+               for a in by_program["decode"])
+    # counted once an executable, at warm()'s touch, and shared by a
+    # hot-swapped set
+    ps = eng._get("lm").active_ps
+    assert ps.host_args[("decode",)] == (1, S * (6 + mb) * 4)
+    assert set(ps.host_args) == set(ps._compiled) - {("cow",)}

@@ -43,7 +43,7 @@ from deeplearning4j_tpu.serving import GenerationEngine  # noqa: E402
 from deeplearning4j_tpu.serving.generation.kvcache import (  # noqa: E402
     PagedStore, make_rings, ring_pages, ring_prefill_fill, ring_tables)
 from deeplearning4j_tpu.serving.generation.programs import (  # noqa: E402
-    GenerationConfig, GenerationProgramSet)
+    GenerationConfig, GenerationProgramSet, pack_prefill)
 
 with open(os.path.join(ROOT, "benchmarks", "configs", "laguna-xs.2",
                        "config.json")) as _f:
@@ -277,8 +277,10 @@ def test_prefill_then_decode_token_by_token_is_the_references_forward(
     tokens[0, :n] = ids[:n]
     z = lambda k, dt=np.int32: np.zeros(k, dt)
     first, cache, _ = ps.run_prefill(
-        ps.make_cache(), tokens, np.asarray([n], np.int32), tables[1:2],
-        np.asarray([1], np.int32), ps.fresh_key(), z(1, np.float32), z(1))
+        ps.make_cache(),
+        pack_prefill(tokens, np.asarray([n], np.int32), tables[1:2],
+                     np.asarray([1], np.int32), z(1, np.float32), z(1)),
+        ps.fresh_key())
     assert int(ps.split_stats(first)[0][0]) == int(np.argmax(want[n - 1]))
     active = jnp.asarray([False, True, False])
 
@@ -777,20 +779,22 @@ KANANA = {
     "hyperparameters": TOY["hyperparameters"], "precision": TOY["precision"],
 }
 # sha256 (first 16 hex digits) of the jaxpr text of each program as traced
-# by ``_program_text`` under this suite's conftest (x64 on), taken at this
-# PR's parent (4ee199f): gpt2's and lfm2's are the hashes
-# ``tests/test_mla_serving.py`` holds them to, kanana's were taken with the
-# same function in a copy of the parent (git archive), under this file's
-# highest-precision fixture. What this PR adds to
-# the kernels, the layers, the stores and the specification are new cases;
-# the calls these three families make trace to what they traced to before.
+# by ``_program_text`` under this suite's conftest (x64 on) and this file's
+# highest-precision fixture. Taken anew at PR 45 (parent 5fcaa60), which
+# MEANT to change every serving program (one packed host array, unpacked
+# as the program's first act: ``tests/test_mla_serving.py`` says what of
+# the text differs from the parent's); gpt2's and lfm2's are the hashes
+# that file holds them to. Before, they were PR 44's parent's (4ee199f):
+# what PR 44 added to the kernels, the layers, the stores and the
+# specification are new cases, and the calls these three families make
+# trace to what they traced to before.
 PARENT_PROGRAMS = {
-    ("gpt2", "prefill"): "c5416f549b9a0e76",
-    ("gpt2", "decode"): "0f7b970879784f8b",
-    ("lfm2", "prefill"): "0da8642ba6a73b96",
-    ("lfm2", "decode"): "46c233dfc02c1801",
-    ("kanana", "prefill"): "ff58db01f2971d2d",
-    ("kanana", "decode"): "405b07a8d826bf9c",
+    ("gpt2", "prefill"): "9136f6ee64a270a1",
+    ("gpt2", "decode"): "10a41b56698b4941",
+    ("lfm2", "prefill"): "d7e71823e122800d",
+    ("lfm2", "decode"): "6692baeca2ac488b",
+    ("kanana", "prefill"): "5dae05d5500ba61c",
+    ("kanana", "decode"): "27df67f7cf202269",
 }
 
 
@@ -798,20 +802,13 @@ def _program_text(net, which):
     cfg = GenerationConfig(block_len=16, max_seq_len=256, decode_slots=3,
                            prompt_rungs=(256,), prefill_batches=(2,))
     ps = GenerationProgramSet(net, config=cfg)
-    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
-    mb, S, P, L = cfg.blocks_per_seq, 3, 2, 256
-    cache, key = ps._cache_spec(), ps._key_spec()
+    cache = ps._cache_spec()
     if which == "prefill":
         jaxpr = jax.make_jaxpr(ps._prefill_fn())(
-            ps.params, ps.state, cache, sds((P, L), i32), sds((P,), i32),
-            sds((P, mb), i32), sds((P,), i32), key, sds((P,), jnp.float32),
-            sds((P,), i32))
+            ps.params, ps.state, cache, *ps._prefill_avals(2, 256))
     else:
         jaxpr = jax.make_jaxpr(ps._decode_fn())(
-            ps.params, ps.state, cache, sds((S,), i32),
-            sds((S + ps.stats_len,), i32), sds((S,), jnp.bool_),
-            sds((S,), i32), sds((S, mb), i32), sds((S,), jnp.bool_), key,
-            sds((S,), jnp.float32), sds((S,), i32))
+            ps.params, ps.state, cache, *ps._decode_avals())
     return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
 

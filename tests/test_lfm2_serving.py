@@ -27,7 +27,7 @@ from deeplearning4j_tpu.models.decode import (GraphDecodeSpec,  # noqa: E402
 from deeplearning4j_tpu.models.zoo_extra import transformer_lm  # noqa: E402
 from deeplearning4j_tpu.serving import GenerationEngine  # noqa: E402
 from deeplearning4j_tpu.serving.generation.programs import (  # noqa: E402
-    GenerationConfig, GenerationProgramSet)
+    GenerationConfig, GenerationProgramSet, pack_prefill)
 
 TOY = {
     "family": "lfm2_moe", "conv_L_cache": 3, "hidden_size": 128,
@@ -127,8 +127,9 @@ def test_prefill_at_a_padded_rung_then_twenty_decode_steps(toy):
     lengths = np.asarray([7, 29], np.int32)
     z = lambda n, dt=np.int32: np.zeros(n, dt)
     first, cache, key = ps.run_prefill(
-        cache, tokens, lengths, tables[:2], np.asarray([0, 1], np.int32),
-        ps.fresh_key(), z(2, np.float32), z(2))
+        cache, pack_prefill(tokens, lengths, tables[:2],
+                            np.asarray([0, 1], np.int32), z(2, np.float32),
+                            z(2)), ps.fresh_key())
     first, stats = ps.split_stats(first)
     assert stats is not None and 1 <= int(stats[1]) <= 3 * 8
     served = [[int(first[0])], [int(first[1])]]

@@ -37,7 +37,7 @@ from deeplearning4j_tpu.serving import GenerationEngine  # noqa: E402
 from deeplearning4j_tpu.serving.generation.kvcache import (  # noqa: E402
     PagedStore, make_pools)
 from deeplearning4j_tpu.serving.generation.programs import (  # noqa: E402
-    GenerationConfig, GenerationProgramSet)
+    GenerationConfig, GenerationProgramSet, pack_decode, pack_prefill)
 
 TOY = {
     "family": "deepseek_v3", "hidden_size": 128, "intermediate_size": 256,
@@ -186,8 +186,9 @@ def test_prefill_at_a_padded_rung_then_twenty_decode_steps(toy):
         tables[i] = 1 + i * mb + np.arange(mb)
     z = lambda n, dt=np.int32: np.zeros(n, dt)
     first, cache, key = ps.run_prefill(
-        cache, tokens, np.asarray([7, 29], np.int32), tables[:2],
-        np.asarray([0, 1], np.int32), ps.fresh_key(), z(2, np.float32), z(2))
+        cache, pack_prefill(tokens, np.asarray([7, 29], np.int32), tables[:2],
+                            np.asarray([0, 1], np.int32), z(2, np.float32),
+                            z(2)), ps.fresh_key())
     first, stats = ps.split_stats(first)
     assert stats is not None and 1 <= int(stats[1]) <= 2 * 8
     served = [[int(first[0])], [int(first[1])]]
@@ -231,23 +232,27 @@ def test_a_row_fed_from_the_device_gives_the_logits_of_the_host_fed_token(
         tables[i] = 1 + i * mb + np.arange(mb)
     toks = np.asarray([17, 201, 5], np.int32)
     pos = np.asarray([0, 3, 9], np.int32)
-    rest = (pos, tables, np.ones(S, np.bool_), ps.fresh_key(),
-            np.zeros(S, np.float32), np.zeros(S, np.int32))
-    host, (pool_h,), _ = step(ps.params, ps.state, ps.make_cache(), toks,
-                              np.zeros(S, np.int32), np.ones(S, np.bool_),
-                              *rest)
+    rest = (pos, tables, np.ones(S, np.bool_), np.zeros(S, np.float32),
+            np.zeros(S, np.int32))
+    host, (pool_h,), _ = step(
+        ps.params, ps.state, ps.make_cache(),
+        pack_decode(toks, np.ones(S, np.bool_), *rest),
+        np.zeros(S, np.int32), ps.fresh_key())
     # rows 0 and 2 from the device (the host's copy of them is stale), row
     # 1 from the host (the device's is another token)
     prev = jnp.asarray([17, 99, 5], jnp.int32)
-    dev, (pool_d,), _ = step(ps.params, ps.state, ps.make_cache(),
-                             np.asarray([3, 201, 250], np.int32), prev,
-                             np.asarray([False, True, False]), *rest)
+    dev, (pool_d,), _ = step(
+        ps.params, ps.state, ps.make_cache(),
+        pack_decode(np.asarray([3, 201, 250], np.int32),
+                    np.asarray([False, True, False]), *rest),
+        prev, ps.fresh_key())
     assert host.shape == (S, TOY["vocab_size"])
     assert jnp.array_equal(host, dev) and jnp.array_equal(pool_h, pool_d)
     # and the host's stale copy, had it been fed, gives other logits
-    stale, _, _ = step(ps.params, ps.state, ps.make_cache(),
-                       np.asarray([3, 201, 250], np.int32), prev,
-                       np.ones(S, np.bool_), *rest)
+    stale, _, _ = step(
+        ps.params, ps.state, ps.make_cache(),
+        pack_decode(np.asarray([3, 201, 250], np.int32),
+                    np.ones(S, np.bool_), *rest), prev, ps.fresh_key())
     assert not jnp.array_equal(stale[0], host[0])
     assert jnp.array_equal(stale[1], host[1])
 
@@ -493,21 +498,22 @@ LFM2 = {
     "hyperparameters": TOY["hyperparameters"], "precision": TOY["precision"],
 }
 # sha256 (first 16 hex digits) of the jaxpr text of each program as traced
-# by this very function under this suite's conftest (x64 on). The two
-# ``prefill`` hashes are those of commit 7936418 (PR 40's parent): what PR
-# 40 added to the kernels, the stores and the specification are new cases,
-# and the calls these two families make trace to what they traced to
-# before. The two ``decode`` hashes were taken anew at PR 41 (parent
-# a13c243), which MEANT to change the program: it takes the step before's
-# result and a mask of the rows the host knows beside ``tokens`` and
-# selects between them on ``[S]`` (and hands lfm2's counters back in the
-# tokens' own type, so that the result can be fed to the next step);
-# nothing else of the step's text differs from the parent's
+# by this very function under this suite's conftest (x64 on). Taken anew at
+# PR 45 (parent 5fcaa60), which MEANT to change every serving program: a
+# program takes its per-row host arguments as ONE packed int32 array
+# (``programs.pack_decode`` / ``pack_prefill``) and unpacks it as its first
+# act. Beside the signature line the texts differ from the parent's by the
+# unpacking alone (a slice and a squeeze a column, two ``ne 0`` for the
+# decode step's masks, one ``bitcast_convert_type`` for the temperature:
+# 19-21 lines a program, compared line by line with the variables' names
+# taken out). History: the prefill hashes before were those of commit
+# 7936418 (PR 40's parent), the decode hashes PR 41's (the step before's
+# result and the mask of the rows the host knows).
 PARENT_PROGRAMS = {
-    ("gpt2", "prefill"): "c5416f549b9a0e76",
-    ("gpt2", "decode"): "0f7b970879784f8b",
-    ("lfm2", "prefill"): "0da8642ba6a73b96",
-    ("lfm2", "decode"): "46c233dfc02c1801",
+    ("gpt2", "prefill"): "9136f6ee64a270a1",
+    ("gpt2", "decode"): "10a41b56698b4941",
+    ("lfm2", "prefill"): "d7e71823e122800d",
+    ("lfm2", "decode"): "6692baeca2ac488b",
 }
 
 
@@ -515,20 +521,13 @@ def _program_text(net, which):
     cfg = GenerationConfig(block_len=16, max_seq_len=256, decode_slots=3,
                            prompt_rungs=(256,), prefill_batches=(2,))
     ps = GenerationProgramSet(net, config=cfg)
-    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
-    mb, S, P, L = cfg.blocks_per_seq, 3, 2, 256
-    cache, key = ps._cache_spec(), ps._key_spec()
+    cache = ps._cache_spec()
     if which == "prefill":
         jaxpr = jax.make_jaxpr(ps._prefill_fn())(
-            ps.params, ps.state, cache, sds((P, L), i32), sds((P,), i32),
-            sds((P, mb), i32), sds((P,), i32), key, sds((P,), jnp.float32),
-            sds((P,), i32))
+            ps.params, ps.state, cache, *ps._prefill_avals(2, 256))
     else:
         jaxpr = jax.make_jaxpr(ps._decode_fn())(
-            ps.params, ps.state, cache, sds((S,), i32),
-            sds((S + ps.stats_len,), i32), sds((S,), jnp.bool_),
-            sds((S,), i32), sds((S, mb), i32), sds((S,), jnp.bool_), key,
-            sds((S,), jnp.float32), sds((S,), i32))
+            ps.params, ps.state, cache, *ps._decode_avals())
     return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
 

@@ -68,20 +68,13 @@ def test_the_serving_programs_matmuls_carry_their_vertex_names(tiny, which):
     cfg = GenerationConfig(block_len=8, max_seq_len=8, decode_slots=2,
                            prompt_rungs=(8,), prefill_batches=(1,))
     ps = GenerationProgramSet(tiny, config=cfg)
-    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
-    mb, S = cfg.blocks_per_seq, 2
-    cache, key = ps._cache_spec(), ps._key_spec()
+    cache = ps._cache_spec()
     if which == "prefill":
-        traced = ps._traced(
-            ps._prefill_fn(), (2,), ps.params, ps.state, cache,
-            sds((1, 8), i32), sds((1,), i32), sds((1, mb), i32),
-            sds((1,), i32), key, sds((1,), jnp.float32), sds((1,), i32))
+        traced = ps._traced(ps._prefill_fn(), (2,), ps.params, ps.state,
+                            cache, *ps._prefill_avals(1, 8))
     else:
-        traced = ps._traced(
-            ps._decode_fn(), (2,), ps.params, ps.state, cache,
-            sds((S,), i32), sds((S,), i32), sds((S,), jnp.bool_),
-            sds((S,), i32), sds((S, mb), i32), sds((S,), jnp.bool_), key,
-            sds((S,), jnp.float32), sds((S,), i32))
+        traced = ps._traced(ps._decode_fn(), (2,), ps.params, ps.state,
+                            cache, *ps._decode_avals())
     dots = _op_names(traced.lower(), "dot_general")
     for vertex in ("b0_attn", "b0_ff1", "b0_ff2", "head"):
         assert any(f"/{vertex}/" in d for d in dots), (vertex, dots)

@@ -207,13 +207,16 @@ def decode_pair():
 
 
 def _greedy_tokens(ps, n_decode=3):
+    from deeplearning4j_tpu.serving.generation.programs import pack_prefill
     cache, key = ps.make_cache(), ps.fresh_key()
     prompt = np.zeros((1, 16), np.int32)
     prompt[0, :3] = [3, 5, 7]
     t, cache, key = ps.run_prefill(
-        cache, prompt, np.array([3], np.int32),
-        np.array([[1, 2]], np.int32), np.array([0], np.int32), key,
-        np.zeros((1,), np.float32), np.zeros((1,), np.int32))
+        cache, pack_prefill(prompt, np.array([3], np.int32),
+                            np.array([[1, 2]], np.int32),
+                            np.array([0], np.int32),
+                            np.zeros((1,), np.float32),
+                            np.zeros((1,), np.int32)), key)
     out = [int(np.asarray(t)[0])]
     for i in range(n_decode):
         t, cache, key = ps.run_decode(

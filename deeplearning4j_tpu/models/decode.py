@@ -83,16 +83,31 @@ class KVStore(Protocol):
         store keeps apart from the pages (no more than the window and a
         page a sequence), and the row sees the keys ``pos - window <
         s <= pos``. A store that keeps whole contexts alone takes no such
-        call."""
+        call.
+
+        A layer with a block-sparse selection calls with the keywords
+        ``select`` (its ``ops.sparse_select.Selection``) and
+        ``select_index`` (its place among the selecting layers): its
+        pages are layer ``i``'s of the full-context pools like any other's;
+        the store also keeps its COMPRESSED keys, scores the query against
+        them and reads the chosen pages alone."""
         ...
 
-    def state(self, j: int) -> Any:
-        """The state of recurrent mixer ``j`` for the step's sequences (a
-        store for a model that has none needs no such method)."""
+    def state(self, j) -> Any:
+        """The state of recurrent mixer ``j`` = (kind, place in its kind)
+        for the step's sequences (a store for a model that has none needs
+        no such method)."""
         ...
 
-    def set_state(self, j: int, new) -> None:
+    def set_state(self, j, new) -> None:
         """Leave recurrent mixer ``j``'s state after the step."""
+        ...
+
+    def state_pool(self, kind: int) -> Any:
+        """The whole pool ``[layers of the kind, slots + 1, ...]`` of one
+        kind of recurrent state, for a mixer that advances its slots'
+        states in place (``decode_step``); ``set_state_pool`` hands it
+        back; ``active`` [B] says which sequences are real."""
         ...
 
 
@@ -117,10 +132,21 @@ def window_attention(q, k, v, row_mask):
 # ---------------------------------------------------------------- transformer
 class StatefulDecodeUnsupportedError(ValueError):
     """A serving feature that does not carry a recurrent mixer's per-slot
-    state (a multi-token decode window: speculative verify, the int8
-    tier's fake-quantized prefill) was asked of a model that has one. It
-    is refused by name: the one outcome not allowed is a silent wrong
-    token."""
+    state (a gated short convolution's last rows, a lightning-attention
+    layer's float32 matrix a head) was asked of a model that has one: a
+    multi-token decode window (speculative verify, the int8 tier's
+    fake-quantized prefill), or pools split over a mesh. It is refused by
+    name: the one outcome not allowed is a silent wrong token."""
+
+
+class SparseDecodeUnsupportedError(ValueError):
+    """A serving feature that reads a sequence's whole context, or keeps
+    none of what a selection is scored against (the int8 tier, a
+    speculative verify window or draft, the prefix cache's shared pages,
+    pools split over a mesh), was asked of a model with block-sparse
+    attention layers, whose cache keeps compressed keys beside the pages
+    and whose rows read the pages their own lists name. Refused by name,
+    as a recurrent state, a latent cache and a window's ring are."""
 
 
 class LatentDecodeUnsupportedError(ValueError):
@@ -146,11 +172,15 @@ class GraphDecodeSpec:
     sequence MIXERS are causal ``SelfAttentionLayer``s (served through a
     ``KVStore``) or recurrent layers with a fixed-shape state
     (``apply_with_final_state`` + ``state_at``: served from a per-slot
-    state the store carries), then a final norm and an ``RnnOutputLayer``
-    head. Every other vertex (norms, dense and gated MLPs,
-    mixture-of-experts layers, residual adds) is position-wise and
-    replays its own ``apply``. ``models.transformer_lm`` (GPT-2) is one
-    case, the hybrid convolution / attention / expert models another.
+    state the store carries; the gated short convolution's rows in the
+    model's dtype, a lightning-attention layer's float32 matrix a head:
+    one pool a KIND of state, ``recurrent_kinds``), then a final norm
+    (and whatever position-wise vertex lies between it and the head) and
+    an ``RnnOutputLayer`` head. Every other vertex (norms, dense and gated
+    MLPs, mixture-of-experts layers, residual adds, scalings) is
+    position-wise and replays its own ``apply``. ``models.transformer_lm``
+    (GPT-2) is one case, the hybrid convolution / attention / expert
+    models and the sparse / linear attention hybrid others.
 
     What the attention layers must share is the cache's ROW: all latent
     (one ``row_lanes`` and ``kv_rank``) or all K/V with one ``kv_heads`` x
@@ -159,7 +189,11 @@ class GraphDecodeSpec:
     context (``window`` None: the paged pools, ``full_names``) or a
     sliding window's rows (``window_names``, all of one width: a ring a
     slot beside the pages). At least one layer keeps the whole context:
-    the pages' tables are what admission counts."""
+    the pages' tables are what admission counts. A layer that keeps the
+    whole context may READ a selection of it (``sparse_names``: a
+    block-sparse selection, all of one size): its pages and its admission
+    are a full layer's own, and beside them the store keeps its compressed
+    keys, one row every ``stride`` positions a slot."""
 
     def __init__(self, net):
         from ..nn.layers import (EmbeddingSequenceLayer,
@@ -259,6 +293,17 @@ class GraphDecodeSpec:
                 "every attention layer has a sliding window "
                 f"({self.window_names}): at least one must keep the whole "
                 "context (admission counts the pages of those layers)")
+        # full-context layers that READ a block-sparse selection of it
+        self.sparse_names = [n for n in self.full_names
+                             if getattr(layer(n), "sparse", None)]
+        sizes = {layer(n).selection for n in self.sparse_names}
+        if len(sizes) > 1:
+            raise ValueError("the block-sparse layers must share one "
+                             f"selection (one compressed row), got {sizes}")
+        self.selection = sizes.pop() if sizes else None
+        if self.selection is not None and self.latent:
+            raise ValueError("a block-sparse selection reads K/V pages")
+        self._sparse_i = {n: i for i, n in enumerate(self.sparse_names)}
         for n in self.recurrent_names:
             if not hasattr(layer(n), "state_at"):
                 raise ValueError(
@@ -269,7 +314,22 @@ class GraphDecodeSpec:
         self._attn_i = {n: i for names in (self.full_names,
                                            self.window_names)
                         for i, n in enumerate(names)}
-        self._rec_j = {n: j for j, n in enumerate(self.recurrent_names)}
+        self.dtype = jnp.dtype(net.conf.dtype)
+        # the recurrent mixers by KIND of state (one row's shape and
+        # dtype): one pool a kind, in the order the kinds first appear
+        self.recurrent_kinds = []      # [(shape of a row, dtype, [names])]
+        self._rec_j = {}               # name -> (kind, place in the kind)
+        for n in self.recurrent_names:
+            z = jax.eval_shape(lambda n=n: layer(n).zero_state(1, self.dtype))
+            key = (tuple(z.shape[1:]), jnp.dtype(z.dtype))
+            for g, (shape, dtype, names_g) in enumerate(self.recurrent_kinds):
+                if (shape, dtype) == key:
+                    break
+            else:
+                g = len(self.recurrent_kinds)
+                self.recurrent_kinds.append(key + ([],))
+            self._rec_j[n] = (g, len(self.recurrent_kinds[g][2]))
+            self.recurrent_kinds[g][2].append(n)
         self.n_blocks = len(self.full_names)       # layers the pools hold
         self.n_window_layers = len(self.window_names)
         # the FIRST attention layer's; a layer's own count is what its
@@ -283,7 +343,6 @@ class GraphDecodeSpec:
         self.vocab = layer(self.head_name).n_out
         self.max_length = layer(self.pos_name).max_length \
             if self.pos_name else None
-        self.dtype = jnp.dtype(net.conf.dtype)
         self.n_moe = len(self.moe_names)
         self.moe_top_k = layer(self.moe_names[0]).top_k if self.n_moe else 0
         self.moe_experts = layer(self.moe_names[0]).n_experts \
@@ -304,23 +363,29 @@ class GraphDecodeSpec:
         even head split keeps every per-head row on one shard and decode
         stays token-for-token identical to the single-chip program. A
         latent pool has no head axis: every head reads every row."""
-        if self.latent or self.window is not None:
+        if self.latent or self.window is not None \
+                or self.selection is not None:
             return m == 1
         return m >= 1 and self.kv_heads % m == 0 and all(
             self._v[n].layer_conf.n_heads % m == 0 for n in self.attn_names)
 
+    def recurrent_state_specs(self, rows: int):
+        """[((layers of the kind, rows, ...), dtype)] a kind of recurrent
+        state: what a cache keeps for ``rows`` sequences, each kind in a
+        pool of its own shape and dtype. Empty for a model without
+        recurrent mixers."""
+        return [((len(names), rows) + shape, dtype)
+                for shape, dtype, names in self.recurrent_kinds]
+
     def recurrent_state_shape(self, rows: int):
-        """[recurrent layers, rows, ...]: the per-slot state of the
-        recurrent mixers stacked (they must share a shape), or None."""
-        if not self.recurrent_names:
-            return None
-        shapes = {tuple(self._v[n].layer_conf.zero_state(rows,
-                                                         self.dtype).shape)
-                  for n in self.recurrent_names}
-        if len(shapes) != 1:
-            raise ValueError("the recurrent mixers must share one state "
-                             f"shape, got {sorted(shapes)}")
-        return (len(self.recurrent_names),) + shapes.pop()
+        """``recurrent_state_specs`` for a model whose mixers are all of
+        one kind: that kind's shape, or None without recurrent mixers."""
+        specs = self.recurrent_state_specs(rows)
+        if len(specs) > 1:
+            raise ValueError("the recurrent mixers keep states of "
+                             f"{len(specs)} kinds: recurrent_state_specs "
+                             "lists them")
+        return specs[0][0] if specs else None
 
     # index/param helpers ---------------------------------------------------
     def vi(self, name: str) -> int:
@@ -409,7 +474,9 @@ class GraphDecodeSpec:
         latent layer's ks[i] is its cache rows [B,L,1,row] and vs is
         empty: one pool); states[j] a recurrent mixer's state after
         ``lengths`` rows; stats int32 [2] (fullest expert's pairs, experts
-        touched) or None."""
+        touched) or None. A model with block-sparse layers also keeps their
+        compressed keys: ``compressed_rows(ks)`` makes them from these
+        ``ks``."""
         x_in = tokens if self.token_input else \
             jax.nn.one_hot(tokens, self.vocab, dtype=self.dtype)
         acts, _ = self.net.apply_fn(params, state, [x_in], train=False)
@@ -434,6 +501,23 @@ class GraphDecodeSpec:
             stats = [self._moe_stats(params, n, acts[self._inputs[n][0]],
                                      live) for n in self.moe_names]
         return logits, ks, vs, states, self._fold_stats(stats)
+
+    def compressed_rows(self, ks):
+        """The block-sparse layers' compressed keys from a prefill's keys
+        (``ks`` in ``attn_names``' order): [P, L / stride, Hkv * Dh] a
+        selecting layer."""
+        from ..ops.sparse_select import compress_keys
+        by_name = dict(zip(self.attn_names, ks))
+        return [compress_keys(by_name[n], self.selection).reshape(
+            by_name[n].shape[0], -1, self.kv_heads * self.head_dim)
+            for n in self.sparse_names]
+
+    def states_by_kind(self, states):
+        """A prefill's states (``recurrent_names``' order) stacked a kind:
+        [layers of the kind, P, ...] each, in ``recurrent_kinds``' order."""
+        by_name = dict(zip(self.recurrent_names, states))
+        return [jnp.stack([by_name[n] for n in names])
+                for _, _, names in self.recurrent_kinds]
 
     def split_kinds(self, per_layer):
         """A list an attention layer (``attn_names``' order) -> (the
@@ -481,11 +565,19 @@ class GraphDecodeSpec:
                         raise StatefulDecodeUnsupportedError(
                             f"a decode window does not carry {name}'s state")
                     j = self._rec_j[name]
-                    out, new = v.apply_with_final_state(
-                        self._p(params, name), state[self._idx[name]],
-                        [acts[ins[0]]], train=False, rng=None,
-                        initial_state=store.state(j))
-                    store.set_state(j, new)
+                    if hasattr(v.layer_conf, "decode_step"):
+                        # a state too large to copy a step: the layer
+                        # advances its slots' rows of the pool in place
+                        out, pool = v.layer_conf.decode_step(
+                            self._p(params, name), acts[ins[0]], w_pos,
+                            store.state_pool(j[0]), j[1], store.active)
+                        store.set_state_pool(j[0], pool)
+                    else:
+                        out, new = v.apply_with_final_state(
+                            self._p(params, name), state[self._idx[name]],
+                            [acts[ins[0]]], train=False, rng=None,
+                            initial_state=store.state(j))
+                        store.set_state(j, new)
                 else:
                     out = self._apply(params, state, name,
                                       [acts[i] for i in ins])
@@ -517,6 +609,13 @@ class GraphDecodeSpec:
                     f"a decode window does not carry {name}'s ring of "
                     f"{layer.window} rows")
             out = store.attend(i, q, k[:, 0], v[:, 0], window=layer.window)
+        elif name in self._sparse_i:
+            if window:
+                raise SparseDecodeUnsupportedError(
+                    f"a decode window does not make {name}'s selection a "
+                    "row")
+            out = store.attend(i, q, k[:, 0], v[:, 0], select=self.selection,
+                               select_index=self._sparse_i[name])
         else:
             out = store.attend(i, q, k, v) if window else \
                 store.attend(i, q, k[:, 0], v[:, 0])

@@ -10,7 +10,8 @@ from .conv import (Convolution1DLayer, ConvolutionLayer, GlobalPoolingLayer,
 from .norm import (BatchNormalization, LayerNormalization,
                    LocalResponseNormalization, RMSNorm)
 from .gated import GatedMLP, GatedShortConvLayer, MixtureOfExpertsLayer
-from .attention import LatentAttentionLayer, SelfAttentionLayer
+from .attention import (LatentAttentionLayer, LightningAttentionLayer,
+                        SelfAttentionLayer)
 from .recurrent import (GravesBidirectionalLSTM, GravesLSTM, LSTM,
                         LastTimeStepLayer)
 from .variational import (BernoulliReconstructionDistribution,
@@ -21,6 +22,7 @@ from .variational import (BernoulliReconstructionDistribution,
 
 __all__ = [
     "SelfAttentionLayer", "LatentAttentionLayer",
+    "LightningAttentionLayer",
     "BernoulliReconstructionDistribution", "CompositeReconstructionDistribution",
     "ExponentialReconstructionDistribution", "GaussianReconstructionDistribution",
     "LossFunctionWrapper", "RBM", "VariationalAutoencoder",

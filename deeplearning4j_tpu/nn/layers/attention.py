@@ -22,6 +22,20 @@ from ..inputs import InputTypeRecurrent
 from .base import LayerConf, maybe_dropout, resolve_ff_size
 
 
+def rotate_half(x, positions, theta: float):
+    """Rotary positions over the whole head, rotate-half form: x
+    [B,T,H,Dh], positions [B,T]; frequencies ``theta^(-2i/Dh)`` made in
+    the program, angles in float32."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, :, None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 @register
 @dataclass
 class SelfAttentionLayer(LayerConf):
@@ -42,6 +56,13 @@ class SelfAttentionLayer(LayerConf):
         o_h = softmax over the keys s with t - window < s <= t
         g = sigmoid(x Wg) -> [H]              (``head_gate``)
         out = concat_h(g_h o_h) Wo            ([H * Dh, n_out])
+
+    ``out_gate``: the gate is as wide as the heads' output, ``sigmoid(x
+    Wg) -> [H * Dh]``, one value a feature. ``sparse``: a block-sparse
+    SELECTION (``ops/sparse_select.py``): a query position attends to the
+    key blocks its own list names (the first, the nearest, and the best of
+    the rest by its key-value group's scores against compressed keys);
+    causal only, dense where the context is short.
     """
     n_in: Optional[int] = None
     n_out: int = 0
@@ -77,6 +98,13 @@ class SelfAttentionLayer(LayerConf):
     # {"rope_type": "yarn", "factor", "original_max_position_embeddings",
     # "beta_fast", "beta_slow"[, "attention_factor"]}
     rope_scaling: Optional[dict] = None
+    # one sigmoid gate a FEATURE of the heads' output (``Wg: n_in -> H x
+    # head size``, no bias), before ``Wo``; not beside ``head_gate``
+    out_gate: bool = False
+    # block-sparse selection: the sizes of ``ops.sparse_select.Selection``
+    # ({"block", "kernel", "stride", "topk", "init_blocks", "local_blocks",
+    # "dense_len"}); None = every key a position may see
+    sparse: Optional[dict] = None
 
     param_order: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wo", "b",
                                               "q_gain", "k_gain", "Wg")
@@ -116,7 +144,16 @@ class SelfAttentionLayer(LayerConf):
             self.rope_frequencies()        # a rule it does not know: now
         elif self.rotary_dim or self.rope_scaling:
             raise ValueError("rotary_dim and rope_scaling need a rope_theta")
-        ks = jax.random.split(rng, 5 if self.head_gate else 4)
+        if self.head_gate and self.out_gate:
+            raise ValueError("head_gate and out_gate are two forms of one "
+                             "gate: set one")
+        if self.sparse is not None:
+            if not self.causal or self.window is not None:
+                raise ValueError("a block-sparse selection is causal and "
+                                 "takes no sliding window")
+            self.selection                 # sizes it cannot take: now
+        ks = jax.random.split(rng, 5 if self.head_gate or self.out_gate
+                              else 4)
         d = self.n_out
         dq = self.n_heads * self.head_dim  # == d unless head_size is set
         dkv = self.kv_heads * self.head_dim
@@ -134,7 +171,15 @@ class SelfAttentionLayer(LayerConf):
         if self.head_gate:
             params["Wg"] = self._winit(ks[4], (n_in, self.n_heads), n_in,
                                        self.n_heads, dtype)
+        if self.out_gate:
+            params["Wg"] = self._winit(ks[4], (n_in, dq), n_in, dq, dtype)
         return params, {}
+
+    @property
+    def selection(self):
+        """The block-sparse selection's sizes, or None."""
+        from ...ops.sparse_select import Selection
+        return None if self.sparse is None else Selection.of(self.sparse)
 
     def rope_frequencies(self):
         """(inv_freq float32 numpy [rotated / 2], the factor on cos and
@@ -177,15 +222,7 @@ class SelfAttentionLayer(LayerConf):
         if self.rotary_dim is None and self.rope_scaling is None:
             # the default form as it always traced (the frequencies made in
             # the program): the accepted families' programs are held to it
-            half = self.head_dim // 2
-            inv = self.rope_theta ** (-jnp.arange(half, dtype=jnp.float32)
-                                      / half)
-            ang = positions.astype(jnp.float32)[:, :, None, None] * inv
-            cos, sin = jnp.cos(ang), jnp.sin(ang)
-            xf = x.astype(jnp.float32)
-            x1, x2 = xf[..., :half], xf[..., half:]
-            return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                                   axis=-1).astype(x.dtype)
+            return rotate_half(x, positions, self.rope_theta)
         # part of the head, scaled frequencies, or both: rotate-half within
         # the first ``R`` values, the rest passes through
         R = self.rotary_dim or self.head_dim
@@ -227,6 +264,8 @@ class SelfAttentionLayer(LayerConf):
             B, T, _ = out.shape
             out = (out.reshape(B, T, self.n_heads, self.head_dim)
                    * g[..., None]).reshape(B, T, -1)
+        if self.out_gate:
+            out = out * jax.nn.sigmoid(x @ params["Wg"])
         if self.project_out:
             out = out @ params["Wo"]
             if self.bias:
@@ -242,6 +281,12 @@ class SelfAttentionLayer(LayerConf):
         if self.window is not None:
             return self.project_output(
                 params, self._windowed(q, k, v, train, mask), x), state
+        sel = self.selection
+        if sel is not None and x.shape[1] > sel.dense_len \
+                and x.shape[1] > sel.topk * sel.block:
+            # somewhere in the sequence a list is shorter than the context
+            return self.project_output(
+                params, self._selected(q, k, v, train, mask), x), state
         group = self.n_heads // self.kv_heads
         if group > 1:
             # the kernels take equal heads: each key-value head repeated
@@ -278,6 +323,179 @@ class SelfAttentionLayer(LayerConf):
             out = attention(q, k, v, causal=True, key_mask=mask,
                             window=self.window)
         return out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
+
+
+    def _selected(self, q, k, v, train, mask):
+        """Attention under the block-sparse selection: q [B,T,H,Dh], k/v
+        [B,T,Hkv,Dh] -> [B,T,H*Dh]. The choice is made per query position
+        (``chosen_mask``); the sparse flash kernel is forward only, takes no
+        key mask and reads grouped key-value heads in place; a training
+        step, a masked batch and the CPU run the XLA path under the same
+        mask of chosen blocks."""
+        from ...ops.pallas_attention import (flash_attention_sparse,
+                                             fused_attention_applicable)
+        from ...ops.sparse_select import (chosen_mask, compress_keys,
+                                          sparse_attention_xla)
+        sel = self.selection
+        B, T, H, Dh = q.shape
+        scale = float(Dh) ** -0.5
+        pad = -T % sel.block
+        kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else k
+        chosen = chosen_mask(q, compress_keys(kc, sel), sel, scale)
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        if not train and mask is None and T % sel.block == 0 and \
+                fused_attention_applicable(B, H, T, Dh, q.dtype):
+            out = flash_attention_sparse(q, k, v, chosen, scale=scale)
+        else:
+            out = sparse_attention_xla(q, k, v, chosen, sel, scale,
+                                       key_mask=mask)
+        return out.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
+
+
+@register
+@dataclass
+class LightningAttentionLayer(LayerConf):
+    """Lightning (decayed linear) attention, [B,T,F] -> [B,T,n_out]: a
+    recurrent sequence mixer whose state is a MATRIX a head.
+
+        q = rope(rms_head(x Wq)), k = rope(rms_head(x Wk)), v = x Wv
+                                      each [H, Dh]; the norm before the
+                                      rotation, the rotation over the whole
+                                      head (rotate-half)
+        S_t = lam_h S_{t-1} + k_t^T v_t      [Dh, Dh] float32, S_{-1} = 0
+        o_t = q_t S_t / sqrt(Dh)
+        y = (rms(o; o_gain) * sigmoid(x Wz)) Wo      the norm over all H x Dh
+
+    ``lam_h = exp(-2^(-8 (h + 1) / H))``, the same in every layer. A
+    sequence runs in chunks (``ops/pallas_linear_attention.py``: the Pallas
+    kernel where it applies and the call is not training, else the XLA
+    form), a decode step is the recurrence. The state is float32 whatever
+    the model's dtype: ``zero_state`` says so, and a serving cache keeps it
+    in a pool of its own kind. ``state_at`` gives the state at a row of
+    each sequence's own (a padded prompt's true end)."""
+    n_in: Optional[int] = None
+    n_out: int = 0
+    n_heads: int = 4
+    head_size: Optional[int] = None
+    norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+
+    param_order: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wz", "Wo",
+                                              "q_gain", "k_gain", "o_gain")
+    weight_param_names: ClassVar[Tuple[str, ...]] = ("Wq", "Wk", "Wv", "Wz",
+                                                     "Wo")
+    expected_input: ClassVar[str] = "rnn"
+
+    def output_type(self, itype):
+        t = itype.timestep_length if isinstance(itype, InputTypeRecurrent) else -1
+        return InputTypeRecurrent(self.n_out, t)
+
+    @property
+    def head_dim(self) -> int:
+        return self.head_size or self.n_out // self.n_heads
+
+    @property
+    def scale(self) -> float:
+        return float(self.head_dim) ** -0.5
+
+    def slopes(self):
+        from ...ops.pallas_linear_attention import slopes
+        return slopes(self.n_heads)
+
+    def init(self, rng, itype, dtype):
+        n_in = self.n_in or resolve_ff_size(itype)
+        self.n_in = n_in
+        self.n_out = d = self.n_out or n_in
+        if self.head_dim % 2:
+            raise ValueError("the head size must be even (it rotates in "
+                             "halves)")
+        dq = self.n_heads * self.head_dim
+        ks = jax.random.split(rng, 5)
+        params = {n: self._winit(k, (n_in, dq), n_in, dq, dtype)
+                  for n, k in zip(("Wq", "Wk", "Wv", "Wz"), ks)}
+        params["Wo"] = self._winit(ks[4], (dq, d), dq, d, dtype)
+        params["q_gain"] = jnp.ones((self.head_dim,), dtype)
+        params["k_gain"] = jnp.ones((self.head_dim,), dtype)
+        params["o_gain"] = jnp.ones((dq,), dtype)
+        return params, {}
+
+    def zero_state(self, batch: int, dtype=None):
+        """[batch, H, Dh, Dh], float32 whatever ``dtype``."""
+        return jnp.zeros((batch, self.n_heads, self.head_dim, self.head_dim),
+                         jnp.float32)
+
+    def project_qkv(self, params, x, positions=None):
+        """x [B,T,F] -> q, k, v [B,T,H,Dh], q and k normed then rotated."""
+        from .norm import rms_norm
+        B, T, _ = x.shape
+        q, k, v = ((x @ params[n]).reshape(B, T, self.n_heads, self.head_dim)
+                   for n in ("Wq", "Wk", "Wv"))
+        q = rms_norm(q, params["q_gain"], self.norm_eps)
+        k = rms_norm(k, params["k_gain"], self.norm_eps)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        return (rotate_half(q, positions, self.rope_theta),
+                rotate_half(k, positions, self.rope_theta), v)
+
+    def project_output(self, params, o, x):
+        """The heads' output [B,T,H*Dh] normed, gated by the layer's input
+        and through Wo."""
+        from .norm import rms_norm
+        o = rms_norm(o, params["o_gain"], self.norm_eps)
+        return self.act((o * jax.nn.sigmoid(x @ params["Wz"])) @ params["Wo"])
+
+    def state_at(self, params, u, lengths):
+        """The state after ``lengths`` [B] rows of u [B,T,d] (the layer's
+        input): one contraction over T a head, the rows from a sequence's
+        true end on at weight 0."""
+        from ...ops.pallas_linear_attention import lightning_state_at
+        _, k, v = self.project_qkv(params, u)
+        return lightning_state_at(k, v, self.slopes(), lengths)
+
+    def apply_with_final_state(self, params, state, x, *, train=False,
+                               rng=None, mask=None, initial_state=None):
+        """(out [B,T,d], the state after the last row [B,H,Dh,Dh]
+        float32)."""
+        from ...ops.pallas_linear_attention import lightning_attention_xla
+        x = maybe_dropout(x, self.dropout, rng, train)
+        B, T, _ = x.shape
+        q, k, v = (a.transpose(0, 2, 1, 3)
+                   for a in self.project_qkv(params, x))
+        o, final = lightning_attention_xla(
+            q, k, v, self.slopes(), scale=self.scale,
+            initial_state=initial_state)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        return self.project_output(params, o, x), final
+
+    def apply(self, params, state, x, *, train=False, rng=None):
+        from ...ops.pallas_linear_attention import (
+            lightning_attention_fwd, lightning_kernel_applicable)
+        B, T, _ = x.shape
+        if train or not lightning_kernel_applicable(
+                T, self.head_dim, self.head_dim, x.dtype):
+            out, _ = self.apply_with_final_state(params, state, x,
+                                                 train=train, rng=rng)
+            return out, state
+        q, k, v = (a.transpose(0, 2, 1, 3)
+                   for a in self.project_qkv(params, x))
+        o = lightning_attention_fwd(q, k, v, self.slopes(), scale=self.scale)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+        return self.project_output(params, o, x), state
+
+    def decode_step(self, params, x, positions, pool, index: int, active):
+        """One row a slot against the states a serving cache keeps: x
+        [S,1,d] at ``positions`` [S,1]; ``pool`` [layers, slots + 1, H, Dh,
+        Dh] float32, this layer's states at ``pool[index]``; ``active`` [S]
+        (idle slots keep their state). Returns (out [S,1,d], the pool:
+        updated in place on the TPU, so rebind it)."""
+        from ...ops import pallas_linear_attention as la
+        S = x.shape[0]
+        q, k, v = (a[:, 0] for a in self.project_qkv(params, x, positions))
+        step = la.lightning_decode_xla if la._interpret() \
+            else la.lightning_decode
+        o, pool = step(q, k, v, pool, index, active, self.slopes(),
+                       scale=self.scale)
+        return self.project_output(params, o.reshape(S, 1, -1), x), pool
 
 
 @register

@@ -171,7 +171,7 @@ def _resident(T: int, BQ: int, BK: int, causal: bool) -> tuple:
 SKIP, FULL, DIAG = "skip", "full", "diag"
 
 
-def _tile_rule(row0, rows, col0, cols, window=None):
+def _tile_rule(row0, rows, col0, cols, window=None, listed=None):
     """THE rule of which score tiles causal attention visits, for the tile
     of query rows [row0, row0 + rows) and key columns [col0, col0 + cols):
     ``(skip, full)``. skip: every column lies after every row, the tile
@@ -185,33 +185,56 @@ def _tile_rule(row0, rows, col0, cols, window=None):
     column lies at or before ``row0 - window`` (not even the tile's first
     row sees its last column), full only where the tile's last row still
     sees its first column; a tile either edge crosses, or both, is masked.
+    A block-sparse SELECTION (every row brings its own list of key
+    blocks: ``ops/sparse_select.py``) is the case position alone does not
+    decide: ``listed`` = (some row of the tile lists some block among its
+    columns, every row lists all of them), bools or traced predicates. A
+    tile nobody lists is skipped; one not everybody lists whole is masked
+    (by the rows' lists, under the name DIAG like every masked tile).
     A segment mask would change this function and ``_scores``' mask,
     nothing else."""
     skip, full = col0 > row0 + rows - 1, col0 + cols - 1 <= row0
-    if window is None:
-        return skip, full
-    return (skip | (col0 + cols - 1 <= row0 - window),
-            full & (col0 > row0 + rows - 1 - window))
+    if window is not None:
+        skip = skip | (col0 + cols - 1 <= row0 - window)
+        full = full & (col0 > row0 + rows - 1 - window)
+    if listed is not None:
+        some, every = listed
+        none = (not some) if isinstance(some, bool) else jnp.logical_not(some)
+        skip, full = skip | none, full & every
+    return skip, full
 
 
 def tile_kind(row0: int, rows: int, col0: int, cols: int,
-              causal: bool = True, window: Optional[int] = None) -> str:
+              causal: bool = True, window: Optional[int] = None,
+              listed=None) -> str:
     """SKIP, FULL or DIAG for one tile at static offsets (DIAG: masked, by
-    the diagonal, by a window's trailing edge, or by both)."""
+    the diagonal, by a window's trailing edge, by the rows' block lists,
+    or by several)."""
     if not causal:
         return FULL
-    skip, full = _tile_rule(row0, rows, col0, cols, window)
+    skip, full = _tile_rule(row0, rows, col0, cols, window, listed)
     return SKIP if skip else FULL if full else DIAG
 
 
-def tile_schedule(T: int, causal: bool, window: Optional[int] = None) -> tuple:
+def tile_schedule(T: int, causal: bool, window: Optional[int] = None,
+                  chosen=None) -> tuple:
     """(visited, masked, total) score tiles a head's [T,T] square costs
     with the tiles ``_blocks`` picks: how many the kernels compute, how
     many of those pay a mask (the causal one, a window's, or both), how
     many the square has. A count from the same rule the kernels run, so it
-    can be tested on a CPU."""
+    can be tested on a CPU. ``chosen`` [T / block, T] (numpy bools: key
+    blocks down, query positions across) is one head's block-sparse
+    selection: a tile counts where some query of its rows lists some block
+    of its columns."""
     BQ, BK = _blocks(T, causal)
-    kinds = [tile_kind(r, BQ, c, BK, causal, window)
+
+    def listed(r, c):
+        if chosen is None:
+            return None
+        block = T // chosen.shape[0]
+        tile = np.asarray(chosen)[c // block:-(-(c + BK) // block), r:r + BQ]
+        return bool(tile.any()), bool(tile.all())
+    kinds = [tile_kind(r, BQ, c, BK, causal, window, listed(r, c))
              for r in range(0, T, BQ) for c in range(0, T, BK)]
     return (sum(k != SKIP for k in kinds), sum(k == DIAG for k in kinds),
             len(kinds))
@@ -564,6 +587,133 @@ def _fwd_window_call(q3, k3, v3, *, window, scale, tiles, group, interpret):
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q3, k3, v3)
+
+
+# ---------------------------------------- block-sparse (selected) forward
+SPARSE_FWD_NAME = "flash_attention_sparse_fwd"
+
+
+def _softmax_block_sparse(diagonal, scale, BQ, BK, block, strip0, some_ref,
+                          q_ref, k_ref, v_ref, ch_ref, acc, m, l):
+    """``_softmax_block`` under a block-sparse selection: ``ch_ref`` [1,
+    RK / block, RQ] holds, for every key block of the resident columns
+    (down) and every query of the resident rows (across, as the scores lie),
+    whether the query's list names the block. A strip (one key tile under
+    all the queries that reach it) nobody lists is not computed:
+    ``some_ref[strip0 + c0 / BK]`` is the rule's ``listed`` for it, made
+    with the lists. A query meets key 0 in its first strip (the first block
+    is on every list) and its own block in its last, so no row of the carry
+    is ever left at the mask's value."""
+    RQ, RK = q_ref.shape[1], k_ref.shape[1]
+    q = _scaled(q_ref[0], scale)
+    vT = v_ref[0].T
+    for c0, r_lo, masked in _visits(diagonal, RQ, RK, BQ, BK):
+        @pl.when(some_ref[strip0 + c0 // BK] > 0)
+        def _strip(c0=c0, r_lo=r_lo, masked=masked):
+            at = slice(r_lo, RQ)
+            s = _scores(k_ref[0, c0:c0 + BK, :], q[at], r_lo, c0, masked,
+                        None)
+            s = jnp.concatenate([
+                jnp.where(ch_ref[0, c0 // block + b:c0 // block + b + 1, at]
+                          > 0, s[b * block:(b + 1) * block], NEG)
+                for b in range(BK // block)], axis=0)
+            _fold_scores(s, vT[:, c0:c0 + BK], at, acc, m, l)
+
+
+def _fwd_sparse_body(scale, BQ, BK, block, group, some_ref, q_ref, k_ref,
+                     v_ref, ch_ref, o_ref, acc, m, l):
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nj = pl.num_programs(2)
+    RK = k_ref.shape[1]
+    strips = RK // BK
+    # strips of this head's key-value group, this row block, this column
+    # block: [Hkv, T / RQ, T / BK] flattened
+    strip0 = ((b // group) * pl.num_programs(1) + i) * (nj * strips) \
+        + j * strips
+
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m[:] = jnp.full_like(m, NEG)
+        l[:] = jnp.zeros_like(l)
+
+    _for_block(True, i, j, q_ref.shape[1], RK,
+               lambda diagonal: _softmax_block_sparse(
+                   diagonal, scale, BQ, BK, block, strip0, some_ref, q_ref,
+                   k_ref, v_ref, ch_ref, acc, m, l))
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        o_ref[0] = (acc[:] / l[:]).T.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "tiles", "group",
+                                             "interpret"))
+def _fwd_sparse_call(q3, k3, v3, chosen, *, scale, tiles, group, interpret):
+    """Causal attention in which every query reads the key blocks its own
+    list names, forward only: q3 [BH, T, D], k3 / v3 [BH / group, T, D]
+    (read in place through the index map), chosen [BH / group, T / block,
+    T] float32 (1: the query, across, lists the block, down). The walk is
+    the causal one; a strip of score tiles is computed where some query
+    lists some of its blocks (``_tile_rule``'s ``listed``, reduced from
+    ``chosen`` here and prefetched as scalars) and masked by the lists."""
+    BH, T, D = q3.shape
+    BQ, BK, RQ, RK = tiles
+    assert RQ == RK, "causal resident blocks are square"
+    nb = chosen.shape[1]
+    block = T // nb
+    if BK % block or RK % block:
+        raise ValueError(f"key tiles of {BK} are not whole blocks of {block}")
+    # [Hkv, T / RQ, T / BK]: some query of the row block lists some block
+    # of the key tile
+    some = chosen.reshape(chosen.shape[0], T // BK, BK // block, T // RQ,
+                          RQ).max(axis=(2, 4)).transpose(0, 2, 1)
+    some = (some > 0).astype(jnp.int32).reshape(-1)
+
+    def col(i, j):
+        return jnp.minimum(j, _last_col_block(i, RQ, RK))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(BH, T // RQ, T // RK),
+        in_specs=[
+            pl.BlockSpec((1, RQ, D), lambda b, i, j, *_: (b, i, 0)),
+            pl.BlockSpec((1, RK, D),
+                         lambda b, i, j, *_: (b // group, col(i, j), 0)),
+            pl.BlockSpec((1, RK, D),
+                         lambda b, i, j, *_: (b // group, col(i, j), 0)),
+            pl.BlockSpec((1, RK // block, RQ),
+                         lambda b, i, j, *_: (b // group, col(i, j), i))],
+        out_specs=pl.BlockSpec((1, RQ, D), lambda b, i, j, *_: (b, i, 0)),
+        scratch_shapes=[pltpu.VMEM((D, RQ), f32), pltpu.VMEM((1, RQ), f32),
+                        pltpu.VMEM((1, RQ), f32)])
+    with jax.named_scope(SCOPE):
+        return pl.pallas_call(
+            functools.partial(_fwd_sparse_body, scale, BQ, BK, block, group),
+            name=SPARSE_FWD_NAME, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), q3.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(some, q3, k3, v3, chosen)
+
+
+def flash_attention_sparse(q, k, v, chosen, *, scale: Optional[float] = None):
+    """Causal attention over the key blocks each query's list names
+    (``flash_attention_sparse_fwd``): q [B,H,T,D], k / v [B,Hkv,T,D],
+    ``chosen`` [B,Hkv,T / block,T] (``ops.sparse_select.chosen_mask``: one
+    list a query position and key-value group, shared by the group's query
+    heads) -> [B,H,T,D]. Forward only; one device."""
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
+    if _device_split(B, H) is not None:
+        raise ValueError("block-sparse attention runs on one device")
+    o = _fwd_sparse_call(
+        q.reshape(B * H, T, D), k.reshape(B * Hkv, T, D),
+        v.reshape(B * Hkv, T, D),
+        chosen.reshape(B * Hkv, chosen.shape[2], T).astype(f32), scale=scale,
+        tiles=_tiles(T, True), group=H // Hkv, interpret=_interpret())
+    return o.reshape(B, H, T, D)
 
 
 # ------------------------------------------------------------------ dq pass

@@ -68,6 +68,7 @@ SCOPE = "paged_attention"
 KERNEL_NAME = "paged_attention_decode"
 LATENT_KERNEL_NAME = "paged_attention_latent_decode"
 WINDOW_KERNEL_NAME = "paged_attention_window_decode"
+SPARSE_KERNEL_NAME = "paged_attention_sparse_decode"
 
 # keys folded into the online softmax per step: 16 pages of 16, the DMAs of
 # one step in flight while the previous step's pages are computed on. On
@@ -306,6 +307,182 @@ def _one_device_latent(layer, q, pool, tables, lens, *, scale, value_lanes,
         )(layer.reshape(1), tables.astype(jnp.int32), lens.astype(jnp.int32),
           qr, pool)
     return o.reshape(S, W, Hq, value_lanes).transpose(0, 2, 1, 3)
+
+
+def _sparse_body(Hkv, Dh, blk, G, L, scale, layer_ref, pages_ref, nkeys_ref,
+                 q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_s, l_s,
+                 acc_s):
+    """Grid step (slot, key-value group): the group's query heads against
+    the pages its list names, ``pages_ref[(s * Hkv + g) * L + j]`` the j-th
+    of them, ``nkeys_ref[s * Hkv + g]`` the keys they hold up to the slot's
+    own position (whole pages but the last). Of a page's rows only the
+    group's own lanes are fetched: a row of the pool holds every key-value
+    head side by side."""
+    s, g = pl.program_id(0), pl.program_id(1)
+    sg = s * Hkv + g
+    layer = layer_ref[0]
+    n = nkeys_ref[sg]                     # 0: an idle slot
+    npages = (n + (blk - 1)) // blk
+    ngroups = (npages + (G - 1)) // G
+    T = G * blk
+    lanes = pl.ds(pl.multiple_of(g * Dh, 128), Dh)
+
+    def each_copy(group, slot, act):
+        for p in range(G):
+            page = group * G + p
+
+            @pl.when(page < npages)
+            def _():
+                bid = pages_ref[sg * L + page]
+                for c, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf))):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[layer, bid, :, lanes],
+                        buf.at[slot, pl.ds(p * blk, blk)],
+                        sem.at[c, slot]), act)()
+
+    m_s[:] = jnp.full_like(m_s, NEG)
+    l_s[:] = jnp.zeros_like(l_s)
+    acc_s[:] = jnp.zeros_like(acc_s)
+
+    @pl.when(ngroups > 0)
+    def _first():
+        each_copy(0, 0, "start")
+
+    def step(gi, carry):
+        slot = gi % 2
+
+        @pl.when(gi + 1 < ngroups)
+        def _next():
+            each_copy(gi + 1, 1 - slot, "start")
+
+        each_copy(gi, slot, "wait")
+        k, v = kbuf[slot], vbuf[slot]
+        sc = jax.lax.dot_general(q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=f32) * scale
+        kpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(kpos < n, sc, NEG)
+        m_prev = m_s[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[:] = jnp.broadcast_to(
+            l_s[:, :1] * corr + p.sum(1, keepdims=True), l_s.shape)
+        # rows never fetched, or the unwritten tail of the last page:
+        # whatever they hold, 0 * it must stay 0
+        vpos = gi * T + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(vpos < n, v, jnp.zeros_like(v))
+        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        return carry
+
+    jax.lax.fori_loop(0, ngroups, step, 0)
+    l = l_s[:, :1]
+    o_ref[0, 0] = (acc_s[:] * jnp.where(l > 0, 1.0 / l, 0.0)).astype(
+        o_ref.dtype)
+
+
+def _listed_keys(counts, lens, blk):
+    """Keys a (slot, group)'s list holds: whole pages but the last listed,
+    which is the slot's own, its rows up to the slot's position; 0 for an
+    idle slot. counts [S,Hkv], lens [S] -> [S,Hkv]."""
+    return jnp.where(lens[:, None] > 0,
+                     (counts - 1) * blk + (lens[:, None] - 1) % blk + 1, 0)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _one_device_sparse(layer, q, k_pool, v_pool, pages, counts, lens, *,
+                       interpret):
+    S, Hq, _, Dh = q.shape
+    blk = k_pool.shape[2]
+    Hkv = k_pool.shape[3] // Dh
+    Gq = Hq // Hkv
+    L = pages.shape[2]
+    G = max(1, min(L, _KEYS_PER_STEP // blk))
+    T = G * blk
+    Rp = -(-Gq // 16) * 16
+    qg = jnp.pad(q.reshape(S, Hkv, Gq, Dh),
+                 ((0, 0), (0, 0), (0, Rp - Gq), (0, 0)))
+    nkeys = _listed_keys(counts, lens, blk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,            # layer, pages, keys a (slot, group)
+        grid=(S, Hkv),
+        in_specs=[pl.BlockSpec((1, 1, Rp, Dh), lambda s, g, *_: (s, g, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, Rp, Dh), lambda s, g, *_: (s, g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, T, Dh), k_pool.dtype),
+                        pltpu.VMEM((2, T, Dh), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, 128), f32),
+                        pltpu.VMEM((Rp, Dh), f32)])
+    with jax.named_scope(SCOPE):
+        o = pl.pallas_call(
+            functools.partial(_sparse_body, Hkv, Dh, blk, G, L,
+                              1.0 / float(np.sqrt(Dh))),
+            name=SPARSE_KERNEL_NAME, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, Hkv, Rp, Dh), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(layer.reshape(1), pages.astype(jnp.int32).reshape(-1),
+          nkeys.astype(jnp.int32).reshape(-1), qg, k_pool, v_pool)
+    return o[:, :, :Gq].reshape(S, Hq, 1, Dh)
+
+
+def paged_attention_sparse_decode(q, k_pool, v_pool, layer: int, pages,
+                                  counts, lens):
+    """Softmax attention of ONE query row a slot over the pages its
+    SELECTION names (``ops/sparse_select.py``), a list a slot and
+    key-value group:
+
+    q       [S, Hq, 1, Dh]; query head i reads key-value head
+            ``i // (Hq // Hkv)`` and its group's list
+    k_pool, v_pool  [n_layers, num_blocks, block_len, Hkv * Dh]; a page is
+            one block of the selection
+    pages   [S, Hkv, L] int32: the POOL's pages of the chosen blocks, in
+            the sequence's order, the slot's own (last, partly filled)
+            page last
+    counts  [S, Hkv] int32: how many of them are listed
+    lens    [S] int32: ``pos + 1``; 0 marks an idle slot
+
+    Every listed page but the last is whole, so a list reads as a short
+    sequence of its own: the kernel is the paged walk over ``pages`` in
+    place of the slot's table, fetching the group's lanes of each page.
+    Returns [S, Hq, 1, Dh]. One device."""
+    if _device_split(q.shape[0], 1) is not None:
+        raise ValueError("a selected decode reads its pools on one device")
+    return _one_device_sparse(jnp.asarray(layer, jnp.int32), q, k_pool,
+                              v_pool, pages, counts, lens,
+                              interpret=_interpret())
+
+
+def paged_attention_sparse_reference(q, k_pool, v_pool, layer: int, pages,
+                                     counts, lens):
+    """The same the plain way: gather the listed pages and attend under a
+    mask. The kernel's parity pin, and the path off the TPU."""
+    S, Hq, _, Dh = q.shape
+    blk = k_pool.shape[2]
+    Hkv = k_pool.shape[3] // Dh
+    L = pages.shape[2]
+
+    def listed(pool):                    # [S, Hkv, L * blk, Dh]
+        rows = pool[layer][pages].reshape(S, Hkv, L * blk, Hkv, Dh)
+        return jnp.stack([rows[:, g, :, g] for g in range(Hkv)], axis=1)
+
+    nkeys = _listed_keys(counts, lens, blk)
+    qg = q.reshape(S, Hkv, Hq // Hkv, Dh)
+    s = jnp.einsum("sghd,sgkd->sghk", qg, listed(k_pool),
+                   preferred_element_type=f32) / np.sqrt(Dh)
+    seen = jnp.arange(L * blk)[None, None, :] < nkeys[:, :, None]
+    p = jax.nn.softmax(jnp.where(seen[:, :, None], s, NEG), axis=-1)
+    p = jnp.where(seen[:, :, None], p, 0.0)
+    o = jnp.einsum("sghk,sgkd->sghd", p.astype(q.dtype), listed(v_pool),
+                   preferred_element_type=f32)
+    return o.reshape(S, Hq, 1, Dh).astype(q.dtype)
 
 
 def paged_attention_decode(q, k_pool, v_pool, layer: int, tables, lens, *,

@@ -201,6 +201,19 @@ class GenerationEngine:
             "cache_kind": getattr(rt.active_ps.spec, "cache_kind", None),
             "cache_bytes_per_token": rt.active_ps.kv_bytes_per_token(),
             "conv_state_bytes": rt.active_ps.recurrent_state_bytes(),
+            # recurrent mixers of every kind (a short convolution's rows, a
+            # lightning-attention layer's float32 matrix a head): what one
+            # slot's states hold; ``conv_state_bytes`` is all slots'
+            "state_bytes_per_slot": rt.active_ps.state_bytes_per_slot(),
+            "linear_layers": len(rt.active_ps.in_place_names),
+            # block-sparse layers (full-context pages, read by selection):
+            # how many, what their compressed keys cost a token, and the
+            # selection's sizes (None: the model selects nothing)
+            "sparse_layers": len(getattr(rt.active_ps.spec, "sparse_names",
+                                         [])),
+            "index_bytes_per_token": rt.active_ps.index_bytes_per_token(),
+            "selection": rt.active_ps.spec.selection._asdict()
+            if rt.active_ps.sparse else None,
             # sliding-window layers: their width (None: the model has
             # none), how many there are, and what one slot's rings hold
             # (K and V, all of them); ``cache_bytes_per_token`` counts the

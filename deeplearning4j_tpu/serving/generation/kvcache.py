@@ -37,6 +37,18 @@ of the window's first key. The full-context layers keep the pools, the
 tables and the allocator below as they are; the rings ride behind them in
 the same cache pytree.
 
+A full-context layer may READ a block-sparse SELECTION of its pages
+(``ops/sparse_select.py``; a page is one block of the selection). Its keys
+and values are paged like any other's; beside them each decode slot keeps
+the layer's COMPRESSED keys, one row of ``H * Dh`` every ``stride``
+positions (``make_compressed``: ``[selecting layers, slots + 1, capacity /
+stride, H * Dh]``, the last slot the trash row; a thirty-second of the
+keys' bytes at the published sizes). A prefill fills a slot's rows from the
+prompt's keys; a decode step that completes a window of ``kernel`` keys
+reads them back from the pages and appends their mean; every step scores
+its query against the slot's rows, chooses, and reads the chosen pages
+alone (``PagedWindowStore._attend_selected``).
+
 Block 0 is the reserved TRASH block: inactive decode slots and the unused
 tail of a prefill's table all point at it, so the fixed-shape scatter always
 has a legal destination and garbage lands where nothing ever reads it
@@ -59,6 +71,7 @@ import jax
 import jax.numpy as jnp
 
 from ...models.decode import window_attention
+from ...ops import pallas_paged_attention as _paged
 from ...ops.pallas_paged_attention import paged_attention_decode
 from ..errors import BlockPoolExhaustedError
 
@@ -257,6 +270,28 @@ def ring_prefill_fill(ring, layer_kv, lengths, slots):
     return ring
 
 
+def make_compressed(n_layers: int, slots: int, capacity: int, stride: int,
+                    n_heads: int, head_dim: int, dtype):
+    """Zero-filled compressed-key rows of a model's block-sparse layers:
+    ``[n_layers, slots + 1, capacity / stride, n_heads * head_dim]``; row
+    ``slots`` of the slot axis is the trash row idle slots and a prefill's
+    padding rows write to."""
+    return jnp.zeros((n_layers, slots + 1, capacity // stride,
+                      n_heads * head_dim), dtype)
+
+
+def compressed_prefill_fill(comp, layer_rows, slots):
+    """Leave each prompt's compressed keys in its slot's rows after a
+    prefill: ``layer_rows`` a list of [P, L / stride, H * Dh] a selecting
+    layer (``GraphDecodeSpec.compressed_rows``), ``slots`` [P] (padding
+    rows carry the trash slot). Entries whose window reaches past a
+    prompt's true length hold padding: no position sees one before the
+    decode step that completes its window has written it anew."""
+    for i, rows in enumerate(layer_rows):
+        comp = comp.at[i, slots, :rows.shape[1]].set(rows.astype(comp.dtype))
+    return comp
+
+
 def pool_bytes(pool) -> int:
     """Total device bytes of one pool entry (plain array or QuantizedPool)."""
     return sum(leaf.size * leaf.dtype.itemsize
@@ -391,16 +426,20 @@ class PagedWindowStore:
     nothing and get zeros."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
-                 window: int, rec=None, rings=None):
+                 window: int, rec=None, rings=None, comp=None):
         self.k_pool = k_pool
         self.v_pool = v_pool              # None: a latent cache, one pool
         # (k_ring, v_ring) of the model's sliding-window layers, or None
         self.rings = rings
+        # compressed keys of the model's block-sparse layers, [layers,
+        # slots + 1, capacity / stride, H*Dh], or None
+        self.comp = comp
         self._pos, self._blk = pos, block_len
-        # per-slot state of the model's recurrent mixers, [layers,
-        # slots + 1, ...] (row ``slots`` is the prefill's trash row), or
-        # None for a model that keeps K/V alone
-        self.rec = rec
+        # per-slot state of the model's recurrent mixers, one pool a KIND
+        # of state: a tuple of [layers of the kind, slots + 1, ...] (row
+        # ``slots`` is the prefill's trash row), or None for a model that
+        # keeps K/V alone
+        self.rec = None if rec is None else tuple(rec)
         self._active = active
         self.tables = tables              # [S, max_blocks] int32
         mb = tables.shape[1]
@@ -441,7 +480,46 @@ class PagedWindowStore:
             ring_tables(S, rp, self.tables.shape[1]), self._lens,
             starts=starts)
 
-    def attend(self, i: int, q, k_win, v_win, window=None, **latent):
+    def _attend_selected(self, i: int, q, sel, si: int):
+        """A block-sparse layer's step, its token already in the pages:
+        append the compressed key of a window this position completes,
+        score the query against the slot's compressed keys, choose, and
+        attend to the chosen pages alone."""
+        from ...ops import sparse_select as ss
+        S, mb = self.tables.shape
+        pos, blk = self._pos, self._blk
+        if blk != sel.block:
+            raise ValueError(f"a page of {blk} rows is not the selection's "
+                             f"block of {sel.block}")
+        HD = self.k_pool.shape[3]
+        # the window that ends here, if one does: entry j of the slot's rows
+        back = pos - (sel.kernel - 1)
+        done = self._active & (back >= 0) & (back % sel.stride == 0)
+        w_pos = jnp.maximum(back, 0)[:, None] + jnp.arange(sel.kernel)[None, :]
+        bid = jnp.take_along_axis(self.tables,
+                                  jnp.clip(w_pos // blk, 0, mb - 1), axis=1)
+        rows = self.k_pool[i, bid, w_pos % blk]                # [S,kernel,HD]
+        mean = (rows.astype(jnp.float32).sum(axis=1) / sel.kernel).astype(
+            self.comp.dtype)
+        slot = jnp.where(done, jnp.arange(S), S)       # others: trash row
+        self.comp = self.comp.at[si, slot, jnp.maximum(back, 0) // sel.stride
+                                 ].set(mean)
+        Dh = q.shape[-1]
+        c = self.comp[si, :S].reshape(S, -1, HD // Dh, Dh)
+        t = pos[:, None]
+        R = ss.block_scores(q.transpose(0, 2, 1, 3), c, t, sel,
+                            float(Dh) ** -0.5)
+        blocks, counts = ss.chosen_lists(R, t, sel)            # [S,1,Hkv,L]
+        pages = jnp.take_along_axis(
+            self.tables[:, None, :], jnp.minimum(blocks[:, 0], mb - 1),
+            axis=2)
+        attend = _paged.paged_attention_sparse_reference \
+            if _paged._interpret() else _paged.paged_attention_sparse_decode
+        return attend(q, self.k_pool, self.v_pool, i, pages, counts[:, 0],
+                      self._lens)
+
+    def attend(self, i: int, q, k_win, v_win, window=None, select=None,
+               select_index=None, **latent):
         """q [S,H,W,Dh]; k_win/v_win [S,W,H,Dh] for the window. Returns
         the attention output [S,H,W,Dh]. Over a latent cache: q
         [S,H,W,row] absorbed, k_win [S,W,1,row] the tokens' cache rows,
@@ -459,6 +537,12 @@ class PagedWindowStore:
             return paged_attention_decode(q, self.k_pool, None, i,
                                           self.tables, self._lens, **latent)
         self.v_pool = _pool_write(self.v_pool, i, self._bid, self._off, v_win)
+        if select is not None:
+            if k_win.shape[1] != 1 or self.comp is None:
+                raise ValueError("a block-sparse layer takes one token a "
+                                 "slot and a store that carries its "
+                                 "compressed keys")
+            return self._attend_selected(i, q, select, select_index)
         if isinstance(self.k_pool, QuantizedPool):
             group = q.shape[1] // k_win.shape[2]
             K = _pool_gather(self.k_pool, i, self.tables, k_win.dtype)
@@ -468,17 +552,33 @@ class PagedWindowStore:
         return paged_attention_decode(q, self.k_pool, self.v_pool, i,
                                       self.tables, self._lens)
 
-    def state(self, j: int):
-        """Recurrent mixer ``j``'s state for the step's slots [S, ...]."""
-        return self.rec[j, :self.tables.shape[0]]
+    def state(self, j):
+        """Recurrent mixer ``j`` = (kind, place in its kind): its state
+        for the step's slots [S, ...]."""
+        g, n = j
+        return self.rec[g][n, :self.tables.shape[0]]
 
-    def set_state(self, j: int, new) -> None:
+    def set_state(self, j, new) -> None:
         """Leave mixer ``j``'s state after the step; idle slots keep
         theirs."""
+        g, n = j
         S = self.tables.shape[0]
+        rec = self.rec[g]
         keep = self._active.reshape((S,) + (1,) * (new.ndim - 1))
-        self.rec = self.rec.at[j, :S].set(
-            jnp.where(keep, new.astype(self.rec.dtype), self.rec[j, :S]))
+        self.set_state_pool(g, rec.at[n, :S].set(
+            jnp.where(keep, new.astype(rec.dtype), rec[n, :S])))
+
+    @property
+    def active(self):
+        """[S] bool: the slots that are real this step."""
+        return self._active
+
+    def state_pool(self, g: int):
+        """The whole pool of recurrent states of kind ``g``."""
+        return self.rec[g]
+
+    def set_state_pool(self, g: int, pool) -> None:
+        self.rec = self.rec[:g] + (pool,) + self.rec[g + 1:]
 
     @property
     def pools(self):
@@ -489,7 +589,8 @@ class PagedWindowStore:
     def cache(self):
         """The cache pytree a program hands back: the pools, and the
         recurrent state behind them where the model has one."""
-        return self.pools + (() if self.rec is None else (self.rec,)) \
+        return self.pools + (() if self.rec is None else self.rec) \
+            + (() if self.comp is None else (self.comp,)) \
             + (() if self.rings is None else tuple(self.rings))
 
 
@@ -498,9 +599,9 @@ class PagedStore(PagedWindowStore):
     a window of one token a slot."""
 
     def __init__(self, k_pool, v_pool, tables, pos, active, block_len: int,
-                 rec=None, rings=None):
+                 rec=None, rings=None, comp=None):
         super().__init__(k_pool, v_pool, tables, pos, active, block_len, 1,
-                         rec, rings)
+                         rec, rings, comp)
 
     def attend(self, i: int, q, k_tok, v_tok, **kw):
         """q [S,H,1,Dh]; k_tok/v_tok [S,H,Dh] (v_tok None and the latent

@@ -53,6 +53,7 @@ from jax.interpreters.partial_eval import dce_jaxpr
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ...models.decode import (GraphDecodeSpec, LatentDecodeUnsupportedError,
+                              SparseDecodeUnsupportedError,
                               LSTMDecodeSpec, StatefulDecodeUnsupportedError,
                               WindowDecodeUnsupportedError)
 from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
@@ -60,7 +61,8 @@ from ...parallel.tensor_parallel import (MODEL_AXIS, build_param_specs,
                                          shard_params)
 from ...telemetry import span
 from ..programs import _arch_key, _tree_signature
-from .kvcache import (PagedStore, QuantSimStore, cow_copy, make_pools,
+from .kvcache import (PagedStore, QuantSimStore, compressed_prefill_fill,
+                      cow_copy, make_compressed, make_pools,
                       make_rings, prefill_scatter, ring_pages,
                       ring_prefill_fill)
 from .sampling import sample_tokens
@@ -380,6 +382,24 @@ class GenerationProgramSet:
         # re-reads a sequence's whole context refuses the model by name)
         self.windowed = self.adapter == "paged" \
             and self.spec.window is not None
+        # full-context layers that read a block-sparse selection: their
+        # compressed keys live a slot, behind the recurrent states (what
+        # shares or re-reads whole contexts refuses the model by name)
+        self.sparse = self.adapter == "paged" \
+            and self.spec.selection is not None
+        if self.sparse and config.block_len != self.spec.selection.block:
+            raise ValueError(
+                f"block_len={config.block_len}: a model with block-sparse "
+                f"layers ({self.spec.sparse_names}) is served in pages of "
+                f"its selection's block, {self.spec.selection.block}")
+        # pools of recurrent state in the cache pytree: one a KIND of state
+        self.n_rec = len(self.spec.recurrent_kinds) if self.stateful else 0
+        # recurrent mixers that advance their slots' states in the pool, in
+        # place (lightning attention: a state too large to copy a step)
+        self.in_place_names = [
+            n for n in self.spec.recurrent_names
+            if hasattr(self.spec._v[n].layer_conf, "decode_step")] \
+            if self.stateful else []
         # int32 counters behind the tokens of a program's first result
         self.stats_len = 2 if self.adapter == "paged" and self.spec.n_moe \
             else 0
@@ -406,6 +426,18 @@ class GenerationProgramSet:
                     "model-sharded decode is refused for a model with "
                     f"sliding-window layers ({self.spec.window_names}): "
                     "their rings are not split over a mesh")
+            if self.sparse:
+                raise SparseDecodeUnsupportedError(
+                    "model-sharded decode is refused for a model with "
+                    f"block-sparse layers ({self.spec.sparse_names}): a "
+                    "selection over a mesh is not built (a key-value "
+                    "group's list names pages of every shard)")
+            if self.in_place_names:
+                raise StatefulDecodeUnsupportedError(
+                    "model-sharded decode is refused for a model with "
+                    f"lightning-attention layers ({self.in_place_names}): their "
+                    "float32 state pool is advanced in place by one "
+                    "device's kernel")
             if not self.spec.supports_head_sharding(self.model_shards):
                 raise ValueError(
                     f"n_heads={self.spec.n_heads} does not divide by the "
@@ -431,8 +463,11 @@ class GenerationProgramSet:
                                 if config.prefix_cache is None
                                 else bool(config.prefix_cache)
                                 and self.adapter == "paged")
-                               and not self.stateful and not self.windowed)
-        self.prefix_skipped_stateful = (self.stateful
+                               and not self.stateful and not self.windowed
+                               and not self.sparse)
+        # (a block-sparse layer's compressed keys are kept a slot, like a
+        # state: a hit's shared pages would come without them)
+        self.prefix_skipped_stateful = ((self.stateful or self.sparse)
                                         and config.prefix_cache is not False)
         # the same for sliding-window layers: a hit resumes from shared
         # pages at the matched boundary and would need the window's rows
@@ -464,6 +499,12 @@ class GenerationProgramSet:
                 f"sliding-window layers ({self.spec.window_names}): its "
                 "prefill runs as a decode window, which does not carry "
                 "their rings")
+        if self.kv_quantized and self.sparse:
+            raise SparseDecodeUnsupportedError(
+                "kv_cache_dtype='int8' is refused for a model with "
+                f"block-sparse layers ({self.spec.sparse_names}): its "
+                "prefill runs as a decode window, which makes no "
+                "selection, and the selected decode reads plain pages")
         # speculative decoding: active iff a draft model is attached
         self.draft_net = draft_net
         self.spec_k = 0
@@ -487,6 +528,13 @@ class GenerationProgramSet:
                     f"sliding-window layers ({self.spec.window_names}): "
                     "the verify window does not carry their rings, and a "
                     "rejected proposal could not be taken back out of one")
+            if self.sparse:
+                raise SparseDecodeUnsupportedError(
+                    "speculative decoding is refused for a model with "
+                    f"block-sparse layers ({self.spec.sparse_names}): the "
+                    "verify window makes no selection a row, and a "
+                    "rejected proposal's compressed key could not be "
+                    "taken back")
             if self.adapter != "paged":
                 raise ValueError(
                     "speculative decoding requires a paged (transformer) "
@@ -504,6 +552,10 @@ class GenerationProgramSet:
                 raise WindowDecodeUnsupportedError(
                     "a draft with sliding-window layers is refused: the "
                     "dense draft cache keeps every layer's whole context")
+            if da == "paged" and self.draft_spec.selection is not None:
+                raise SparseDecodeUnsupportedError(
+                    "a draft with block-sparse layers is refused: the "
+                    "dense draft cache keeps no compressed keys")
             if da == "paged" and self.draft_spec.latent:
                 raise LatentDecodeUnsupportedError(
                     "a draft with a latent cache is refused: the dense "
@@ -615,11 +667,18 @@ class GenerationProgramSet:
             if sh is not None:
                 cache = jax.tree.map(lambda a: jax.device_put(a, sh), cache)
             if self.stateful:
-                # [recurrent layers, slots + 1, ...]: donated and updated
-                # by the same programs as the pools
-                cache = cache + (jnp.zeros(
-                    self.spec.recurrent_state_shape(c.decode_slots + 1),
-                    self.dtype),)
+                # [recurrent layers of a kind, slots + 1, ...] a KIND of
+                # state, each in its own dtype: donated and updated by the
+                # same programs as the pools
+                cache = cache + tuple(
+                    jnp.zeros(shape, dtype) for shape, dtype in
+                    self.spec.recurrent_state_specs(c.decode_slots + 1))
+            if self.sparse:
+                # the block-sparse layers' compressed keys, a slot
+                cache = cache + (make_compressed(
+                    len(self.spec.sparse_names), c.decode_slots, c.capacity,
+                    self.spec.selection.stride, self.spec.kv_heads,
+                    self.spec.head_dim, self.dtype),)
             if self.windowed:
                 # a ring a slot for the sliding-window layers, K and V,
                 # last in the pytree
@@ -679,8 +738,25 @@ class GenerationProgramSet:
         alone."""
         if not self.stateful:
             return 0
-        return int(math.prod(self.spec.recurrent_state_shape(
-            self.config.decode_slots + 1))) * jnp.dtype(self.dtype).itemsize
+        return sum(int(math.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self.spec.recurrent_state_specs(
+                       self.config.decode_slots + 1))
+
+    def state_bytes_per_slot(self) -> int:
+        """Device bytes ONE decode slot's recurrent states hold, every
+        kind in its own dtype (a lightning-attention layer's float32
+        matrix a head; a short convolution's rows)."""
+        return self.recurrent_state_bytes() // (self.config.decode_slots + 1)
+
+    def index_bytes_per_token(self) -> float:
+        """Device bytes of compressed keys a token of context costs, every
+        block-sparse layer (one row of the key-value heads every
+        ``stride`` tokens); 0 for a model that selects nothing."""
+        if not self.sparse:
+            return 0.0
+        s = self.spec
+        return (len(s.sparse_names) * s.kv_heads * s.head_dim
+                * jnp.dtype(self.dtype).itemsize / s.selection.stride)
 
     def split_stats(self, first):
         """A program's first result as read back -> (tokens, counters):
@@ -754,6 +830,8 @@ class GenerationProgramSet:
                     last, ks, vs, states, stats = spec.prefill_full(
                         params, state, tokens, rows,
                         lengths if self.stateful or self.stats_len else None)
+                if self.sparse:
+                    crows = spec.compressed_rows(ks)
                 if self.windowed:
                     # the layers that keep the whole context go to the
                     # pages, the sliding-window layers to the rings
@@ -764,10 +842,18 @@ class GenerationProgramSet:
                             for pool, kv in zip(pools, (ks, vs)))
                 if self.stateful:
                     # beside the pools, in the slots' rows (padding rows
-                    # carry slot S: the trash row)
-                    rec = cache[self.n_pools]
-                    out += (rec.at[:, slots].set(
-                        jnp.stack(states).astype(rec.dtype)),)
+                    # carry slot S: the trash row), a kind of state in
+                    # its own pool
+                    out += tuple(
+                        rec.at[:, slots].set(new.astype(rec.dtype))
+                        for rec, new in zip(
+                            cache[self.n_pools:self.n_pools + self.n_rec],
+                            spec.states_by_kind(states)))
+                if self.sparse:
+                    # the block-sparse layers' compressed keys, from the
+                    # prompt's keys as the pages keep them
+                    out += (compressed_prefill_fill(
+                        cache[self.n_pools + self.n_rec], crows, slots),)
                 if self.windowed:
                     # each prompt's last rows at its TRUE length, in its
                     # slot's rings (padding rows: the trash ring)
@@ -806,8 +892,11 @@ class GenerationProgramSet:
                 store = PagedStore(
                     cache[0], None if self.latent else cache[1], tables, pos,
                     active, blk,
-                    cache[self.n_pools] if self.stateful else None,
-                    cache[-2:] if self.windowed else None)
+                    cache[self.n_pools:self.n_pools + self.n_rec]
+                    if self.stateful else None,
+                    cache[-2:] if self.windowed else None,
+                    cache[self.n_pools + self.n_rec] if self.sparse
+                    else None)
                 logits, stats = spec.decode_step_stats(
                     params, state, tokens, pos, store,
                     active if self.stats_len else None)

@@ -22,41 +22,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
-_SIGN = np.int32(-2 ** 31)
-_REST = np.int32(2 ** 31 - 1)
-
-
-def _ordered(bits):
-    """float32 bit patterns (as int32) <-> int32 keys that compare as the
-    floats do (-0.0 just under +0.0): negatives have every bit but the
-    sign flipped. Its own inverse."""
-    return jnp.where(bits < 0, bits ^ _REST, bits)
-
-
-def kth_largest(x, k):
-    """x [N,V] float32, k [N] int32 in 1..V -> [N,1] float32: per row the
-    k-th largest element of ``x``, exactly (an element of the row, ties
-    counted as often as they occur).
-
-    The answer's key is built from the top bit down: a bit stays set if at
-    least ``k`` of the row's keys are no smaller than the candidate. One
-    compare and one row sum over [N,V] a bit. The candidate is kept with
-    its sign bit flipped (``t``), which makes its unsigned order the
-    keys' signed one."""
-    keys = _ordered(lax.bitcast_convert_type(x, jnp.int32))
-
-    def one_bit(i, t):
-        cand = t | lax.shift_right_logical(_SIGN, jnp.int32(i))
-        enough = jnp.sum(keys >= (cand ^ _SIGN)[:, None], axis=-1,
-                         dtype=jnp.int32) >= k
-        return jnp.where(enough, cand, t)
-
-    t = lax.fori_loop(0, 32, one_bit, jnp.zeros(x.shape[:1], jnp.int32))
-    return lax.bitcast_convert_type(_ordered(t ^ _SIGN), jnp.float32)[:, None]
-
+from ...ops.kth_largest import kth_largest  # noqa: F401  (its older home)
 
 def sample_tokens(logits, key, temperature, top_k):
     """logits [N,V] (pre-activation, model dtype); temperature [N] f32

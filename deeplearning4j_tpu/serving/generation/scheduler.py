@@ -121,6 +121,31 @@ except ImportError:                # a platform without per-thread usage
 _USAGE_EVERY = 16
 
 
+def selected_keys(t, sel):
+    """Keys position ``t`` (numpy int64 array) of a block-sparse layer
+    attends to: its chosen blocks' rows up to itself. Every block while
+    the context is short (``ops.sparse_select``), else ``topk`` blocks of
+    which its own holds ``t mod block + 1`` rows."""
+    t = np.asarray(t, np.int64)
+    own = t % sel.block + 1
+    dense = (t < sel.dense_len) | (t // sel.block + 1 <= sel.topk)
+    return np.where(dense, t + 1, (sel.topk - 1) * sel.block + own)
+
+
+def selection_rows(plens, sel):
+    """(keys the block-sparse selection names, compressed keys it is
+    scored against) summed over the positions of prompts of lengths
+    ``plens``, one layer's: what a prefill's sparse attention and its
+    scoring have to do."""
+    chosen = index = 0
+    for n in plens:
+        t = np.arange(int(n), dtype=np.int64)
+        chosen += int(selected_keys(t, sel).sum())
+        index += int(np.maximum((t + 1 - sel.kernel) // sel.stride + 1,
+                                0).sum())
+    return chosen, index
+
+
 class _GcWatch:
     """Nanoseconds spent so far inside garbage collections of generation 1
     or 2, whichever thread ran them (the interpreter lock holds the loop
@@ -846,6 +871,15 @@ class ModelRuntime:
             extra["attn_window_key_rows"] = int(
                 (inside * (inside + 1) // 2
                  + (plens - inside) * coh.ps.spec.window).sum())
+        if coh.ps.sparse:
+            # what the block-sparse layers' lists name, and what they are
+            # scored against (a layer's; host integers from the lengths)
+            sel_rows, index_rows = selection_rows(plens,
+                                                  coh.ps.spec.selection)
+            extra["attn_selected_key_rows"] = sel_rows
+            extra["attn_index_rows"] = index_rows
+        if coh.ps.n_rec:
+            extra["linear_rows"] = live_tokens
         with span("generation.prefill", model=self.name, batch=len(cands),
                   rung=L, rows=P, tokens=live_tokens,
                   padded_tokens=P * L,
@@ -1121,6 +1155,20 @@ class ModelRuntime:
                 # the rows a sliding-window layer attends to this step
                 attrs["window_tokens"] = int(
                     np.minimum(seen, coh.ps.spec.window).sum())
+            if coh.ps.sparse:
+                # the rows a block-sparse layer attends to this step (its
+                # chosen pages, the slot's own up to its position) and the
+                # compressed keys it scores them from
+                sel = coh.ps.spec.selection
+                attrs["selected_tokens"] = int(
+                    selected_keys(seen - 1, sel).sum())
+                attrs["index_rows"] = int(
+                    np.maximum((seen - sel.kernel) // sel.stride + 1,
+                               0).sum())
+            if coh.ps.n_rec:
+                # recurrent states read and written this step, every kind
+                attrs["state_bytes"] = len(live) \
+                    * coh.ps.state_bytes_per_slot()
         # a slot keeps its last request's temperature after it finishes,
         # and another cohort's slots are not this step's: only the live
         # rows may decide whether the program's sampler draws

@@ -180,7 +180,8 @@ def test_every_new_metric_is_appended_with_its_reader_and_its_cells():
             if w["name"] in {"gpt2m-serve-longprompt",
                              "lfm2moe-serve-extract",
                              "kanana2-serve-longdoc",
-                             "laguna-serve-codebase"}]
+                             "laguna-serve-codebase",
+                             "minicpm-sala-serve-longctx"}]
     for m in rows:
         assert os.path.isfile(
             os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
@@ -231,7 +232,8 @@ def test_the_jumped_share_is_appended_behind_pr_42s_with_its_reader():
         "source": "program_counter", "layer": "scheduler",
         "moves": "serve_tokens_per_s",
         "workloads": ["gpt2m-serve-longprompt", "lfm2moe-serve-extract",
-                      "kanana2-serve-longdoc", "laguna-serve-codebase"]}
+                      "kanana2-serve-longdoc", "laguna-serve-codebase",
+                      "minicpm-sala-serve-longctx"]}
     assert os.path.isfile(os.path.join(BENCH, "layer_metrics", JUMPED + ".py"))
 
 

@@ -17,7 +17,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.ops import (grouped_matmul, pallas_attention,
-                                    pallas_compression, pallas_lstm,
+                                    pallas_compression,
+                                    pallas_linear_attention, pallas_lstm,
                                     pallas_paged_attention)
 from deeplearning4j_tpu.ops import kernels
 from deeplearning4j_tpu.ops.kernels import conv, quantized
@@ -47,7 +48,8 @@ def _no_interpreter(monkeypatch):
     # the modules pick the interpreter from the (CPU) default backend;
     # the lowering here targets the TPU, so take the Mosaic path
     for mod in (pallas_attention, pallas_lstm, pallas_compression,
-                pallas_paged_attention, quantized, conv, grouped_matmul):
+                pallas_paged_attention, pallas_linear_attention, quantized,
+                conv, grouped_matmul):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -154,6 +156,79 @@ def test_flash_forward_under_a_sliding_window_compiles(v5e, T, H, Hkv,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert pallas_attention.WINDOW_FWD_NAME in _instruction_names(text)
     assert pallas_attention.FWD_NAME not in _instruction_names(text)
+
+
+@pytest.mark.parametrize("T", [2048, 20480, 33280])
+def test_flash_forward_under_a_block_sparse_selection_compiles(v5e, T):
+    """minicpm-sala-serve-longctx's sparse prefills: 32 query heads over 2
+    key-value heads of 128 read in place, one list of blocks of 64 a query
+    and group as a [blocks, queries] mask, the strips' flags prefetched as
+    scalars; a rung of whole 1,024-row resident blocks and the capacity
+    rung (33,280: blocks of 512)."""
+    text = _compiled_text(
+        v5e, lambda q, k, v, c: pallas_attention.flash_attention_sparse(
+            q, k, v, c),
+        ((1, 32, T, 128), bf16), ((1, 2, T, 128), bf16),
+        ((1, 2, T, 128), bf16), ((1, 2, T // 64, T), f32))
+    assert pallas_attention.SPARSE_FWD_NAME in _instruction_names(text)
+
+
+def test_paged_attention_over_selected_pages_compiles(v5e):
+    """The cell's selected decode: 16 slots, 32 query heads in 2 groups,
+    lists of up to 128 pages of 64 a slot and group, a pool row of 2 heads
+    (256 lanes) of which a group's 128 are fetched."""
+    S, nb = 16, 8321
+    text = _compiled_text(
+        v5e, lambda q, kp, vp, pages, counts, lens:
+        pallas_paged_attention.paged_attention_sparse_decode(
+            q, kp, vp, 1, pages, counts, lens),
+        ((S, 32, 1, 128), bf16), ((2, nb, 64, 256), bf16),
+        ((2, nb, 64, 256), bf16), ((S, 2, 128), jnp.int32),
+        ((S, 2), jnp.int32), ((S,), jnp.int32))
+    assert pallas_paged_attention.SPARSE_KERNEL_NAME in \
+        _instruction_names(text)
+
+
+def test_the_selection_of_a_decode_step_and_of_a_prefill_compiles(v5e):
+    """The cell's selection in XLA at its shapes: a decode step's (16 slots
+    of one row against 2,080 compressed keys: scores, the choice, the
+    choice LISTED: the k-th largest's loop, a sort of 520) and a prefill's
+    mask over a rung (256 positions a pass). Its loops are the only ones
+    these programs run (``attn.sparse_busy_pct.tput`` reads them)."""
+    from deeplearning4j_tpu.ops import sparse_select as ss
+    sel = ss.Selection()
+    t = jnp.arange(16, dtype=jnp.int32)[:, None] * 1024 + 17000
+
+    def lists(q, c):
+        return ss.chosen_lists(ss.block_scores(q, c, t, sel, 0.088), t, sel)
+    text = _compiled_text(v5e, lists, ((16, 1, 32, 128), bf16),
+                          ((16, 2080, 2, 128), bf16))
+    assert len(re.findall(r"\bwhile\(", text)) == 1          # the k-th largest
+    assert "tpu_custom_call" not in text
+    text = _compiled_text(
+        v5e, lambda q, k: ss.chosen_mask(q, ss.compress_keys(k, sel), sel,
+                                         0.088),
+        ((1, 20480, 32, 128), bf16), ((1, 20480, 2, 128), bf16))
+    assert len(re.findall(r"\bwhile\(", text)) >= 1
+
+
+def test_lightning_attention_kernels_compile(v5e):
+    """The cell's lightning layers: the chunked forward of 32 heads of 128
+    over a rung, and the decode step over 16 slots' float32 states of 6
+    layers, the pool aliased to the result."""
+    sl = pallas_linear_attention.slopes(32)
+    qkv = ((1, 32, 4096, 128), bf16)
+    text = _compiled_text(
+        v5e, lambda q, k, v: pallas_linear_attention.lightning_attention_fwd(
+            q, k, v, sl, scale=0.088), qkv, qkv, qkv)
+    assert pallas_linear_attention.FWD_NAME in _instruction_names(text)
+    row = ((16, 32, 128), bf16)
+    text = _compiled_text(
+        v5e, lambda q, k, v, pool, active:
+        pallas_linear_attention.lightning_decode(
+            q, k, v, pool, 2, active, sl, scale=0.088),
+        row, row, row, ((6, 17, 32, 128, 128), f32), ((16,), jnp.bool_))
+    assert pallas_linear_attention.DECODE_NAME in _instruction_names(text)
 
 
 def test_flash_attention_splits_per_device_on_a_mesh(v5e_devices):

@@ -376,14 +376,25 @@ def test_gpt2_prefill_and_decode_equal_to_the_bit_what_the_tree_gave():
 
 
 # ----------------------------------------- the cell's rehearsal on the CPU
-def test_the_closed_loop_kind_runs_the_family_and_the_control_fails():
+def test_the_closed_loop_kind_runs_the_family_and_the_control_fails(
+        toy, engine, monkeypatch):
     """``lfm2moe-serve-extract`` rehearsed at the toy size: the closed-loop
     kind's own ``run`` (engine, callers, window, sampling, the reference's
     check) with the family's modules, then the float8 control in the
     program's place, which must stand off from the reference where the
-    float32 program sits on it."""
+    float32 program sits on it. Nothing here hangs on the machine's speed
+    or on the real cell's limits (the driver's run of PR 45's tree failed
+    this test on a loaded CPU: a slow window completes one or two requests,
+    and over their handful of tokens float8 may put the reference's own
+    token first everywhere, a gap of 0.006 where 0.05 was asked): one
+    completion is enough for the kind's run, the toy is held to limits of
+    its own (nothing left out for its routing margin), and how far float8
+    stands off is read over a FIXED sample of a hundred served tokens,
+    handed to the comparison the kind's run makes (``check_outputs``: the
+    sampling, the weights made anew, the control in the program's place,
+    the checks), so the control fails THROUGH the harness."""
     from benchmarks import run as harness
-    from benchmarks.kinds import closed_loop
+    from benchmarks.kinds import _serve, closed_loop
     from benchmarks.lib.correct import Checks
     fam = {k: __import__(f"benchmarks.families.lfm2_moe.{k}",
                          fromlist=[k])
@@ -397,13 +408,14 @@ def test_the_closed_loop_kind_runs_the_family_and_the_control_fails():
         "engine": {"block_len": 8, "max_seq_len": CAP, "decode_slots": 3,
                    "prompt_rungs": [16, 32], "prefill_batches": [1, 2]},
         "check": {"min_tokens": 40, "max_requests": 12}}
-    limits = harness.load_json(harness.HERE, "limits",
-                               "lfm2moe-serve-extract.json")
+    limits = {"widest_logit_gap": 1e-3, "routing_margin": 0.0,
+              "close_margin_share": 0.0}
+    monkeypatch.setattr(reference, "cell_limits", lambda cfg: limits)
     out = {}
     for control in (False, True):
         ctx = {"cell": {"name": "toy", "chips": 1}, "config": TOY,
                "traffic": traffic, "limits": limits, "seed": 2 ** 31 + 5,
-               "seconds": 3.0, "trace": False, "rehearsal": True,
+               "seconds": 6.0, "trace": False, "rehearsal": True,
                "device": {"platform": "cpu", "kind": "cpu", "count": 1},
                "t_start": time.perf_counter(), "log": lambda m: None,
                "checks": Checks(), "control": control, "family": fam,
@@ -413,11 +425,21 @@ def test_the_closed_loop_kind_runs_the_family_and_the_control_fails():
         res = closed_loop.run(ctx)
         out[control] = (ctx, res)
     ctx, res = out[False]
-    assert res["failed"] == 0 and res["counts"]["completed"] >= 3
+    assert res["failed"] == 0 and res["counts"]["completed"] >= 1
     assert res["counts"]["compiles_in_window"] == 0
     assert ctx["checks"].correct, ctx["checks"].rows
     assert res["obs"]["engine"]["conv_state_bytes"] > 0
-    c = out[True][0]["control_result"]
+    assert out[True][0]["control_result"]["tokens"] > 0
     # the toy's logits are not the cell's: what holds at any size is that
     # the float32 program sits on the reference and float8 does not
+    done = [{"prompt": p,
+             "tokens": engine.generate(p, max_tokens=25, stream=False)[0]}
+            for p in _prompts(11, [12, 20, 27, 30])]
+    fixed = dict(out[True][0], checks=Checks(), seed=7,   # the toy's weights
+                 traffic=dict(traffic, check={"min_tokens": 100,
+                                              "max_requests": 4}))
+    _serve.check_outputs(fixed, done, 0)
+    c = fixed["control_result"]
+    assert fixed["checks"].correct, fixed["checks"].rows
+    assert c["tokens"] == 100
     assert c["control_widest_gap"] > 0.05 and c["kept_widest_gap"] < 1e-3
